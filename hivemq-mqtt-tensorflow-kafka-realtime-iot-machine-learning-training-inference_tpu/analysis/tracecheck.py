@@ -161,8 +161,10 @@ def _collect_roots(tree: ast.Module,
             elif isinstance(node.func, ast.Attribute) \
                     and node.func.attr == "scan":
                 fn = resolve(node.args[0])
-            elif isinstance(node.func, ast.Name) \
-                    and node.func.id == "shard_map":
+            elif (isinstance(node.func, ast.Name)
+                  and node.func.id == "shard_map") or \
+                    (isinstance(node.func, ast.Attribute)
+                     and node.func.attr == "shard_map"):
                 fn = resolve(node.args[0])
             if fn is not None:
                 add(fn, static)

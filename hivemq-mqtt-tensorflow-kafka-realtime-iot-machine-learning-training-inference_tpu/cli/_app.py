@@ -92,7 +92,12 @@ def run_streaming_app(argv, *, prog: str, usage: str, make_model: Callable,
     from ..train.artifacts import ArtifactStore
     from ..train.checkpoint import CheckpointManager
     from ..train.loop import Trainer
+    from ..utils.device import claim_device, device_text
 
+    # restart-per-job is this CLI's deployment shape (a K8s Job per fit, a
+    # restarted predict pod): every start after the first loads its
+    # programs from the persistent compile cache claim_device() places
+    print("Device: ", device_text(claim_device()))
     broker = _broker_for(servers, topic, cfg)
     store = ArtifactStore(artifact_root)
 

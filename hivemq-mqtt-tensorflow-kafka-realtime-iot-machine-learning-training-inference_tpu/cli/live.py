@@ -187,6 +187,13 @@ def main(argv=None) -> int:
     if dev_norm and mesh_devices < 2:
         ap.error("IOTML_DEVICE_NORMALIZE=1 needs IOTML_MESH_DATA >= 2 "
                  "(the affine fold lives in the sharded step)")
+    # this process owns a device: place the compile cache, start the
+    # backend now (a missing accelerator fails here, before any topic or
+    # artifact is touched) and keep what it got for the banner and stats
+    from ..stream import native
+    from ..utils.device import claim_device, device_text
+
+    device = dict(claim_device(), native_engine=native.available())
     if args.metrics_port:
         from ..obs.metrics import start_http_server
 
@@ -211,9 +218,13 @@ def main(argv=None) -> int:
             return 1
         time.sleep(0.1)
 
+    # the first line of a stats stream names the device it ran on
+    first_line = {"device": device}
+
     def emit(stats: dict) -> None:
         if args.stats:
-            print(json.dumps(stats), flush=True)
+            print(json.dumps({**stats, **first_line}), flush=True)
+            first_line.clear()
 
     from ..core.normalize import CAR_NORMALIZER, FULL_NORMALIZER
     from ..train.artifacts import ArtifactStore
@@ -275,7 +286,8 @@ def main(argv=None) -> int:
               + (f" + registry {args.registry}" if registry else "")
               + (f" [mesh data={mesh_devices}"
                  f"{', device-normalize' if dev_norm else ''}]"
-                 if mesh is not None else ""),
+                 if mesh is not None else "")
+              + f" on {device_text(device)}",
               flush=True)
         rounds = svc.run(stop=stop, on_round=emit)
         svc.close()  # flush pending checkpoints, stop the writer
@@ -295,8 +307,10 @@ def main(argv=None) -> int:
                          batch_size=args.batch_size,
                          normalizer=normalizer, registry=registry)
         artifact = svc.wait_for_model(args.wait_model_seconds)
+        svc.scorer.warm_buckets((svc.model.input_dim,))
         print(f"live score: model {artifact} loaded; "
-              f"{args.topic} -> {args.result_topic}", flush=True)
+              f"{args.topic} -> {args.result_topic} on "
+              f"{device_text(device)}", flush=True)
         n = svc.run(stop=stop, on_drain=emit)
         q = svc.scorer.quality
         print(f"live score done: {n} rows, {svc.model_updates} model "

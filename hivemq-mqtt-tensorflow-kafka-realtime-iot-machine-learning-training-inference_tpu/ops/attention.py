@@ -27,15 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def _tpu_compiler_params(pltpu, **kw):
-    """Pallas-TPU compiler params across JAX versions: the class is
-    `CompilerParams` on newer JAX and `TPUCompilerParams` on 0.4.x."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
-
 NEG_INF = -1e30
 
 
@@ -343,7 +334,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int,
                 scratch_shapes=scratch_shapes,
             ),
             out_shape=out_shape,
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(jnp.asarray(im), jnp.asarray(jm), qf, kf, vf)
@@ -364,7 +355,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int,
             ],
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(qf, kf, vf)
@@ -622,7 +613,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                 scratch_shapes=dkv_scratch,
             ),
             out_shape=dkv_out_shape,
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(jnp.asarray(imc), jnp.asarray(jmc), qf, dof, lse_f, delta_f,
@@ -640,7 +631,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                        pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))],
             out_shape=dkv_out_shape,
             scratch_shapes=dkv_scratch,
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(qf, dof, lse_f, delta_f, kf, vf)
@@ -660,7 +651,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                 scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(jnp.asarray(imr), jnp.asarray(jmr), qf, dof, lse_f, delta_f,
@@ -677,7 +668,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
             out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            compiler_params=_tpu_compiler_params(pltpu, 
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(qf, dof, lse_f, delta_f, kf, vf)

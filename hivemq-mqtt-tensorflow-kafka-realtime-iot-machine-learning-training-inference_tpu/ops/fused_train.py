@@ -22,16 +22,17 @@ Exact math replicated (see `train.loop` / `models.autoencoder`):
   acc  = sum((out==x) * m) / max(sum(m)*F, 1)             (Keras 'accuracy')
   Adam: optax defaults b1=.9 b2=.999 eps=1e-8, bias correction at t=step+1
 
-Supports any DenseAutoencoder geometry (18- and 30-dim variants).  Falls
-back transparently to interpret mode off-TPU, so CPU tests run the same
-kernel.
+Supports any DenseAutoencoder geometry (18- and 30-dim variants).  On the
+CPU backend the kernel runs under the Pallas interpreter so the tier-1
+tests exercise the same code; everywhere else it is compiled by Mosaic
+(`interpret_mode` is the one place that decides, and callers report it).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -199,18 +200,27 @@ def supported(state, supervised: bool) -> bool:
     return True
 
 
+def interpret_mode() -> bool:
+    """Whether the kernel runs under the Pallas interpreter: only on the
+    CPU backend, where Mosaic cannot compile it.  Any other backend gets
+    the compiled kernel — one Mosaic does not serve fails at lowering
+    instead of running interpreted under a device's name."""
+    return jax.default_backend() == "cpu"
+
+
 def fused_fit(state, xs, masks, epochs: int, lr: float = 1e-3,
-              l1: float = 1e-7, interpret: bool = None
+              l1: float = 1e-7, interpret: Optional[bool] = None
               ) -> Tuple[object, jnp.ndarray, jnp.ndarray]:
     """Run the whole fit in one Pallas kernel.
 
     state: TrainState (DenseAutoencoder params + optax.adam opt_state)
     xs: [S, B, F] float32 batches; masks: [S, B] float32
+    interpret: None = `interpret_mode()`; pass a bool to force either.
     Returns (new_state, losses [epochs], accs [epochs]) — per-epoch means,
     the same history `make_scanned_fit` reports.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     adam_state = state.opt_state[0]
     flat_p = _flatten_params(state.params)
     flat_m = _flatten_params(adam_state.mu)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import shard_map
 
 from ..train.loop import TrainState
 
@@ -78,7 +77,7 @@ def make_ep_train_step(model, tx, mesh: Mesh, data_axis: str = "data",
 
     def build_loss(params):
         specs = expert_param_specs(params, ep_axis)
-        return shard_map(
+        return jax.shard_map(
             local_loss, mesh=mesh,
             in_specs=(specs, x_spec), out_specs=(P(), P()),
             check_vma=False)

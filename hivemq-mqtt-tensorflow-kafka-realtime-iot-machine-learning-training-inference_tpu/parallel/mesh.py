@@ -19,28 +19,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None):
-    """`jax.shard_map` across JAX versions.
-
-    Newer JAX exposes `jax.shard_map(..., check_vma=...)`; 0.4.x has it
-    at `jax.experimental.shard_map.shard_map(..., check_rep=...)` (same
-    replication-checking knob under its old name).  Every sharded train
-    step in this package routes through here so a JAX upgrade is a
-    one-line change, not a five-module sweep."""
-    kw = {}
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
               devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()

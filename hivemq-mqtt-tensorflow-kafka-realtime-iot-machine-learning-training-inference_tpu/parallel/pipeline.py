@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import shard_map
 
 from ..train.loop import TrainState
 
@@ -89,7 +88,7 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str = "pipe"):
     def body(stacked_local, mbs):
         return pipeline_schedule(stage_fn, stacked_local, mbs, axis)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
                          out_specs=P(), check_vma=False)
 
 
@@ -175,7 +174,7 @@ def make_pp_train_step(model, tx, mesh: Mesh, n_microbatches: int,
         return se_tot / cnt_tot
 
     x_spec = P(data_axis)
-    loss_fn = shard_map(
+    loss_fn = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(P(), P(pipe_axis), x_spec), out_specs=P(),
         check_vma=False)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
 
 from ..ops.attention import blockwise_update, finalize_blockwise
 
@@ -41,13 +40,9 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
     qpos = my * Tl + jnp.arange(Tl)  # global positions of local queries
 
     # mark the accumulators as device-varying over the seq axis so the scan
-    # carry type matches its output (shard_map vma typing, jax>=0.8);
-    # pre-vma JAX has no pcast and needs no marking
-    _pcast = getattr(jax.lax, "pcast", None)
-    if _pcast is not None:
-        vary = lambda x: _pcast(x, (axis_name,), to="varying")  # noqa: E731
-    else:
-        vary = lambda x: x  # noqa: E731
+    # carry type matches its output (shard_map vma typing)
+    vary = lambda x: jax.lax.pcast(  # noqa: E731
+        x, (axis_name,), to="varying")
     o0 = vary(jnp.zeros((B, Tl, H, D), jnp.float32))
     m0 = vary(jnp.full((B, H, Tl), -1e30, jnp.float32))
     l0 = vary(jnp.zeros((B, H, Tl), jnp.float32))
@@ -95,7 +90,7 @@ def make_ring_attention(mesh: Mesh, seq_axis: str = "seq",
     body = functools.partial(_ring_attention_local, axis_name=seq_axis,
                              causal=causal)
     spec = P(None, seq_axis, None, None)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec)
     return fn
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import shard_map
 
 from ..train.loop import TrainState
 
@@ -77,7 +76,7 @@ def make_sp_train_step(model, tx, mesh: Mesh, data_axis: str = "data",
         cnt_tot = jax.lax.psum(jnp.sum(mask) * B * F, (data_axis, seq_axis))
         return se_tot / cnt_tot
 
-    loss_fn = shard_map(
+    loss_fn = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(P(), x_spec), out_specs=P(),
         check_vma=False)

@@ -367,6 +367,7 @@ class ShardedStreamTrainer:
         dev_s = 0.0
         last_row_loss = None
         last_counts = None
+        shard_records = np.zeros(len(self.feeds), np.int64)
         for row in self.feeds.rounds():
             xg, mg, n_valid = self._assemble(row)
             if self._st.state is None:
@@ -381,6 +382,7 @@ class ShardedStreamTrainer:
             losses.append(m["loss"])  # device scalar: no per-step sync
             last_row_loss = m["row_loss"]
             last_counts = [0 if b is None else b.n_valid for b in row]
+            shard_records += last_counts
             records += n_valid
         if not losses:
             return {"loss": [], "accuracy": [], "records": [],
@@ -404,7 +406,16 @@ class ShardedStreamTrainer:
                 "accuracy": [float("nan")],
                 "records": [records],
                 "seconds": [time.perf_counter() - t0],
-                "steps": len(losses), "step_loss": losses}
+                "steps": len(losses), "step_loss": losses,
+                "fit": "sharded", "interpret": False,
+                # per data-axis device — the evidence that every chip
+                # trained on rows of its own: valid rows over the round,
+                # and from its last step the mean loss (0.0 for a feed
+                # that had run dry by then) and the device holding it
+                "shard_records": shard_records.tolist(),
+                "shard_losses": self.last_shard_losses.tolist(),
+                "shard_devices": [s.device.id
+                                  for s in _row_shards(last_row_loss)]}
 
     def fit_compiled(self, _batches=None, epochs: int = 1) -> dict:
         """Trainer-API shim: the feeds ARE the batch source.  Mesh
@@ -416,6 +427,13 @@ class ShardedStreamTrainer:
         return self.fit_round()
 
 
+def _row_shards(row_loss) -> list:
+    """The sharded [B] row vector's addressable shards in global row
+    order — the feed/device order by construction."""
+    return sorted(row_loss.addressable_shards,
+                  key=lambda s: s.index[0].start or 0)
+
+
 def shard_mean_losses(row_loss, valid_counts: Sequence[int]) -> np.ndarray:
     """Per-chip mean pre-update loss out of the sharded row-loss vector.
 
@@ -424,8 +442,7 @@ def shard_mean_losses(row_loss, valid_counts: Sequence[int]) -> np.ndarray:
     valid-row counts per feed (padding rows carry mask 0, so shard sums
     need only dividing by the true counts).  Shards are ordered by their
     global row index, which is the feed/device order by construction."""
-    pieces = sorted(row_loss.addressable_shards,
-                    key=lambda s: s.index[0].start or 0)
+    pieces = _row_shards(row_loss)
     if len(pieces) != len(valid_counts):
         # a >1 model axis replicates row blocks; streaming refuses that
         # mesh shape upstream, so this is a defensive invariant

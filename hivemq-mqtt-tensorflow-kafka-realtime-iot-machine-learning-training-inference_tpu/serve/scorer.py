@@ -166,6 +166,27 @@ class StreamScorer:
             # recalibrates per-update through the fold transient
             self.carhealth.notify_model_swap()
 
+    def warm_buckets(self, row_shape: tuple) -> None:
+        """Compile the eval for every super-batch bucket before the first
+        drain.  Drain sizes follow arrival timing, so without this the
+        set of compiled shapes — and when each compile stalls the stream
+        — differs from run to run; with it a long-lived scorer compiles
+        (or loads from the persistent cache) exactly the same programs
+        every start, and never mid-stream."""
+        out = None
+        for s in sorted({self._bucket(s)
+                         for s in range(1, self.max_super_batches + 1)}):
+            out = self._eval(self.params, np.zeros(
+                (s * self.batches.batch_size,) + tuple(row_shape),
+                np.float32))
+        jax.block_until_ready(out)
+
+    @staticmethod
+    def _bucket(n_batches: int) -> int:
+        """Batch count padded to a power of two: drains vary in size and
+        jit would otherwise recompile the eval for every distinct count."""
+        return 1 << max(0, (n_batches - 1).bit_length())
+
     def score_available(self, max_rows: Optional[int] = None) -> int:
         """Drain whatever is currently in the stream; returns rows scored.
 
@@ -255,9 +276,7 @@ class StreamScorer:
             xs = np.stack([b.x for b in bs])  # [S, B, ...] (F, or T×F)
         S, B = xs.shape[:2]
         row_shape = xs.shape[2:]
-        # pad the batch count to a power-of-two bucket: drains vary in size
-        # and jit would otherwise recompile the eval for every distinct S
-        S_pad = 1 << max(0, (S - 1).bit_length())
+        S_pad = self._bucket(S)
         if S_pad != S:
             xs_in = np.concatenate(
                 [xs, np.zeros((S_pad - S, B) + row_shape, xs.dtype)])

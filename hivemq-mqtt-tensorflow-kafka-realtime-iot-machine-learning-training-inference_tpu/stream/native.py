@@ -7,8 +7,10 @@ correctness (the pure codec is the test oracle; `tests/test_native.py`
 cross-checks byte-for-byte); the engine is used automatically by the data
 path when the shared library is present.
 
-Build lazily on first use (`make -C iotml/cpp`, no external deps, <1s) and
-fall back silently to the pure-Python codec when no toolchain exists.
+Build lazily on first use (`make -C iotml/cpp`, no external deps, a few
+seconds) and fall back to the pure-Python codec when no toolchain exists —
+saying so once on stderr, because the fallback decodes an order of
+magnitude slower.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -60,11 +63,23 @@ def _stale() -> bool:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The engine library, building it on first call; None if unavailable."""
+    """The engine library, building it on first call; None if unavailable
+    (reported once on stderr: the pure-Python fallback is correct but an
+    order of magnitude slower, which a benchmark must not mistake for the
+    data plane)."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
+    _lib = _load()
+    if _lib is None:
+        print("iotml: native stream engine unavailable (build or load of "
+              f"{_SO_PATH} failed); using the pure-Python codecs",
+              file=sys.stderr, flush=True)
+    return _lib
+
+
+def _load() -> Optional[ctypes.CDLL]:
     if (not os.path.exists(_SO_PATH) or _stale()) and not _build() \
             and not os.path.exists(_SO_PATH):
         return None
@@ -76,7 +91,6 @@ def load() -> Optional[ctypes.CDLL]:
         if lib.iotml_engine_version() < ENGINE_VERSION:
             # stale binary and the rebuild failed (or produced an old ABI):
             # treat as unavailable rather than risk missing symbols
-            _lib = None
             return None
         lib.iotml_decode_batch.restype = ctypes.c_int64
         lib.iotml_decode_batch_nulls.restype = ctypes.c_int64
@@ -95,10 +109,9 @@ def load() -> Optional[ctypes.CDLL]:
         lib.iotml_frames_encode_values.restype = ctypes.c_int64
         lib.iotml_frames_restamp.restype = ctypes.c_int64
         lib.iotml_frames_validate.restype = ctypes.c_int64
-        _lib = lib
+        return lib
     except (OSError, AttributeError):
-        _lib = None
-    return _lib
+        return None
 
 
 def available() -> bool:

@@ -270,12 +270,17 @@ class ContinuousTrainer:
             # resume contract: a crash re-trains the slice rather than
             # skipping it)
             self.consumer.commit()
-        return {"t": time.time(), "round": self.rounds,
-                "loss": self.last_loss,
-                "records": history["records"][-1],
-                "records_cum": self.records_trained,
-                "seconds": round(time.perf_counter() - t0, 4),
-                "artifact": artifact}
+        stats = {"t": time.time(), "round": self.rounds,
+                 "loss": self.last_loss,
+                 "records": history["records"][-1],
+                 "records_cum": self.records_trained,
+                 "seconds": round(time.perf_counter() - t0, 4),
+                 "artifact": artifact,
+                 "fit": history["fit"], "interpret": history["interpret"]}
+        if self.mesh is not None:
+            for key in ("shard_records", "shard_losses", "shard_devices"):
+                stats[key] = history[key]
+        return stats
 
     def publish(self) -> str:
         """Upload round K's weights as an immutable blob, flip the pointer."""

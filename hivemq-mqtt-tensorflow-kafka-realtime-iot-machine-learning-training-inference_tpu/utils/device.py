@@ -1,0 +1,94 @@
+"""The device a process owns, and where its compiled programs are kept.
+
+A chip belongs to one process at a time, and JAX falls back to the CPU
+without a word when it cannot start an accelerator.  Entry points that
+own a device call `claim_device()` once, before any other JAX work: it
+initialises the backend, refuses a CPU nobody asked for, places the
+persistent compile cache, and returns what the process got so banners
+and stats lines can name it.  `python -m iotml.utils.device` prints that
+report plus the installed versions as one JSON line.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+from typing import Optional
+
+#: the package lives one level below the checkout root
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.realpath(__file__))))
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache; returns its directory,
+    or None where this process keeps none.
+
+    A cache that moves never hits, so the directory is fixed:
+    `JAX_COMPILATION_CACHE_DIR` wins when set (JAX reads it itself — no
+    directory is set in code), otherwise one path inside the checkout.
+    A process pinned to the CPU gets no in-checkout cache: XLA:CPU
+    executables are tied to the machine that compiled them (loading one
+    elsewhere risks SIGILL, and jaxlib warns at length on every load
+    even at home), they recompile in milliseconds, and a checkout
+    travels between machines.
+
+    The live path's programs compile in well under JAX's default 1 s
+    persistence threshold (the fused round fit, the scorer's eval
+    buckets), so the threshold drops to zero — a restarted service then
+    loads every program instead of recompiling it."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        # asked of the config, not the backend: callers that only host
+        # the data plane must not start (and so take) a device here
+        if jax.config.jax_platforms == "cpu":
+            return None
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
+def claim_device() -> dict:
+    """Initialise this process's backend and report it.
+
+    Raises RuntimeError when JAX fell back to the CPU although
+    `JAX_PLATFORMS` did not name it: a service that believes it is on
+    the chip must not run on the host unnoticed."""
+    import jax
+
+    devices = jax.devices()
+    first = devices[0]
+    if first.platform == "cpu" and \
+            "cpu" not in (jax.config.jax_platforms or ""):
+        raise RuntimeError(
+            "JAX found no accelerator and fell back to the CPU; set "
+            "JAX_PLATFORMS=cpu to run on the host on purpose")
+    return {"platform": first.platform, "device_kind": first.device_kind,
+            "count": len(devices),
+            "compile_cache_dir": enable_compile_cache()}
+
+
+def device_text(report: dict) -> str:
+    """'4x TPU v5 lite (tpu)' — the banner form of a claim_device() report."""
+    return (f"{report['count']}x {report['device_kind']} "
+            f"({report['platform']})")
+
+
+def versions() -> dict:
+    """Installed jax / jaxlib / libtpu versions (libtpu None if absent)."""
+    from importlib import metadata
+
+    out = {}
+    for name in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[name] = metadata.version(name)
+        except metadata.PackageNotFoundError:
+            out[name] = None
+    return out
+
+
+if __name__ == "__main__":
+    print(json.dumps({**claim_device(), "versions": versions()}), flush=True)
