@@ -7,7 +7,7 @@ idempotent-only auto-retry, context-managed locks, no blocking I/O under
 a broker lock, engine-owned topic write exclusivity) are machine-checked
 here rather than left as tribal knowledge:
 
-- ``lint``       AST lint pass over the tree: rules R1-R15, run via
+- ``lint``       AST lint pass over the tree: rules R1-R17, run via
                  ``python -m iotml.analysis lint`` (exit 1 on findings).
 - ``protocol``   whole-program wire-protocol conformance (P1-P7):
                  api-id ↔ handler ↔ encoder ↔ error-code ↔ idempotency

@@ -43,7 +43,7 @@ def main(argv=None) -> int:
         description="concurrency & protocol-invariant checkers")
     sub = ap.add_subparsers(dest="cmd")
 
-    lp = sub.add_parser("lint", help="run the AST lint pass (R1-R15)")
+    lp = sub.add_parser("lint", help="run the AST lint pass (R1-R17)")
     lp.add_argument("paths", nargs="*",
                     help="files/dirs to lint (default: the iotml package)")
     lp.add_argument("--rule", action="append", dest="rules", metavar="RN",

@@ -84,6 +84,14 @@ Rules (see ARCHITECTURE.md §analysis for the full table):
       A foreign mutation would let acks=all ack records a failover can
       lose (the exact loss the quorum exists to rule out).
 
+  R17 device and span names are a contract the trace reducers and the
+      compile counters key on: every ``pallas_call(...)`` passes a
+      ``name=`` that is a string starting ``iotml_`` (a literal, or a
+      module-level constant holding one), and a ``tracing.phase(...)``
+      is never opened while a lock is held (R6's walk, phases are
+      span-recording calls) nor under a jit/scan trace (tracecheck T2:
+      it would run once, at trace time).
+
 Suppression: append ``# lint-ok: RN <reason>`` to the flagged line (for
 R4, to the ``with`` line holding the lock).  A suppression WITHOUT a
 reason is itself a finding — justifications are the point.
@@ -158,7 +166,9 @@ CHAOS_HARNESS_MODULES = frozenset({
 # CLI's aggregation would silently fork on.
 _METRIC_FACTORY_CALLS = frozenset({"counter", "gauge", "histogram"})
 _SPAN_LITERAL_CALLS = frozenset({"mark", "close"})  # TraceContext methods
-_TRACING_MODULE_CALLS = frozenset({"start", "flush", "liveness"})
+_TRACING_MODULE_CALLS = frozenset({"start", "flush", "liveness", "phase"})
+#: R17: the prefix every kernel name carries in a device trace
+_KERNEL_NAME_PREFIX = "iotml_"
 _SNAKE_NAME_RE = re.compile(r"[a-z][a-z0-9_]*\Z")
 _IOTML_NAME_RE = re.compile(r"iotml_[a-z0-9_]+\Z")
 # R6 label vocabulary (ISSUE 13): metric labels at .inc/.set/.observe/
@@ -171,7 +181,8 @@ _METRIC_RECORD_CALLS = frozenset({"inc", "observe", "set", "time"})
 _ALLOWED_METRIC_LABELS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
-    "unit", "le", "slo", "window", "shard", "route", "code",
+    "unit", "le", "slo", "window", "shard", "route", "code", "program",
+    "result",
 })
 
 RULES: Dict[str, str] = {
@@ -227,6 +238,8 @@ RULES: Dict[str, str] = {
            "ONE wire→disk→host contract with ONE codec — consume raw "
            "batches via Broker.fetch_raw + FrameDecoder, produce them "
            "via ops.framing helpers / RawBatchProducer",
+    "R17": "pallas_call without a name= starting 'iotml_' (the device "
+           "trace and the compile counters key on kernel names)",
     "R16": "direct TwinTable access outside iotml/twin/ + "
            "iotml/gateway/ (TwinTable(...) construction, "
            ".apply_changelog(...), or reaching through a service's "
@@ -568,6 +581,13 @@ class _FileLinter(ast.NodeVisitor):
         if graph is None and rules & {"R4", "R6"}:
             graph = _ModuleCallGraph(tree)
         self.graph = graph
+        # R17: module-level string constants a kernel's name= may cite
+        self.str_consts: Dict[str, str] = {
+            t.id: node.value.value
+            for node in tree.body if isinstance(node, ast.Assign)
+            and isinstance(node.value, ast.Constant)
+            and isinstance(node.value.value, str)
+            for t in node.targets if isinstance(t, ast.Name)}
         parts = rel.replace(os.sep, "/").split("/")
         self.r1_scoped = any(seg in parts for seg in R1_PATH_SEGMENTS)
         self.in_streamproc = "streamproc" in parts
@@ -743,6 +763,21 @@ class _FileLinter(ast.NodeVisitor):
                            "IDEMPOTENT_APIS allowlist: a reconnect will NOT "
                            "auto-retry it; add '# retry-ok: <redelivery "
                            "story>' acknowledging the contract")
+
+        # R17 — a kernel without a stable name
+        if name == "pallas_call":
+            kw = next((k.value for k in node.keywords if k.arg == "name"),
+                      None)
+            kname = kw.value if isinstance(kw, ast.Constant) else \
+                self.str_consts.get(kw.id) if isinstance(kw, ast.Name) \
+                else None
+            if not (isinstance(kname, str)
+                    and kname.startswith(_KERNEL_NAME_PREFIX)):
+                self._emit("R17", node,
+                           "pallas_call without name='iotml_...': the "
+                           "kernel shows in a device trace under "
+                           "whatever scope encloses it, and per-kernel "
+                           "sums cannot find it after a refactor")
 
         # R3 — bare acquire
         if name == "acquire" and isinstance(node.func, ast.Attribute):

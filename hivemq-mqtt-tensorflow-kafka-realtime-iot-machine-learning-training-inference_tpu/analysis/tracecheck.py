@@ -16,7 +16,9 @@ T1  Python-value branching on a traced argument (``if x > 0:`` where
     ``.shape``/``.ndim``/``len()``/``is None`` is static and allowed.
 T2  Host sync reachable under trace: ``.item()``, ``.tolist()``,
     ``float()``/``int()`` of a traced value, ``np.asarray``/``np.array``
-    on a traced value, ``jax.device_get``, ``.block_until_ready()``.
+    on a traced value, ``jax.device_get``, ``.block_until_ready()`` —
+    and ``tracing.phase(...)``, host timing that would run once, at
+    trace time.
 T3  Per-call (re)jit: a ``jax.jit(...)`` whose compiled callable cannot
     outlive the call site — invoked immediately (``jax.jit(f)(x)``), or
     built inside a function that neither returns it, stores it on
@@ -270,6 +272,14 @@ class _RootChecker:
                 self.emit(
                     "T2", node.lineno,
                     "jax.device_get under trace is a host sync")
+                return
+            if func.attr == "phase" and isinstance(func.value, ast.Name) \
+                    and func.value.id == "tracing":
+                self.emit(
+                    "T2", node.lineno,
+                    "tracing.phase() under trace times the TRACING of "
+                    "the function, once, and nothing of its runs: open "
+                    "phases on the host, around the jitted call")
                 return
             # T4: traced value in a shape position.  Names that only
             # appear under an attribute access (x.shape, x.ndim) or a

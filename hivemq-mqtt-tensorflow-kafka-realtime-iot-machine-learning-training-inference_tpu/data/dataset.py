@@ -246,10 +246,14 @@ class SensorBatches:
         max_bytes = pl.raw_batch_bytes()
         while True:
             slot = self._ring.next_slot()
-            res = self.consumer.poll_into(
-                self._framedec, slot.x, slot.labels, slot.keys,
-                max_rows=min(self._poll_limit(), self._ring.rows),
-                max_bytes=max_bytes)
+            # `fetch`: the wire and, fused into the same native call,
+            # the frame decode (one span per consumer call, never per
+            # record; its loop is the enclosing phase's)
+            with tracing.phase(None, "fetch"):
+                res = self.consumer.poll_into(
+                    self._framedec, slot.x, slot.labels, slot.keys,
+                    max_rows=min(self._poll_limit(), self._ring.rows),
+                    max_bytes=max_bytes)
             if res is None:
                 # broker lost raw support (wire server downgrade):
                 # permanently hand back to the legacy paths
@@ -277,16 +281,28 @@ class SensorBatches:
                 # evolved writer (or legacy-only bytes) at the cursor:
                 # decode ONE chunk via the resolving message path, then
                 # resume columnar
-                msgs = self.consumer.poll(self._poll_limit())
+                msgs = self._poll_msgs()
                 if msgs:
                     yield self._decode_msgs(msgs)
                 continue
             if n == 0:
                 return  # log end: same contract as an empty poll()
 
+    def _poll_msgs(self):
+        """One message-list poll, as a `fetch` phase."""
+        with tracing.phase(None, "fetch"):
+            return self.consumer.poll(self._poll_limit())
+
     def _decode_msgs(self, msgs):
-        """Message-list decode (the fallback/oracle leg): trace forking,
-        schema-evolution resolution, native-or-pure codec."""
+        """Message-list decode (the fallback/oracle leg), as a `decode`
+        phase: the only leg on which decode is a call of its own (the
+        native legs fuse it into the fetch)."""
+        with tracing.phase(None, "decode"):
+            return self._decode_chunk(msgs)
+
+    def _decode_chunk(self, msgs):
+        """Trace forking, schema-evolution resolution, native-or-pure
+        codec."""
         label_f = self.schema.label_field
         if any(m.value is None for m in msgs):
             # tombstones (compaction delete markers) carry no payload:
@@ -385,12 +401,13 @@ class SensorBatches:
 
             while True:
                 try:
-                    res = self.consumer.poll_decoded(
-                        self._native, strip=5,
-                        max_messages=self._poll_limit(),
-                        with_keys=self._capture_keys)
+                    with tracing.phase(None, "fetch"):
+                        res = self.consumer.poll_decoded(
+                            self._native, strip=5,
+                            max_messages=self._poll_limit(),
+                            with_keys=self._capture_keys)
                 except SchemaIdMismatchError:
-                    msgs = self.consumer.poll(self._poll_limit())
+                    msgs = self._poll_msgs()
                     if msgs:
                         yield self._decode_msgs(msgs)
                     continue
@@ -401,7 +418,7 @@ class SensorBatches:
                                        self._native_labels(lab, len(num)),
                                        res[2] if self._capture_keys else None)
         while True:
-            msgs = self.consumer.poll(self._poll_limit())
+            msgs = self._poll_msgs()
             if not msgs:
                 return
             yield self._decode_msgs(msgs)

@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attention_reference, flash_attention
@@ -63,13 +64,18 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = x + MultiHeadAttention(self.d_model, self.num_heads,
-                                   self.attn_mode, self.ring_axis,
-                                   name="attn")(nn.LayerNorm(name="ln1")(x))
-        h = nn.LayerNorm(name="ln2")(x)
-        h = nn.Dense(self.d_model * self.mlp_ratio, name="mlp_in")(h)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, name="mlp_out")(h)
+        # named scopes: the fused XLA operations of each half carry
+        # `attn` or `mlp` in their metadata, whatever flax names the
+        # modules inside (a device trace groups by them)
+        with jax.named_scope("attn"):
+            x = x + MultiHeadAttention(
+                self.d_model, self.num_heads, self.attn_mode,
+                self.ring_axis, name="attn")(nn.LayerNorm(name="ln1")(x))
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(name="ln2")(x)
+            h = nn.Dense(self.d_model * self.mlp_ratio, name="mlp_in")(h)
+            h = nn.gelu(h)
+            h = nn.Dense(self.d_model, name="mlp_out")(h)
         return x + h
 
 

@@ -22,7 +22,10 @@ ate the budget between the sensor reading and its anomaly score?*
 A FLEET run appends every process's spans to one log (`proc` field);
 ``--require-cross-process N`` asserts a closed e2e trace really
 crossed the wire through N processes and ``--show-trace`` prints that
-journey (stages, offset ranges, which process ran what).
+journey (stages, offset ranges, which process ran what).  Where the
+log holds the loops' phase spans (`tracing.phase`, written whenever a
+path is set) it also prints self time per phase — phases nest, so their
+totals must not be summed — and the slowest round with its phases.
 
 ``fleet`` is the metrics federation collector (ISSUE 13): scrape every
 endpoint in the manifest (processes auto-join it via
@@ -65,13 +68,19 @@ def load_spans(path: str):
     return stages, e2e
 
 
-def load_spans_traces(path: str):
+def load_spans_traces(path: str, phases: Dict[str, list] = None):
     """Parse a span log with per-trace reconstruction: returns
     (stages, e2e, traces) where traces maps trace id → {spans:
     [(start_us, stage, dur_us, proc)], e2e: [(closer, dur_us, proc)],
     batches: [batch docs], procs: set} — the cross-process view a
     fleet run appends into ONE log (O_APPEND lines from every
-    process, disambiguated by the `proc` field)."""
+    process, disambiguated by the `proc` field).  Given a `phases`
+    dict, the log's phase spans (`tracing.phase`) are gathered into it
+    in the same pass: proc → [PhaseSpan], in microseconds since the
+    writing process's anchor (ids and parent links are a process's
+    own)."""
+    from .tracing import PhaseSpan
+
     stages: Dict[str, List[int]] = {}
     e2e: Dict[str, List[int]] = {}
     traces: Dict[str, dict] = {}
@@ -111,7 +120,71 @@ def load_spans_traces(path: str):
                 t = tr(doc.get("trace", "?"))
                 t["batches"].append(doc)
                 t["procs"].add(proc)
+            elif kind == "phase" and phases is not None:
+                start = int(doc["start_us"])
+                phases.setdefault(proc, []).append(PhaseSpan(
+                    doc["name"], start, start + int(doc["dur_us"]),
+                    doc.get("parent"), doc.get("round"),
+                    doc.get("thread", "?"), int(doc["id"])))
     return stages, e2e, traces
+
+
+def summarize_phases(by_proc: Dict[str, list]) -> dict:
+    """Per phase name: count, total and SELF time (its duration less
+    its children's cover — phases nest, so totals must not be summed),
+    and per root name the slowest round with its phases."""
+    from .tracing import self_seconds
+
+    rows: Dict[str, dict] = {}
+    slowest: Dict[str, tuple] = {}
+    for proc, spans in sorted(by_proc.items()):
+        own = self_seconds(spans)
+        for s in spans:
+            r = rows.setdefault(s.name, {"phase": s.name, "count": 0,
+                                         "total_ms": 0.0, "self_ms": 0.0,
+                                         "max_ms": 0.0})
+            ms = (s.end - s.start) / 1000.0
+            r["count"] += 1
+            r["total_ms"] += ms
+            r["self_ms"] += own[s.id] / 1000.0
+            r["max_ms"] = max(r["max_ms"], ms)
+            if s.parent is None and \
+                    ms > slowest.get(s.name, (-1.0,))[0]:
+                slowest[s.name] = (ms, proc, s, spans, own)
+    rounds = []
+    for name, (ms, proc, root, spans, own) in sorted(slowest.items()):
+        kids: Dict[int, list] = {}
+        for s in spans:
+            kids.setdefault(s.parent, []).append(s)
+        tree, todo = [], [(root, 0)]
+        while todo:
+            s, depth = todo.pop()
+            tree.append({"phase": s.name, "depth": depth,
+                         "ms": (s.end - s.start) / 1000.0,
+                         "self_ms": own[s.id] / 1000.0})
+            todo += [(k, depth + 1) for k in sorted(
+                kids.get(s.id, ()), key=lambda k: -k.start)]
+        rounds.append({"root": name, "round": root.round, "proc": proc,
+                       "ms": ms, "phases": tree})
+    return {"phases": sorted(rows.values(), key=lambda r: -r["self_ms"]),
+            "slowest_rounds": rounds}
+
+
+def print_phase_table(summary: dict) -> None:
+    hdr = f"{'phase':<30} {'count':>8} {'total_ms':>11} {'self_ms':>11} " \
+          f"{'max_ms':>10}"
+    print("\nphase spans (self = duration less the children's cover):")
+    print(hdr)
+    print("-" * len(hdr))
+    for r in summary["phases"]:
+        print(f"{r['phase']:<30} {r['count']:>8} {r['total_ms']:>11.3f} "
+              f"{r['self_ms']:>11.3f} {r['max_ms']:>10.3f}")
+    for rd in summary["slowest_rounds"]:
+        print(f"\nslowest {rd['root']}: round {rd['round']}, "
+              f"{rd['ms']:.3f} ms [{rd['proc']}]")
+        for ph in rd["phases"]:
+            print(f"  {'  ' * ph['depth']}{ph['phase']:<{30 - 2 * ph['depth']}}"
+                  f" {ph['ms']:>10.3f} ms  (self {ph['self_ms']:.3f})")
 
 
 def best_cross_process_trace(traces: Dict[str, dict]):
@@ -203,7 +276,8 @@ def print_table(summary: dict) -> None:
 
 def cmd_trace(args) -> int:
     try:
-        stages, e2e, traces = load_spans_traces(args.path)
+        phases: Dict[str, list] = {}
+        stages, e2e, traces = load_spans_traces(args.path, phases)
     except OSError as e:
         print(f"cannot read span log: {e}", file=sys.stderr)
         return 2
@@ -212,10 +286,16 @@ def cmd_trace(args) -> int:
         keep = sorted(stages, key=lambda s: -sum(stages[s]))[: args.top]
         stages = {s: stages[s] for s in keep}
     summary = summarize(stages, e2e)
+    if phases:
+        summary.update(summarize_phases(phases))
     if args.json:
         print(json.dumps(summary, indent=2, sort_keys=True))
     else:
-        print_table(summary)
+        # a log of phase spans alone is not "no spans found"
+        if summary["stages"] or not phases:
+            print_table(summary)
+        if phases:
+            print_phase_table(summary)
     failures = []
     if args.min_stages and len(summary["stages"]) < args.min_stages:
         failures.append(f"expected >= {args.min_stages} distinct stages, "

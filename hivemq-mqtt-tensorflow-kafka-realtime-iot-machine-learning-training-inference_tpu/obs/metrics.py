@@ -414,10 +414,28 @@ consumer_lag_records = default_registry.gauge(
 # item 3 (multi-chip training) starts from.
 step_seconds = default_registry.histogram(
     "iotml_step_seconds",
-    "hot-loop wall time by loop (train|score|online) and phase "
-    "(host_wait | device_compute | host_pipeline)",
+    "hot-loop wall time by loop (train|score|online|stream) and phase; "
+    "phases NEST, so never sum the family: train fit > host_pipeline "
+    "(> fetch, decode), stack, device_compute (> transfer, dispatch, "
+    "sync); train round > fit, publish, checkpoint, commit; score "
+    "drain > host_pipeline (> fetch, decode), device_compute, "
+    "writeback; host_wait is the prefetcher's",
     buckets=(0.0001, 0.001, 0.005, 0.02, 0.05, 0.1, 0.5, 1.0, 5.0,
              30.0))
+# what JAX reports of its own compilations (utils.device listens):
+# seconds by stage and program.  `backend` is one per program built OR
+# loaded — it covers `cache_read`, the part spent reading an executable
+# back from the persistent cache — so a count that moves mid-stream is
+# a stall, whichever it was.  `program` is an iotml_* jitted function's
+# name, or "other".
+compile_seconds = default_registry.histogram(
+    "iotml_compile_seconds",
+    "JAX compilation time by stage (trace | lower | backend | "
+    "cache_read; cache_read is part of backend) and program",
+    buckets=(0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 20.0, 60.0, 180.0))
+compile_cache = default_registry.counter(
+    "iotml_compile_cache_total",
+    "persistent compile cache lookups by result (hit | miss)")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "
@@ -451,7 +469,8 @@ quorum_hwm_lag = default_registry.gauge(
 ALLOWED_LABEL_KEYS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
-    "unit", "le", "slo", "window", "shard", "route", "code",
+    "unit", "le", "slo", "window", "shard", "route", "code", "program",
+    "result",
 })
 
 #: per-metric ceiling on distinct label-value combinations.  Generous —
@@ -472,6 +491,8 @@ DECLARED_METRIC_LABELS = {
     "checkpoint_seconds": ("phase",),
     "cluster_shard_epoch": ("shard",),
     "cluster_shard_failovers": ("shard",),
+    "compile_cache": ("result",),
+    "compile_seconds": ("program", "stage"),
     "consumer_autoresets": ("topic",),
     "consumer_lag_records": ("group", "partition", "topic"),
     "dlq_total": ("source",),

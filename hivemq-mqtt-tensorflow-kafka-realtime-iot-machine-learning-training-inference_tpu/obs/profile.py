@@ -9,13 +9,14 @@ are written in the same TensorBoard `plugins/profile` layout, viewable with
 
 Usage:
 
-    from iotml.obs.profile import trace, annotate
+    from iotml.obs.profile import trace
 
     with trace("./logs"):                  # one captured window
         trainer.fit_compiled(batches, epochs=20)
 
-    with annotate("decode"):               # named span inside a capture
-        batches = list(iter(sensor_batches))
+The program's own phases (`iotml.train.fit`, `.host_pipeline`, `.fetch`,
+`.decode`, `.dispatch`, ... — `obs.tracing.phase`) are already spans of
+that capture's host plane; `annotate` adds a caller's own.
 
 `bench.py` honors `IOTML_PROFILE=<dir>` to capture its warm measurement
 pass without changing the bench contract.
@@ -28,6 +29,8 @@ import os
 from typing import Iterator, Optional
 
 import jax
+
+from . import tracing
 
 
 @contextlib.contextmanager
@@ -42,8 +45,9 @@ def trace(logdir: str = "./logs") -> Iterator[None]:
 
 
 def annotate(name: str):
-    """Named span that shows up on the trace timeline (host + device)."""
-    return jax.profiler.TraceAnnotation(name)
+    """Named span on the trace timeline's host plane: the annotation
+    `tracing.phase` opens, for a caller's own code."""
+    return tracing.annotation(name)
 
 
 @contextlib.contextmanager

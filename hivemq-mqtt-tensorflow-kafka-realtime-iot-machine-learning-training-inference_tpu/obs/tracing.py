@@ -28,8 +28,18 @@ Design:
   ``iotml_e2e_ingest_to_*_seconds``, and — when a path is configured —
   in a JSONL span log the ``python -m iotml.obs trace`` CLI summarizes.
 
-Off by default, zero-ish cost: every instrumentation site guards on the
-module flag (`tracing.ENABLED`) and allocates nothing when it is False.
+Between a histogram's sum and a record sits the **phase span**: one
+span per phase per round of a hot loop (`phase()`), with a parent and a
+round number.  It observes ``iotml_step_seconds{loop,phase}``, lands in
+a per-thread ring `phases()` reads, rides the span log where a path is
+set, and is a ``jax.profiler.TraceAnnotation`` for its duration — only
+where `jax` is already imported: this module never imports it.  Phase
+spans are unconditional and round-granular (per round, per consumer
+call; never per record, never inside a compiled step).
+
+The record level is off by default, zero-ish cost: every instrumentation
+site guards on the module flag (`tracing.ENABLED`, which gates the
+record-level contexts only) and allocates nothing when it is False.
 Enable with ``IOTML_TRACE=1``; sample with ``IOTML_TRACE_SAMPLE=0.01``;
 log spans to ``IOTML_TRACE_PATH=/tmp/spans.jsonl``.  These are process
 toggles, not pipeline config — registered in `iotml.config`'s
@@ -47,12 +57,14 @@ from __future__ import annotations
 
 import atexit
 import collections
+import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from . import metrics as _metrics
 
@@ -83,6 +95,19 @@ def proc_name() -> str:
 #: per-thread span buffer bound — overload drops oldest, counted below.
 _BUFFER_BOUND = 65536
 
+#: per-thread ring of phase spans: round-granular, so hours of a loop
+_PHASE_BOUND = 4096
+
+#: loop label of a phase opened with no loop and no parent (a batcher
+#: iterated outside any loop's phase)
+_NO_LOOP = "stream"
+
+#: the module's one wall-clock anchor: a phase span carries monotonic
+#: times, and (monotonic - anchor) + wall anchor lays a span log over a
+#: profiler trace
+_ANCHOR_MONO = time.monotonic()
+_ANCHOR_WALL_NS = time.time_ns()  # wallclock-ok: the anchor the span log carries, not a deadline
+
 # ------------------------------------------------------------- exporters
 stage_seconds = _metrics.default_registry.histogram(
     "iotml_stage_seconds", "per-stage pipeline latency (label: stage)",
@@ -108,12 +133,19 @@ class _Buf:
     into the shared counter at drain) so the record path touches no
     shared lock even when the buffer is saturated."""
 
-    __slots__ = ("q", "drops", "thread")
+    __slots__ = ("q", "drops", "thread", "phases", "stack", "logged")
 
     def __init__(self, thread: threading.Thread):
         self.q: collections.deque = collections.deque(maxlen=_BUFFER_BOUND)
         self.drops = 0
         self.thread = thread
+        # phase spans: a ring that drains never empty (phases() reads
+        # it), the open phases of this thread, and the id of the newest
+        # span the log exporter has written
+        self.phases: collections.deque = collections.deque(
+            maxlen=_PHASE_BOUND)
+        self.stack: list = []
+        self.logged = 0
 
 
 class _Collector:
@@ -141,9 +173,13 @@ class _Collector:
             buf.drops += 1  # thread-local; folded in at drain (no lock)
         buf.q.append(entry)
 
-    def drain(self) -> List[tuple]:
+    def buffers(self) -> List[_Buf]:
+        """Every live thread's buffer (a snapshot of the registry)."""
         with self._reg_lock:
-            buffers = list(self._buffers)
+            return list(self._buffers)
+
+    def drain(self) -> List[tuple]:
+        buffers = self.buffers()
         out: List[tuple] = []
         for buf in buffers:
             # popleft until empty: concurrent appends land at the right
@@ -172,7 +208,8 @@ class _Collector:
                 # is a thread per connection: without this the registry
                 # grows one dead deque per reconnect, forever).  Just
                 # drained empty + owner dead = nothing can land in it.
-                if not buf.q and not buf.thread.is_alive():
+                if not buf.q and not buf.thread.is_alive() and (
+                        not buf.phases or buf.phases[-1].id <= buf.logged):
                     dead.append(buf)
             if dead:
                 self._buffers = [b for b in self._buffers
@@ -342,6 +379,123 @@ def touch(stage: str) -> None:
         _last_seen[stage] = time.monotonic()
 
 
+# ----------------------------------------------------------- phase spans
+class PhaseSpan(NamedTuple):
+    """One phase of one round of a loop.  `start`/`end` are monotonic
+    seconds (`wall_ns()` maps them onto the wall clock through the
+    module's anchor); `parent` is the enclosing phase's `id` on this
+    thread, None for a root; ids count up per process."""
+
+    name: str
+    start: float
+    end: float
+    parent: Optional[int]
+    round: Optional[int]
+    thread: str
+    id: int
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def wall_ns(self) -> int:
+        return _ANCHOR_WALL_NS + int((self.start - _ANCHOR_MONO) * 1e9)
+
+
+_phase_ids = itertools.count(1)  # next() is GIL-atomic
+
+
+def annotation(name: str, **kw):
+    """`jax.profiler.TraceAnnotation(name, **kw)` where this process has
+    imported jax, else None: the one place the obs package reaches the
+    profiler.  Importing jax here would put launchers and the host
+    plane's children on it (and on the chip's lock); a process that
+    never imported it has no profiler session to annotate."""
+    jax = sys.modules.get("jax")
+    prof = getattr(jax, "profiler", None)
+    return None if prof is None else prof.TraceAnnotation(name, **kw)
+
+
+class phase:
+    """``with tracing.phase("train", "fit", round=n):`` — the one idiom
+    for timing a phase of a loop.  On exit it observes
+    ``iotml_step_seconds{loop,phase}``, appends one `PhaseSpan` named
+    ``iotml.<loop>.<phase>`` to this thread's ring, and for its duration
+    it is a profiler `TraceAnnotation` of that name (see `annotation`),
+    so the program's spans sit in the profiler's host plane on the
+    clock the device plane is aligned to.
+
+    `loop` and `round` are inherited from the enclosing phase of this
+    thread where None.  Unconditional, and round-granular by contract:
+    open one per round or per consumer call, never per record and never
+    inside a jitted function."""
+
+    __slots__ = ("loop", "phase", "round", "id", "name", "_buf",
+                 "_parent", "_note", "_t0")
+
+    def __init__(self, loop: Optional[str], phase: str,
+                 round: Optional[int] = None):
+        self.loop, self.phase, self.round = loop, phase, round
+
+    def __enter__(self) -> "phase":
+        buf = self._buf = _collector.buffer()
+        parent = buf.stack[-1] if buf.stack else None
+        if self.loop is None:
+            self.loop = parent.loop if parent is not None else _NO_LOOP
+        if self.round is None and parent is not None:
+            self.round = parent.round
+        self._parent = parent.id if parent is not None else None
+        self.id = next(_phase_ids)
+        buf.stack.append(self)
+        name = self.name = f"iotml.{self.loop}.{self.phase}"
+        self._note = annotation(name) if self.round is None \
+            else annotation(name, round=self.round)
+        if self._note is not None:
+            self._note.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.monotonic()
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        buf = self._buf
+        buf.stack.pop()
+        _metrics.step_seconds.observe(t1 - self._t0, loop=self.loop,
+                                      phase=self.phase)
+        buf.phases.append(PhaseSpan(
+            self.name, self._t0, t1, self._parent, self.round,
+            buf.thread.name, self.id))
+
+
+def phases() -> List[PhaseSpan]:
+    """Every phase span still in the rings, oldest first.  Reading
+    empties nothing: the rings are bounded and drop their oldest."""
+    out: List[PhaseSpan] = []
+    for buf in _collector.buffers():
+        out.extend(buf.phases)  # C-level copy: atomic under the GIL
+    out.sort(key=lambda s: s.id)
+    return out
+
+
+def self_seconds(spans) -> Dict[int, float]:
+    """Span id → its duration less the cover of its direct children:
+    the time a phase spent in no phase of its own."""
+    kids: Dict[int, list] = {}
+    for s in spans:
+        if s.parent is not None:
+            kids.setdefault(s.parent, []).append((s.start, s.end))
+    out = {}
+    for s in spans:
+        cover, edge = 0.0, s.start
+        for a, b in sorted(kids.get(s.id, ())):
+            a, b = max(a, edge), min(b, s.end)
+            if b > a:
+                cover, edge = cover + (b - a), b
+        out[s.id] = (s.end - s.start) - cover
+    return out
+
+
 def mark_batch(ctx: Optional[TraceContext], stage: str,
                topic: Optional[str] = None, partition: int = -1,
                first_offset: int = -1, last_offset: int = -1,
@@ -394,15 +548,28 @@ def from_headers(headers) -> Optional[TraceContext]:
 # ---------------------------------------------------------------- drain
 def flush() -> Dict[str, int]:
     """Drain the collector into the Prometheus histograms, the liveness
-    table and (when configured) the JSONL span log.  Returns counts.
+    table and (when configured) the JSONL span log.  Returns counts
+    (of record-level spans; phase spans go to the log alone).
     Exporting happens HERE, never on the record path — the histograms'
     internal locks are only ever taken by drainers."""
     entries = _collector.drain()
-    if not entries:
-        return {"spans": 0, "e2e": 0}
     n_span = n_e2e = 0
-    lines: List[str] = []
     proc = proc_name()
+    # phase spans: to the span log only (`phase()` observed the
+    # histogram itself), each once, and the rings stay as they are
+    lines: List[str] = []
+    for buf in _collector.buffers():
+        if not buf.phases or buf.phases[-1].id <= buf.logged:
+            continue
+        new = [s for s in list(buf.phases) if s.id > buf.logged]
+        buf.logged = new[-1].id
+        if _PATH:
+            lines += [json.dumps(
+                {"kind": "phase", "name": s.name, "id": s.id,
+                 "parent": s.parent, "round": s.round, "thread": s.thread,
+                 "start_us": int((s.start - _ANCHOR_MONO) * 1e6),
+                 "dur_us": int((s.end - s.start) * 1e6),
+                 "wall0_ns": _ANCHOR_WALL_NS, "proc": proc}) for s in new]
     for e in entries:
         if e[0] == "span":
             _, tid, stage, start_s, dur_s, wall0_ns, t_mark = e
@@ -474,6 +641,9 @@ def reset() -> None:
     """Test hook: drop collected spans, liveness and current-trace state
     (the module flag and sampling survive — configure() owns those)."""
     _collector.drain()
+    for buf in _collector.buffers():
+        buf.phases.clear()
+        buf.logged = 0
     _last_seen.clear()
     _current.ctx = None
 

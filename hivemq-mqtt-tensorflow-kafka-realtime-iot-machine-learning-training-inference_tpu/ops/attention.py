@@ -29,6 +29,12 @@ import numpy as np
 
 NEG_INF = -1e30
 
+#: the kernels' names in a device trace and in the HLO: one per kernel,
+#: whichever grid (dense or triangular) runs it
+FWD_KERNEL = "iotml_flash_fwd"
+BWD_DKV_KERNEL = "iotml_flash_bwd_dkv"
+BWD_DQ_KERNEL = "iotml_flash_bwd_dq"
+
 
 def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int,
                   order: str) -> tuple:
@@ -314,6 +320,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int,
                                    nk=Tk // block_k)
         out, lse = pl.pallas_call(
             kernel,
+            name=FWD_KERNEL,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B * H, len(im)),
@@ -343,6 +350,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int,
                                    block_q=block_q, block_k=block_k)
         out, lse = pl.pallas_call(
             kernel,
+            name=FWD_KERNEL,
             grid=(B * H, Tq // block_q, Tk // block_k),
             in_specs=[
                 pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -605,6 +613,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                               lambda b, t, im, jm: (b, jm[t], 0))
         dk_f, dv_f = pl.pallas_call(
             dkv_kernel,
+            name=BWD_DKV_KERNEL,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B * H, len(imc)),
@@ -624,6 +633,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
             block_k=bk, t_real=T)
         dk_f, dv_f = pl.pallas_call(
             dkv_kernel,
+            name=BWD_DKV_KERNEL,
             grid=(B * H, Tk // bk, Tq // bq),
             in_specs=[q_spec_j, q_spec_j, r_spec_j, r_spec_j,
                       kv_spec_j, kv_spec_j],
@@ -643,6 +653,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
             block_k=bk, t_real=T, nk=Tk // bk)
         dq_f = pl.pallas_call(
             dq_kernel,
+            name=BWD_DQ_KERNEL,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B * H, len(imr)),
@@ -662,6 +673,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
             block_k=bk, t_real=T)
         dq_f = pl.pallas_call(
             dq_kernel,
+            name=BWD_DQ_KERNEL,
             grid=(B * H, Tq // bq, Tk // bk),
             in_specs=[q_spec_i, q_spec_i, r_spec_i, r_spec_i,
                       kv_spec_i, kv_spec_i],

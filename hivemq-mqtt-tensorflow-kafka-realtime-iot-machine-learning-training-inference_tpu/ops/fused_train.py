@@ -178,7 +178,8 @@ def _fused_fit(flat_p, flat_m, flat_v, t0, xs, masks, epochs: int,
         _fit_kernel, n_tensors=n_tensors, steps_per_epoch=steps_per_epoch,
         total_steps=total, lr=lr, l1=l1, b1=b1, b2=b2, eps=eps)
     t0_arr = jnp.asarray(t0, jnp.int32).reshape(1)
-    res = pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret)(
+    res = pl.pallas_call(kernel, name="iotml_fused_fit", out_shape=out_shape,
+                         interpret=interpret)(
         xs, masks, t0_arr, *flat_p, *flat_m, *flat_v)
     n3 = 3 * n_tensors
     return res[:n3], res[n3], res[n3 + 1]

@@ -127,6 +127,8 @@ class StreamScorer:
         self.carhealth_topic = carhealth_topic
         self._eval = make_eval_step(model)
         self.scored = 0
+        #: calls of score_available so far: the `round` of its spans
+        self.drains = 0
         #: registry version of the loaded weights (None = not registry-
         #: managed); stamped by set_params(version=) / RegistryWatcher
         self.model_version: Optional[int] = None
@@ -205,6 +207,14 @@ class StreamScorer:
         and offsets only commit once the drain truly reaches the stream
         end (committing at the truncation point would persist the cursor
         past polled-but-unscored rows and silently drop them)."""
+        self.drains += 1
+        with tracing.phase("score", "drain", round=self.drains):
+            return self._drain(max_rows)
+
+    def _drain(self, max_rows: Optional[int]) -> int:
+        """`score_available` inside its `iotml.score.drain` span: each
+        super-batch is a `host_pipeline`, a `device_compute` and a
+        `writeback` phase of it; the closing commit is its self time."""
         start = self.scored
         if self._resume is not None:
             # continue the truncated drain: same iterator, same index base
@@ -217,18 +227,13 @@ class StreamScorer:
             chaos.point("scorer.poll")  # injected stall/crash lands at a
             # super-batch boundary: exactly where a real broker death
             # surfaces, upstream of the commit (redelivery covers it)
-            with obs_metrics.step_seconds.time(loop="score",
-                                               phase="host_pipeline"):
+            with tracing.phase("score", "host_pipeline"):
                 # the host leg: poll + columnar decode + batching (the
                 # batcher's iterator does all three)
                 bs = list(itertools.islice(it, self.max_super_batches))
             if not bs:
                 break
             self._score_super_batch(bs, it_base)
-            # flush per super-batch: indices are monotone so the ordered
-            # flush is preserved and host memory stays bounded by one
-            # super-batch of formatted predictions
-            self.out.flush()
             if max_rows is not None and self.scored - start >= max_rows:
                 self._resume = (it, it_base)
                 break
@@ -282,11 +287,22 @@ class StreamScorer:
                 [xs, np.zeros((S_pad - S, B) + row_shape, xs.dtype)])
         else:
             xs_in = xs
-        with obs_metrics.step_seconds.time(loop="score",
-                                           phase="device_compute"):
+        with tracing.phase("score", "device_compute"):
             preds = jax.device_get(self._eval(
                 self.params, xs_in.reshape((S_pad * B,) + row_shape)))
         preds = preds.reshape((S_pad, B) + preds.shape[1:])[:S]
+        with tracing.phase("score", "writeback"):
+            self._write_back(bs, base, xs, preds)
+            # flush per super-batch: indices are monotone so the ordered
+            # flush is preserved and host memory stays bounded by one
+            # super-batch of formatted predictions
+            self.out.flush()
+
+    def _write_back(self, bs, base: int, xs, preds) -> None:
+        """A super-batch's host tail (the `writeback` phase, with the
+        flush that follows it): errors, the per-car detector, formatting
+        and the ordered write into the output sequence."""
+        S, B = xs.shape[:2]
         # per-row reconstruction error over every non-batch axis
         err_axes = tuple(range(2, preds.ndim))
         sq = np.square(preds - xs)

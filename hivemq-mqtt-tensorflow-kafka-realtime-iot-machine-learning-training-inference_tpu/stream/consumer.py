@@ -141,23 +141,29 @@ class StreamConsumer:
             except IndexError:
                 return out
 
-    def record_lag(self) -> int:
+    def record_lag(self, cached_only: bool = False) -> int:
         """Refresh ``iotml_consumer_lag_records{group,topic,partition}``
         from the high-water mark and return the total lag.  Wire
         brokers answer from the hwm CACHED off every fetch response —
         classic FETCH and RAW_FETCH both carry it (zero extra round
         trips); otherwise one ``end_offset`` read per partition —
-        called at commit/drain granularity, never per record.  This is
-        TELEMETRY riding the commit path: no failure here may crash a
-        drain, so anything the broker throws (dead socket, transient
-        wire error, racing topic deletion) degrades to a skipped
-        refresh."""
+        called at commit/drain granularity and, ``cached_only``, once at
+        the end of every poll: there a wire broker is asked nothing (a
+        partition it has no cached hwm for is skipped; an in-process
+        broker's ``end_offset`` is a local read), so the gauge moves
+        through a live window, not only at its commits.  Never per
+        record.  This is TELEMETRY riding the read and commit paths: no
+        failure here may crash a drain, so anything the broker throws
+        (dead socket, transient wire error, racing topic deletion)
+        degrades to a skipped refresh."""
         total = 0
         hwm_of = getattr(self.broker, "last_hwm", None)
         for topic, part, off in self._cursors:
             try:
                 hwm = hwm_of(topic, part) if hwm_of is not None else None
                 if hwm is None:
+                    if cached_only and hwm_of is not None:
+                        continue
                     hwm = self.broker.end_offset(topic, part)
             except (KeyError, RuntimeError, OSError):
                 # OSError covers ConnectionError AND socket timeouts;
@@ -228,6 +234,7 @@ class StreamConsumer:
             # (or fetches are being truncated) — only non-empty polls
             # observe, so idle polling does not flood the 1-bucket
             obs_metrics.fetch_batch_size.observe(len(out))
+        self.record_lag(cached_only=True)
         return out
 
     def poll_decoded(self, codec, strip: int = 5, max_messages: int = 4096,
@@ -283,6 +290,7 @@ class StreamConsumer:
                     keys.append(res[2])
                 got += len(numeric)
                 attempts = 0
+        self.record_lag(cached_only=True)
         if not nums:
             from .native import LABEL_STRIDE
 
@@ -388,6 +396,7 @@ class StreamConsumer:
                     return rows, True
         if rows:
             obs_metrics.fetch_batch_size.observe(rows)
+        self.record_lag(cached_only=True)
         return rows, False
 
     def _extract_batch_trace(self, raw, topic: str, part: int,

@@ -124,6 +124,9 @@ class NativeKafkaBroker(ProducePartitionMixin):
         #: rows whose key filled the stride (possibly truncated by the
         #: engine — the engine writes at most stride-1 bytes)
         self.keys_maybe_truncated = 0
+        # (topic, partition) → the high-water mark its newest fetch
+        # response carried (see last_hwm)
+        self._hwm: dict = {}
         lib = load()
         if lib is None:
             raise RuntimeError("native stream engine unavailable")
@@ -312,6 +315,18 @@ class NativeKafkaBroker(ProducePartitionMixin):
             # treat both transports identically
             raise NotLeaderForPartitionError(topic, partition)
 
+    def _note_hwm(self, topic: str, partition: int) -> None:
+        """After a fetch that succeeded (caller holds the lock): keep
+        the high-water mark the engine staged off the response."""
+        self._hwm[(topic, partition)] = int(
+            self._lib.iotml_kafka_high_watermark(self._h))
+
+    def last_hwm(self, topic: str, partition: int) -> Optional[int]:
+        """The high-water mark of (topic, partition) as its newest fetch
+        response carried it, None before the first: consumer lag at no
+        extra request (the contract of KafkaWireBroker.last_hwm)."""
+        return self._hwm.get((topic, partition))
+
     def fetch(self, topic: str, partition: int, offset: int,
               max_messages: int = 1024) -> List[Message]:
         with self._lock:
@@ -322,6 +337,7 @@ class NativeKafkaBroker(ProducePartitionMixin):
                 raise KeyError(topic)
             self._raise_out_of_range(rc, topic, partition, offset)
             n = _check(rc, f"fetch({topic}:{partition}@{offset})")
+            self._note_hwm(topic, partition)
             if n == 0:
                 return []
             vb, kb = ctypes.c_int64(), ctypes.c_int64()
@@ -381,6 +397,7 @@ class NativeKafkaBroker(ProducePartitionMixin):
                 raise KeyError(topic)
             self._raise_out_of_range(rc, topic, partition, offset)
             n = _check(rc, f"fetch_decode({topic}:{partition}@{offset})")
+            self._note_hwm(topic, partition)
             return (numeric[:n], labels[:n, : codec.n_strings],
                     int(next_off.value))
 
@@ -425,6 +442,7 @@ class NativeKafkaBroker(ProducePartitionMixin):
                 raise KeyError(topic)
             self._raise_out_of_range(rc, topic, partition, offset)
             n = _check(rc, f"fetch_decode_keys({topic}:{partition}@{offset})")
+            self._note_hwm(topic, partition)
             # A key that fills the stride was possibly truncated by the
             # engine (it writes at most stride-1 bytes): two distinct car
             # keys sharing a stride-1-byte prefix would alias into one

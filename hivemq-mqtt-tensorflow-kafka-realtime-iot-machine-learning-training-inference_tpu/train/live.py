@@ -27,7 +27,7 @@ import numpy as np
 from ..chaos import faults as chaos
 from ..data.dataset import SensorBatches
 from ..obs import metrics as obs_metrics
-from ..obs import watermark
+from ..obs import tracing, watermark
 from ..stream.consumer import StreamConsumer
 from .artifacts import ArtifactStore
 from .loop import Trainer
@@ -238,6 +238,13 @@ class ContinuousTrainer:
 
     def train_round(self) -> dict:
         """One fixed-shape fit over the next slice + artifact publish."""
+        with tracing.phase("train", "round", round=self.rounds + 1):
+            return self._train_round()
+
+    def _train_round(self) -> dict:
+        """`train_round` inside its `iotml.train.round` span: the fit
+        (a span tree of its own, see Trainer.fit_compiled), then
+        `checkpoint`, `publish` and `commit` as phases of the round."""
         t0 = time.perf_counter()
         history = self.trainer.fit_compiled(self.batches,
                                             epochs=self.epochs_per_round)
@@ -260,16 +267,20 @@ class ContinuousTrainer:
             # GROUP COMMIT trails durability (_commit_checkpointed runs
             # after the manifest lands), so a crash at ANY point
             # resumes model + stream position as one consistent unit
-            self._snapshot()
+            with tracing.phase("train", "checkpoint"):
+                self._snapshot()
             artifact = f"registry:r{self.rounds}"
             if self.store is not None:  # legacy pointer riders along
-                artifact = self.publish()
+                with tracing.phase("train", "publish"):
+                    artifact = self.publish()
         else:
-            artifact = self.publish()
+            with tracing.phase("train", "publish"):
+                artifact = self.publish()
             # commit AFTER the artifact is durable (the `committed`
             # resume contract: a crash re-trains the slice rather than
             # skipping it)
-            self.consumer.commit()
+            with tracing.phase("train", "commit"):
+                self.consumer.commit()
         stats = {"t": time.time(), "round": self.rounds,
                  "loss": self.last_loss,
                  "records": history["records"][-1],
@@ -325,8 +336,11 @@ class ContinuousTrainer:
         """The writer's post-durability hook: commit the manifest's
         stamped offsets for this group, FORWARD-ONLY (see
         ``commit_manifest_offsets``).  A skipped (dropped) snapshot
-        just means the next one commits further ahead."""
-        commit_manifest_offsets(self.broker, self.group, manifest)
+        just means the next one commits further ahead.  On the writer's
+        thread, so its `commit` phase is a root span there, not a child
+        of the round that took the snapshot."""
+        with tracing.phase("train", "commit"):
+            commit_manifest_offsets(self.broker, self.group, manifest)
 
     def close(self, timeout_s: float = 30.0) -> None:
         """Flush pending checkpoints and stop an owned writer thread."""

@@ -105,11 +105,15 @@ def make_raw_train_step(model, tx, supervised: bool = False,
     eliminated."""
     loss_fn = make_loss_fn(model, supervised)
 
-    def step(state: TrainState, x, y, mask):
+    def iotml_train_step(state: TrainState, x, y, mask):
         (loss, (pred, target)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, x, y, mask)
-        updates, opt_state = state.tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        # the optimizer's operations carry `adam` in their metadata, as
+        # the model's carry `attn`/`mlp`: a device trace tells them apart
+        with jax.named_scope("adam"):
+            updates, opt_state = state.tx.update(grads, state.opt_state,
+                                                 state.params)
+            params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "accuracy": _keras_accuracy(pred, target, mask)}
         if row_loss:
             per_elem = jnp.square(pred - target)
@@ -118,7 +122,7 @@ def make_raw_train_step(model, tx, supervised: bool = False,
         return state.replace(step=state.step + 1, params=params,
                              opt_state=opt_state), metrics
 
-    return step
+    return iotml_train_step
 
 
 def make_train_step(model, tx, supervised: bool = False):
@@ -137,7 +141,7 @@ def make_scanned_fit(model, tx, supervised: bool = False):
     """
     raw = make_raw_train_step(model, tx, supervised)
 
-    def fit(state: TrainState, xs, ys, masks, epochs: int):
+    def iotml_scanned_fit(state: TrainState, xs, ys, masks, epochs: int):
         def batch_step(st, inp):
             x, y, m = inp
             st, metrics = raw(st, x, y, m)
@@ -149,9 +153,14 @@ def make_scanned_fit(model, tx, supervised: bool = False):
 
         return jax.lax.scan(epoch_step, state, None, length=epochs)
 
-    return jax.jit(fit, static_argnames=("epochs",), donate_argnums=(0,))
+    return jax.jit(iotml_scanned_fit, static_argnames=("epochs",),
+                   donate_argnums=(0,))
 
 
+# The jitted programs are named `iotml_*` (the function's __name__ is what
+# the HLO module, a device trace and JAX's compile events carry): the
+# compile counters of utils.device key on that prefix.
+#
 # jax.jit caches per function object; a fresh closure per fit_compiled call
 # would re-trace (and without backend caching, re-compile) every time.  Keyed
 # on (model, tx identity-or-descriptor, supervised) so repeated jobs — e.g.
@@ -210,11 +219,11 @@ def jitted_state_init(model, tx, tx_key=None):
 
     def make():
         @jax.jit
-        def init(rng, x):
+        def iotml_state_init(rng, x):
             params = model.init(rng, x)["params"]
             return params, tx.init(params)
 
-        return init
+        return iotml_state_init
 
     return _lru_get(_INIT_CACHE, key, make)
 
@@ -237,7 +246,7 @@ def make_scanned_window_steps(model, tx, supervised: bool = False):
     through a fused group."""
     raw = make_raw_train_step(model, tx, supervised)
 
-    def run(state: TrainState, xs, masks):
+    def iotml_window_steps(state: TrainState, xs, masks):
         def step(st, inp):
             x, m = inp
             st, metrics = raw(st, x, x, m)
@@ -245,7 +254,7 @@ def make_scanned_window_steps(model, tx, supervised: bool = False):
 
         return jax.lax.scan(step, state, (xs, masks))
 
-    return jax.jit(run, donate_argnums=(0,))
+    return jax.jit(iotml_window_steps, donate_argnums=(0,))
 
 
 def scanned_window_steps_cached(model, tx, tx_key=None):
@@ -261,10 +270,10 @@ def make_eval_step(model, supervised: bool = False):
     call would recompile the eval program on every drain."""
     def make():
         @jax.jit
-        def step(params, x):
+        def iotml_eval_step(params, x):
             return model.apply({"params": params}, x)
 
-        return step
+        return iotml_eval_step
 
     return _lru_get(_EVAL_CACHE, model, make)
 
@@ -284,6 +293,8 @@ class Trainer:
         self.supervised = supervised
         self.state: Optional[TrainState] = None
         self._step = None
+        #: calls of fit_compiled so far: the `round` of its phase spans
+        self.fits = 0
 
     def _ensure_state(self, sample_x):
         if self.state is None:
@@ -358,6 +369,14 @@ class Trainer:
         The history names the fit that ran: ``fit`` is "fused" or
         "scanned", ``interpret`` whether the fused kernel ran under the
         Pallas interpreter (CPU backend only)."""
+        self.fits += 1
+        with tracing.phase("train", "fit", round=self.fits):
+            return self._fit_compiled(batches, epochs, fused)
+
+    def _fit_compiled(self, batches, epochs: int, fused: str) -> dict:
+        """`fit_compiled` inside its `iotml.train.fit` span; each leg is
+        a phase of it (`tracing.phase`: a span, a series of
+        `iotml_step_seconds`, a profiler annotation)."""
         import numpy as np
 
         t0 = time.perf_counter()
@@ -375,15 +394,21 @@ class Trainer:
         # fit over the same source would see nothing).
         it = next(batches.epochs(1)) if hasattr(batches, "epochs") \
             else iter(batches)
-        with obs_metrics.step_seconds.time(loop="train",
-                                           phase="host_pipeline"):
+        with tracing.phase("train", "host_pipeline"):
             # the host leg of the round: poll + decode + batch assembly
-            # all happen inside the batcher's iterator
+            # all happen inside the batcher's iterator (its consumer
+            # calls are `fetch`/`decode` phases of their own; windowing
+            # and normalisation are this phase's self time)
             bs = list(it)
         if not bs:
             return {"loss": [], "accuracy": [], "records": [], "seconds": []}
-        xs = np.stack([b.x for b in bs])
-        masks = np.stack([b.mask for b in bs])
+        with tracing.phase("train", "stack"):
+            xs = np.stack([b.x for b in bs])
+            masks = np.stack([b.mask for b in bs])
+            # autoencoder mode targets the input itself: no ys, and the
+            # transferred xs is reused instead of a byte-identical copy
+            ys = np.stack([b.y if b.y is not None else b.x for b in bs]) \
+                if any(b.y is not None for b in bs) else None
         records = sum(b.n_valid for b in bs)
         self._ensure_state(bs[0].x)
 
@@ -398,46 +423,47 @@ class Trainer:
         if fused == "always" and not use_fused:
             raise ValueError("fused fit unsupported for this model/optimizer/"
                              "slice size")
-        # device leg: transfer + compiled program + the one sync below —
-        # measured through the device_get because dispatch is async and
-        # the program is not "done" until the host observes its results
-        t_dev = time.perf_counter()
         # which fit ran rides the history: a caller that believes it is
         # on the compiled kernel can see when it is not
         interpret = use_fused and fused_train.interpret_mode()
-        if use_fused:
-            xs, masks = jax.device_put((xs, masks))
-            self.state, losses, accs = fused_train.fused_fit(
-                self.state, xs, masks, epochs,
-                lr=self.learning_rate, l1=activity_l1, interpret=interpret)
-        else:
-            scanned = scanned_fit_cached(self.model, self.tx, self.supervised,
-                                         tx_key=self._tx_key)
-            if any(b.y is not None for b in bs):
-                ys = np.stack([b.y if b.y is not None else b.x for b in bs])
-                xs, ys, masks = jax.device_put((xs, ys, masks))
-            else:
-                # autoencoder mode targets the input itself: reuse the
-                # transferred xs instead of shipping a byte-identical copy
-                xs, masks = jax.device_put((xs, masks))
-                ys = xs
-            self.state, (losses, accs) = scanned(self.state, xs, ys, masks,
-                                                 epochs)
-        obs_metrics.records_trained.inc(records * epochs)
-        if tracing.ENABLED and hasattr(batches, "take_traces"):
-            # the whole fit ran as one device program: per-record close
-            # lands here, after the scan — the e2e span includes the
-            # compiled fit, which is exactly what ingest-to-train means
-            # for this path
-            for ctx in batches.take_traces():
-                ctx.close("train")
-        # ONE sync for both metric vectors: each device_get blocks on the
-        # device, and the second would wait on nothing new
-        losses, accs = (np.asarray(a)
-                        for a in jax.device_get((losses, accs)))
-        obs_metrics.step_seconds.observe(time.perf_counter() - t_dev,
-                                         loop="train",
-                                         phase="device_compute")
+        # device leg: transfer + compiled program + the one sync below —
+        # measured through the device_get because dispatch is async and
+        # the program is not "done" until the host observes its results.
+        # Its three parts time the HOST's calls (no extra sync is added):
+        # what the device does under each is the profiler trace's to show
+        with tracing.phase("train", "device_compute"):
+            with tracing.phase("train", "transfer"):
+                if use_fused or ys is None:
+                    xs, masks = jax.device_put((xs, masks))
+                    ys = xs
+                else:
+                    xs, ys, masks = jax.device_put((xs, ys, masks))
+            with tracing.phase("train", "dispatch"):
+                if use_fused:
+                    self.state, losses, accs = fused_train.fused_fit(
+                        self.state, xs, masks, epochs,
+                        lr=self.learning_rate, l1=activity_l1,
+                        interpret=interpret)
+                else:
+                    scanned = scanned_fit_cached(
+                        self.model, self.tx, self.supervised,
+                        tx_key=self._tx_key)
+                    self.state, (losses, accs) = scanned(
+                        self.state, xs, ys, masks, epochs)
+            obs_metrics.records_trained.inc(records * epochs)
+            if tracing.ENABLED and hasattr(batches, "take_traces"):
+                # the whole fit ran as one device program: per-record
+                # close lands here, after the scan — the e2e span
+                # includes the compiled fit, which is exactly what
+                # ingest-to-train means for this path
+                for ctx in batches.take_traces():
+                    ctx.close("train")
+            with tracing.phase("train", "sync"):
+                # ONE sync for both metric vectors: each device_get
+                # blocks on the device, and the second would wait on
+                # nothing new
+                losses, accs = (np.asarray(a)
+                                for a in jax.device_get((losses, accs)))
         dt = time.perf_counter() - t0
         return {"loss": losses.tolist(), "accuracy": accs.tolist(),
                 "records": [records] * epochs, "seconds": [dt / epochs] * epochs,

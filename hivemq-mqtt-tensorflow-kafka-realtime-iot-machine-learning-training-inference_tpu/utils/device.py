@@ -4,7 +4,8 @@ A chip belongs to one process at a time, and JAX falls back to the CPU
 without a word when it cannot start an accelerator.  Entry points that
 own a device call `claim_device()` once, before any other JAX work: it
 initialises the backend, refuses a CPU nobody asked for, places the
-persistent compile cache, and returns what the process got so banners
+persistent compile cache, starts counting compilations
+(`iotml_compile_seconds`), and returns what the process got so banners
 and stats lines can name it.  `python -m iotml.utils.device` prints that
 report plus the installed versions as one JSON line.
 """
@@ -51,6 +52,73 @@ def enable_compile_cache() -> Optional[str]:
     return path
 
 
+#: JAX's monitoring events → `stage` of iotml_compile_seconds
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_RESULTS = {"/jax/compilation_cache/cache_hits": "hit",
+                  "/jax/compilation_cache/cache_misses": "miss"}
+_listening = False
+
+
+def listen_for_compiles() -> None:
+    """Book what JAX reports of its own compilations into
+    `iotml_compile_seconds{stage,program}` and
+    `iotml_compile_cache_total{result}`; registered once a process (JAX
+    has no unregister).  `program` is the jitted function's name where
+    it is one of the program's own (`iotml_*`, train/loop.py), else
+    "other", so the label set is bounded.  JAX times a cache read
+    without naming the program; the read happens inside that program's
+    `backend` event, on the same thread, so it is held until that event
+    names it."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import threading
+
+    import jax.monitoring
+
+    from ..obs import metrics as obs_metrics
+
+    pending = threading.local()
+
+    def program_of(kw) -> str:
+        # "iotml_scanned_fit" when traced, "jit(iotml_scanned_fit)"
+        # when lowered and compiled
+        name = str(kw.get("fun_name", ""))
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        return name if name.startswith("iotml_") else "other"
+
+    def on_duration(event: str, seconds: float, **kw) -> None:
+        if event == _CACHE_READ:
+            pending.read_s = seconds
+            return
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        program = program_of(kw)
+        obs_metrics.compile_seconds.observe(seconds, stage=stage,
+                                            program=program)
+        read_s = getattr(pending, "read_s", None)
+        if stage == "backend" and read_s is not None:
+            pending.read_s = None
+            obs_metrics.compile_seconds.observe(read_s, stage="cache_read",
+                                                program=program)
+
+    def on_event(event: str, **kw) -> None:
+        result = _CACHE_RESULTS.get(event)
+        if result is not None:
+            obs_metrics.compile_cache.inc(result=result)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
 def claim_device() -> dict:
     """Initialise this process's backend and report it.
 
@@ -66,6 +134,7 @@ def claim_device() -> dict:
         raise RuntimeError(
             "JAX found no accelerator and fell back to the CPU; set "
             "JAX_PLATFORMS=cpu to run on the host on purpose")
+    listen_for_compiles()
     return {"platform": first.platform, "device_kind": first.device_kind,
             "count": len(devices),
             "compile_cache_dir": enable_compile_cache()}

@@ -384,7 +384,9 @@ def test_rest_concurrency_guard_sheds_with_503():
             urllib.request.urlopen(f"{srv.url}/ping", timeout=5)
         assert ei.value.code == 503
         assert ei.value.headers["Connection"] == "close"
-        assert rest_requests.value(route="(guard)", code=503) == base + 1
+        # the counter lands after the response's bytes: await it
+        _await(lambda: rest_requests.value(route="(guard)", code=503)
+               == base + 1, what="the shed connection counted")
         # freeing a slot readmits new connections
         held.pop().close()
         _await(lambda: srv.active_connections() == 1,
