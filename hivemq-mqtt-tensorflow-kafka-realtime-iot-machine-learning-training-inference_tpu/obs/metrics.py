@@ -436,6 +436,19 @@ compile_seconds = default_registry.histogram(
 compile_cache = default_registry.counter(
     "iotml_compile_cache_total",
     "persistent compile cache lookups by result (hit | miss)")
+# the flash kernels' step geometry (ops/attention.py `flash_geometry`),
+# set at trace time by each pallas_call site: what the last compiled
+# call of each kernel (fwd | bwd_dkv | bwd_dq) engaged.
+flash_grid_steps = default_registry.gauge(
+    "iotml_flash_grid_steps",
+    "grid steps a call of a flash-attention kernel, by kernel")
+flash_block_q = default_registry.gauge(
+    "iotml_flash_block_q", "query rows a flash kernel's tile holds")
+flash_block_k = default_registry.gauge(
+    "iotml_flash_block_k", "keys a flash kernel's tile holds")
+flash_heads_per_step = default_registry.gauge(
+    "iotml_flash_heads_per_step",
+    "heads of the folded B*H axis one grid step of a flash kernel handles")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "
@@ -470,7 +483,7 @@ ALLOWED_LABEL_KEYS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
     "unit", "le", "slo", "window", "shard", "route", "code", "program",
-    "result",
+    "result", "kernel",
 })
 
 #: per-metric ceiling on distinct label-value combinations.  Generous —
@@ -496,6 +509,10 @@ DECLARED_METRIC_LABELS = {
     "consumer_autoresets": ("topic",),
     "consumer_lag_records": ("group", "partition", "topic"),
     "dlq_total": ("source",),
+    "flash_block_k": ("kernel",),
+    "flash_block_q": ("kernel",),
+    "flash_grid_steps": ("kernel",),
+    "flash_heads_per_step": ("kernel",),
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),

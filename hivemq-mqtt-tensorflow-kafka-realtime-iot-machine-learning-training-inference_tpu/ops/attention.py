@@ -9,8 +9,9 @@ never contemplated.  The attention stack here:
 - `attention_reference`: straight jnp softmax attention — the oracle for
   every other path, and the XLA-fused fallback on CPU.
 - `flash_attention`: blocked online-softmax attention as a Pallas TPU
-  kernel — O(T) memory instead of O(T²), MXU-shaped [128×128] tiles, the
-  single-chip hot op of the transformer model family.
+  kernel — O(T) memory instead of O(T²), tiles and heads a grid step
+  derived from the shape (`flash_geometry`), the single-chip hot op of
+  the transformer model family.
 - `blockwise_update`: one online-softmax accumulation step, shared between
   the flash kernel's inner loop (conceptually) and the ring-attention
   cross-chip loop (`parallel.ring_attention`), which is the same math with
@@ -19,6 +20,7 @@ never contemplated.  The attention stack here:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Optional
@@ -142,65 +144,216 @@ def finalize_blockwise(o, l):
     return o / denom
 
 
+# ------------------------------------------------------------------- geometry
+#: the three kernels the rule knows, by the suffix of their trace names
+KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashGeometry:
+    """What one grid step of one flash kernel does, and the grid that
+    follows from it: `heads` of the folded B·H axis a step, one
+    [block_q, block_k] tile of scores each."""
+    block_q: int
+    block_k: int
+    heads: int
+    t_q: int          # query length padded to block_q
+    t_k: int          # key length padded to block_k
+    tri: bool         # triangular grid (live causal tiles only) or dense
+    tiles: int        # grid tiles a head (the live ones when `tri`)
+    grid_steps: int   # grid steps a call: B·H / heads × tiles
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _geometry(T: int, bh: int, causal: bool, block_q: int, block_k: int,
+              heads: int) -> FlashGeometry:
+    """The grid that `(block_q, block_k, heads)` names at length T."""
+    t_q, t_k = _round_up(T, block_q), _round_up(T, block_k)
+    nq, nk = t_q // block_q, t_k // block_k
+    live = _tri_tile_count(nq, nk, block_q, block_k) if causal else nq * nk
+    # past the cap the scalar-prefetch maps outgrow SMEM-class storage:
+    # the dense grid has O(1) metadata and skips dead tiles by pl.when
+    tri = causal and live <= _TRI_TILE_CAP
+    tiles = live if tri else nq * nk
+    return FlashGeometry(block_q, block_k, heads, t_q, t_k, tri, tiles,
+                         bh // heads * tiles)
+
+
+#: scoped VMEM a kernel may take on the chip: Mosaic's default limit on
+#: a v5e, which no call here raises
+_VMEM_BUDGET = 16 * 2 ** 20
+#: the float32 [block_q, block_k] arrays a tile's arithmetic holds at
+#: once, counted from the kernels' bodies: the forward's scores and
+#: probabilities; the backward's probabilities, dP and dS.  (The mask's
+#: iotas and compare are consumed as they are made.)  Heads of one step
+#: run one after the other and reuse them.
+_TILE_TEMPS = {"fwd": 2, "bwd_dkv": 3, "bwd_dq": 3}
+#: the largest block the rule derives, and the cap on a block named for
+#: the backward kernels: at 2048² the temporaries alone are 32-48 MiB
+_MAX_BLOCK = 1024
+#: heads of B·H one grid step may handle
+_HEADS = (1, 2, 4, 8)
+#: what a call costs, in µs on a v5e: a grid step (DMA set-up, index
+#: maps, pl.when bookkeeping); a 128×128 unit of tile area (the
+#: products and the elementwise softmax work); a 128-row chunk of the
+#: q side a tile (the forward's m/l/acc rescale, the backward's q, dO,
+#: lse and delta streaming in); a 128-key chunk of the kv side a tile.
+#: Fitted to PR 25's sweep of each kernel alone over blocks
+#: {128..1024}² × heads {1..8} at three shapes — (B·H, T, D) = (64,
+#: 1024, 64) and (256, 256, 64) in float32, (2, 65536, 128) in bfloat16
+#: — one set for all three: the kernels are bound by stepping and
+#: vector work, not by the products, so neither dtype nor head width
+#: moves the constants far.  PERF.md §6 (PR 25) has the table.
+_COST_US = {"fwd": (0.25, 0.030, 0.28, 0.025),
+            "bwd_dkv": (0.31, 0.040, 0.21, 0.075),
+            "bwd_dq": (0.26, 0.043, 0.078, 0.062)}
+
+
+def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
+                itemsize: int) -> int:
+    """Scoped VMEM one grid step needs, counted: every operand's block
+    twice (the pipeline double-buffers), the float32 scratch, and the
+    tile's temporaries.  The minor dimension of a block pads to 128
+    lanes, so a [block_q, 1] row statistic is as wide as a q block."""
+    lanes = _round_up(D, 128)
+    q_blk, k_blk = heads * block_q * lanes, heads * block_k * lanes
+    stat = heads * block_q * 128 * 4
+    if kernel == "fwd":
+        piped = (2 * q_blk + 2 * k_blk) * itemsize + stat   # q, o; k, v; lse
+        scratch = q_blk * 4 + 2 * stat                      # acc; m, l
+    elif kernel == "bwd_dkv":
+        piped = (2 * q_blk + 4 * k_blk) * itemsize + 2 * stat
+        scratch = 2 * k_blk * 4                             # dk, dv
+    else:
+        piped = (3 * q_blk + 2 * k_blk) * itemsize + 2 * stat
+        scratch = q_blk * 4                                 # dq
+    return 2 * piped + scratch + _TILE_TEMPS[kernel] * block_q * block_k * 4
+
+
+def _cost_us(kernel: str, geom: FlashGeometry, bh: int) -> float:
+    step, unit, q_chunk, k_chunk = _COST_US[kernel]
+    nbq, nbk = geom.block_q // 128, geom.block_k // 128
+    return geom.grid_steps * step + bh * geom.tiles * (
+        nbq * nbk * unit + nbq * q_chunk + nbk * k_chunk)
+
+
+def flash_geometry(kernel: str, T: int, D: int, itemsize: int, bh: int,
+                   causal: bool, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> FlashGeometry:
+    """The step geometry of one flash kernel (`fwd`, `bwd_dkv`,
+    `bwd_dq`), from what the code can see at trace time — the one place
+    that knows tile sizes.
+
+    A block left `None` is derived: of the multiples of 128 that divide
+    T padded to 128 (up to `_MAX_BLOCK`) and the heads a step that
+    divide B·H, the geometry whose counted VMEM fits `_VMEM_BUDGET` and
+    whose modelled time (`_COST_US`) is least.  Larger tiles save grid
+    steps and per-chunk state and compute more of the causal triangle's
+    dead area (at T = 1,024: 128² 36 units of 128² in 36 steps a head,
+    512² 48 in 3, 1024² 64 in 1); more heads a step save steps with no
+    such waste, which is what short windows need.  A block that is
+    named is honoured as it stands — one head a step, the backward
+    kernels capped at `_MAX_BLOCK` — and only the other is derived."""
+    named = block_q is not None, block_k is not None
+    cap = float("inf") if kernel == "fwd" else _MAX_BLOCK
+    n = _round_up(T, 128) // 128
+    derived = [128 * m for m in range(1, _MAX_BLOCK // 128 + 1) if n % m == 0]
+    geoms = [_geometry(T, bh, causal, bq, bk, h)
+             for bq in ([min(block_q, cap)] if named[0] else derived)
+             for bk in ([min(block_k, cap)] if named[1] else derived)
+             for h in ((1,) if any(named) else _HEADS) if bh % h == 0]
+    if not all(named):
+        # the first is the smallest: what is left when nothing fits, for
+        # the compiler to refuse
+        geoms = [g for g in geoms if _vmem_bytes(
+            kernel, g.block_q, g.block_k, g.heads, D, itemsize)
+            <= _VMEM_BUDGET] or geoms[:1]
+    return min(geoms, key=lambda g: _cost_us(kernel, g, bh))
+
+
 # --------------------------------------------------------------------- pallas
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
-                  scale: float, causal: bool, block_q: int, block_k: int):
-    """Flash attention kernel.  Grid: (batch*heads, q_blocks, kv_blocks) —
-    the kv dimension iterates sequentially on-core, so K/V stream through
-    VMEM one [block_k, D] tile at a time (O(T) VMEM, long-context safe) and
-    the online-softmax state lives in scratch that persists across the kv
-    iterations of one q block."""
+def _tile_positions(i, j, block_q: int, block_k: int):
+    """(qi, kj): global row and column positions of tile (i, j)."""
+    qi = jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0) + i * block_q
+    kj = jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1) + j * block_k
+    return qi, kj
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _fwd_tile(q, k, v, mask, m, l, acc, *, scale: float):
+    """One head's tile of the forward, as values: the online-softmax
+    update of (m, l, acc) against one [block_k, D] tile of K/V.  Jitted
+    so that its body is traced once a shape and not once a head, a
+    layer and a kernel: the nested call is inlined when the kernel
+    lowers.
+
+    Every product takes its operands at the INPUT dtype and accumulates
+    in float32; the scale is applied to the f32 scores afterwards.  P is
+    computed in f32 (softmax stability) then cast to the input dtype for
+    P·V — the standard flash-attention trade for bf16 inputs (exact QK
+    products, a P rounding below the bf16 output's own); for float32
+    inputs the cast is the identity and how Mosaic runs a float32
+    product is its default (PERF.md §7)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alive = m_new > NEG_INF / 2
+    corr = jnp.where(alive, jnp.exp(m - m_new), 0.0)
+    p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_new = acc * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
+              acc, m_s, l_s, *, scale: float, causal: bool, block_q: int,
+              block_k: int):
+    """One grid step of the forward: tile (i, j) of every head in the
+    block.  K/V stream through VMEM one [block_k, D] tile at a time
+    (O(T) VMEM, long-context safe); the online-softmax state lives in
+    scratch that persists across the kv tiles of one q block.  `first`
+    and `last` say whether (i, j) opens or closes its q row, `live`
+    (dense causal grid only) whether the tile holds any past key."""
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
     def compute():
-        # EVERY matmul rides the MXU at the INPUT dtype (bf16 inputs →
-        # bf16 systolic passes at ~4× the f32 rate, f32 ACCUMULATION
-        # always).  QK's bf16 products are exact (inputs are bf16); the
-        # scale is applied to the f32 scores afterwards.  P is computed in
-        # f32 (softmax stability) then cast to the input dtype for P·V —
-        # the standard flash-attention trade: an f32 P·V matmul runs at ¼
-        # the MXU rate and capped this kernel's whole-step MFU at ~33%
-        # (see ARCHITECTURE.md roofline); the bf16 P rounding (~3 decimal
-        # digits) is below the bf16 output's own quantization.
-        s = jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        # the elementwise causal mask runs on EVERY tile even though only
+        # diagonal-straddling tiles need it: branch-specializing it
+        # behind a lax.cond was measured slower and, in the backward,
+        # the duplicated branch temporaries overflowed scoped VMEM at
+        # 1024² tiles (ARCHITECTURE §5).  One mask a step, shared by
+        # the step's heads.
+        mask = None
         if causal:
-            qi = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + i * block_q
-            kj = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + j * block_k
-            s = jnp.where(qi >= kj, s, NEG_INF)
-        m = m_s[:]
-        l = l_s[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alive = m_new > NEG_INF / 2
-        corr = jnp.where(alive, jnp.exp(m - m_new), 0.0)
-        p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
-        m_s[:] = m_new
-        l_s[:] = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            qi, kj = _tile_positions(i, j, block_q, block_k)
+            mask = qi >= kj
+        for g in range(q_ref.shape[0]):
+            m_s[g], l_s[g], acc[g] = _fwd_tile(
+                q_ref[g], k_ref[g], v_ref[g], mask, m_s[g], l_s[g], acc[g],
+                scale=scale)
 
-    if causal:
-        # whole KV block strictly in the future of this q block → skip
-        @pl.when(j * block_k <= i * block_q + (block_q - 1))
-        def _():
-            compute()
-    else:
+    if live is None:
         compute()
+    else:
+        # whole KV block strictly in the future of this q block → skip
+        pl.when(live)(compute)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _emit():
         l = l_s[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -209,496 +362,365 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         lse_ref[:] = jnp.where(l == 0.0, NEG_INF, m_s[:] + jnp.log(safe_l))
 
 
-def _flash_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc, m_s, l_s, *, scale: float, block_q: int,
-                      block_k: int, nk: int):
-    """Causal flash forward on the TRIANGULAR grid: the grid's second
-    axis walks only the live lower-triangle tiles (row-major), with the
-    (i, j) tile coordinates arriving via scalar prefetch.  Strictly-future
-    tiles no longer exist, so they pay neither their K/V DMA nor a grid
-    step (the dense grid's `pl.when` skip still paid both — measured at
-    ≈½ a computed tile, ARCHITECTURE.md roofline lever 2)."""
-    from jax.experimental import pallas as pl
-
-    t = pl.program_id(1)
-    i = im_ref[t]
-    j = jm_ref[t]
-
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-
-    # same math + dtype policy as _flash_kernel (see its comment): bf16
-    # systolic passes, f32 accumulation, f32 softmax, P cast for P·V
-    s = jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-
-    # the elementwise causal mask runs on EVERY tile even though only
-    # diagonal-straddling tiles need it: branch-specializing it behind a
-    # lax.cond was MEASURED SLOWER (54.2% vs 57.7% MFU same-session —
-    # the cond defeats Mosaic's fusion/pipelining and, in the backward,
-    # the duplicated branch temporaries blow the 16 MB scoped-VMEM
-    # budget at 1024^2 tiles).  Roofline lever 3 stays on the table via
-    # cheaper masks, not control flow.
-    qi = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) + i * block_q
-    kj = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1) + j * block_k
-    s = jnp.where(qi >= kj, s, NEG_INF)
-    m = m_s[:]
-    l = l_s[:]
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    alive = m_new > NEG_INF / 2
-    corr = jnp.where(alive, jnp.exp(m - m_new), 0.0)
-    p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
-    m_s[:] = m_new
-    l_s[:] = l * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc[:] = acc[:] * corr + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    # last live tile of this q row = the diagonal block
-    jmax = jnp.minimum(nk - 1, (i * block_q + block_q - 1) // block_k)
-
-    @pl.when(j == jmax)
-    def _emit():
-        lf = l_s[:]
-        safe_l = jnp.where(lf == 0.0, 1.0, lf)
-        o_ref[:] = (acc[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[:] = jnp.where(lf == 0.0, NEG_INF,
-                               m_s[:] + jnp.log(safe_l))
-
-
-def _flash_forward(q, k, v, causal: bool, block_q: int,
-                   block_k: int, interpret: bool):
-    """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T])."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    Tq = ((T + block_q - 1) // block_q) * block_q
-    Tk = ((T + block_k - 1) // block_k) * block_k
-    if not causal and Tk != T:
-        # padded keys are only excluded by the causal mask; non-causal
-        # callers must supply block-multiple sequence lengths
-        raise ValueError(f"non-causal flash attention needs T % {block_k} == 0")
-    if Tq != T:
-        pad = [(0, 0), (0, Tq - T), (0, 0), (0, 0)]
-        q = jnp.pad(q, pad)
-    if Tk != T:
-        pad = [(0, 0), (0, Tk - T), (0, 0), (0, 0)]
-        # pad keys so padded positions never win the max: values 0, and the
-        # causal mask (global positions) excludes them for every real query
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
-
-    # layout: fold batch & heads into the grid's first axis, T-major blocks
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-
-    out_shape = [
-        jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32),
-    ]
-    scratch_shapes = [
-        pltpu.VMEM((block_q, D), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-    ]
-    tri = causal and _tri_tile_count(Tq // block_q, Tk // block_k,
-                                     block_q, block_k) <= _TRI_TILE_CAP
-    if tri:
-        # triangular grid: only live tiles exist (see _flash_kernel_tri)
-        im, jm = _causal_tiles(Tq // block_q, Tk // block_k,
-                               block_q, block_k, "row")
-        kernel = functools.partial(_flash_kernel_tri, scale=scale,
-                                   block_q=block_q, block_k=block_k,
-                                   nk=Tk // block_k)
-        out, lse = pl.pallas_call(
-            kernel,
-            name=FWD_KERNEL,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B * H, len(im)),
-                in_specs=[
-                    pl.BlockSpec((None, block_q, D),
-                                 lambda b, t, im, jm: (b, im[t], 0)),
-                    pl.BlockSpec((None, block_k, D),
-                                 lambda b, t, im, jm: (b, jm[t], 0)),
-                    pl.BlockSpec((None, block_k, D),
-                                 lambda b, t, im, jm: (b, jm[t], 0)),
-                ],
-                out_specs=[
-                    pl.BlockSpec((None, block_q, D),
-                                 lambda b, t, im, jm: (b, im[t], 0)),
-                    pl.BlockSpec((None, block_q, 1),
-                                 lambda b, t, im, jm: (b, im[t], 0)),
-                ],
-                scratch_shapes=scratch_shapes,
-            ),
-            out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(jnp.asarray(im), jnp.asarray(jm), qf, kf, vf)
-    else:
-        kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                                   block_q=block_q, block_k=block_k)
-        out, lse = pl.pallas_call(
-            kernel,
-            name=FWD_KERNEL,
-            grid=(B * H, Tq // block_q, Tk // block_k),
-            in_specs=[
-                pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
-            ],
-            out_shape=out_shape,
-            scratch_shapes=scratch_shapes,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-        )(qf, kf, vf)
-    out = out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)[:, :T]
-    lse = lse.reshape(B, H, Tq)[:, :, :T]
-    return out, lse
-
-
-def _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-                scale, causal, block_q, block_k, t_real, i, j):
-    """Shared recompute for both backward kernels: returns (p, ds) f32.
-
-    Matmul dtype policy mirrors the forward: score/dP matmuls run at the
-    input dtype (exact products for bf16, MXU bf16 rate, f32 accumulate);
-    p/ds stay f32 — they are exp-of-f32 quantities the gradient
-    tolerances pin.  The mask runs on every tile: branch-specializing it
-    (lax.cond on straddle/tail tiles) was measured slower AND blew the
-    scoped-VMEM budget at 1024^2 tiles — see the forward kernel's note."""
-    s = jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    qi = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) + i * block_q
-    kj = jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1) + j * block_k
+def _bwd_mask(i, j, *, causal, block_q, block_k, t_real):
+    qi, kj = _tile_positions(i, j, block_q, block_k)
     mask = kj < t_real
     if causal:
         mask = mask & (qi >= kj)
-    p = jnp.where(mask, jnp.exp(s - lse_ref[:]), 0.0)
-    dp = jax.lax.dot_general(do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+    return mask
+
+
+def _bwd_tile(q, k, v, do, lse, delta, mask, scale):
+    """Shared recompute for both backward kernels, one head's tile:
+    returns (p, ds) in float32.
+
+    Dtype policy mirrors the forward: the score and dP products take
+    their operands at the input dtype and accumulate in float32; p/ds
+    stay f32 — they are exp-of-f32 quantities the gradient tolerances
+    pin.  The mask runs on every tile (see the forward's note)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[:]) * scale
+    ds = p * (dp - delta) * scale
     return p, ds
 
 
-def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                          causal, block_q, block_k, t_real):
-    """dK/dV: grid (BH, kv_blocks, q_blocks) — for one kv block, stream
-    the q blocks through VMEM accumulating dk/dv in scratch; p never
-    touches HBM (the jnp fallback's bandwidth wall)."""
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _dkv_tile(q, k, v, do, lse, delta, mask, dk, dv, *, scale: float):
+    """One head's tile of dK/dV, as values (jitted as `_fwd_tile` is):
+    p/ds cast to the input dtype, f32 accumulation."""
+    p, ds = _bwd_tile(q, k, v, do, lse, delta, mask, scale)
+    dv = dv + jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dk = dk + jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return dk, dv
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _dq_tile(q, k, v, do, lse, delta, mask, dq, *, scale: float):
+    """One head's tile of dQ, as values."""
+    _, ds = _bwd_tile(q, k, v, do, lse, delta, mask, scale)
+    return dq + jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
+              k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
+              block_q, block_k, t_real):
+    """One grid step of dK/dV: for one kv block, the q blocks stream
+    through VMEM accumulating dk/dv in scratch; p never touches HBM.
+    On the triangular grid a column entirely in the future of every
+    query keeps one dead diagonal tile whose mask zeroes p/ds, so its
+    dk/dv block is still zero-written (see _causal_tiles)."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
-    i = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(i == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def compute():
-        p, ds = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            scale=scale, causal=causal, block_q=block_q,
-                            block_k=block_k, t_real=t_real, i=i, j=j)
-        # p/ds cast to the input dtype: bf16 MXU passes with f32
-        # accumulation (see the forward's dtype-policy note + the
-        # ARCHITECTURE.md roofline — f32 operand matmuls were the MFU cap)
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
+                         block_k=block_k, t_real=t_real)
+        for g in range(q_ref.shape[0]):
+            dk_acc[g], dv_acc[g] = _dkv_tile(
+                q_ref[g], k_ref[g], v_ref[g], do_ref[g], lse_ref[g],
+                delta_ref[g], mask, dk_acc[g], dv_acc[g], scale=scale)
 
-    if causal:
+    if live is None:
+        compute()
+    else:
         # q blocks strictly before this kv block contribute nothing
-        @pl.when(i * block_q + (block_q - 1) >= j * block_k)
-        def _():
-            compute()
-    else:
-        compute()
+        pl.when(live)(compute)
 
-    @pl.when(i == nq - 1)
+    @pl.when(last)
     def _emit():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, *, scale, causal, block_q,
-                         block_k, t_real):
-    """dQ: grid (BH, q_blocks, kv_blocks) — one q block accumulates over
-    its (causally relevant) kv blocks."""
+def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
+             k_ref, v_ref, dq_ref, dq_acc, *, scale, causal, block_q,
+             block_k, t_real):
+    """One grid step of dQ: one q block accumulates over its (causally
+    relevant) kv blocks."""
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def compute():
-        _, ds = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            scale=scale, causal=causal, block_q=block_q,
-                            block_k=block_k, t_real=t_real, i=i, j=j)
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
+                         block_k=block_k, t_real=t_real)
+        for g in range(q_ref.shape[0]):
+            dq_acc[g] = _dq_tile(
+                q_ref[g], k_ref[g], v_ref[g], do_ref[g], lse_ref[g],
+                delta_ref[g], mask, dq_acc[g], scale=scale)
 
-    if causal:
-        @pl.when(j * block_k <= i * block_q + (block_q - 1))
-        def _():
-            compute()
-    else:
+    if live is None:
         compute()
+    else:
+        pl.when(live)(compute)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _emit():
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, do_ref, lse_ref,
-                              delta_ref, k_ref, v_ref, dk_ref, dv_ref,
-                              dk_acc, dv_acc, *, scale, block_q, block_k,
-                              t_real, nq):
-    """dK/dV on the triangular grid: column-major live tiles (the scratch
-    accumulates q blocks within one kv column).  A column entirely in the
-    future of every query keeps one dead diagonal tile whose mask zeroes
-    p/ds, so its dk/dv block is still zero-written (see _causal_tiles)."""
+def _dense_kernel(*refs, step, kv_outer: bool, causal: bool, block_q: int,
+                  block_k: int, **static):
+    """A step function on the dense grid (B·H/heads, outer, inner): the
+    inner axis iterates sequentially on-core — kv blocks for the
+    forward and dQ, q blocks for dK/dV (`kv_outer`)."""
+    from jax.experimental import pallas as pl
+
+    outer, inner = pl.program_id(1), pl.program_id(2)
+    i, j = (inner, outer) if kv_outer else (outer, inner)
+    live = j * block_k <= i * block_q + (block_q - 1) if causal else None
+    step(i, j, inner == 0, inner == pl.num_programs(2) - 1, live, *refs,
+         causal=causal, block_q=block_q, block_k=block_k, **static)
+
+
+def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
+                block_k: int, nq: int, nk: int, **static):
+    """A step function on the TRIANGULAR grid: the grid's second axis
+    walks only the live lower-triangle tiles (row-major for the forward
+    and dQ, column-major for dK/dV), the (i, j) tile coordinates
+    arriving via scalar prefetch.  Strictly-future tiles do not exist,
+    so they pay neither their DMA nor a grid step."""
     from jax.experimental import pallas as pl
 
     t = pl.program_id(1)
     i = im_ref[t]
     j = jm_ref[t]
-    imin = jnp.minimum(nq - 1, (j * block_k) // block_q)
-
-    @pl.when(i == imin)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    p, ds = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        scale=scale, causal=True, block_q=block_q,
-                        block_k=block_k, t_real=t_real, i=i, j=j)
-    dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-        p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[:], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(i == nq - 1)
-    def _emit():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+    if kv_outer:
+        # first live q block of this kv column … the last q block
+        first = i == jnp.minimum(nq - 1, (j * block_k) // block_q)
+        last = i == nq - 1
+    else:
+        # first kv block … the diagonal block of this q row
+        first = j == 0
+        last = j == jnp.minimum(nk - 1,
+                                (i * block_q + block_q - 1) // block_k)
+    step(i, j, first, last, None, *refs, causal=True, block_q=block_q,
+         block_k=block_k, **static)
 
 
-def _flash_bwd_dq_kernel_tri(im_ref, jm_ref, q_ref, do_ref, lse_ref,
-                             delta_ref, k_ref, v_ref, dq_ref, dq_acc, *,
-                             scale, block_q, block_k, t_real, nk):
-    """dQ on the triangular grid: row-major live tiles (one q block
-    accumulates its causally-relevant kv blocks)."""
-    from jax.experimental import pallas as pl
-
-    t = pl.program_id(1)
-    i = im_ref[t]
-    j = jm_ref[t]
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    _, ds = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        scale=scale, causal=True, block_q=block_q,
-                        block_k=block_k, t_real=t_real, i=i, j=j)
-    dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    jmax = jnp.minimum(nk - 1, (i * block_q + block_q - 1) // block_k)
-
-    @pl.when(j == jmax)
-    def _emit():
-        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
-
-
-# backward tile cap: 1024² measured fastest on v5e (the three [bq, bk]
-# f32 temporaries fit VMEM; 2048² fails to compile) — sweep in PARITY
-_BWD_CAP = 1024
-
-
-def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
-                    block_k: int, interpret: bool):
-    """Pallas flash-attention backward: the standard two-kernel split
-    (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
-    recomputed blockwise in VMEM, never materialized to HBM)."""
+def _flash_grid(kernel: str, geom: FlashGeometry, step, *, bh: int, D: int,
+                kv_outer: bool, causal: bool, ins: str, outs: str,
+                scratch, **static):
+    """(kernel function, grid spec, compiler params, prefetch operands)
+    of one flash kernel on the grid `geom` names — and the record of
+    that geometry (`iotml_flash_*{kernel}`, at trace time).  `ins` and
+    `outs` give each operand's side and width: Q/K a [heads, block, D]
+    block along the q or the kv axis, q a [heads, block_q, 1] row
+    statistic."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    G, bq, bk = geom.heads, geom.block_q, geom.block_k
+    nq, nk = geom.t_q // bq, geom.t_k // bk
+    if geom.tri:
+        im, jm = _causal_tiles(nq, nk, bq, bk, "col" if kv_outer else "row")
+        prefetch = (jnp.asarray(im), jnp.asarray(jm))
+        grid = (bh // G, len(im))
+        qmap = lambda b, t, im, jm: (b, im[t], 0)  # noqa: E731
+        kmap = lambda b, t, im, jm: (b, jm[t], 0)  # noqa: E731
+        fn = functools.partial(_tri_kernel, step=step, kv_outer=kv_outer,
+                               block_q=bq, block_k=bk, nq=nq, nk=nk,
+                               **static)
+    else:
+        prefetch = ()
+        # the grid's last two axes arrive as (outer, inner)
+        grid = (bh // G, nk, nq) if kv_outer else (bh // G, nq, nk)
+        qmap = lambda b, o, n: (b, n if kv_outer else o, 0)  # noqa: E731
+        kmap = lambda b, o, n: (b, o if kv_outer else n, 0)  # noqa: E731
+        fn = functools.partial(_dense_kernel, step=step, kv_outer=kv_outer,
+                               causal=causal, block_q=bq, block_k=bk,
+                               **static)
+    assert math.prod(grid) == geom.grid_steps, (grid, geom)
+    _record_geometry(kernel, geom)
+    blocks = {"Q": pl.BlockSpec((G, bq, D), qmap),
+              "q": pl.BlockSpec((G, bq, 1), qmap),
+              "K": pl.BlockSpec((G, bk, D), kmap)}
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=grid,
+        in_specs=[blocks[c] for c in ins],
+        out_specs=[blocks[c] for c in outs],
+        scratch_shapes=scratch)
+    params = pltpu.CompilerParams(dimension_semantics=(
+        "parallel",) + ("arbitrary",) * (len(grid) - 1))
+    return fn, grid_spec, params, prefetch
+
+
+def _record_geometry(kernel: str, geom: FlashGeometry) -> None:
+    """Say what engaged: Python at trace time, once a compilation, no
+    cost in the step.  The last traced call of each kernel stands."""
+    from ..obs import metrics as obs_metrics
+
+    obs_metrics.flash_grid_steps.set(geom.grid_steps, kernel=kernel)
+    obs_metrics.flash_block_q.set(geom.block_q, kernel=kernel)
+    obs_metrics.flash_block_k.set(geom.block_k, kernel=kernel)
+    obs_metrics.flash_heads_per_step.set(geom.heads, kernel=kernel)
+
+
+def _fold(x):
+    """[B, T, H, D] → [B·H, T, D]: batch and heads folded into the
+    grid's first axis, T-major blocks."""
+    B, T, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+
+def _pad_t(x, t_pad: int, pad_value=0.0):
+    T = x.shape[1]
+    if t_pad == T:
+        return x
+    return jnp.pad(x, [(0, 0), (0, t_pad - T), (0, 0)],
+                   constant_values=pad_value)
+
+
+def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
+               interpret: bool):
+    """The forward of folded, unpadded operands ([B·H, T, D]) on
+    `geom`: (out [B·H, t_q, D], lse [B·H, t_q, 1])."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, D = qf.shape
+    G, bq, Tq, Tk = geom.heads, geom.block_q, geom.t_q, geom.t_k
+    if not causal and Tk != T:
+        # padded keys are only excluded by the causal mask; non-causal
+        # callers must supply block-multiple sequence lengths
+        raise ValueError(
+            f"non-causal flash attention needs T % {geom.block_k} == 0")
+    fn, grid_spec, params, prefetch = _flash_grid(
+        "fwd", geom, _fwd_step, bh=BH, D=D, kv_outer=False, causal=causal,
+        ins="QKK", outs="Qq",
+        scratch=[pltpu.VMEM((G, bq, D), jnp.float32),
+                 pltpu.VMEM((G, bq, 1), jnp.float32),
+                 pltpu.VMEM((G, bq, 1), jnp.float32)],
+        scale=1.0 / math.sqrt(D))
+    # padded keys never win the max: values 0, and the causal mask
+    # (global positions) excludes them for every real query
+    return pl.pallas_call(
+        fn, name=FWD_KERNEL, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
+                   jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)],
+        compiler_params=params, interpret=interpret,
+    )(*prefetch, _pad_t(qf, Tq), _pad_t(kf, Tk), _pad_t(vf, Tk))
+
+
+def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
+                   block_k: Optional[int], interpret: bool):
+    """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T])."""
     B, T, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    # independent backward tile sizes (see _BWD_CAP)
-    bq = min(block_q, _BWD_CAP)
-    bk = min(block_k, _BWD_CAP)
-    Tq = ((T + bq - 1) // bq) * bq
-    Tk = ((T + bk - 1) // bk) * bk
+    geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B * H, causal,
+                          block_q, block_k)
+    out, lse = _flash_fwd(_fold(q), _fold(k), _fold(v), causal, geom,
+                          interpret)
+    out = out.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
+    lse = lse.reshape(B, H, -1)[:, :, :T]
+    return out, lse
 
-    do_f = do.astype(jnp.float32)
+
+def _pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom: FlashGeometry):
+    """The backward's operands padded to `geom`.  Padded q rows take a
+    +BIG lse → p = exp(s - BIG) = 0, so they contribute nothing to
+    dk/dv and their dq rows are sliced off; padded keys are masked by
+    `t_real`."""
+    Tq, Tk = geom.t_q, geom.t_k
+    return (_pad_t(qf, Tq), _pad_t(dof, Tq), _pad_t(lse_f, Tq, 1e30),
+            _pad_t(delta_f, Tq), _pad_t(kf, Tk), _pad_t(vf, Tk))
+
+
+def _flash_bwd_dkv(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
+                   geom: FlashGeometry, interpret: bool):
+    """dK/dV of folded, unpadded operands ([B·H, T, D]; lse and delta
+    [B·H, T, 1]) on `geom`: ([B·H, t_k, D],) × 2."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, D = qf.shape
+    G, bk, Tk = geom.heads, geom.block_k, geom.t_k
+    fn, grid_spec, params, prefetch = _flash_grid(
+        "bwd_dkv", geom, _dkv_step, bh=BH, D=D, kv_outer=True,
+        causal=causal, ins="QQqqKK", outs="KK",
+        scratch=[pltpu.VMEM((G, bk, D), jnp.float32),
+                 pltpu.VMEM((G, bk, D), jnp.float32)],
+        scale=1.0 / math.sqrt(D), t_real=T)
+    return pl.pallas_call(
+        fn, name=BWD_DKV_KERNEL, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), kf.dtype),
+                   jax.ShapeDtypeStruct((BH, Tk, D), vf.dtype)],
+        compiler_params=params, interpret=interpret,
+    )(*prefetch, *_pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom))
+
+
+def _flash_bwd_dq(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
+                  geom: FlashGeometry, interpret: bool):
+    """dQ of the same operands on `geom`: [B·H, t_q, D]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, D = qf.shape
+    G, bq, Tq = geom.heads, geom.block_q, geom.t_q
+    fn, grid_spec, params, prefetch = _flash_grid(
+        "bwd_dq", geom, _dq_step, bh=BH, D=D, kv_outer=False,
+        causal=causal, ins="QQqqKK", outs="Q",
+        scratch=[pltpu.VMEM((G, bq, D), jnp.float32)],
+        scale=1.0 / math.sqrt(D), t_real=T)
+    (dq_f,) = pl.pallas_call(
+        fn, name=BWD_DQ_KERNEL, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype)],
+        compiler_params=params, interpret=interpret,
+    )(*prefetch, *_pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom))
+    return dq_f
+
+
+def _flash_backward(q, k, v, out, lse, do, causal: bool,
+                    block_q: Optional[int], block_k: Optional[int],
+                    interpret: bool):
+    """Pallas flash-attention backward: the standard two-kernel split
+    (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
+    recomputed blockwise in VMEM, never materialized to HBM), each
+    kernel on its own geometry."""
+    B, T, H, D = q.shape
     # rowwise D_i = sum_d dO_i·O_i (softmax-jacobian diagonal term)
-    delta = jnp.einsum("bqhd,bqhd->bhq", do_f, out.astype(jnp.float32))
+    delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    folded = (_fold(q), _fold(do), lse.reshape(B * H, T, 1),
+              delta.reshape(B * H, T, 1), _fold(k), _fold(v))
+    dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B * H, causal,
+                              block_q, block_k)
+               for kernel in ("bwd_dkv", "bwd_dq"))
+    dk_f, dv_f = _flash_bwd_dkv(*folded, causal, dkv, interpret)
+    dq_f = _flash_bwd_dq(*folded, causal, dq, interpret)
 
-    def fold_q(x, pad_value=0.0):
-        x = x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-        return jnp.pad(x, [(0, 0), (0, Tq - T), (0, 0)],
-                       constant_values=pad_value)
+    def unfold(x):
+        return x.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
 
-    qf = fold_q(q)
-    dof = fold_q(do)
-    kf = jnp.pad(k.transpose(0, 2, 1, 3).reshape(B * H, T, D),
-                 [(0, 0), (0, Tk - T), (0, 0)])
-    vf = jnp.pad(v.transpose(0, 2, 1, 3).reshape(B * H, T, D),
-                 [(0, 0), (0, Tk - T), (0, 0)])
-    # padded q rows: +BIG lse → p = exp(s - BIG) = 0, so they contribute
-    # nothing to dk/dv and their dq rows are sliced off
-    lse_f = jnp.pad(lse.reshape(B * H, T, 1),
-                    [(0, 0), (0, Tq - T), (0, 0)],
-                    constant_values=1e30)
-    delta_f = jnp.pad(delta.reshape(B * H, T, 1),
-                      [(0, 0), (0, Tq - T), (0, 0)])
-
-    q_spec_i = pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))
-    q_spec_j = pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0))
-    r_spec_i = pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0))
-    r_spec_j = pl.BlockSpec((None, bq, 1), lambda b, j, i: (b, i, 0))
-    kv_spec_i = pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0))
-    kv_spec_j = pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))
-
-    dkv_out_shape = [jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-                     jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype)]
-    dkv_scratch = [pltpu.VMEM((bk, D), jnp.float32),
-                   pltpu.VMEM((bk, D), jnp.float32)]
-    tri = causal and _tri_tile_count(Tq // bq, Tk // bk,
-                                     bq, bk) <= _TRI_TILE_CAP
-    if tri:
-        imc, jmc = _causal_tiles(Tq // bq, Tk // bk, bq, bk, "col")
-        dkv_kernel = functools.partial(
-            _flash_bwd_dkv_kernel_tri, scale=scale, block_q=bq,
-            block_k=bk, t_real=T, nq=Tq // bq)
-        q_tri = pl.BlockSpec((None, bq, D),
-                             lambda b, t, im, jm: (b, im[t], 0))
-        r_tri = pl.BlockSpec((None, bq, 1),
-                             lambda b, t, im, jm: (b, im[t], 0))
-        kv_tri = pl.BlockSpec((None, bk, D),
-                              lambda b, t, im, jm: (b, jm[t], 0))
-        dk_f, dv_f = pl.pallas_call(
-            dkv_kernel,
-            name=BWD_DKV_KERNEL,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B * H, len(imc)),
-                in_specs=[q_tri, q_tri, r_tri, r_tri, kv_tri, kv_tri],
-                out_specs=[kv_tri, kv_tri],
-                scratch_shapes=dkv_scratch,
-            ),
-            out_shape=dkv_out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(jnp.asarray(imc), jnp.asarray(jmc), qf, dof, lse_f, delta_f,
-          kf, vf)
-    else:
-        dkv_kernel = functools.partial(
-            _flash_bwd_dkv_kernel, scale=scale, causal=causal, block_q=bq,
-            block_k=bk, t_real=T)
-        dk_f, dv_f = pl.pallas_call(
-            dkv_kernel,
-            name=BWD_DKV_KERNEL,
-            grid=(B * H, Tk // bk, Tq // bq),
-            in_specs=[q_spec_j, q_spec_j, r_spec_j, r_spec_j,
-                      kv_spec_j, kv_spec_j],
-            out_specs=[pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-                       pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))],
-            out_shape=dkv_out_shape,
-            scratch_shapes=dkv_scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-        )(qf, dof, lse_f, delta_f, kf, vf)
-
-    if tri:
-        imr, jmr = _causal_tiles(Tq // bq, Tk // bk, bq, bk, "row")
-        dq_kernel = functools.partial(
-            _flash_bwd_dq_kernel_tri, scale=scale, block_q=bq,
-            block_k=bk, t_real=T, nk=Tk // bk)
-        dq_f = pl.pallas_call(
-            dq_kernel,
-            name=BWD_DQ_KERNEL,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B * H, len(imr)),
-                in_specs=[q_tri, q_tri, r_tri, r_tri, kv_tri, kv_tri],
-                out_specs=q_tri,
-                scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            ),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(jnp.asarray(imr), jnp.asarray(jmr), qf, dof, lse_f, delta_f,
-          kf, vf)
-    else:
-        dq_kernel = functools.partial(
-            _flash_bwd_dq_kernel, scale=scale, causal=causal, block_q=bq,
-            block_k=bk, t_real=T)
-        dq_f = pl.pallas_call(
-            dq_kernel,
-            name=BWD_DQ_KERNEL,
-            grid=(B * H, Tq // bq, Tk // bk),
-            in_specs=[q_spec_i, q_spec_i, r_spec_i, r_spec_i,
-                      kv_spec_i, kv_spec_i],
-            out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-        )(qf, dof, lse_f, delta_f, kf, vf)
-
-    def unfold(x, Tp):
-        return x.reshape(B, H, Tp, D).transpose(0, 2, 1, 3)[:, :T]
-
-    return unfold(dq_f, Tq), unfold(dk_f, Tk), unfold(dv_f, Tk)
+    return unfold(dq_f), unfold(dk_f), unfold(dv_f)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+def flash_attention(q, k, v, causal: bool = True,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, interpret: bool = False):
     """Pallas flash attention. q,k,v: [B, T, H, D] → [B, T, H, D].
 
-    T is padded to the block size internally (padding keys are masked out by
-    the causal structure; non-causal callers must pass T multiple of the
-    block).  `interpret=True` runs the same kernel on CPU for tests.
+    `block_q`/`block_k` left `None` are derived from the shape, each of
+    the three kernels its own (`flash_geometry`); a value given is
+    honoured as it stands.  T is padded to the block size internally
+    (padding keys are masked out by the causal structure; non-causal
+    callers must pass T multiple of the block).  `interpret=True` runs
+    the same kernel on CPU for tests.
 
     Differentiable via custom VJP: the forward kernel emits the per-row
     log-sum-exp; the backward is the standard two-kernel Pallas split

@@ -59,3 +59,21 @@ def test_flash_attention_fwd_bwd_lowers_for_v5e(v5e):
         return jnp.sum(flash_attention(q, k, v, True, 128, 128, False))
 
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4, 1024, 16, 64), jnp.float32),     # the benchmark's cell
+    ((16, 256, 16, 64), jnp.float32),     # the short window
+    ((1, 65536, 2, 128), jnp.bfloat16),   # bench.py's long context
+])
+def test_flash_attention_derived_tiles_lower_for_v5e(v5e, shape, dtype):
+    """Tiles left to the rule: a geometry that overflows scoped VMEM, or
+    that Mosaic refuses, fails here and not on the chip."""
+    qkv = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+           for _ in range(3)]
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()

@@ -387,7 +387,9 @@ def test_every_pallas_call_in_ops_is_named():
                      and getattr(n.func, "attr", None) == "pallas_call"
                      for n in ast.walk(tree))
         assert [f for f in lint_file(path) if f.rule == "R17"] == [], path
-    assert calls >= 7
+    # the three flash kernels (a dense and a triangular grid each behind
+    # one call since PR 25) and the fused fit
+    assert calls >= 4
     from iotml.ops import attention
 
     assert (attention.FWD_KERNEL, attention.BWD_DKV_KERNEL,

@@ -89,3 +89,28 @@ def test_sp_gradients_match_dense_oracle():
     got = jax.device_get(state.params)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_tracing_a_flash_model_records_the_kernels_geometry():
+    """`iotml_flash_grid_steps{kernel}` and its neighbours say what each
+    kernel's last compiled call engaged: one value a kernel, the grid
+    the rule implies."""
+    from iotml.obs.metrics import default_registry
+    from iotml.ops import attention
+
+    B, T, H, D = 2, 300, 2, 16
+    flash = SensorFormer(features=18, d_model=H * D, num_heads=H,
+                         num_layers=1, attn_mode="flash_interpret")
+    x = jnp.asarray(_x(B=B, T=T))
+    params = flash.init(jax.random.PRNGKey(2), x)["params"]
+    jax.grad(lambda p: jnp.sum(flash.apply({"params": p}, x)))(params)
+    got = default_registry.collect()
+    for kernel in attention.KERNELS:
+        g = attention.flash_geometry(kernel, T, D, 4, B * H, True)
+        for name, want in (("grid_steps", g.grid_steps),
+                           ("block_q", g.block_q), ("block_k", g.block_k),
+                           ("heads_per_step", g.heads)):
+            assert got[f'iotml_flash_{name}{{kernel="{kernel}"}}'] == want
+    assert sorted(k for k in got if k.startswith("iotml_flash_grid_steps")) \
+        == sorted(f'iotml_flash_grid_steps{{kernel="{k}"}}'
+                  for k in attention.KERNELS)
