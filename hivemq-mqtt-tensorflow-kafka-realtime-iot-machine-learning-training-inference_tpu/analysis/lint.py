@@ -182,7 +182,7 @@ _ALLOWED_METRIC_LABELS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
     "unit", "le", "slo", "window", "shard", "route", "code", "program",
-    "result", "kernel",
+    "result", "kernel", "kind",
 })
 
 RULES: Dict[str, str] = {

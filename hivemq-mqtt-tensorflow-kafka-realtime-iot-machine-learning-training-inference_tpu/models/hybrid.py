@@ -1,0 +1,195 @@
+"""SensorHybrid: a layer stack of two kinds — Mamba-2 state-space mixers
+beside grouped-query attention — over long per-car sensor histories.
+
+The block is IBM Granite 4.0-H's (`model_type` granitemoehybrid, dense):
+`layer_types` names each layer's mixer in order, every layer is
+
+    h ← h + r · mixer(RMSNorm(h))        h ← h + r · mlp(RMSNorm(h))
+
+with the residual multiplier r, a gated-SiLU MLP, weight-only RMSNorm and
+no bias on any projection.  The Mamba-2 mixer projects to a gate z, a
+convolved stream xBC and a step Δ per head, runs the selective
+state-space recurrence in its chunked form (`ops.ssd.ssd_scan`), gates,
+normalises and projects back; the attention mixer has fewer key/value
+heads than query heads, no positional encoding (the state-space layers
+carry order) and a softmax scale of its own.  One sensor record is one
+position: `Dense(features → d_model)` in, `Dense(d_model → features)`
+out, where a language model has its vocabulary.
+
+Every block is recomputed in the backward pass (`nn.remat`): a window of
+thousands of positions keeps one [T, d_model] input a block instead of
+each block's projections, decay tiles and MLP activations.
+
+The recurrent state (ssm_heads × ssm_head_dim × ssm_state a layer and
+sequence) starts at zero at the window's start; carrying it from window
+to window, and one-step decoding against it, is the scorer's later work
+(ROADMAP M2).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..obs import metrics as obs_metrics
+from ..ops.attention import attention_reference, flash_attention
+from ..ops.ssd import causal_conv1d, ssd_scan
+
+KINDS = ("mamba", "attention")
+_normal = nn.initializers.normal(0.02)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """The stack's widths, under the names the text above uses; small
+    defaults for tests, Granite 4.0-H Micro's in `benchmark/configs/`."""
+
+    d_model: int = 64
+    layer_types: Tuple[str, ...] = ("mamba", "attention", "mamba")
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    mlp_dim: int = 128
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16
+    ssm_state: int = 8
+    conv_width: int = 4
+    chunk: int = 8
+    eps: float = 1e-5
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """a = -exp(A_log) uniform in [-16, -1]: Mamba-2's usual range."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus(dt_bias) log-uniform in [1e-3, 1e-1]: steps that keep a
+    state alive for tens to thousands of positions."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                    math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))   # the inverse of softplus
+
+
+def _dense(features: int, name: str):
+    return nn.Dense(features, use_bias=False, kernel_init=_normal, name=name)
+
+
+class MambaMixer(nn.Module):
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        B, T, _ = u.shape
+        H, P, N = m.ssm_heads, m.ssm_head_dim, m.ssm_state
+        inner = H * P
+        with jax.named_scope("ssm_proj"):
+            z, xbc, dt = jnp.split(
+                _dense(2 * inner + 2 * N + H, "in_proj")(u),
+                [inner, 2 * inner + 2 * N], axis=-1)
+        with jax.named_scope("conv"):
+            xbc = nn.silu(causal_conv1d(
+                xbc,
+                self.param("conv_kernel", _normal,
+                           (m.conv_width, inner + 2 * N)),
+                self.param("conv_bias", nn.initializers.zeros,
+                           (inner + 2 * N,))))
+        with jax.named_scope("ssd"):
+            x, b, c = jnp.split(xbc, [inner, inner + N], axis=-1)
+            x = x.reshape(B, T, H, P)
+            dt = nn.softplus(dt + self.param("dt_bias", _dt_bias_init, (H,)))
+            a = -jnp.exp(self.param("A_log", _a_log_init, (H,)))
+            y = ssd_scan(x, dt, a, b, c, m.chunk)
+            y = y + x * self.param("D", nn.initializers.ones, (H,))[:, None]
+        with jax.named_scope("gate_norm"):
+            # one group: the gate first, then the norm over all channels
+            y = nn.RMSNorm(epsilon=m.eps, name="norm")(
+                y.reshape(B, T, inner) * nn.silu(z))
+        with jax.named_scope("ssm_proj"):
+            return _dense(m.d_model, "out_proj")(y)
+
+
+class GroupedAttention(nn.Module):
+    cfg: HybridConfig
+    attn_mode: str   # dense | flash | flash_interpret
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        B, T, _ = u.shape
+        H, G = m.num_heads, m.num_kv_heads
+        D = m.d_model // H
+        q = _dense(H * D, "q")(u).reshape(B, T, H, D)
+        k = _dense(G * D, "k")(u).reshape(B, T, G, D)
+        v = _dense(G * D, "v")(u).reshape(B, T, G, D)
+        if self.attn_mode == "dense":
+            o = attention_reference(q, k, v, causal=True,
+                                    scale=m.attention_multiplier)
+        elif self.attn_mode in ("flash", "flash_interpret"):
+            o = flash_attention(
+                q, k, v, causal=True, scale=m.attention_multiplier,
+                interpret=self.attn_mode == "flash_interpret")
+        else:
+            raise ValueError(f"unknown attn_mode {self.attn_mode}")
+        return _dense(m.d_model, "o")(o.reshape(B, T, H * D))
+
+
+class HybridBlock(nn.Module):
+    kind: str
+    cfg: HybridConfig
+    attn_mode: str
+
+    @nn.compact
+    def __call__(self, h):
+        m = self.cfg
+        u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
+        if self.kind == "mamba":
+            mixed = MambaMixer(m, name="mixer")(u)
+        else:
+            with jax.named_scope("attn"):
+                mixed = GroupedAttention(m, self.attn_mode, name="mixer")(u)
+        h = h + m.residual_multiplier * mixed
+        with jax.named_scope("mlp"):
+            gate, value = jnp.split(
+                _dense(2 * m.mlp_dim, "mlp_in")(
+                    nn.RMSNorm(epsilon=m.eps, name="norm2")(h)), 2, axis=-1)
+            out = _dense(m.d_model, "mlp_out")(nn.silu(gate) * value)
+        return h + m.residual_multiplier * out
+
+
+class SensorHybrid(nn.Module):
+    """Next-record prediction over [B, T, features]."""
+
+    cfg: HybridConfig = HybridConfig()
+    features: int = 18
+    attn_mode: str = "dense"
+
+    @nn.compact
+    def __call__(self, x):
+        m = self.cfg
+        unknown = set(m.layer_types) - set(KINDS)
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}; "
+                             f"known kinds are {KINDS}")
+        # what engaged, at trace time (as the flash geometry is said)
+        for kind in KINDS:
+            obs_metrics.model_layers.set(m.layer_types.count(kind),
+                                         kind=kind)
+        obs_metrics.remat_blocks.set(len(m.layer_types))
+        h = m.embedding_multiplier * nn.Dense(
+            m.d_model, kernel_init=_normal, name="embed")(x)
+        block = nn.remat(HybridBlock)
+        for i, kind in enumerate(m.layer_types):
+            h = block(kind, m, self.attn_mode, name=f"layer{i}")(h)
+        h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)
+        return nn.Dense(self.features, kernel_init=_normal,
+                        name="head")(h) / m.logits_scaling

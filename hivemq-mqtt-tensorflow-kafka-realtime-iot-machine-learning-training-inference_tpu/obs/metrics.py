@@ -449,6 +449,22 @@ flash_block_k = default_registry.gauge(
 flash_heads_per_step = default_registry.gauge(
     "iotml_flash_heads_per_step",
     "heads of the folded B*H axis one grid step of a flash kernel handles")
+# the chunked state-space scan (ops/ssd.py) and the hybrid model's layer
+# stack (models/hybrid.py), set at trace time like the flash geometry:
+# what the last traced scan and model engaged.
+ssd_chunk_size = default_registry.gauge(
+    "iotml_ssd_chunk_size", "positions a chunk of the state-space scan holds")
+ssd_chunks = default_registry.gauge(
+    "iotml_ssd_chunks", "chunks a sequence of the state-space scan is cut in")
+ssd_state_bytes = default_registry.gauge(
+    "iotml_ssd_state_bytes",
+    "bytes of recurrent state one sequence holds in one state-space layer")
+model_layers = default_registry.gauge(
+    "iotml_model_layers",
+    "layers of the last traced hybrid model, by kind (mamba | attention)")
+remat_blocks = default_registry.gauge(
+    "iotml_remat_blocks",
+    "blocks of the last traced model recomputed in the backward pass")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "
@@ -483,7 +499,7 @@ ALLOWED_LABEL_KEYS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
     "unit", "le", "slo", "window", "shard", "route", "code", "program",
-    "result", "kernel",
+    "result", "kernel", "kind",
 })
 
 #: per-metric ceiling on distinct label-value combinations.  Generous —
@@ -516,6 +532,7 @@ DECLARED_METRIC_LABELS = {
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),
+    "model_layers": ("kind",),
     "model_offsets_lag": ("component",),
     "model_version": ("component",),
     "online_adaptations": ("action",),

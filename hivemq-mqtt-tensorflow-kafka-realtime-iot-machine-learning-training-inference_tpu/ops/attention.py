@@ -96,14 +96,32 @@ def _tri_tile_count(nq: int, nk: int, block_q: int, block_k: int) -> int:
     return int((jmax + 1).sum())
 
 
+def _repeat_kv(q, k, v):
+    """k and v with fewer heads than q (grouped-query attention), each
+    repeated over its group of query heads; the gradient sums back over
+    the group through the repeat.  Equal heads pass through untouched."""
+    H, Hkv = q.shape[2], k.shape[2]
+    if Hkv == H:
+        return k, v
+    if H % Hkv or v.shape[2] != Hkv:
+        raise ValueError(f"{H} query heads do not group over {Hkv} "
+                         f"key/{v.shape[2]} value heads")
+    return jnp.repeat(k, H // Hkv, axis=2), jnp.repeat(v, H // Hkv, axis=2)
+
+
 def attention_reference(q, k, v, causal: bool = True,
-                        q_offset: int = 0, k_offset: int = 0):
-    """Plain softmax attention. q,k,v: [B, T, H, D] → [B, Tq, H, D].
+                        q_offset: int = 0, k_offset: int = 0,
+                        scale: Optional[float] = None):
+    """Plain softmax attention. q: [B, T, H, D], k,v: [B, T, Hkv, D]
+    with Hkv dividing H → [B, Tq, H, D].
 
     q_offset/k_offset give the global positions of local blocks so the
-    causal mask stays correct under sequence sharding.
+    causal mask stays correct under sequence sharding.  `scale`
+    multiplies the scores; left `None` it is 1/√D.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    k, v = _repeat_kv(q, k, v)
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         qpos = q_offset + jnp.arange(q.shape[1])[:, None]
@@ -587,7 +605,7 @@ def _pad_t(x, t_pad: int, pad_value=0.0):
 
 
 def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
-               interpret: bool):
+               interpret: bool, scale: float):
     """The forward of folded, unpadded operands ([B·H, T, D]) on
     `geom`: (out [B·H, t_q, D], lse [B·H, t_q, 1])."""
     from jax.experimental import pallas as pl
@@ -606,7 +624,7 @@ def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
         scratch=[pltpu.VMEM((G, bq, D), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32)],
-        scale=1.0 / math.sqrt(D))
+        scale=scale)
     # padded keys never win the max: values 0, and the causal mask
     # (global positions) excludes them for every real query
     return pl.pallas_call(
@@ -618,13 +636,13 @@ def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
-                   block_k: Optional[int], interpret: bool):
+                   block_k: Optional[int], interpret: bool, scale: float):
     """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T])."""
     B, T, H, D = q.shape
     geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B * H, causal,
                           block_q, block_k)
     out, lse = _flash_fwd(_fold(q), _fold(k), _fold(v), causal, geom,
-                          interpret)
+                          interpret, scale)
     out = out.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
     lse = lse.reshape(B, H, -1)[:, :, :T]
     return out, lse
@@ -641,7 +659,7 @@ def _pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom: FlashGeometry):
 
 
 def _flash_bwd_dkv(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
-                   geom: FlashGeometry, interpret: bool):
+                   geom: FlashGeometry, interpret: bool, scale: float):
     """dK/dV of folded, unpadded operands ([B·H, T, D]; lse and delta
     [B·H, T, 1]) on `geom`: ([B·H, t_k, D],) × 2."""
     from jax.experimental import pallas as pl
@@ -654,7 +672,7 @@ def _flash_bwd_dkv(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
         causal=causal, ins="QQqqKK", outs="KK",
         scratch=[pltpu.VMEM((G, bk, D), jnp.float32),
                  pltpu.VMEM((G, bk, D), jnp.float32)],
-        scale=1.0 / math.sqrt(D), t_real=T)
+        scale=scale, t_real=T)
     return pl.pallas_call(
         fn, name=BWD_DKV_KERNEL, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), kf.dtype),
@@ -664,7 +682,7 @@ def _flash_bwd_dkv(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
 
 
 def _flash_bwd_dq(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
-                  geom: FlashGeometry, interpret: bool):
+                  geom: FlashGeometry, interpret: bool, scale: float):
     """dQ of the same operands on `geom`: [B·H, t_q, D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -675,7 +693,7 @@ def _flash_bwd_dq(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
         "bwd_dq", geom, _dq_step, bh=BH, D=D, kv_outer=False,
         causal=causal, ins="QQqqKK", outs="Q",
         scratch=[pltpu.VMEM((G, bq, D), jnp.float32)],
-        scale=1.0 / math.sqrt(D), t_real=T)
+        scale=scale, t_real=T)
     (dq_f,) = pl.pallas_call(
         fn, name=BWD_DQ_KERNEL, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype)],
@@ -686,7 +704,7 @@ def _flash_bwd_dq(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
 
 def _flash_backward(q, k, v, out, lse, do, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
-                    interpret: bool):
+                    interpret: bool, scale: float):
     """Pallas flash-attention backward: the standard two-kernel split
     (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
     recomputed blockwise in VMEM, never materialized to HBM), each
@@ -700,8 +718,8 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B * H, causal,
                               block_q, block_k)
                for kernel in ("bwd_dkv", "bwd_dq"))
-    dk_f, dv_f = _flash_bwd_dkv(*folded, causal, dkv, interpret)
-    dq_f = _flash_bwd_dq(*folded, causal, dq, interpret)
+    dk_f, dv_f = _flash_bwd_dkv(*folded, causal, dkv, interpret, scale)
+    dq_f = _flash_bwd_dq(*folded, causal, dq, interpret, scale)
 
     def unfold(x):
         return x.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
@@ -709,18 +727,45 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     return unfold(dq_f), unfold(dk_f), unfold(dv_f)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, interpret, scale):
+    """`flash_attention` of equal heads: the differentiable core."""
+    out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                            scale)
+    return out
+
+
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret, scale):
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                              scale)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd_rule(causal, block_q, block_k, interpret, scale, res, do):
+    q, k, v, out, lse = res
+    return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
+                           interpret, scale)
+
+
+_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None, interpret: bool = False):
-    """Pallas flash attention. q,k,v: [B, T, H, D] → [B, T, H, D].
+                    block_k: Optional[int] = None, interpret: bool = False,
+                    scale: Optional[float] = None):
+    """Pallas flash attention. q: [B, T, H, D], k,v: [B, T, Hkv, D] with
+    Hkv dividing H → [B, T, H, D].
 
     `block_q`/`block_k` left `None` are derived from the shape, each of
     the three kernels its own (`flash_geometry`); a value given is
     honoured as it stands.  T is padded to the block size internally
     (padding keys are masked out by the causal structure; non-causal
     callers must pass T multiple of the block).  `interpret=True` runs
-    the same kernel on CPU for tests.
+    the same kernel on CPU for tests.  `scale` multiplies the scores;
+    left `None` it is 1/√D.  Fewer key/value heads than query heads
+    (grouped-query attention) are repeated over their groups ahead of
+    the kernels, which see equal heads.
 
     Differentiable via custom VJP: the forward kernel emits the per-row
     log-sum-exp; the backward is the standard two-kernel Pallas split
@@ -728,19 +773,7 @@ def flash_attention(q, k, v, causal: bool = True,
     block) with blockwise probability recompute in VMEM — O(T·block)
     memory and no HBM round trip for the probability matrices.
     """
-    out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    return out
-
-
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd_rule(causal, block_q, block_k, interpret, res, do):
-    q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
-                           interpret)
-
-
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+    k, v = _repeat_kv(q, k, v)
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return _flash(q, k, v, causal, block_q, block_k, interpret, scale)

@@ -1,0 +1,263 @@
+"""The hybrid stack (Mamba-2 state-space mixers beside grouped-query
+attention): the chunked scan against the step-by-step recurrence, the
+model and one compiled job against the benchmark's plain reference
+(loaded by path, as `benchmark/tests` loads it), grouped heads and an
+explicit scale through the flash kernels, and what the trace-time
+counters say.  All at a tiny preset on the CPU."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from iotml.models.hybrid import HybridConfig, SensorHybrid
+from iotml.ops.attention import attention_reference, flash_attention
+from iotml.ops.ssd import causal_conv1d, ssd_scan
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = os.path.join(ROOT, "benchmark", "configs",
+                      "sensorformer-granite-4.0-h-micro")
+#: width 64, 4 heads of 16 over 2 key/value heads, 4 state heads of 16
+#: (hence the expansion of 1), state 8, chunk 8
+TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            shared_intermediate_size=128, mamba_n_heads=4, mamba_d_head=16,
+            mamba_expand=1, mamba_d_state=8, mamba_chunk_size=8,
+            num_hidden_layers=3,
+            layer_types=["mamba", "attention", "mamba"])
+
+
+@pytest.fixture(scope="module")
+def ref():
+    """The configuration's plain reference at the tiny preset."""
+    spec = importlib.util.spec_from_file_location("bench_granite_reference",
+                                                  CONFIG + ".py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(CONFIG + ".json") as fh:
+        cfg = json.load(fh)
+    cfg.update(TINY)
+    mod.use(cfg)
+    return mod
+
+
+def _scan_inputs(B, T, H=4, P=16, N=8, seed=0, dt_scale=1.0):
+    rng = np.random.default_rng(seed)
+    f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    return (f32(B, T, H, P), dt_scale * jax.nn.softplus(f32(B, T, H)),
+            -jnp.exp(f32(H)), f32(B, T, N), f32(B, T, N))
+
+
+def _close(got, want, rtol=2e-4):
+    """Within `rtol` of the reference's largest entry, leaf by leaf."""
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(float(jnp.abs(w).max()), 1e-30)
+        assert float(jnp.abs(g - w).max()) <= rtol * scale
+
+
+# ------------------------------------------------------------- the scan
+@pytest.mark.parametrize("T,B,chunk", [
+    (32, 2, 8),     # a multiple of the chunk
+    (32, 1, 16),
+    (21, 2, 8),     # not a multiple: padded with Δ = 0
+    (43, 1, 16),
+    (5, 2, 8),      # shorter than one chunk
+    (8, 3, 8),      # exactly one chunk
+])
+def test_chunked_scan_matches_the_recurrence(ref, T, B, chunk):
+    args = _scan_inputs(B, T, seed=T)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[0].shape),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        # one compilation a side: the value and all five gradients
+        got, want = (jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(w * f(*a)), argnums=range(5)))(*args)
+            for f in (lambda *a: ssd_scan(*a, chunk), ref._recurrence))
+        _close(jax.jit(lambda *a: ssd_scan(*a, chunk))(*args),
+               jax.jit(ref._recurrence)(*args))
+    _close(got, want)
+
+
+def test_chunked_scan_takes_decays_a_naive_exp_would_overflow(ref):
+    """Δ·|a| sums to thousands within a chunk: exp(-cum_s) alone is
+    inf in float32, the masked difference never leaves [0, 1]."""
+    x, dt, a, b, c = _scan_inputs(1, 64, seed=3, dt_scale=20.0)
+    a = a - 5.0
+    assert float(jnp.sum(dt * -a, axis=1).max()) > 1000.0
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(x, dt, a, b, c, 32)
+        want = jax.jit(ref._recurrence)(x, dt, a, b, c)
+        g = jax.jit(jax.grad(lambda *v: jnp.sum(ssd_scan(*v, 32)),
+                             argnums=range(5)))(x, dt, a, b, c)
+    assert all(bool(jnp.isfinite(v).all()) for v in (got, *g))
+    _close(got, want)
+
+
+def test_causal_conv_matches_the_grouped_convolution():
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(2, 13, 24)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 24)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(24,)), jnp.float32)
+    want = jax.lax.conv_general_dilated(
+        x, w[:, None, :], (1,), [(3, 0)],
+        dimension_numbers=("NWC", "WIO", "NWC"),
+        feature_group_count=24) + bias
+    _close(causal_conv1d(x, w, bias), want, rtol=1e-6)
+    # causal: a later input moves no earlier output
+    moved = causal_conv1d(x.at[:, 9:].set(0.0), w, bias)
+    np.testing.assert_array_equal(np.asarray(moved[:, :9]),
+                                  np.asarray(causal_conv1d(x, w, bias)[:, :9]))
+
+
+# ------------------------------------------------------------ the model
+def _batch(B=2, T=21, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
+            jnp.ones((B,), jnp.float32))
+
+
+@pytest.mark.parametrize("attn_mode", ["dense", "flash_interpret"])
+def test_model_loss_and_gradients_match_the_reference(ref, attn_mode):
+    from iotml.train.loop import make_loss_fn
+
+    model = SensorHybrid(ref.hybrid_config(ref.CFG), attn_mode=attn_mode)
+    params = ref.init_params(3)
+    x, y, mask = _batch()
+    made = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
+    assert jax.tree.structure(made) == jax.tree.structure(params)
+    loss = make_loss_fn(model, supervised=True)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda p: loss(p, x, y, mask)[0]))(params)
+        want = jax.jit(jax.value_and_grad(ref.loss_fn))(params, x, y, mask)
+    _close(got, want)
+
+
+def test_one_compiled_job_matches_the_references_adam(ref):
+    """`Trainer.fit_compiled` over four batches and two epochs: the
+    parameters' change, both moments and the epoch losses."""
+    from iotml.data.dataset import Batch
+    from iotml.train.loop import Trainer
+
+    trainer = Trainer(SensorHybrid(ref.hybrid_config(ref.CFG)),
+                      supervised=True, learning_rate=1e-3)
+    batches = [_batch(seed=s) for s in range(4)]
+    params0 = ref.init_params(11)
+    trainer._ensure_state(batches[0][0])
+    trainer.state = trainer.state.replace(params=params0)
+    params0 = jax.device_get(params0)
+    ref.CFG["model"]["optimizer"]["learning_rate"] = 1e-3
+    with jax.default_matmul_precision("highest"):
+        history = trainer.fit_compiled(
+            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2, first_index=0)
+             for x, y, _ in batches], epochs=2)
+        p, mu, nu, losses = ref.make_fit(ref.loss_fn, 2)(
+            params0, *(jnp.stack(v) for v in zip(*batches)))
+    assert history["fit"] == "scanned"
+    np.testing.assert_allclose(history["loss"], np.asarray(losses),
+                               rtol=1e-5)
+    adam = trainer.state.opt_state[0]
+    sub = lambda a, b: jax.tree.map(lambda u, v: u - v, a, b)  # noqa: E731
+    _close(sub(trainer.state.params, params0), sub(p, params0), rtol=2e-3)
+    _close(adam.mu, mu, rtol=2e-3)
+    _close(adam.nu, nu, rtol=2e-3)
+
+
+def test_layer_types_are_data_of_the_model():
+    x = _batch()[0]
+    for kinds in (("attention",), ("mamba", "mamba"),
+                  ("mamba", "attention", "mamba", "attention")):
+        model = SensorHybrid(HybridConfig(layer_types=kinds))
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), x)["params"]
+        for i, kind in enumerate(kinds):
+            assert ("A_log" in params[f"layer{i}"]["mixer"]) \
+                == (kind == "mamba")
+        assert jax.jit(model.apply)({"params": params}, x).shape == x.shape
+    with pytest.raises(ValueError, match="known kinds"):
+        SensorHybrid(HybridConfig(layer_types=("mamba", "lstm"))).init(
+            jax.random.PRNGKey(0), x)
+
+
+def test_a_tiny_fit_says_what_engaged():
+    """The trace-time counters after a fit: the scan's chunking, the
+    state a sequence holds, the layers by kind, the recomputed blocks."""
+    from iotml.data.dataset import Batch
+    from iotml.obs.metrics import default_registry
+    from iotml.train.loop import Trainer
+
+    cfg = HybridConfig(layer_types=("mamba", "attention", "mamba", "mamba"))
+    x, y, _ = _batch(T=21)
+    Trainer(SensorHybrid(cfg), supervised=True).fit_compiled(
+        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2, first_index=0)],
+        epochs=1)
+    got = default_registry.collect()
+    assert got["iotml_ssd_chunk_size"] == cfg.chunk
+    assert got["iotml_ssd_chunks"] == 3          # 21 positions in eights
+    assert got["iotml_ssd_state_bytes"] == \
+        cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+    assert got['iotml_model_layers{kind="mamba"}'] == 3
+    assert got['iotml_model_layers{kind="attention"}'] == 1
+    assert got["iotml_remat_blocks"] == 4
+
+
+# ----------------------------- grouped heads and a scale through the kernels
+def _gqa(B=2, T=40, H=4, G=2, D=16, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda h: jnp.asarray(rng.normal(size=(B, T, h, D)),  # noqa: E731
+                               jnp.float32)
+    return mk(H), mk(G), mk(G)
+
+
+@pytest.mark.parametrize("H,G,scale", [(4, 2, 0.015625), (4, 1, None),
+                                       (8, 2, 0.3), (2, 2, 0.05)])
+def test_flash_grouped_heads_and_scale_match_the_reference(H, G, scale):
+    q, k, v = _gqa(H=H, G=G, seed=H + G)
+    rep = lambda t: jnp.repeat(t, H // G, axis=2)  # noqa: E731
+    w = jnp.asarray(np.random.default_rng(2).normal(size=q.shape),
+                    jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                               interpret=True, scale=scale)
+
+    def plain(q, k, v):
+        d = q.shape[-1]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, rep(k)) \
+            * (1 / np.sqrt(d) if scale is None else scale)
+        mask = jnp.arange(q.shape[1])[:, None] >= jnp.arange(q.shape[1])
+        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, rep(v))
+
+    want = plain(q, k, v)
+    np.testing.assert_allclose(flash(q, k, v), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        attention_reference(q, k, v, causal=True, scale=scale), want,
+        rtol=2e-5, atol=2e-5)
+    got_g, want_g = (jax.jit(jax.grad(lambda *a: jnp.sum(w * f(*a)),
+                                      argnums=(0, 1, 2)))(q, k, v)
+                     for f in (flash, plain))
+    for a, b in zip(got_g, want_g):
+        assert a.shape == b.shape   # dk, dv summed back over the group
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+def test_flash_default_path_is_bit_for_bit_unchanged():
+    """Equal heads and no scale: the same kernels on the same operands
+    as a call that names 1/sqrt(D), forward and backward."""
+    q, k, v = _gqa(H=2, G=2, T=40)
+    plain = lambda *a: flash_attention(  # noqa: E731
+        *a, True, 16, 16, True)
+    named = lambda *a: flash_attention(  # noqa: E731
+        *a, causal=True, block_q=16, block_k=16, interpret=True,
+        scale=1.0 / np.sqrt(16))
+    np.testing.assert_array_equal(plain(q, k, v), named(q, k, v))
+    for a, b in zip(*(jax.grad(lambda *t: jnp.sum(jnp.sin(f(*t))),
+                               argnums=(0, 1, 2))(q, k, v)
+                      for f in (plain, named))):
+        np.testing.assert_array_equal(a, b)
+    with pytest.raises(ValueError, match="do not group"):
+        flash_attention(*_gqa(H=4, G=3), interpret=True)

@@ -77,3 +77,15 @@ def test_flash_attention_derived_tiles_lower_for_v5e(v5e, shape, dtype):
                        .astype(jnp.float32))
 
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()
+
+
+def test_flash_attention_grouped_heads_lower_for_v5e(v5e):
+    """`gh-train-backlog`'s one attention layer: 32 query heads over 8
+    key/value heads of 64 at T = 4,096, the scale the source names."""
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.float32, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.float32, sharding=v5e)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=0.015625))
+
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
