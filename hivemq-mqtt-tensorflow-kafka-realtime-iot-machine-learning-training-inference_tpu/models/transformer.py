@@ -18,6 +18,7 @@ dispatched by mode:
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import flax.linen as nn
@@ -25,6 +26,48 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attention_reference, flash_attention
+
+
+def _flat_dot_general(lhs, rhs, dimension_numbers, precision=None):
+    """A `DenseGeneral`'s product over its input's trailing axes as ONE
+    2-D product of the flattened operands: `[B, T, H, D]` by
+    `[H, D, d_model]` is `[B, T, H·D]` by `[H·D, d_model]`.  XLA gives
+    a product of rank-4 operands a T-minor layout and copies the
+    kernels' row-major arrays into it, forward and backward."""
+    (contract, _), _ = dimension_numbers
+    n = len(contract)
+    return jnp.dot(lhs.reshape(lhs.shape[:-n] + (-1,)),
+                   rhs.reshape((-1,) + rhs.shape[n:]), precision=precision)
+
+
+class QKVProjection(nn.Module):
+    """q, k and v, each `[B, T, H, D]`, out of ONE kernel
+    `[d_model, 3, H, D]` and bias `[3, H, D]` — the parameters (names,
+    shapes, initial values) of a `DenseGeneral((3, H, D))` — as three
+    2-D products by the kernel's three column slices.  One product
+    gives `[B, T, 3, H, D]`, which XLA lays out T-minor so that its
+    three slices are free and then transposes, slice by slice, into the
+    row-major arrays the flash kernels index in place (and the same
+    back for dq, dk, dv); a slice of the weights is 4 MB where a slice
+    of the activations is 16 MiB."""
+    num_heads: int
+    head_dim: int
+
+    @nn.compact
+    def __call__(self, x):
+        shape = (x.shape[-1], 3, self.num_heads, self.head_dim)
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            # as DenseGeneral: fans of the flattened [in, out] matrix
+            flat = (shape[0], math.prod(shape[1:]))
+            return nn.initializers.lecun_normal()(rng, flat, dtype).reshape(
+                shape)
+
+        kernel = self.param("kernel", kernel_init, shape)
+        bias = self.param("bias", nn.initializers.zeros_init(), shape[1:])
+        w, b = kernel.reshape(shape[0], 3, -1), bias.reshape(3, -1)
+        return tuple((x @ w[:, i] + b[i]).reshape(x.shape[:-1] + shape[2:])
+                     for i in range(3))
 
 
 class MultiHeadAttention(nn.Module):
@@ -38,8 +81,7 @@ class MultiHeadAttention(nn.Module):
         B, T, _ = x.shape
         H = self.num_heads
         D = self.d_model // H
-        qkv = nn.DenseGeneral((3, H, D), name="qkv")(x)  # [B,T,3,H,D]
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = QKVProjection(H, D, name="qkv")(x)  # [B,T,H,D] each
         if self.attn_mode == "dense":
             o = attention_reference(q, k, v, causal=True)
         elif self.attn_mode == "flash":
@@ -52,7 +94,8 @@ class MultiHeadAttention(nn.Module):
             o = ring_attention(q, k, v, axis_name=self.ring_axis, causal=True)
         else:
             raise ValueError(f"unknown attn_mode {self.attn_mode}")
-        return nn.DenseGeneral(self.d_model, axis=(-2, -1), name="out")(o)
+        return nn.DenseGeneral(self.d_model, axis=(-2, -1), name="out",
+                               dot_general=_flat_dot_general)(o)
 
 
 class Block(nn.Module):

@@ -448,7 +448,14 @@ flash_block_k = default_registry.gauge(
     "iotml_flash_block_k", "keys a flash kernel's tile holds")
 flash_heads_per_step = default_registry.gauge(
     "iotml_flash_heads_per_step",
-    "heads of the folded B*H axis one grid step of a flash kernel handles")
+    "neighbouring heads one grid step of a flash kernel handles")
+flash_lanes_per_step = default_registry.gauge(
+    "iotml_flash_lanes_per_step",
+    "lanes of a flash kernel's [B, T, H*D] column block: heads a step x D")
+flash_operand_copies = default_registry.gauge(
+    "iotml_flash_operand_copies",
+    "operands of a flash kernel's call copied ahead of it "
+    "(a T pad, a repeated k or v)")
 # the chunked state-space scan (ops/ssd.py) and the hybrid model's layer
 # stack (models/hybrid.py), set at trace time like the flash geometry:
 # what the last traced scan and model engaged.
@@ -529,6 +536,8 @@ DECLARED_METRIC_LABELS = {
     "flash_block_q": ("kernel",),
     "flash_grid_steps": ("kernel",),
     "flash_heads_per_step": ("kernel",),
+    "flash_lanes_per_step": ("kernel",),
+    "flash_operand_copies": ("kernel",),
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),

@@ -9,9 +9,14 @@ never contemplated.  The attention stack here:
 - `attention_reference`: straight jnp softmax attention — the oracle for
   every other path, and the XLA-fused fallback on CPU.
 - `flash_attention`: blocked online-softmax attention as a Pallas TPU
-  kernel — O(T) memory instead of O(T²), tiles and heads a grid step
-  derived from the shape (`flash_geometry`), the single-chip hot op of
-  the transformer model family.
+  kernel — O(T) memory instead of O(T²), the single-chip hot op of the
+  transformer model family.  The kernels read q, k, v, dO and write out,
+  dq, dk, dv where the projections leave and take them: `[B, T, H, D]`
+  seen as `[B, T, H·D]` (a free reshape), a grid step's heads one
+  `[1, block, G·D]` column block reached through the index map — no
+  transposed copy on either side.  Tiles and heads a step are derived
+  from the shape (`flash_geometry`): the heads are what the lanes allow,
+  `G·D` a multiple of 128 or the whole width.
 - `blockwise_update`: one online-softmax accumulation step, shared between
   the flash kernel's inner loop (conceptually) and the ring-attention
   cross-chip loop (`parallel.ring_attention`), which is the same math with
@@ -170,7 +175,8 @@ KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
 @dataclasses.dataclass(frozen=True)
 class FlashGeometry:
     """What one grid step of one flash kernel does, and the grid that
-    follows from it: `heads` of the folded B·H axis a step, one
+    follows from it: `heads` neighbouring heads of one batch row a step
+    (a `heads·D`-lane column block of `[B, T, H·D]`), one
     [block_q, block_k] tile of scores each."""
     block_q: int
     block_k: int
@@ -212,14 +218,18 @@ _TILE_TEMPS = {"fwd": 2, "bwd_dkv": 3, "bwd_dq": 3}
 #: the largest block the rule derives, and the cap on a block named for
 #: the backward kernels: at 2048² the temporaries alone are 32-48 MiB
 _MAX_BLOCK = 1024
-#: heads of B·H one grid step may handle
-_HEADS = (1, 2, 4, 8)
+#: the most heads one grid step handles where the lanes leave a choice:
+#: the loop over a step's heads is unrolled, and `_COST_US` was fitted
+#: up to here
+_MAX_HEADS = 8
 #: what a call costs, in µs on a v5e: a grid step (DMA set-up, index
 #: maps, pl.when bookkeeping); a 128×128 unit of tile area (the
 #: products and the elementwise softmax work); a 128-row chunk of the
 #: q side a tile (the forward's m/l/acc rescale, the backward's q, dO,
 #: lse and delta streaming in); a 128-key chunk of the kv side a tile.
-#: Fitted to PR 25's sweep of each kernel alone over blocks
+#: Fitted to PR 25's sweep of each kernel alone (on folded [B·H, T, D]
+#: copies then; PR 27's sweep of the column blocks kept the constants:
+#: PERF.md §6) over blocks
 #: {128..1024}² × heads {1..8} at three shapes — (B·H, T, D) = (64,
 #: 1024, 64) and (256, 256, 64) in float32, (2, 65536, 128) in bfloat16
 #: — one set for all three: the kernels are bound by stepping and
@@ -234,10 +244,12 @@ def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
                 itemsize: int) -> int:
     """Scoped VMEM one grid step needs, counted: every operand's block
     twice (the pipeline double-buffers), the float32 scratch, and the
-    tile's temporaries.  The minor dimension of a block pads to 128
-    lanes, so a [block_q, 1] row statistic is as wide as a q block."""
-    lanes = _round_up(D, 128)
-    q_blk, k_blk = heads * block_q * lanes, heads * block_k * lanes
+    tile's temporaries.  A q or kv block is `heads·D` lanes wide — the
+    step's heads side by side, padded to 128 only where the whole width
+    is narrower — and a [block_q, 1] row statistic pads to 128 lanes a
+    head, so one head's is as wide as a 128-lane q block."""
+    lanes = _round_up(heads * D, 128)
+    q_blk, k_blk = block_q * lanes, block_k * lanes
     stat = heads * block_q * 128 * 4
     if kernel == "fwd":
         piped = (2 * q_blk + 2 * k_blk) * itemsize + stat   # q, o; k, v; lse
@@ -258,31 +270,44 @@ def _cost_us(kernel: str, geom: FlashGeometry, bh: int) -> float:
         nbq * nbk * unit + nbq * q_chunk + nbk * k_chunk)
 
 
-def flash_geometry(kernel: str, T: int, D: int, itemsize: int, bh: int,
-                   causal: bool, block_q: Optional[int] = None,
+def _head_groups(H: int, D: int) -> list:
+    """The heads a grid step may take, fewest first: `G` neighbouring
+    heads are one column block of `[B, T, H·D]`, so `G` divides H and
+    `G·D` is whole 128-lane columns — or `G = H`, the whole width, which
+    any shape may take.  At D = 64 that is 2, 4, 8 …; at D = 128, 1, 2,
+    ….  Past `_MAX_HEADS` only the fewest is left."""
+    groups = [g for g in range(1, H + 1)
+              if H % g == 0 and (g * D % 128 == 0 or g == H)]
+    return [g for g in groups if g <= _MAX_HEADS] or groups[:1]
+
+
+def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
+                   H: int, causal: bool, block_q: Optional[int] = None,
                    block_k: Optional[int] = None) -> FlashGeometry:
     """The step geometry of one flash kernel (`fwd`, `bwd_dkv`,
     `bwd_dq`), from what the code can see at trace time — the one place
     that knows tile sizes.
 
     A block left `None` is derived: of the multiples of 128 that divide
-    T padded to 128 (up to `_MAX_BLOCK`) and the heads a step that
-    divide B·H, the geometry whose counted VMEM fits `_VMEM_BUDGET` and
-    whose modelled time (`_COST_US`) is least.  Larger tiles save grid
-    steps and per-chunk state and compute more of the causal triangle's
-    dead area (at T = 1,024: 128² 36 units of 128² in 36 steps a head,
-    512² 48 in 3, 1024² 64 in 1); more heads a step save steps with no
-    such waste, which is what short windows need.  A block that is
-    named is honoured as it stands — one head a step, the backward
-    kernels capped at `_MAX_BLOCK` — and only the other is derived."""
+    T padded to 128 (up to `_MAX_BLOCK`) and the heads a step that the
+    lanes allow (`_head_groups`), the geometry whose counted VMEM fits
+    `_VMEM_BUDGET` and whose modelled time (`_COST_US`) is least.
+    Larger tiles save grid steps and per-chunk state and compute more
+    of the causal triangle's dead area (at T = 1,024: 128² 36 units of
+    128² in 36 steps a head, 512² 48 in 3, 1024² 64 in 1); more heads a
+    step save steps with no such waste, which is what short windows
+    need.  A block that is named is honoured as it stands — the fewest
+    heads a step the lanes allow, the backward kernels capped at
+    `_MAX_BLOCK` — and only the other is derived."""
     named = block_q is not None, block_k is not None
     cap = float("inf") if kernel == "fwd" else _MAX_BLOCK
     n = _round_up(T, 128) // 128
     derived = [128 * m for m in range(1, _MAX_BLOCK // 128 + 1) if n % m == 0]
+    groups, bh = _head_groups(H, D), B * H
     geoms = [_geometry(T, bh, causal, bq, bk, h)
              for bq in ([min(block_q, cap)] if named[0] else derived)
              for bk in ([min(block_k, cap)] if named[1] else derived)
-             for h in ((1,) if any(named) else _HEADS) if bh % h == 0]
+             for h in (groups[:1] if any(named) else groups)]
     if not all(named):
         # the first is the smallest: what is left when nothing fits, for
         # the compiler to refuse
@@ -332,16 +357,28 @@ def _fwd_tile(q, k, v, mask, m, l, acc, *, scale: float):
     return m_new, l_new, acc_new
 
 
+def _head_columns(x_ref, stat_ref) -> list:
+    """(g, columns) of each head of a step: the row statistics' block
+    leads with the step's heads, an operand's `[1, block, G·D]` column
+    block holds them side by side, D lanes each."""
+    G = stat_ref.shape[0]
+    D = x_ref.shape[2] // G
+    return [(g, slice(g * D, (g + 1) * D)) for g in range(G)]
+
+
 def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
               acc, m_s, l_s, *, scale: float, causal: bool, block_q: int,
               block_k: int):
     """One grid step of the forward: tile (i, j) of every head in the
-    block.  K/V stream through VMEM one [block_k, D] tile at a time
-    (O(T) VMEM, long-context safe); the online-softmax state lives in
-    scratch that persists across the kv tiles of one q block.  `first`
-    and `last` say whether (i, j) opens or closes its q row, `live`
-    (dense causal grid only) whether the tile holds any past key."""
+    column block.  K/V stream through VMEM one [block_k, G·D] tile at a
+    time (O(T) VMEM, long-context safe); the online-softmax state lives
+    in scratch that persists across the kv tiles of one q block.
+    `first` and `last` say whether (i, j) opens or closes its q row,
+    `live` (dense causal grid only) whether the tile holds any past
+    key."""
     from jax.experimental import pallas as pl
+
+    heads = _head_columns(q_ref, lse_ref)
 
     @pl.when(first)
     def _init():
@@ -360,10 +397,10 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             qi, kj = _tile_positions(i, j, block_q, block_k)
             mask = qi >= kj
-        for g in range(q_ref.shape[0]):
-            m_s[g], l_s[g], acc[g] = _fwd_tile(
-                q_ref[g], k_ref[g], v_ref[g], mask, m_s[g], l_s[g], acc[g],
-                scale=scale)
+        for g, cols in heads:
+            m_s[g], l_s[g], acc[:, cols] = _fwd_tile(
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
+                mask, m_s[g], l_s[g], acc[:, cols], scale=scale)
 
     if live is None:
         compute()
@@ -375,7 +412,8 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _emit():
         l = l_s[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc[:] / safe_l).astype(o_ref.dtype)
+        for g, cols in heads:
+            o_ref[0, :, cols] = (acc[:, cols] / safe_l[g]).astype(o_ref.dtype)
         # log-sum-exp per query row (needed by the custom-VJP backward)
         lse_ref[:] = jnp.where(l == 0.0, NEG_INF, m_s[:] + jnp.log(safe_l))
 
@@ -438,6 +476,8 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     dk/dv block is still zero-written (see _causal_tiles)."""
     from jax.experimental import pallas as pl
 
+    heads = _head_columns(q_ref, lse_ref)
+
     @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
@@ -446,10 +486,11 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
                          block_k=block_k, t_real=t_real)
-        for g in range(q_ref.shape[0]):
-            dk_acc[g], dv_acc[g] = _dkv_tile(
-                q_ref[g], k_ref[g], v_ref[g], do_ref[g], lse_ref[g],
-                delta_ref[g], mask, dk_acc[g], dv_acc[g], scale=scale)
+        for g, cols in heads:
+            dk_acc[:, cols], dv_acc[:, cols] = _dkv_tile(
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
+                do_ref[0, :, cols], lse_ref[g], delta_ref[g], mask,
+                dk_acc[:, cols], dv_acc[:, cols], scale=scale)
 
     if live is None:
         compute()
@@ -459,8 +500,8 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(last)
     def _emit():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
@@ -470,6 +511,8 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     relevant) kv blocks."""
     from jax.experimental import pallas as pl
 
+    heads = _head_columns(q_ref, lse_ref)
+
     @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
@@ -477,10 +520,11 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
                          block_k=block_k, t_real=t_real)
-        for g in range(q_ref.shape[0]):
-            dq_acc[g] = _dq_tile(
-                q_ref[g], k_ref[g], v_ref[g], do_ref[g], lse_ref[g],
-                delta_ref[g], mask, dq_acc[g], scale=scale)
+        for g, cols in heads:
+            dq_acc[:, cols] = _dq_tile(
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
+                do_ref[0, :, cols], lse_ref[g], delta_ref[g], mask,
+                dq_acc[:, cols], scale=scale)
 
     if live is None:
         compute()
@@ -489,33 +533,33 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(last)
     def _emit():
-        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _dense_kernel(*refs, step, kv_outer: bool, causal: bool, block_q: int,
                   block_k: int, **static):
-    """A step function on the dense grid (B·H/heads, outer, inner): the
+    """A step function on the dense grid (B, H/heads, outer, inner): the
     inner axis iterates sequentially on-core — kv blocks for the
     forward and dQ, q blocks for dK/dV (`kv_outer`)."""
     from jax.experimental import pallas as pl
 
-    outer, inner = pl.program_id(1), pl.program_id(2)
+    outer, inner = pl.program_id(2), pl.program_id(3)
     i, j = (inner, outer) if kv_outer else (outer, inner)
     live = j * block_k <= i * block_q + (block_q - 1) if causal else None
-    step(i, j, inner == 0, inner == pl.num_programs(2) - 1, live, *refs,
+    step(i, j, inner == 0, inner == pl.num_programs(3) - 1, live, *refs,
          causal=causal, block_q=block_q, block_k=block_k, **static)
 
 
 def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
                 block_k: int, nq: int, nk: int, **static):
-    """A step function on the TRIANGULAR grid: the grid's second axis
-    walks only the live lower-triangle tiles (row-major for the forward
-    and dQ, column-major for dK/dV), the (i, j) tile coordinates
-    arriving via scalar prefetch.  Strictly-future tiles do not exist,
-    so they pay neither their DMA nor a grid step."""
+    """A step function on the TRIANGULAR grid (B, H/heads, tiles): the
+    last axis walks only the live lower-triangle tiles (row-major for
+    the forward and dQ, column-major for dK/dV), the (i, j) tile
+    coordinates arriving via scalar prefetch.  Strictly-future tiles do
+    not exist, so they pay neither their DMA nor a grid step."""
     from jax.experimental import pallas as pl
 
-    t = pl.program_id(1)
+    t = pl.program_id(2)
     i = im_ref[t]
     j = jm_ref[t]
     if kv_outer:
@@ -531,69 +575,71 @@ def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
          block_k=block_k, **static)
 
 
-def _flash_grid(kernel: str, geom: FlashGeometry, step, *, bh: int, D: int,
-                kv_outer: bool, causal: bool, ins: str, outs: str,
-                scratch, **static):
+def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
+                D: int, kv_outer: bool, causal: bool, ins: str, outs: str,
+                scratch, copies: int, **static):
     """(kernel function, grid spec, compiler params, prefetch operands)
     of one flash kernel on the grid `geom` names — and the record of
     that geometry (`iotml_flash_*{kernel}`, at trace time).  `ins` and
-    `outs` give each operand's side and width: Q/K a [heads, block, D]
-    block along the q or the kv axis, q a [heads, block_q, 1] row
-    statistic."""
+    `outs` give each operand's side and width: Q/K a [1, block, G·D]
+    column block of a `[B, T, H·D]` array along the q or the kv axis —
+    batch row b, the c-th group of G heads — and q the [G, block_q, 1]
+    row statistics of the same heads in a `[B·H, t_q, 1]` array."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     G, bq, bk = geom.heads, geom.block_q, geom.block_k
-    nq, nk = geom.t_q // bq, geom.t_k // bk
+    nq, nk, nc = geom.t_q // bq, geom.t_k // bk, H // G
     if geom.tri:
         im, jm = _causal_tiles(nq, nk, bq, bk, "col" if kv_outer else "row")
         prefetch = (jnp.asarray(im), jnp.asarray(jm))
-        grid = (bh // G, len(im))
-        qmap = lambda b, t, im, jm: (b, im[t], 0)  # noqa: E731
-        kmap = lambda b, t, im, jm: (b, jm[t], 0)  # noqa: E731
+        grid = (B, nc, len(im))
+        qtile = lambda t, im, jm: im[t]  # noqa: E731
+        ktile = lambda t, im, jm: jm[t]  # noqa: E731
         fn = functools.partial(_tri_kernel, step=step, kv_outer=kv_outer,
                                block_q=bq, block_k=bk, nq=nq, nk=nk,
                                **static)
     else:
         prefetch = ()
         # the grid's last two axes arrive as (outer, inner)
-        grid = (bh // G, nk, nq) if kv_outer else (bh // G, nq, nk)
-        qmap = lambda b, o, n: (b, n if kv_outer else o, 0)  # noqa: E731
-        kmap = lambda b, o, n: (b, o if kv_outer else n, 0)  # noqa: E731
+        grid = (B, nc, nk, nq) if kv_outer else (B, nc, nq, nk)
+        qtile = lambda o, n: n if kv_outer else o  # noqa: E731
+        ktile = lambda o, n: o if kv_outer else n  # noqa: E731
         fn = functools.partial(_dense_kernel, step=step, kv_outer=kv_outer,
                                causal=causal, block_q=bq, block_k=bk,
                                **static)
     assert math.prod(grid) == geom.grid_steps, (grid, geom)
-    _record_geometry(kernel, geom)
-    blocks = {"Q": pl.BlockSpec((G, bq, D), qmap),
-              "q": pl.BlockSpec((G, bq, 1), qmap),
-              "K": pl.BlockSpec((G, bk, D), kmap)}
+    _record_geometry(kernel, geom, lanes=G * D, copies=copies)
+    blocks = {
+        "Q": pl.BlockSpec((1, bq, G * D),
+                          lambda b, c, *t: (b, qtile(*t), c)),
+        "q": pl.BlockSpec((G, bq, 1),
+                          lambda b, c, *t: (b * nc + c, qtile(*t), 0)),
+        "K": pl.BlockSpec((1, bk, G * D),
+                          lambda b, c, *t: (b, ktile(*t), c))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[blocks[c] for c in ins],
         out_specs=[blocks[c] for c in outs],
         scratch_shapes=scratch)
     params = pltpu.CompilerParams(dimension_semantics=(
-        "parallel",) + ("arbitrary",) * (len(grid) - 1))
+        "parallel", "parallel") + ("arbitrary",) * (len(grid) - 2))
     return fn, grid_spec, params, prefetch
 
 
-def _record_geometry(kernel: str, geom: FlashGeometry) -> None:
-    """Say what engaged: Python at trace time, once a compilation, no
-    cost in the step.  The last traced call of each kernel stands."""
+def _record_geometry(kernel: str, geom: FlashGeometry, *, lanes: int,
+                     copies: int) -> None:
+    """Say what engaged: Python at trace time, once a shape and
+    compilation, no cost in the step.  The last newly traced call of
+    each kernel stands."""
     from ..obs import metrics as obs_metrics
 
     obs_metrics.flash_grid_steps.set(geom.grid_steps, kernel=kernel)
     obs_metrics.flash_block_q.set(geom.block_q, kernel=kernel)
     obs_metrics.flash_block_k.set(geom.block_k, kernel=kernel)
     obs_metrics.flash_heads_per_step.set(geom.heads, kernel=kernel)
-
-
-def _fold(x):
-    """[B, T, H, D] → [B·H, T, D]: batch and heads folded into the
-    grid's first axis, T-major blocks."""
-    B, T, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    obs_metrics.flash_lanes_per_step.set(lanes, kernel=kernel)
+    obs_metrics.flash_operand_copies.set(copies, kernel=kernel)
 
 
 def _pad_t(x, t_pad: int, pad_value=0.0):
@@ -604,14 +650,25 @@ def _pad_t(x, t_pad: int, pad_value=0.0):
                    constant_values=pad_value)
 
 
-def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
-               interpret: bool, scale: float):
-    """The forward of folded, unpadded operands ([B·H, T, D]) on
-    `geom`: (out [B·H, t_q, D], lse [B·H, t_q, 1])."""
+def _operand_copies(T: int, geom: FlashGeometry, q_side: int,
+                    repeated: int) -> int:
+    """How many of a call's operands were copied ahead of the kernel:
+    the `q_side` operands of the q axis where T pads to `t_q`; k and v
+    where T pads to `t_k` or the caller repeated them (`repeated`)."""
+    return q_side * (geom.t_q != T) + max(repeated, 2 * (geom.t_k != T))
+
+
+def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
+               interpret: bool, scale: float, repeated: int = 0):
+    """The forward of unpadded operands in the projections' layout
+    ([B, T, H·D]) on `geom`: (out [B, t_q, H·D], lse [B·H, t_q, 1]).
+    `repeated` says how many of them the caller copied ahead of this
+    (a repeated k or v), for `iotml_flash_operand_copies`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BH, T, D = qf.shape
+    B, T, HD = q.shape
+    D = HD // H
     G, bq, Tq, Tk = geom.heads, geom.block_q, geom.t_q, geom.t_k
     if not causal and Tk != T:
         # padded keys are only excluded by the causal mask; non-causal
@@ -619,92 +676,106 @@ def _flash_fwd(qf, kf, vf, causal: bool, geom: FlashGeometry,
         raise ValueError(
             f"non-causal flash attention needs T % {geom.block_k} == 0")
     fn, grid_spec, params, prefetch = _flash_grid(
-        "fwd", geom, _fwd_step, bh=BH, D=D, kv_outer=False, causal=causal,
-        ins="QKK", outs="Qq",
-        scratch=[pltpu.VMEM((G, bq, D), jnp.float32),
+        "fwd", geom, _fwd_step, B=B, H=H, D=D, kv_outer=False,
+        causal=causal, ins="QKK", outs="Qq",
+        scratch=[pltpu.VMEM((bq, G * D), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32)],
-        scale=scale)
+        copies=_operand_copies(T, geom, 1, repeated), scale=scale)
     # padded keys never win the max: values 0, and the causal mask
     # (global positions) excludes them for every real query
     return pl.pallas_call(
         fn, name=FWD_KERNEL, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
-                   jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tq, HD), q.dtype),
+                   jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32)],
         compiler_params=params, interpret=interpret,
-    )(*prefetch, _pad_t(qf, Tq), _pad_t(kf, Tk), _pad_t(vf, Tk))
+    )(*prefetch, _pad_t(q, Tq), _pad_t(k, Tk), _pad_t(v, Tk))
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
-                   block_k: Optional[int], interpret: bool, scale: float):
-    """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T])."""
+                   block_k: Optional[int], interpret: bool, scale: float,
+                   repeated: int):
+    """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T]).
+    Jitted, as `_flash_backward` is, so that a model of many equal
+    layers traces and lowers the kernels once a shape and not once a
+    layer (`_record_geometry` runs then, once)."""
     B, T, H, D = q.shape
-    geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B * H, causal,
+    geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B, H, causal,
                           block_q, block_k)
-    out, lse = _flash_fwd(_fold(q), _fold(k), _fold(v), causal, geom,
-                          interpret, scale)
-    out = out.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
-    lse = lse.reshape(B, H, -1)[:, :, :T]
-    return out, lse
+    out, lse = _flash_fwd(*(x.reshape(B, T, H * D) for x in (q, k, v)), H,
+                          causal, geom, interpret, scale, repeated)
+    return (out[:, :T].reshape(B, T, H, D),
+            lse.reshape(B, H, -1)[:, :, :T])
 
 
-def _pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom: FlashGeometry):
-    """The backward's operands padded to `geom`.  Padded q rows take a
-    +BIG lse → p = exp(s - BIG) = 0, so they contribute nothing to
-    dk/dv and their dq rows are sliced off; padded keys are masked by
-    `t_real`."""
+def _bwd_operands(q, do, lse, delta, k, v, geom: FlashGeometry,
+                  repeated: int):
+    """The backward's operands padded to `geom`, and how many of the
+    six were copied on the way (`repeated` of them by the caller).
+    Padded q rows take a +BIG lse → p = exp(s - BIG) = 0, so they
+    contribute nothing to dk/dv and their dq rows are sliced off;
+    padded keys are masked by `t_real`."""
     Tq, Tk = geom.t_q, geom.t_k
-    return (_pad_t(qf, Tq), _pad_t(dof, Tq), _pad_t(lse_f, Tq, 1e30),
-            _pad_t(delta_f, Tq), _pad_t(kf, Tk), _pad_t(vf, Tk))
+    return (_pad_t(q, Tq), _pad_t(do, Tq), _pad_t(lse, Tq, 1e30),
+            _pad_t(delta, Tq), _pad_t(k, Tk), _pad_t(v, Tk)), \
+        _operand_copies(q.shape[1], geom, 4, repeated)
 
 
-def _flash_bwd_dkv(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
-                   geom: FlashGeometry, interpret: bool, scale: float):
-    """dK/dV of folded, unpadded operands ([B·H, T, D]; lse and delta
-    [B·H, T, 1]) on `geom`: ([B·H, t_k, D],) × 2."""
+def _flash_bwd_dkv(q, do, lse, delta, k, v, H: int, causal: bool,
+                   geom: FlashGeometry, interpret: bool, scale: float,
+                   repeated: int = 0):
+    """dK/dV of unpadded operands ([B, T, H·D]; lse and delta
+    [B·H, T, 1]) on `geom`: ([B, t_k, H·D],) × 2."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BH, T, D = qf.shape
+    B, T, HD = q.shape
+    D = HD // H
     G, bk, Tk = geom.heads, geom.block_k, geom.t_k
+    operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dkv", geom, _dkv_step, bh=BH, D=D, kv_outer=True,
+        "bwd_dkv", geom, _dkv_step, B=B, H=H, D=D, kv_outer=True,
         causal=causal, ins="QQqqKK", outs="KK",
-        scratch=[pltpu.VMEM((G, bk, D), jnp.float32),
-                 pltpu.VMEM((G, bk, D), jnp.float32)],
-        scale=scale, t_real=T)
+        scratch=[pltpu.VMEM((bk, G * D), jnp.float32),
+                 pltpu.VMEM((bk, G * D), jnp.float32)],
+        copies=copies, scale=scale, t_real=T)
     return pl.pallas_call(
         fn, name=BWD_DKV_KERNEL, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), kf.dtype),
-                   jax.ShapeDtypeStruct((BH, Tk, D), vf.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tk, HD), k.dtype),
+                   jax.ShapeDtypeStruct((B, Tk, HD), v.dtype)],
         compiler_params=params, interpret=interpret,
-    )(*prefetch, *_pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom))
+    )(*prefetch, *operands)
 
 
-def _flash_bwd_dq(qf, dof, lse_f, delta_f, kf, vf, causal: bool,
-                  geom: FlashGeometry, interpret: bool, scale: float):
-    """dQ of the same operands on `geom`: [B·H, t_q, D]."""
+def _flash_bwd_dq(q, do, lse, delta, k, v, H: int, causal: bool,
+                  geom: FlashGeometry, interpret: bool, scale: float,
+                  repeated: int = 0):
+    """dQ of the same operands on `geom`: [B, t_q, H·D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BH, T, D = qf.shape
+    B, T, HD = q.shape
+    D = HD // H
     G, bq, Tq = geom.heads, geom.block_q, geom.t_q
+    operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dq", geom, _dq_step, bh=BH, D=D, kv_outer=False,
+        "bwd_dq", geom, _dq_step, B=B, H=H, D=D, kv_outer=False,
         causal=causal, ins="QQqqKK", outs="Q",
-        scratch=[pltpu.VMEM((G, bq, D), jnp.float32)],
-        scale=scale, t_real=T)
-    (dq_f,) = pl.pallas_call(
+        scratch=[pltpu.VMEM((bq, G * D), jnp.float32)],
+        copies=copies, scale=scale, t_real=T)
+    (dq,) = pl.pallas_call(
         fn, name=BWD_DQ_KERNEL, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tq, HD), q.dtype)],
         compiler_params=params, interpret=interpret,
-    )(*prefetch, *_pad_bwd(qf, dof, lse_f, delta_f, kf, vf, geom))
-    return dq_f
+    )(*prefetch, *operands)
+    return dq
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_backward(q, k, v, out, lse, do, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
-                    interpret: bool, scale: float):
+                    interpret: bool, scale: float, repeated: int):
     """Pallas flash-attention backward: the standard two-kernel split
     (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
     recomputed blockwise in VMEM, never materialized to HBM), each
@@ -713,38 +784,35 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     # rowwise D_i = sum_d dO_i·O_i (softmax-jacobian diagonal term)
     delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
-    folded = (_fold(q), _fold(do), lse.reshape(B * H, T, 1),
-              delta.reshape(B * H, T, 1), _fold(k), _fold(v))
-    dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B * H, causal,
+    flat = lambda x: x.reshape(B, T, H * D)  # noqa: E731
+    operands = (flat(q), flat(do), lse.reshape(B * H, T, 1),
+                delta.reshape(B * H, T, 1), flat(k), flat(v))
+    dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B, H, causal,
                               block_q, block_k)
                for kernel in ("bwd_dkv", "bwd_dq"))
-    dk_f, dv_f = _flash_bwd_dkv(*folded, causal, dkv, interpret, scale)
-    dq_f = _flash_bwd_dq(*folded, causal, dq, interpret, scale)
-
-    def unfold(x):
-        return x.reshape(B, H, -1, D).transpose(0, 2, 1, 3)[:, :T]
-
-    return unfold(dq_f), unfold(dk_f), unfold(dv_f)
+    dk, dv = _flash_bwd_dkv(*operands, H, causal, dkv, interpret, scale,
+                            repeated)
+    dq = _flash_bwd_dq(*operands, H, causal, dq, interpret, scale, repeated)
+    return tuple(x[:, :T].reshape(B, T, H, D) for x in (dq, dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, scale, repeated):
     """`flash_attention` of equal heads: the differentiable core."""
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
-                            scale)
+                            scale, repeated)
     return out
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret, scale):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
-                              scale)
+def _flash_fwd_rule(q, k, v, *static):
+    out, lse = _flash_forward(q, k, v, *static)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, scale, res, do):
-    q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
-                           interpret, scale)
+def _flash_bwd_rule(causal, block_q, block_k, interpret, scale, repeated,
+                    res, do):
+    return _flash_backward(*res, do, causal, block_q, block_k, interpret,
+                           scale, repeated)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -757,9 +825,18 @@ def flash_attention(q, k, v, causal: bool = True,
     """Pallas flash attention. q: [B, T, H, D], k,v: [B, T, Hkv, D] with
     Hkv dividing H → [B, T, H, D].
 
+    The kernels index that layout in place: each operand and each result
+    is `[B, T, H·D]` to them (a free reshape), a grid step's heads one
+    128-lane-aligned column block of it, so nothing is transposed or
+    copied on the way in or out — q, k, v as the projections wrote them,
+    the gradients as the projections' backward reads them.
+
     `block_q`/`block_k` left `None` are derived from the shape, each of
-    the three kernels its own (`flash_geometry`); a value given is
-    honoured as it stands.  T is padded to the block size internally
+    the three kernels its own (`flash_geometry`), with as many heads a
+    step as the lanes allow and the model of their cost prefers; a value
+    given is honoured as it stands, with the fewest heads the lanes
+    allow (`G·D` a multiple of 128, or all H).  T is padded to the
+    block size internally
     (padding keys are masked out by the causal structure; non-causal
     callers must pass T multiple of the block).  `interpret=True` runs
     the same kernel on CPU for tests.  `scale` multiplies the scores;
@@ -773,7 +850,9 @@ def flash_attention(q, k, v, causal: bool = True,
     block) with blockwise probability recompute in VMEM — O(T·block)
     memory and no HBM round trip for the probability matrices.
     """
+    repeated = 2 * (k.shape[2] != q.shape[2])
     k, v = _repeat_kv(q, k, v)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, causal, block_q, block_k, interpret, scale)
+    return _flash(q, k, v, causal, block_q, block_k, interpret, scale,
+                  repeated)

@@ -13,10 +13,11 @@ from iotml.parallel.mesh import make_mesh
 from iotml.parallel.ring_attention import make_ring_attention
 
 
-def _qkv(B=2, T=32, H=2, D=8, seed=0, dtype=jnp.float32):
+def _qkv(B=2, T=32, H=2, D=8, seed=0, dtype=jnp.float32, kv_heads=None):
     rng = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(rng.normal(size=(B, T, H, D)), dtype)  # noqa: E731
-    return mk(), mk(), mk()
+    mk = lambda h=H: jnp.asarray(  # noqa: E731
+        rng.normal(size=(B, T, h, D)), dtype)
+    return mk(), mk(kv_heads or H), mk(kv_heads or H)
 
 
 def test_reference_attention_is_causal():
@@ -104,27 +105,40 @@ def test_ring_attention_output_is_seq_sharded():
 #: shapes that hit every branch of the rule: T below one tile, T not a
 #: multiple of the tile, one tile a head with heads to group (B·H = 8),
 #: a window several tiles long, both head widths, both dtypes, causal
-#: and (at block-multiple T) not
+#: and (at block-multiple T) not.  And what the lanes rule adds (the
+#: last column is the key/value heads, `None` for as many as H): two
+#: heads of 64 a 128-lane column block out of two and out of sixteen,
+#: an odd head count and a narrow model (the whole width a step), heads
+#: of 128 one and two a step, fewer key/value heads than query heads.
 DERIVED_CASES = [
-    # B, T, H, D, dtype, causal
-    (2, 100, 2, 64, jnp.float32, True),
-    (2, 500, 2, 64, jnp.float32, True),
-    (2, 256, 4, 64, jnp.float32, True),
-    (2, 256, 4, 64, jnp.float32, False),
-    (1, 256, 2, 128, jnp.bfloat16, True),
-    (1, 500, 2, 128, jnp.bfloat16, True),
-    (1, 1024, 2, 64, jnp.float32, True),
-    (1, 1024, 2, 64, jnp.float32, False),
-    (1, 1024, 2, 128, jnp.float32, True),
-    (1, 1024, 2, 64, jnp.bfloat16, True),
+    # B, T, H, D, dtype, causal, key/value heads
+    (2, 100, 2, 64, jnp.float32, True, None),
+    (2, 500, 2, 64, jnp.float32, True, None),
+    (2, 256, 4, 64, jnp.float32, True, None),
+    (2, 256, 4, 64, jnp.float32, False, None),
+    (1, 256, 2, 128, jnp.bfloat16, True, None),
+    (1, 500, 2, 128, jnp.bfloat16, True, None),
+    (1, 1024, 2, 64, jnp.float32, True, None),
+    (1, 1024, 2, 64, jnp.float32, False, None),
+    (1, 1024, 2, 128, jnp.float32, True, None),
+    (1, 1024, 2, 64, jnp.bfloat16, True, None),
+    (1, 256, 16, 64, jnp.float32, True, None),
+    (1, 256, 16, 64, jnp.bfloat16, True, None),
+    (2, 256, 3, 64, jnp.float32, True, None),
+    (1, 300, 3, 64, jnp.float32, True, None),
+    (2, 256, 4, 8, jnp.float32, True, None),
+    (2, 200, 4, 8, jnp.bfloat16, True, None),
+    (1, 256, 4, 128, jnp.float32, False, None),
+    (1, 256, 8, 64, jnp.float32, True, 2),
+    (1, 300, 4, 64, jnp.bfloat16, True, 1),
 ]
 
 
-@pytest.mark.parametrize("B,T,H,D,dtype,causal", DERIVED_CASES)
+@pytest.mark.parametrize("B,T,H,D,dtype,causal,kv_heads", DERIVED_CASES)
 def test_flash_attention_derived_tiles_match_reference(B, T, H, D, dtype,
-                                                       causal):
+                                                       causal, kv_heads):
     """Forward and all three gradients with tiles the rule derives."""
-    q, k, v = _qkv(B, T, H, D, dtype=dtype)
+    q, k, v = _qkv(B, T, H, D, dtype=dtype, kv_heads=kv_heads)
     up = lambda x: x.astype(jnp.float32)  # noqa: E731
     f = lambda q, k, v: jnp.sum(jnp.sin(up(  # noqa: E731
         flash_attention(q, k, v, causal=causal, interpret=True))))
@@ -148,6 +162,48 @@ def test_flash_attention_derived_tiles_match_reference(B, T, H, D, dtype,
                                    **grad_tol)
 
 
+def test_flash_grad_transposes_nothing_and_copies_no_operand():
+    """The kernels take q, k, v, dO and give out, dq, dk, dv where the
+    projections leave and take them: at `sf-train-backlog`'s shape the
+    gradient's jaxpr holds no transpose of a rank-4 array (the folds
+    were ten of them), each operand reaches its kernel uncopied, and a
+    step takes 128 lanes or more."""
+    from iotml.obs.metrics import default_registry
+
+    jax.clear_caches()   # the geometry is recorded when a shape is traced
+    qkv = [jax.ShapeDtypeStruct((4, 1024, 16, 64), jnp.float32)] * 3
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True)),
+        argnums=(0, 1, 2)))(*qkv)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if eqn.primitive.name != "pallas_call":
+                    yield from equations(sub)
+
+    names = [e.primitive.name for e in equations(jaxpr.jaxpr)]
+    assert names.count("pallas_call") == 3
+    assert not [e for e in equations(jaxpr.jaxpr)
+                if e.primitive.name == "transpose"
+                and e.invars[0].aval.ndim >= 4]
+    assert not {"pad", "concatenate", "gather"} & set(names)
+    got = default_registry.collect()
+    for kernel in attention.KERNELS:
+        assert got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}'] == 0
+        lanes = got[f'iotml_flash_lanes_per_step{{kernel="{kernel}"}}']
+        heads = got[f'iotml_flash_heads_per_step{{kernel="{kernel}"}}']
+        assert lanes == heads * 64 and lanes % 128 == 0
+    # a T pad and a repeated k, v are copies, and are counted
+    q, k, v = _qkv(1, 300, 4, 64, kv_heads=2)
+    jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    got = default_registry.collect()
+    assert [got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}']
+            for kernel in attention.KERNELS] == [3, 6, 6]
+
+
 def test_non_causal_flash_attention_still_needs_whole_tiles():
     q, k, v = _qkv(1, 100, 2, 64)
     with pytest.raises(ValueError, match="non-causal"):
@@ -155,25 +211,31 @@ def test_non_causal_flash_attention_still_needs_whole_tiles():
 
 
 RULE_SHAPES = [
-    # T, D, itemsize, B·H, causal
-    (100, 64, 4, 4, True), (500, 64, 4, 4, True), (256, 64, 4, 256, True),
-    (256, 64, 4, 6, True), (1024, 64, 4, 64, True), (1024, 64, 4, 64, False),
-    (1024, 128, 2, 2, True), (1152, 64, 4, 8, True), (4096, 128, 2, 32, True),
-    (65536, 128, 2, 2, True), (65536, 64, 4, 4, True),
-    (1_000_000, 128, 2, 2, True),
+    # T, D, itemsize, B, H, causal
+    (100, 64, 4, 2, 2, True), (500, 64, 4, 2, 2, True),
+    (256, 64, 4, 16, 16, True), (256, 64, 4, 2, 3, True),
+    (1024, 64, 4, 4, 16, True), (1024, 64, 4, 4, 16, False),
+    (1024, 128, 2, 1, 2, True), (1152, 64, 4, 2, 4, True),
+    (4096, 128, 2, 1, 32, True), (65536, 128, 2, 1, 2, True),
+    (65536, 64, 4, 2, 2, True), (1_000_000, 128, 2, 1, 2, True),
+    (4096, 64, 4, 1, 32, True), (256, 8, 4, 2, 4, True),
 ]
 
 
 @pytest.mark.parametrize("kernel", attention.KERNELS)
-@pytest.mark.parametrize("T,D,itemsize,bh,causal", RULE_SHAPES)
-def test_flash_geometry_invariants(kernel, T, D, itemsize, bh, causal):
-    g = attention.flash_geometry(kernel, T, D, itemsize, bh, causal)
+@pytest.mark.parametrize("T,D,itemsize,B,H,causal", RULE_SHAPES)
+def test_flash_geometry_invariants(kernel, T, D, itemsize, B, H, causal):
+    g = attention.flash_geometry(kernel, T, D, itemsize, B, H, causal)
+    bh = B * H
     t_pad = -(-T // 128) * 128
     for block in (g.block_q, g.block_k):
         assert block % 128 == 0 and t_pad % block == 0
         assert block <= attention._MAX_BLOCK
     assert (g.t_q, g.t_k) == (t_pad, t_pad)
-    assert bh % g.heads == 0
+    # a step's heads are whole 128-lane columns of [B, T, H·D], or all
+    assert H % g.heads == 0
+    assert g.heads * D % 128 == 0 or g.heads == H
+    assert g.heads <= attention._MAX_HEADS or g.heads == H
     assert attention._vmem_bytes(kernel, g.block_q, g.block_k, g.heads, D,
                                  itemsize) <= attention._VMEM_BUDGET
     nq, nk = g.t_q // g.block_q, g.t_k // g.block_k
@@ -189,17 +251,24 @@ def test_flash_geometry_invariants(kernel, T, D, itemsize, bh, causal):
 
 @pytest.mark.parametrize("kernel", attention.KERNELS)
 def test_flash_geometry_explicit_blocks_win(kernel):
-    g = attention.flash_geometry(kernel, 1024, 64, 4, 64, True, 128, 128)
+    # the fewest heads the lanes allow: two of 64, one of 128
+    g = attention.flash_geometry(kernel, 1024, 64, 4, 4, 16, True, 128, 128)
     assert (g.block_q, g.block_k, g.heads, g.grid_steps) == (
-        128, 128, 1, 64 * 36)
-    # one named, the other derived around it; still one head a step
-    g = attention.flash_geometry(kernel, 1024, 64, 4, 64, True, block_k=256)
-    assert (g.block_k, g.heads) == (256, 1) and 1024 % g.block_q == 0
-    # blocks that are no multiple of 128 pad T to themselves, as before
-    g = attention.flash_geometry(kernel, 40, 8, 4, 4, True, 16, 16)
-    assert (g.block_q, g.block_k, g.t_q, g.t_k) == (16, 16, 48, 48)
+        128, 128, 2, 32 * 36)
+    g = attention.flash_geometry(kernel, 1024, 128, 2, 4, 16, True, 128, 128)
+    assert (g.heads, g.grid_steps) == (1, 64 * 36)
+    # one named, the other derived around it; still the fewest heads
+    g = attention.flash_geometry(kernel, 1024, 64, 4, 4, 16, True,
+                                 block_k=256)
+    assert (g.block_k, g.heads) == (256, 2) and 1024 % g.block_q == 0
+    # blocks that are no multiple of 128 pad T to themselves, as before;
+    # a model narrower than 128 lanes takes its whole width
+    g = attention.flash_geometry(kernel, 40, 8, 4, 2, 2, True, 16, 16)
+    assert (g.block_q, g.block_k, g.t_q, g.t_k, g.heads) == (
+        16, 16, 48, 48, 2)
     # the backward's cap sits in the rule: the forward takes 2048 as
     # named, the backward kernels stop at the largest tile that compiles
-    g = attention.flash_geometry(kernel, 65536, 128, 2, 2, True, 2048, 2048)
+    g = attention.flash_geometry(kernel, 65536, 128, 2, 1, 2, True,
+                                 2048, 2048)
     want = 2048 if kernel == "fwd" else attention._MAX_BLOCK
     assert (g.block_q, g.block_k) == (want, want)

@@ -62,13 +62,18 @@ def test_flash_attention_fwd_bwd_lowers_for_v5e(v5e):
 
 
 @pytest.mark.parametrize("shape,dtype", [
-    ((4, 1024, 16, 64), jnp.float32),     # the benchmark's cell
+    ((4, 1024, 16, 64), jnp.float32),     # `sf-train-backlog`, exactly
     ((16, 256, 16, 64), jnp.float32),     # the short window
     ((1, 65536, 2, 128), jnp.bfloat16),   # bench.py's long context
+    ((2, 512, 3, 64), jnp.float32),       # odd heads: all 192 lanes a step
+    ((2, 512, 4, 8), jnp.float32),        # a model 32 lanes wide
+    ((2, 300, 6, 64), jnp.bfloat16),      # padded T, 16-row sublane tiles
 ])
 def test_flash_attention_derived_tiles_lower_for_v5e(v5e, shape, dtype):
-    """Tiles left to the rule: a geometry that overflows scoped VMEM, or
-    that Mosaic refuses, fails here and not on the chip."""
+    """Tiles and heads a step left to the rule, forward and backward: a
+    geometry that overflows scoped VMEM, or a lane slice of a
+    [1, block, G·D] column block that Mosaic refuses, fails here and
+    not on the chip."""
     qkv = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
            for _ in range(3)]
 
@@ -80,8 +85,9 @@ def test_flash_attention_derived_tiles_lower_for_v5e(v5e, shape, dtype):
 
 
 def test_flash_attention_grouped_heads_lower_for_v5e(v5e):
-    """`gh-train-backlog`'s one attention layer: 32 query heads over 8
-    key/value heads of 64 at T = 4,096, the scale the source names."""
+    """`gh-train-backlog`'s one attention layer, exactly, forward and
+    backward: 32 query heads over 8 key/value heads of 64 at T = 4,096,
+    the scale the source names."""
     q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.float32, sharding=v5e)
     kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.float32, sharding=v5e)
 

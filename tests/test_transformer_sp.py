@@ -91,6 +91,34 @@ def test_sp_gradients_match_dense_oracle():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
+def test_qkv_projection_keeps_dense_generals_parameters_and_values():
+    """`attn/qkv` is read by the benchmark's reference, `mlops/
+    checkpoint.py` and `models/h5_*` as a `DenseGeneral((3, H, D))`'s
+    tree: same names, shapes and initial values, and the three products
+    are that layer's three slices."""
+    import flax.linen as nn
+    from iotml.models.transformer import MultiHeadAttention, QKVProjection
+
+    H, D = 4, 8
+    x = jnp.asarray(_x(B=2, T=16))[..., :12]
+    key = jax.random.PRNGKey(5)
+    one = nn.DenseGeneral((3, H, D))
+    want = one.init(key, x)["params"]
+    got = QKVProjection(H, D).init(key, x)["params"]
+    assert jax.tree.map(jnp.shape, got) == jax.tree.map(jnp.shape, want) \
+        == {"kernel": (12, 3, H, D), "bias": (3, H, D)}
+    np.testing.assert_array_equal(got["kernel"], want["kernel"])
+    got["bias"] = want["bias"] = jnp.asarray(
+        np.random.default_rng(0).normal(size=(3, H, D)), jnp.float32)
+    qkv = one.apply({"params": want}, x)
+    for i, part in enumerate(QKVProjection(H, D).apply({"params": got}, x)):
+        np.testing.assert_allclose(part, qkv[:, :, i], rtol=1e-6, atol=1e-6)
+    tree = MultiHeadAttention(H * D, H).init(key, jnp.zeros((1, 4, H * D)))
+    assert jax.tree.map(jnp.shape, tree["params"]) == {
+        "qkv": {"kernel": (H * D, 3, H, D), "bias": (3, H, D)},
+        "out": {"kernel": (H, D, H * D), "bias": (H * D,)}}
+
+
 def test_tracing_a_flash_model_records_the_kernels_geometry():
     """`iotml_flash_grid_steps{kernel}` and its neighbours say what each
     kernel's last compiled call engaged: one value a kernel, the grid
@@ -98,6 +126,7 @@ def test_tracing_a_flash_model_records_the_kernels_geometry():
     from iotml.obs.metrics import default_registry
     from iotml.ops import attention
 
+    jax.clear_caches()   # recorded when a shape is traced, not on a hit
     B, T, H, D = 2, 300, 2, 16
     flash = SensorFormer(features=18, d_model=H * D, num_heads=H,
                          num_layers=1, attn_mode="flash_interpret")
@@ -106,10 +135,12 @@ def test_tracing_a_flash_model_records_the_kernels_geometry():
     jax.grad(lambda p: jnp.sum(flash.apply({"params": p}, x)))(params)
     got = default_registry.collect()
     for kernel in attention.KERNELS:
-        g = attention.flash_geometry(kernel, T, D, 4, B * H, True)
+        g = attention.flash_geometry(kernel, T, D, 4, B, H, True)
         for name, want in (("grid_steps", g.grid_steps),
                            ("block_q", g.block_q), ("block_k", g.block_k),
-                           ("heads_per_step", g.heads)):
+                           ("heads_per_step", g.heads),
+                           ("lanes_per_step", g.heads * D),
+                           ("operand_copies", 3 if kernel == "fwd" else 6)):
             assert got[f'iotml_flash_{name}{{kernel="{kernel}"}}'] == want
     assert sorted(k for k in got if k.startswith("iotml_flash_grid_steps")) \
         == sorted(f'iotml_flash_grid_steps{{kernel="{k}"}}'
