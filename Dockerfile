@@ -35,7 +35,7 @@ COPY hivemq-mqtt-tensorflow-kafka-realtime-iot-machine-learning-training-inferen
      ./hivemq-mqtt-tensorflow-kafka-realtime-iot-machine-learning-training-inference_tpu
 COPY tests ./tests
 COPY deploy ./deploy
-COPY bench.py __graft_entry__.py ./
+COPY __graft_entry__.py ./
 
 # short import alias (mirrors the repo's `iotml` symlink)
 RUN ln -s hivemq-mqtt-tensorflow-kafka-realtime-iot-machine-learning-training-inference_tpu iotml \

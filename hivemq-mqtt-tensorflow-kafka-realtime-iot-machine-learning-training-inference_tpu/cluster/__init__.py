@@ -1,8 +1,8 @@
 """iotml.cluster — partitioned multi-broker data plane.
 
-The single-leader broker saturated at ~13.3k rec/s end to end
-(BENCH_r05) while one TPU chip trains at 60k rec/s: the data plane, not
-the compute, became the ceiling.  This package shards topic partitions
+One leader serves every partition of a single broker, so the data
+plane saturates before the compute does (neither rate is measured on
+the chip).  This package shards topic partitions
 across N live brokers — the reference's 10-partitions / 3-brokers shape
 (PAPER.md L3) — and makes every client partition-aware:
 

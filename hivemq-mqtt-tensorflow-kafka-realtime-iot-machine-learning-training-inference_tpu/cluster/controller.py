@@ -5,7 +5,7 @@ each), the shared ``PartitionMap``, and optionally one ``FollowerReplica``
 per shard.  It is the ZooKeeper-controller role of the reference's
 3-broker deployment (PAPER.md L3), scoped the way this rebuild scopes
 infrastructure: in-process objects speaking the real wire protocol, so
-the same code drives tests, chaos drills, the CLI and the bench.
+the same code drives tests, chaos drills and the CLI.
 
 Topology on disk (``store_root=``)::
 

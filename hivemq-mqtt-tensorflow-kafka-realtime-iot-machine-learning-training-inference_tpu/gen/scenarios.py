@@ -26,8 +26,8 @@ condition here targets one:
 
 Everything is seeded and wall-clock-free: the same (scenario,
 condition, seed) triple generates the byte-identical stream, which is
-what lets bench score each condition with the detection-quality and
-saturation harnesses instead of merely narrating it.
+what lets a harness score each condition instead of merely narrating
+it.
 """
 
 from __future__ import annotations
@@ -76,7 +76,7 @@ class FleetCondition:
     schema_v2_fraction: float = 0.0
 
 
-#: the scenario suite bench + chaos drill by name
+#: the scenario suite the chaos drill runs by name
 FLEET_CONDITIONS: Dict[str, FleetCondition] = {
     "baseline": FleetCondition(
         "baseline", "the reference's benign fleet, unmodified"),

@@ -11,8 +11,8 @@ system.  This package closes it:
   pointers with promote/rollback history;
 - ``AsyncCheckpointer``: device→host snapshot on the train thread,
   serialize+fsync on a supervised writer thread behind a bounded
-  drop-oldest queue — checkpointing never stalls training
-  (``bench_checkpoint`` pins the claim); group offsets commit only
+  drop-oldest queue, so the train thread never waits on a write
+  (the stall: not measured on the chip); group offsets commit only
   AFTER the checkpoint is durable, so model state and stream position
   always resume consistently;
 - ``RegistryWatcher``: scorers hot-swap to a newly promoted version

@@ -17,9 +17,6 @@ Usage:
 The program's own phases (`iotml.train.fit`, `.host_pipeline`, `.fetch`,
 `.decode`, `.dispatch`, ... — `obs.tracing.phase`) are already spans of
 that capture's host plane; `annotate` adds a caller's own.
-
-`bench.py` honors `IOTML_PROFILE=<dir>` to capture its warm measurement
-pass without changing the bench contract.
 """
 
 from __future__ import annotations
@@ -53,7 +50,7 @@ def annotate(name: str):
 @contextlib.contextmanager
 def maybe_trace(logdir: Optional[str]) -> Iterator[None]:
     """`trace` when a directory is given, no-op otherwise — for call sites
-    driven by an env var (e.g. bench.py's IOTML_PROFILE)."""
+    driven by an optional setting."""
     if logdir:
         with trace(logdir):
             yield

@@ -18,8 +18,8 @@ past it:
   the A/B rollback gate protecting against a bad adaptation.
 
 Proof lives in ``iotml.online.drill`` (the live drift-adapt-swap
-drill), the ``drift-storm`` chaos scenario, and ``bench_online``'s
-online-vs-micro-batch comparison.  Lint rule R13 keeps model updates
+drill) and the ``drift-storm`` chaos scenario; online against
+micro-batch throughput is not measured on the chip.  Lint rule R13 keeps model updates
 flowing through the registry — no in-place ``set_params`` on a serving
 scorer outside the mlops/online machinery.
 """

@@ -255,8 +255,7 @@ def drill_drift_adapt_swap(seed: int = 7, records: int = 12_000,
             f"{auc_post and round(auc_post, 3)} recovered "
             f"(within {auc_margin} of pre, or a >=30%-of-dip heal — "
             f"a drifted COHORT MIX can have a lower quality ceiling "
-            f"than the pristine fleet; the quantitative online-vs-"
-            f"micro-batch trajectory is bench_online's)"),
+            f"than the pristine fleet)"),
         Invariant(
             "zero_lost_zero_double_scored",
             scorer.scored == live_records

@@ -23,8 +23,7 @@ Discipline shared with ``ContinuousTrainer`` (train/live.py):
 
 The drift signal is the train step's own pre-update loss — the step
 computes it anyway, so detection costs zero extra device dispatches
-and incremental updates stay within the throughput SLO
-(``bench_online`` pins >= 80% of micro-batch train throughput).
+(throughput against micro-batch training: not measured on the chip).
 """
 
 from __future__ import annotations

@@ -60,7 +60,7 @@ def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int,
     diagonal tile so their dk/dv output block is still zero-written.
 
     Cost bound: the maps hold ~nq·nk/2 int32 pairs (vectorized numpy —
-    no Python loop), shipped through scalar prefetch.  At the benched
+    no Python loop), shipped through scalar prefetch.  At the
     long-context shape (T=65,536, 1024² tiles) that is 2,080 tiles =
     16 KB; callers picking tiny blocks at huge T pay O((T/block)²)
     map memory, which `_flash_forward` caps (falls back to the dense

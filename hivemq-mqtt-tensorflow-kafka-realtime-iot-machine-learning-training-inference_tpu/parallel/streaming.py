@@ -450,104 +450,3 @@ def shard_mean_losses(row_loss, valid_counts: Sequence[int]) -> np.ndarray:
                          f"{len(valid_counts)} feeds")
     return np.asarray([float(np.asarray(p.data).sum()) / max(c, 1)
                        for p, c in zip(pieces, valid_counts)])
-
-
-# --------------------------------------------------------------- benching
-def leg_record(leg: str, devices: int, records: int, seconds: float,
-               loss_first: Optional[float], loss_last: Optional[float],
-               **extra) -> dict:
-    """One scaling-curve leg in the SHARED schema: `bench_multichip`
-    (bench.py) and the driver's MULTICHIP_r* harness
-    (__graft_entry__.dryrun_multichip) both emit exactly this, so
-    curves are comparable across rounds and sources."""
-    rec = {"leg": leg, "devices": int(devices), "records": int(records),
-           "seconds": round(float(seconds), 4),
-           "records_per_sec": round(records / seconds, 1)
-           if seconds > 0 else 0.0,
-           "loss_first": None if loss_first is None
-           else round(float(loss_first), 6),
-           "loss_last": None if loss_last is None
-           else round(float(loss_last), 6)}
-    rec.update(extra)
-    return rec
-
-
-def bench_leg(n_devices: int, records: int = 40_000,
-              warmup_records: int = 8_000, batch_size: int = 100,
-              partitions: int = 8, store_dir: Optional[str] = None) -> dict:
-    """One measured point of the 1→N scaling curve: a durable columnar
-    broker seeded with ``warmup + records`` rows, partition-parallel
-    feeds over the first ``n_devices`` local devices, device-side
-    normalization ON, one warm (compile) round, then a timed drain of
-    the remaining stream through the sharded step.
-
-    Runs in-process over `jax.devices()[:n]` — the caller owns the
-    device count (bench.py spawns one child per leg with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; tests call
-    it directly under the suite's 8-virtual-device mesh)."""
-    import shutil
-    import tempfile
-
-    import jax
-
-    from ..core.normalize import CAR_NORMALIZER
-    from ..gen.simulator import FleetGenerator, FleetScenario
-    from ..models.autoencoder import CAR_AUTOENCODER
-    from ..store.log import StorePolicy
-    from ..stream.broker import Broker
-    from .mesh import make_mesh
-
-    if n_devices > len(jax.devices()):
-        raise ValueError(f"need {n_devices} devices, have "
-                         f"{len(jax.devices())}")
-    tmp = None
-    if store_dir is None:
-        tmp = store_dir = tempfile.mkdtemp(prefix="iotml_multichip_")
-    broker = None
-    feeds = None
-    try:
-        broker = Broker(store_dir=store_dir,
-                        store_policy=StorePolicy(fsync="never"))
-        num_cars = 100
-        gen = FleetGenerator(FleetScenario(num_cars=num_cars,
-                                           failure_rate=0.01))
-        total = warmup_records + records
-        gen.publish(broker, "SENSOR_DATA_S_AVRO",
-                    n_ticks=max(total // num_cars, 1),
-                    partitions=partitions)
-        mesh = make_mesh((n_devices,), ("data",),
-                         devices=jax.devices()[:n_devices])
-        feeds = MeshFeeds(broker, "SENSOR_DATA_S_AVRO", n_devices,
-                          group=f"multichip-bench-{n_devices}",
-                          batch_size=batch_size, only_normal=True,
-                          device_normalize=True)
-        trainer = ShardedStreamTrainer(CAR_AUTOENCODER, mesh, feeds,
-                                       normalizer=CAR_NORMALIZER)
-        # warm round: bounded per-feed take → compile + cache warm
-        warm_take = max(warmup_records // (n_devices * batch_size), 1)
-        feeds.set_take(warm_take)
-        warm = trainer.fit_round()
-        # timed leg: drain the rest of the stream through the mesh
-        feeds.set_take(None)
-        t0 = time.perf_counter()
-        hist = trainer.fit_round()
-        seconds = time.perf_counter() - t0
-        trained = hist["records"][-1] if hist["records"] else 0
-        step_losses = (warm.get("step_loss") or []) + \
-            (hist.get("step_loss") or [])
-        return leg_record(
-            "streaming dp", n_devices, trained, seconds,
-            step_losses[0] if step_losses else None,
-            step_losses[-1] if step_losses else None,
-            per_device_batch=batch_size, partitions=partitions,
-            steps=hist.get("steps", 0), device_normalize=True)
-    finally:
-        # close on EVERY exit: a raised round must not leak broker
-        # threads / open segments into the calling process (tests run
-        # this in-process)
-        if feeds is not None:
-            feeds.close()
-        if broker is not None:
-            broker.close()
-        if tmp is not None:
-            shutil.rmtree(tmp, ignore_errors=True)

@@ -6,7 +6,7 @@ anomalies"), yet its pipeline stops at per-record reconstruction error.
 Per-record detection is noise-limited: the car autoencoder's irreducible
 error (unpredictable sensors: air temp, accelerometers, per-car tire
 baselines) overlaps the failure modes' per-record signal, capping
-per-record F1 near 0.6 (ARCHITECTURE.md; the e2e bench measures it live).
+per-record F1 near 0.6 (ARCHITECTURE.md).
 A car's failure, however, PERSISTS: every record it emits is drawn from
 the shifted distribution, so averaging per-record errors over a car's
 recent records shrinks the noise by ~1/√N while the failure signal stays

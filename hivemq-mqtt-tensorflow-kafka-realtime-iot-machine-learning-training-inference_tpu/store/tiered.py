@@ -381,7 +381,7 @@ class TieredLog(SegmentedLog):
         budget (``tier.local_hot_bytes``); the records stay readable
         through the remote fall-through.  An explicit ``budget_bytes``
         overrides the policy (0 = evict every covered sealed segment —
-        the cold-backfill bench and the trim tests use this)."""
+        the trim tests use this)."""
         if self.remote is None:
             return 0
         budget = self.tier.local_hot_bytes if budget_bytes is None \

@@ -503,7 +503,7 @@ class KafkaWireBroker(ProducePartitionMixin):
         #: default required_acks for produce paths (None = -1, the
         #: classic client default: quorum where the topic is
         #: replicated, leader-ack otherwise — Kafka RF-1 semantics).
-        #: Per-call `acks=` overrides (the bench's acks=1 leg).
+        #: Per-call `acks=` overrides.
         self._acks = -1 if acks is None else int(acks)
         #: >= 0 marks this client as replica `replica_id`'s mirror leg:
         #: FETCH/RAW_FETCH carry the id, the leader tracks the fetch

@@ -26,9 +26,8 @@ from ..obs import tracing
 from ..obs.metrics import default_registry as _metrics
 from .broker import Broker
 
-#: write-plane telemetry — the produce-leg breakdown the e2e bench
-#: publishes (convert+frame seconds live with the encoder; these cover
-#: the append/ship leg)
+#: write-plane telemetry — the produce-leg breakdown (convert+frame
+#: seconds live with the encoder; these cover the append/ship leg)
 raw_produce_records = _metrics.counter(
     "iotml_raw_produce_records_total",
     "records shipped as pre-framed RAW_PRODUCE batches")

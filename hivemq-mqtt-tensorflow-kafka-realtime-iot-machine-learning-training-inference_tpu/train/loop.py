@@ -164,7 +164,7 @@ def make_scanned_fit(model, tx, supervised: bool = False):
 # jax.jit caches per function object; a fresh closure per fit_compiled call
 # would re-trace (and without backend caching, re-compile) every time.  Keyed
 # on (model, tx identity-or-descriptor, supervised) so repeated jobs — e.g.
-# bench warm passes, periodic retrains — reuse the compiled program.
+# periodic retrains — reuse the compiled program.
 # Bounded LRU (not a bare dict): the closures hold their models strongly,
 # so an unbounded cache in a long-lived process that rebuilds models per
 # retrain cycle would pin every dead model and compiled program forever.

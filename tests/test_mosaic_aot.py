@@ -64,7 +64,7 @@ def test_flash_attention_fwd_bwd_lowers_for_v5e(v5e):
 @pytest.mark.parametrize("shape,dtype", [
     ((4, 1024, 16, 64), jnp.float32),     # `sf-train-backlog`, exactly
     ((16, 256, 16, 64), jnp.float32),     # the short window
-    ((1, 65536, 2, 128), jnp.bfloat16),   # bench.py's long context
+    ((1, 65536, 2, 128), jnp.bfloat16),   # the long context
     ((2, 512, 3, 64), jnp.float32),       # odd heads: all 192 lanes a step
     ((2, 512, 4, 8), jnp.float32),        # a model 32 lanes wide
     ((2, 300, 6, 64), jnp.bfloat16),      # padded T, 16-row sublane tiles

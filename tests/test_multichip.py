@@ -13,8 +13,8 @@ from iotml.gen.simulator import FleetGenerator, FleetScenario
 from iotml.parallel.distributed import assign_partitions
 from iotml.parallel.mesh import make_mesh
 from iotml.parallel.streaming import (MeshFeeds, ShardedStreamTrainer,
-                                      bench_leg, data_axis_devices,
-                                      leg_record, shard_mean_losses)
+                                      data_axis_devices, shard_mean_losses)
+from iotml.store.log import StorePolicy
 from iotml.stream.broker import Broker
 
 
@@ -212,14 +212,26 @@ def test_raw_columns_normalizer_is_passthrough():
 
 
 # ----------------------------------------------------- sharded training
-def test_sharded_stream_trainer_trains_and_tracks():
+@pytest.fixture(params=[False, True], ids=["in-memory", "durable"])
+def stream_broker(request, tmp_path):
+    """The in-memory broker, and one with segmented partitions on disk
+    (the feeds' columnar leg where the native engine is built)."""
+    broker = Broker(store_dir=str(tmp_path),
+                    store_policy=StorePolicy(fsync="never")) \
+        if request.param else Broker()
+    yield broker
+    broker.close()
+
+
+def test_sharded_stream_trainer_trains_and_tracks(stream_broker, request):
     from iotml.models.autoencoder import CAR_AUTOENCODER
 
-    broker = Broker()
+    broker = stream_broker
     n = _fill(broker, n_ticks=120, failure_rate=0.0)
     mesh = _mesh(4)
     feeds = MeshFeeds(broker, "S", 4, group="train", batch_size=50,
                       only_normal=True, device_normalize=True)
+    request.addfinalizer(feeds.close)
     tr = ShardedStreamTrainer(CAR_AUTOENCODER, mesh, feeds,
                               normalizer=CAR_NORMALIZER)
     h = tr.fit_round()
@@ -415,34 +427,6 @@ def test_mesh_knob_validation(monkeypatch):
     finally:
         __import__("os").environ.pop("IOTML_MESH_DATA", None)
         __import__("os").environ.pop("IOTML_DEVICE_NORMALIZE", None)
-
-
-# --------------------------------------------------------- bench schema
-def test_bench_leg_matches_shared_schema():
-    """bench_multichip legs and the MULTICHIP_r* harness legs must stay
-    comparable: both come from leg_record, and bench_leg's output
-    carries the shared keys."""
-    leg = bench_leg(2, records=2000, warmup_records=1000, batch_size=50)
-    shared = {"leg", "devices", "records", "seconds", "records_per_sec",
-              "loss_first", "loss_last"}
-    assert shared <= set(leg)
-    assert leg["devices"] == 2 and leg["records"] > 0
-    assert leg["records_per_sec"] > 0
-    assert leg["loss_last"] < leg["loss_first"]
-    ref = leg_record("x", 1, 10, 1.0, None, None)
-    assert shared <= set(ref)
-
-
-def test_bench_tables_consistent():
-    """run_named derives from the same tables main() prints from —
-    every directly-runnable bench must resolve to a known metric and a
-    real function (the anti-drift pin)."""
-    import bench
-
-    units = {m for m, _u, _b in bench.METRIC_ORDER}
-    for fn_name, metric in bench.SINGLE_BENCH.items():
-        assert metric in units, (fn_name, metric)
-        assert callable(getattr(bench, fn_name, None)), fn_name
 
 
 def test_shard_mean_losses_maps_chips_in_feed_order():
