@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
 from ..ops.attention import attention_reference, flash_attention
-from ..ops.ssd import causal_conv1d, ssd_scan
+from ..ops.ssd import causal_conv1d_silu, ssd_scan
 
 KINDS = ("mamba", "attention")
 _normal = nn.initializers.normal(0.02)
@@ -97,14 +97,15 @@ class MambaMixer(nn.Module):
                 _dense(2 * inner + 2 * N + H, "in_proj")(u),
                 [inner, 2 * inner + 2 * N], axis=-1)
         with jax.named_scope("conv"):
-            xbc = nn.silu(causal_conv1d(
+            # out as the scan's three operands: nothing splits it again
+            x, b, c = causal_conv1d_silu(
                 xbc,
                 self.param("conv_kernel", _normal,
                            (m.conv_width, inner + 2 * N)),
                 self.param("conv_bias", nn.initializers.zeros,
-                           (inner + 2 * N,))))
+                           (inner + 2 * N,)),
+                splits=(inner, N, N))
         with jax.named_scope("ssd"):
-            x, b, c = jnp.split(xbc, [inner, inner + N], axis=-1)
             x = x.reshape(B, T, H, P)
             dt = nn.softplus(dt + self.param("dt_bias", _dt_bias_init, (H,)))
             a = -jnp.exp(self.param("A_log", _a_log_init, (H,)))

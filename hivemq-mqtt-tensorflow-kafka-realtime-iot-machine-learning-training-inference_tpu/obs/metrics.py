@@ -466,6 +466,22 @@ ssd_chunks = default_registry.gauge(
 ssd_state_bytes = default_registry.gauge(
     "iotml_ssd_state_bytes",
     "bytes of recurrent state one sequence holds in one state-space layer")
+# the convolution kernels ahead of the scan (ops/ssd.py
+# `causal_conv1d_silu`), set where the calls of a direction (fwd | bwd)
+# are built: what `conv_geometry` gave the last traced convolution.
+conv_grid_steps = default_registry.gauge(
+    "iotml_conv_grid_steps",
+    "grid steps the convolution kernel's calls of one direction take, "
+    "by kernel")
+conv_block_t = default_registry.gauge(
+    "iotml_conv_block_t", "positions a block of the convolution kernels holds")
+conv_block_c = default_registry.gauge(
+    "iotml_conv_block_c", "channels a block of the convolution kernels holds")
+conv_operand_copies = default_registry.gauge(
+    "iotml_conv_operand_copies",
+    "operands of the convolution kernels copied ahead of them by their "
+    "wrapper (a run of channels sliced out of its array, positions padded "
+    "to whole blocks); XLA's own layout copies around a call are not counted")
 model_layers = default_registry.gauge(
     "iotml_model_layers",
     "layers of the last traced hybrid model, by kind (mamba | attention)")
@@ -530,6 +546,8 @@ DECLARED_METRIC_LABELS = {
     "compile_cache": ("result",),
     "compile_seconds": ("program", "stage"),
     "consumer_autoresets": ("topic",),
+    "conv_grid_steps": ("kernel",),
+    "conv_operand_copies": ("kernel",),
     "consumer_lag_records": ("group", "partition", "topic"),
     "dlq_total": ("source",),
     "flash_block_k": ("kernel",),

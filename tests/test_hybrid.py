@@ -2,8 +2,9 @@
 attention): the chunked scan against the step-by-step recurrence, the
 model and one compiled job against the benchmark's plain reference
 (loaded by path, as `benchmark/tests` loads it), grouped heads and an
-explicit scale through the flash kernels, and what the trace-time
-counters say.  All at a tiny preset on the CPU."""
+explicit scale through the flash kernels, the convolution kernels
+(interpreted) against the plain form, and what the trace-time counters
+say.  All at a tiny preset on the CPU."""
 
 import importlib.util
 import json
@@ -16,7 +17,8 @@ import pytest
 
 from iotml.models.hybrid import HybridConfig, SensorHybrid
 from iotml.ops.attention import attention_reference, flash_attention
-from iotml.ops.ssd import causal_conv1d, ssd_scan
+from iotml.ops import ssd
+from iotml.ops.ssd import causal_conv1d, causal_conv1d_silu, ssd_scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = os.path.join(ROOT, "benchmark", "configs",
@@ -96,20 +98,85 @@ def test_chunked_scan_takes_decays_a_naive_exp_would_overflow(ref):
     _close(got, want)
 
 
-def test_causal_conv_matches_the_grouped_convolution():
+@pytest.fixture
+def short_blocks(monkeypatch):
+    """Blocks of one lane tile (128 positions), so that a few hundred
+    positions are several blocks: the tile ahead of a block and the
+    backward's carry from block to block are in play."""
+    monkeypatch.setattr(ssd, "_CONV_MAX_T", 128)
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_causal_conv_matches_the_grouped_convolution(form, short_blocks):
     rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.normal(size=(2, 13, 24)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(2, 141, 24)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(4, 24)), jnp.float32)
     bias = jnp.asarray(rng.normal(size=(24,)), jnp.float32)
     want = jax.lax.conv_general_dilated(
         x, w[:, None, :], (1,), [(3, 0)],
         dimension_numbers=("NWC", "WIO", "NWC"),
         feature_group_count=24) + bias
-    _close(causal_conv1d(x, w, bias), want, rtol=1e-6)
+    conv = causal_conv1d
+    if form == "kernel":   # the activation inside, two blocks of 128
+        want = jax.nn.silu(want)
+        conv = lambda *a: causal_conv1d_silu(*a)[0]  # noqa: E731
+    _close(conv(x, w, bias), want, rtol=1e-6)
     # causal: a later input moves no earlier output
-    moved = causal_conv1d(x.at[:, 9:].set(0.0), w, bias)
-    np.testing.assert_array_equal(np.asarray(moved[:, :9]),
-                                  np.asarray(causal_conv1d(x, w, bias)[:, :9]))
+    moved = conv(x.at[:, 129:].set(0.0), w, bias)
+    np.testing.assert_array_equal(np.asarray(moved[:, :129]),
+                                  np.asarray(conv(x, w, bias)[:, :129]))
+
+
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("T", [5, 256, 200])   # under a block of 128, a
+@pytest.mark.parametrize("C", [80, 256, 384])  # multiple, a ragged tail
+def test_conv_kernels_match_the_plain_form(C, T, K, B, short_blocks):
+    """Output, dx, dkernel and dbias of `causal_conv1d_silu` against
+    silu(causal_conv1d) and its `jax.grad`, in three runs (the second
+    and the third read from a later row block of x); and which operands
+    the wrapper had to copy."""
+    from iotml.obs.metrics import default_registry
+
+    rng = np.random.default_rng(C + T + K + B)
+    f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, kernel, bias = f32(B, T, C), f32(K, C), f32(C)
+    n = C // 3 // 4 * 4
+    splits = (C - 2 * n, n, n)   # 80: 32 + 24 + 24; 256: 88 + 84 + 84
+    weights = tuple(f32(B, T, w) for w in splits)
+
+    def plain(x, kernel, bias):
+        y = jax.nn.silu(causal_conv1d(x, kernel, bias))
+        return jnp.split(y, [splits[0], splits[0] + n], axis=-1)
+
+    def kernels(x, kernel, bias):
+        return causal_conv1d_silu(x, kernel, bias, splits=splits)
+
+    got, want = (jax.value_and_grad(
+        lambda *a: sum(jnp.sum(w * y) for w, y in zip(weights, f(*a))),
+        argnums=(0, 1, 2))(x, kernel, bias) for f in (kernels, plain))
+    _close(kernels(x, kernel, bias), plain(x, kernel, bias), rtol=2e-6)
+    _close(got, want, rtol=2e-5)
+    # in place: a run of whole sublane tiles that starts on one (all of
+    # 80's and 384's, the first of 256's: 84 channels are no whole
+    # tiles), its positions whole lane tiles (256 alone); else x is
+    # sliced out and padded, and dy with it
+    said = default_registry.collect()
+    copied = {80: 0, 256: 2, 384: 0}[C] if T == 256 else 3
+    assert said['iotml_conv_operand_copies{kernel="fwd"}'] == copied
+    assert said['iotml_conv_operand_copies{kernel="bwd"}'] == 2 * copied
+    assert said["iotml_conv_block_t"] == 128
+    assert said["iotml_conv_block_c"] == {80: 32, 256: 88, 384: 128}[C]
+
+
+def test_conv_kernels_refuse_what_they_cannot_place():
+    x, bias = jnp.zeros((1, 16, 24)), jnp.zeros((24,))
+    with pytest.raises(ValueError, match="are not the 24"):
+        causal_conv1d_silu(x, jnp.zeros((4, 24)), bias, splits=(16, 16))
+    with pytest.raises(ValueError, match="are not the 24"):
+        causal_conv1d_silu(jnp.zeros((1, 16, 32)), jnp.zeros((4, 24)), bias)
+    with pytest.raises(ValueError, match="reach past"):
+        causal_conv1d_silu(x, jnp.zeros((130, 24)), bias)
 
 
 # ------------------------------------------------------------ the model
@@ -184,7 +251,8 @@ def test_layer_types_are_data_of_the_model():
 
 def test_a_tiny_fit_says_what_engaged():
     """The trace-time counters after a fit: the scan's chunking, the
-    state a sequence holds, the layers by kind, the recomputed blocks."""
+    state a sequence holds, the convolution kernels' blocks, the layers
+    by kind, the recomputed blocks."""
     from iotml.data.dataset import Batch
     from iotml.obs.metrics import default_registry
     from iotml.train.loop import Trainer
@@ -199,6 +267,15 @@ def test_a_tiny_fit_says_what_engaged():
     assert got["iotml_ssd_chunks"] == 3          # 21 positions in eights
     assert got["iotml_ssd_state_bytes"] == \
         cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+    # the convolution: runs of 64, 8 and 8 channels of the sliced
+    # stream are whole sublane tiles, but 21 positions fill no lane
+    # tile, so every run is padded (x, and dy in the backward) to 128
+    for kernel, copies in (("fwd", 3), ("bwd", 6)):
+        assert got[f'iotml_conv_grid_steps{{kernel="{kernel}"}}'] == 6
+        assert got[f'iotml_conv_operand_copies{{kernel="{kernel}"}}'] \
+            == copies
+    assert got["iotml_conv_block_t"] == 128
+    assert got["iotml_conv_block_c"] == 64
     assert got['iotml_model_layers{kind="mamba"}'] == 3
     assert got['iotml_model_layers{kind="attention"}'] == 1
     assert got["iotml_remat_blocks"] == 4

@@ -11,6 +11,7 @@ Compilation only: whether the kernels run and agree on the device is
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from iotml.ops import fused_train
 from iotml.ops.attention import flash_attention
+from iotml.ops.ssd import causal_conv1d_silu
 
 
 @pytest.fixture(scope="module")
@@ -95,3 +97,93 @@ def test_flash_attention_grouped_heads_lower_for_v5e(v5e):
         return jnp.sum(flash_attention(q, k, v, causal=True, scale=0.015625))
 
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+
+
+@pytest.mark.parametrize("shape,splits,K,copies", [
+    # `gh-train-backlog`, exactly: the convolved stream as the mixer
+    # hands it over, out as x, B and C (the B and C runs read from row
+    # blocks 32 and 33 of it)
+    ((1, 4096, 4352), (4096, 128, 128), 4, (0, 0)),
+    # blocks with a lane tile ahead of them: T past the longest block
+    ((2, 16384, 512), (512,), 4, (0, 0)),
+    # sublanes that divide nothing and positions that fill no lane
+    # tile: every run sliced out and padded ahead of the kernels
+    ((2, 203, 256), (200, 56), 4, (2, 4)),
+    ((3, 5, 80), (80,), 2, (1, 2)),
+])
+def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
+                                    copies):
+    """`iotml_conv_fwd` and `iotml_conv_bwd` through the entry point and
+    `jax.grad`: a lane roll or a dynamic slice Mosaic cannot place, a
+    block `conv_geometry` sized past scoped VMEM or a row block it
+    refuses fail here and not on the chip.  The backend here is the
+    CPU, so the one place that decides is told to compile."""
+    from iotml.obs.metrics import default_registry
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    C = sum(splits)
+    x, kernel, bias = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
+                       for s in (shape, (K, C), (C,)))
+
+    def loss(x, kernel, bias):
+        return sum(jnp.sum(y * y) for y in causal_conv1d_silu(
+            x, kernel, bias, splits=splits))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, kernel, bias).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 2 * len(splits)
+    said = default_registry.collect()
+    assert (said['iotml_conv_operand_copies{kernel="fwd"}'],
+            said['iotml_conv_operand_copies{kernel="bwd"}']) == copies
+
+
+def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
+    """What `iotml_conv_operand_copies` cannot see: the kernels work on
+    [B, channels, T] because that is how XLA lays the mixer's tensors
+    out in the compiled fit, so the `swapaxes` around the calls are
+    bitcasts.  A transposing copy at an edge has a channel-minor array
+    on one side, and on [B, T, channels] rows the cell's fit ran 5%
+    slower than with no kernel at all (PERF.md §6, PR 29): so the
+    scanned fit of one Mamba block at `gh-train-backlog`'s widths, Adam
+    and all, compiled for the described v5e, holds no array of the
+    stream's size — `in_proj`'s [1, 4096, 8512] product, the
+    [1, 4096, 4352] the convolution reads and writes, their cotangents
+    — with the channels fastest."""
+    import optax
+
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+    from iotml.train.loop import TrainState, make_scanned_fit
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    T = 4096
+    model = SensorHybrid(HybridConfig(
+        d_model=2048, layer_types=("mamba",), num_heads=32, num_kv_heads=8,
+        mlp_dim=8192, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+        conv_width=4, chunk=256))
+    tx = optax.adam(1e-5)
+
+    def fresh(rng, x):
+        params = model.init(rng, x)["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=tx.init(params), apply_fn=model.apply,
+                          tx=tx)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    state = described(jax.eval_shape(fresh, jax.random.PRNGKey(0),
+                                     jnp.zeros((1, T, 18))))
+    xs, ys, masks = described(tuple(
+        jax.ShapeDtypeStruct(s, jnp.float32)
+        for s in ((4, 1, T, 18), (4, 1, 1, 18), (4, 1))))
+    text = make_scanned_fit(model, tx, supervised=True).lower(
+        state, xs, ys, masks, epochs=2).compile().as_text()
+    assert "iotml_conv_fwd" in text and "iotml_conv_bwd" in text
+    # minor-to-major {1,2,0} of [1, T, C] and {2,1,0} of [1, C, T] are
+    # the same bytes: time fastest
+    laid = set(re.findall(r"f32\[1,(?:4096,(?:4352|8512)|(?:4352|8512),4096)"
+                          r"\]\{[\d,]+", text))
+    assert laid and laid <= {"f32[1,4096,4352]{1,2,0", "f32[1,4352,4096]{2,1,0",
+                             "f32[1,4096,8512]{1,2,0", "f32[1,8512,4096]{2,1,0"}
