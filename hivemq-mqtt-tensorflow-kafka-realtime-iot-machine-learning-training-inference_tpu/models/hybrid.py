@@ -20,6 +20,13 @@ Every block is recomputed in the backward pass (`nn.remat`): a window of
 thousands of positions keeps one [T, d_model] input a block instead of
 each block's projections, decay tiles and MLP activations.
 
+The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
+`attention`, or `mla`, the latent attention of `models.latent_moe`) and
+the feed-forward part (`ffn_types`: `dense_ffn`, the gated MLP above, or
+`moe_ffn`, that file's sparse-expert layer; left empty, every layer is
+`dense_ffn`).  Both are data of the model, as published configurations
+state them.
+
 The recurrent state (ssm_heads × ssm_head_dim × ssm_state a layer and
 sequence) starts at zero at the window's start; carrying it from window
 to window, and one-step decoding against it, is the scorer's later work
@@ -39,9 +46,13 @@ import jax.numpy as jnp
 from ..obs import metrics as obs_metrics
 from ..ops.attention import attention_reference, flash_attention
 from ..ops.ssd import causal_conv1d_silu, ssd_scan
+from . import latent_moe
+from .latent_moe import ExpertLayer, LatentAttention, gated_mlp
+from .latent_moe import dense as _dense
+from .latent_moe import normal as _normal
 
-KINDS = ("mamba", "attention")
-_normal = nn.initializers.normal(0.02)
+KINDS = ("mamba", "attention", "mla")
+FFN_KINDS = ("dense_ffn", "moe_ffn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +75,27 @@ class HybridConfig:
     residual_multiplier: float = 0.22
     attention_multiplier: float = 0.015625
     logits_scaling: float = 8.0
+    # the feed-forward kind a layer; () is `dense_ffn` in every layer
+    ffn_types: Tuple[str, ...] = ()
+    # latent attention (`mla`): the latent's rank, and a head's features
+    # without positions, with rotary positions, and of its values
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    rope_theta: float = 10000.0
+    # sparse experts (`moe_ffn`): routed over `experts`, `top_k` a
+    # token; (first, count) of those held here; an expert's width, and
+    # the shared expert's
+    experts: int = 8
+    experts_held: Tuple[int, int] = (0, 8)
+    top_k: int = 2
+    expert_dim: int = 32
+    shared_dim: int = 32
+    routed_scale: float = 1.0
+
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        return self.ffn_types or ("dense_ffn",) * len(self.layer_types)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -77,10 +109,6 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
                                     math.log(1e-1)))
     return dt + jnp.log(-jnp.expm1(-dt))   # the inverse of softplus
-
-
-def _dense(features: int, name: str):
-    return nn.Dense(features, use_bias=False, kernel_init=_normal, name=name)
 
 
 class MambaMixer(nn.Module):
@@ -148,6 +176,7 @@ class HybridBlock(nn.Module):
     kind: str
     cfg: HybridConfig
     attn_mode: str
+    ffn: str = "dense_ffn"
 
     @nn.compact
     def __call__(self, h):
@@ -156,14 +185,18 @@ class HybridBlock(nn.Module):
         if self.kind == "mamba":
             mixed = MambaMixer(m, name="mixer")(u)
         else:
+            attention = LatentAttention if self.kind == "mla" \
+                else GroupedAttention
             with jax.named_scope("attn"):
-                mixed = GroupedAttention(m, self.attn_mode, name="mixer")(u)
+                mixed = attention(m, self.attn_mode, name="mixer")(u)
         h = h + m.residual_multiplier * mixed
-        with jax.named_scope("mlp"):
-            gate, value = jnp.split(
-                _dense(2 * m.mlp_dim, "mlp_in")(
-                    nn.RMSNorm(epsilon=m.eps, name="norm2")(h)), 2, axis=-1)
-            out = _dense(m.d_model, "mlp_out")(nn.silu(gate) * value)
+        if self.ffn == "moe_ffn":
+            out = ExpertLayer(m, name="moe")(
+                nn.RMSNorm(epsilon=m.eps, name="norm2")(h))
+        else:
+            with jax.named_scope("mlp"):
+                out = gated_mlp(nn.RMSNorm(epsilon=m.eps, name="norm2")(h),
+                                m.mlp_dim, m.d_model)
         return h + m.residual_multiplier * out
 
 
@@ -174,23 +207,40 @@ class SensorHybrid(nn.Module):
     features: int = 18
     attn_mode: str = "dense"
 
+    @property
+    def report_collections(self) -> Tuple[str, ...]:
+        """The variable collections this model's layers report data in
+        (`Trainer` reads them back with the losses); none without an
+        expert layer, and the fit is then the program it always was."""
+        return (latent_moe.REPORTS,) if "moe_ffn" in self.cfg.ffn_kinds() \
+            else ()
+
+    def record_reports(self, reports) -> None:
+        latent_moe.record_reports(self.cfg, reports)
+
     @nn.compact
     def __call__(self, x):
         m = self.cfg
-        unknown = set(m.layer_types) - set(KINDS)
-        if unknown:
-            raise ValueError(f"layer_types holds {sorted(unknown)}; "
-                             f"known kinds are {KINDS}")
+        ffns = m.ffn_kinds()
+        unknown = (set(m.layer_types) - set(KINDS)) \
+            | (set(ffns) - set(FFN_KINDS))
+        if unknown or len(ffns) != len(m.layer_types):
+            raise ValueError(
+                f"layer_types {m.layer_types} and ffn_types {m.ffn_types}: "
+                f"known kinds are {KINDS} and {FFN_KINDS}, one of each a "
+                f"layer")
         # what engaged, at trace time (as the flash geometry is said)
         for kind in KINDS:
             obs_metrics.model_layers.set(m.layer_types.count(kind),
                                          kind=kind)
+        for kind in FFN_KINDS:
+            obs_metrics.model_layers.set(ffns.count(kind), kind=kind)
         obs_metrics.remat_blocks.set(len(m.layer_types))
         h = m.embedding_multiplier * nn.Dense(
             m.d_model, kernel_init=_normal, name="embed")(x)
         block = nn.remat(HybridBlock)
-        for i, kind in enumerate(m.layer_types):
-            h = block(kind, m, self.attn_mode, name=f"layer{i}")(h)
+        for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
+            h = block(kind, m, self.attn_mode, ffn, name=f"layer{i}")(h)
         h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)
         return nn.Dense(self.features, kernel_init=_normal,
                         name="head")(h) / m.logits_scaling

@@ -1,0 +1,144 @@
+"""Two parts a layer of `models.hybrid.SensorHybrid` may be made of
+beside the ones that file holds: a latent-attention mixer and a
+sparse-expert feed-forward layer — the DeepSeek-V3-shaped decoder's.
+
+**Latent attention** (multi-head latent attention in its training
+form, no query low-rank): with `u` the normed stream, `q = u W_q` is H
+heads of `nope + rope` features; `[c, k_pe] = u W_kva` is a latent of
+`kv_rank` and ONE rotary key head shared by all H; `c ← RMSNorm(c)`;
+`[k_nope, v] = c W_kvb` is H heads of `nope + v`; rotary positions turn
+`q_pe` and `k_pe`; `k = [k_nope, k_pe]`; scores `q kᵀ / √(nope+rope)`,
+causal softmax, `o = P v` → `W_o`.  Query/key heads are wider than
+value heads (192 beside 128 at the published widths), which the flash
+kernels take as they are.  The weight-absorbed form and a latent cache
+are serving's and are not here.
+
+**Expert layer:** `y = Σ_k w_k · E_{i_k}(u) + S(u)` — the router's
+`top_k` of ALL `experts` (`ops.moe.route`), the terms of the experts
+HELD here (`experts_held`, a contiguous range: one chip's share under
+expert parallelism; the others' terms are left out, with no exchange
+and nothing standing in for them), and a shared expert every token
+takes, computed whole.  Dropless (`ops.moe.experts_apply`).  It reports
+the assignments every expert got, a step, through the `reports`
+collection: data, not shape, so it comes back with the losses.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..obs import metrics as obs_metrics
+from ..ops import moe
+from ..ops.attention import attention_reference, flash_attention
+
+normal = nn.initializers.normal(0.02)
+#: the variable collection a layer's data-dependent counts ride out in
+REPORTS = "reports"
+
+
+def dense(features: int, name: str):
+    return nn.Dense(features, use_bias=False, kernel_init=normal, name=name)
+
+
+def gated_mlp(u, width: int, d_model: int, name: str = "mlp"):
+    """(silu(g) ⊙ v) W_out with [g, v] = u W_in: `<name>_in`, `<name>_out`
+    in the calling module's scope."""
+    gate, value = jnp.split(dense(2 * width, f"{name}_in")(u), 2, axis=-1)
+    return dense(d_model, f"{name}_out")(nn.silu(gate) * value)
+
+
+class LatentAttention(nn.Module):
+    cfg: Any         # models.hybrid.HybridConfig
+    attn_mode: str   # dense | flash | flash_interpret
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        B, T, _ = u.shape
+        H, nope, rope, dv = m.num_heads, m.nope_dim, m.rope_dim, m.v_dim
+        q = dense(H * (nope + rope), "q")(u).reshape(B, T, H, nope + rope)
+        c, k_pe = jnp.split(dense(m.kv_rank + rope, "kv_a")(u),
+                            [m.kv_rank], axis=-1)
+        kv = dense(H * (nope + dv), "kv_b")(
+            nn.RMSNorm(epsilon=m.eps, name="kv_norm")(c)
+        ).reshape(B, T, H, nope + dv)
+        with jax.named_scope("rope"):
+            q = jnp.concatenate(
+                [q[..., :nope], moe.rotary(q[..., nope:], m.rope_theta)],
+                axis=-1)
+            k_pe = moe.rotary(k_pe[:, :, None, :], m.rope_theta)
+            # one rotary head for all H: the copy the kernels' layout asks
+            obs_metrics.latent_assembled_operands.set(1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, H, rope))],
+                axis=-1)
+        v = kv[..., nope:]
+        scale = 1.0 / math.sqrt(nope + rope)
+        if self.attn_mode == "dense":
+            o = attention_reference(q, k, v, causal=True, scale=scale)
+        elif self.attn_mode in ("flash", "flash_interpret"):
+            o = flash_attention(
+                q, k, v, causal=True, scale=scale,
+                interpret=self.attn_mode == "flash_interpret")
+        else:
+            raise ValueError(f"unknown attn_mode {self.attn_mode}")
+        return dense(m.d_model, "o")(o.reshape(B, T, H * dv))
+
+
+class ExpertLayer(nn.Module):
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        B, T, d = u.shape
+        first, held = m.experts_held
+        if not 0 <= first < first + held <= m.experts:
+            raise ValueError(f"experts_held {m.experts_held} is no range of "
+                             f"the {m.experts} experts routed over")
+        obs_metrics.moe_experts.set(held, kind="held")
+        obs_metrics.moe_experts.set(m.experts, kind="routed_over")
+        obs_metrics.moe_top_k.set(m.top_k)
+        obs_metrics.moe_dispatch_rows.set(
+            moe.dispatch_rows(B * T, m.top_k, held))
+        x = u.reshape(B * T, d)
+        with jax.named_scope("router"):
+            experts, weights = moe.route(
+                x, self.param("router", normal, (d, m.experts)),
+                self.param("router_bias", normal, (m.experts,)),
+                m.top_k, m.routed_scale)
+            plan = moe.dispatch_plan(experts, weights, first, held, m.experts)
+        self.sow(REPORTS, "expert_counts", plan.counts,
+                 reduce_fn=lambda _, new: new, init_fn=lambda: 0)
+        routed = moe.experts_apply(
+            x, plan,
+            self.param("experts_in", normal, (held, d, 2 * m.expert_dim)),
+            self.param("experts_out", normal, (held, m.expert_dim, d)))
+        with jax.named_scope("shared"):
+            shared = gated_mlp(u, m.shared_dim, d, "shared")
+        return routed.reshape(B, T, d) + shared
+
+
+def record_reports(cfg, reports) -> None:
+    """What a fit's reports say, into the registry: `reports` is the
+    collection read back with the losses, every leaf
+    [epochs, batches, experts] counts of one expert layer."""
+    import numpy as np
+
+    counts = jax.tree.leaves(reports)
+    if not counts:
+        return
+    first, held = cfg.experts_held
+    per_expert = np.sum([np.asarray(c, np.int64).reshape(-1, cfg.experts)
+                         .sum(axis=0) for c in counts], axis=0)
+    here = per_expert[first:first + held]
+    obs_metrics.moe_assignments.inc(float(here.sum()), kind="held")
+    obs_metrics.moe_assignments.inc(
+        float(per_expert.sum() - here.sum()), kind="elsewhere")
+    obs_metrics.moe_expert_load.set(
+        float(here.max() / max(here.mean(), 1e-30)))

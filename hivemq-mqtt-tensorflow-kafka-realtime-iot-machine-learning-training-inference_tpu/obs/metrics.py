@@ -452,6 +452,17 @@ flash_heads_per_step = default_registry.gauge(
 flash_lanes_per_step = default_registry.gauge(
     "iotml_flash_lanes_per_step",
     "lanes of a flash kernel's [B, T, H*D] column block: heads a step x D")
+flash_value_lanes_per_step = default_registry.gauge(
+    "iotml_flash_value_lanes_per_step",
+    "lanes of a flash kernel's [B, T, H*Dv] column block of v and out: "
+    "heads a step x Dv (equal to iotml_flash_lanes_per_step where value "
+    "heads are as wide as query heads)")
+latent_assembled_operands = default_registry.gauge(
+    "iotml_latent_assembled_operands",
+    "operands of its attention call the last traced latent-attention "
+    "mixer assembled by a copy ahead of it (k: the one rotary key head "
+    "broadcast over the heads beside each head's own features); "
+    "iotml_flash_operand_copies counts the flash wrapper's own")
 flash_operand_copies = default_registry.gauge(
     "iotml_flash_operand_copies",
     "operands of a flash kernel's call copied ahead of it "
@@ -484,7 +495,30 @@ conv_operand_copies = default_registry.gauge(
     "to whole blocks); XLA's own layout copies around a call are not counted")
 model_layers = default_registry.gauge(
     "iotml_model_layers",
-    "layers of the last traced hybrid model, by kind (mamba | attention)")
+    "layers of the last traced hybrid model, by the kind of their mixer "
+    "(mamba | attention | mla) and of their feed-forward part "
+    "(dense_ffn | moe_ffn)")
+# the sparse-expert layer (models/latent_moe.py, ops/moe.py).  Shape at
+# trace time, as above; the assignments are DATA, read back with a
+# fit's losses at its one sync (`Trainer.fit_compiled`).
+moe_experts = default_registry.gauge(
+    "iotml_moe_experts",
+    "experts of the last traced expert layer, by kind (held: computed "
+    "here | routed_over: the router's outputs)")
+moe_top_k = default_registry.gauge(
+    "iotml_moe_top_k", "experts a token is routed to")
+moe_dispatch_rows = default_registry.gauge(
+    "iotml_moe_dispatch_rows",
+    "static rows an expert layer's dispatch is built for: the worst the "
+    "router can produce, tokens x min(top_k, experts held)")
+moe_assignments = default_registry.counter(
+    "iotml_moe_assignments_total",
+    "token-to-expert assignments of the fits so far, all expert layers, "
+    "by kind (held: to an expert computed here | elsewhere: left out)")
+moe_expert_load = default_registry.gauge(
+    "iotml_moe_expert_load_max_over_mean",
+    "the busiest expert held over the mean of the experts held, by "
+    "assignments of the last fit")
 remat_blocks = default_registry.gauge(
     "iotml_remat_blocks",
     "blocks of the last traced model recomputed in the backward pass")
@@ -556,10 +590,13 @@ DECLARED_METRIC_LABELS = {
     "flash_heads_per_step": ("kernel",),
     "flash_lanes_per_step": ("kernel",),
     "flash_operand_copies": ("kernel",),
+    "flash_value_lanes_per_step": ("kernel",),
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),
     "model_layers": ("kind",),
+    "moe_assignments": ("kind",),
+    "moe_experts": ("kind",),
     "model_offsets_lag": ("component",),
     "model_version": ("component",),
     "online_adaptations": ("action",),

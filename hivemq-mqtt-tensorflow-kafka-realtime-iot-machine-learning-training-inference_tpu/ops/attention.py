@@ -241,24 +241,30 @@ _COST_US = {"fwd": (0.25, 0.030, 0.28, 0.025),
 
 
 def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
-                itemsize: int) -> int:
+                itemsize: int, Dv: Optional[int] = None) -> int:
     """Scoped VMEM one grid step needs, counted: every operand's block
     twice (the pipeline double-buffers), the float32 scratch, and the
-    tile's temporaries.  A q or kv block is `heads·D` lanes wide — the
-    step's heads side by side, padded to 128 only where the whole width
-    is narrower — and a [block_q, 1] row statistic pads to 128 lanes a
-    head, so one head's is as wide as a 128-lane q block."""
+    tile's temporaries.  A block of q, k or their gradients is `heads·D`
+    lanes wide, one of v, out or their gradients `heads·Dv` (`Dv` left
+    `None` is D) — the step's heads side by side, padded to 128 only
+    where the whole width is narrower — and a [block_q, 1] row statistic
+    pads to 128 lanes a head, so one head's is as wide as a 128-lane q
+    block."""
     lanes = _round_up(heads * D, 128)
+    v_lanes = _round_up(heads * (D if Dv is None else Dv), 128)
     q_blk, k_blk = block_q * lanes, block_k * lanes
+    o_blk, v_blk = block_q * v_lanes, block_k * v_lanes
     stat = heads * block_q * 128 * 4
     if kernel == "fwd":
-        piped = (2 * q_blk + 2 * k_blk) * itemsize + stat   # q, o; k, v; lse
-        scratch = q_blk * 4 + 2 * stat                      # acc; m, l
+        piped = (q_blk + o_blk + k_blk + v_blk) * itemsize + stat  # .. lse
+        scratch = o_blk * 4 + 2 * stat                      # acc; m, l
     elif kernel == "bwd_dkv":
-        piped = (2 * q_blk + 4 * k_blk) * itemsize + 2 * stat
-        scratch = 2 * k_blk * 4                             # dk, dv
+        # q, dO; k, v, dk, dv
+        piped = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize + 2 * stat
+        scratch = (k_blk + v_blk) * 4                       # dk, dv
     else:
-        piped = (3 * q_blk + 2 * k_blk) * itemsize + 2 * stat
+        # q, dO, dq; k, v
+        piped = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize + 2 * stat
         scratch = q_blk * 4                                 # dq
     return 2 * piped + scratch + _TILE_TEMPS[kernel] * block_q * block_k * 4
 
@@ -270,20 +276,25 @@ def _cost_us(kernel: str, geom: FlashGeometry, bh: int) -> float:
         nbq * nbk * unit + nbq * q_chunk + nbk * k_chunk)
 
 
-def _head_groups(H: int, D: int) -> list:
+def _head_groups(H: int, D: int, Dv: Optional[int] = None) -> list:
     """The heads a grid step may take, fewest first: `G` neighbouring
-    heads are one column block of `[B, T, H·D]`, so `G` divides H and
-    `G·D` is whole 128-lane columns — or `G = H`, the whole width, which
-    any shape may take.  At D = 64 that is 2, 4, 8 …; at D = 128, 1, 2,
-    ….  Past `_MAX_HEADS` only the fewest is left."""
+    heads are one column block of `[B, T, H·D]` (and one of v's and
+    out's `[B, T, H·Dv]`), so `G` divides H and `G·D` and `G·Dv` are
+    whole 128-lane columns — or `G = H`, the whole width, which any
+    shape may take.  At D = 64 that is 2, 4, 8 …; at D = 128, 1, 2, …;
+    at D = 192 beside Dv = 128, 2, 4, ….  Past `_MAX_HEADS` only the
+    fewest is left."""
+    Dv = D if Dv is None else Dv
     groups = [g for g in range(1, H + 1)
-              if H % g == 0 and (g * D % 128 == 0 or g == H)]
+              if H % g == 0 and ((g * D % 128 == 0 and g * Dv % 128 == 0)
+                                 or g == H)]
     return [g for g in groups if g <= _MAX_HEADS] or groups[:1]
 
 
 def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
                    H: int, causal: bool, block_q: Optional[int] = None,
-                   block_k: Optional[int] = None) -> FlashGeometry:
+                   block_k: Optional[int] = None,
+                   Dv: Optional[int] = None) -> FlashGeometry:
     """The step geometry of one flash kernel (`fwd`, `bwd_dkv`,
     `bwd_dq`), from what the code can see at trace time — the one place
     that knows tile sizes.
@@ -298,12 +309,14 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
     step save steps with no such waste, which is what short windows
     need.  A block that is named is honoured as it stands — the fewest
     heads a step the lanes allow, the backward kernels capped at
-    `_MAX_BLOCK` — and only the other is derived."""
+    `_MAX_BLOCK` — and only the other is derived.  `D` is the width of
+    a query and key head, `Dv` that of a value head where it differs
+    (latent attention: 192 beside 128)."""
     named = block_q is not None, block_k is not None
     cap = float("inf") if kernel == "fwd" else _MAX_BLOCK
     n = _round_up(T, 128) // 128
     derived = [128 * m for m in range(1, _MAX_BLOCK // 128 + 1) if n % m == 0]
-    groups, bh = _head_groups(H, D), B * H
+    groups, bh = _head_groups(H, D, Dv), B * H
     geoms = [_geometry(T, bh, causal, bq, bk, h)
              for bq in ([min(block_q, cap)] if named[0] else derived)
              for bk in ([min(block_k, cap)] if named[1] else derived)
@@ -312,7 +325,7 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
         # the first is the smallest: what is left when nothing fits, for
         # the compiler to refuse
         geoms = [g for g in geoms if _vmem_bytes(
-            kernel, g.block_q, g.block_k, g.heads, D, itemsize)
+            kernel, g.block_q, g.block_k, g.heads, D, itemsize, Dv)
             <= _VMEM_BUDGET] or geoms[:1]
     return min(geoms, key=lambda g: _cost_us(kernel, g, bh))
 
@@ -357,13 +370,15 @@ def _fwd_tile(q, k, v, mask, m, l, acc, *, scale: float):
     return m_new, l_new, acc_new
 
 
-def _head_columns(x_ref, stat_ref) -> list:
-    """(g, columns) of each head of a step: the row statistics' block
-    leads with the step's heads, an operand's `[1, block, G·D]` column
-    block holds them side by side, D lanes each."""
+def _head_columns(q_ref, v_ref, stat_ref) -> list:
+    """(g, columns of q and k, columns of v and out) of each head of a
+    step: the row statistics' block leads with the step's heads, an
+    operand's `[1, block, G·D]` column block holds them side by side, D
+    lanes each (v's and out's Dv lanes each)."""
     G = stat_ref.shape[0]
-    D = x_ref.shape[2] // G
-    return [(g, slice(g * D, (g + 1) * D)) for g in range(G)]
+    D, Dv = q_ref.shape[2] // G, v_ref.shape[2] // G
+    return [(g, slice(g * D, (g + 1) * D), slice(g * Dv, (g + 1) * Dv))
+            for g in range(G)]
 
 
 def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -378,7 +393,7 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
     key."""
     from jax.experimental import pallas as pl
 
-    heads = _head_columns(q_ref, lse_ref)
+    heads = _head_columns(q_ref, v_ref, lse_ref)
 
     @pl.when(first)
     def _init():
@@ -397,10 +412,10 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             qi, kj = _tile_positions(i, j, block_q, block_k)
             mask = qi >= kj
-        for g, cols in heads:
-            m_s[g], l_s[g], acc[:, cols] = _fwd_tile(
-                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
-                mask, m_s[g], l_s[g], acc[:, cols], scale=scale)
+        for g, cols, vcols in heads:
+            m_s[g], l_s[g], acc[:, vcols] = _fwd_tile(
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
+                mask, m_s[g], l_s[g], acc[:, vcols], scale=scale)
 
     if live is None:
         compute()
@@ -412,8 +427,9 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _emit():
         l = l_s[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        for g, cols in heads:
-            o_ref[0, :, cols] = (acc[:, cols] / safe_l[g]).astype(o_ref.dtype)
+        for g, _, vcols in heads:
+            o_ref[0, :, vcols] = (acc[:, vcols]
+                                  / safe_l[g]).astype(o_ref.dtype)
         # log-sum-exp per query row (needed by the custom-VJP backward)
         lse_ref[:] = jnp.where(l == 0.0, NEG_INF, m_s[:] + jnp.log(safe_l))
 
@@ -476,7 +492,7 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     dk/dv block is still zero-written (see _causal_tiles)."""
     from jax.experimental import pallas as pl
 
-    heads = _head_columns(q_ref, lse_ref)
+    heads = _head_columns(q_ref, v_ref, lse_ref)
 
     @pl.when(first)
     def _init():
@@ -486,11 +502,11 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
                          block_k=block_k, t_real=t_real)
-        for g, cols in heads:
-            dk_acc[:, cols], dv_acc[:, cols] = _dkv_tile(
-                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
-                do_ref[0, :, cols], lse_ref[g], delta_ref[g], mask,
-                dk_acc[:, cols], dv_acc[:, cols], scale=scale)
+        for g, cols, vcols in heads:
+            dk_acc[:, cols], dv_acc[:, vcols] = _dkv_tile(
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
+                do_ref[0, :, vcols], lse_ref[g], delta_ref[g], mask,
+                dk_acc[:, cols], dv_acc[:, vcols], scale=scale)
 
     if live is None:
         compute()
@@ -511,7 +527,7 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     relevant) kv blocks."""
     from jax.experimental import pallas as pl
 
-    heads = _head_columns(q_ref, lse_ref)
+    heads = _head_columns(q_ref, v_ref, lse_ref)
 
     @pl.when(first)
     def _init():
@@ -520,10 +536,10 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
                          block_k=block_k, t_real=t_real)
-        for g, cols in heads:
+        for g, cols, vcols in heads:
             dq_acc[:, cols] = _dq_tile(
-                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols],
-                do_ref[0, :, cols], lse_ref[g], delta_ref[g], mask,
+                q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
+                do_ref[0, :, vcols], lse_ref[g], delta_ref[g], mask,
                 dq_acc[:, cols], scale=scale)
 
     if live is None:
@@ -576,15 +592,17 @@ def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
 
 
 def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
-                D: int, kv_outer: bool, causal: bool, ins: str, outs: str,
-                scratch, copies: int, **static):
+                D: int, Dv: int, kv_outer: bool, causal: bool, ins: str,
+                outs: str, scratch, copies: int, **static):
     """(kernel function, grid spec, compiler params, prefetch operands)
     of one flash kernel on the grid `geom` names — and the record of
     that geometry (`iotml_flash_*{kernel}`, at trace time).  `ins` and
     `outs` give each operand's side and width: Q/K a [1, block, G·D]
     column block of a `[B, T, H·D]` array along the q or the kv axis —
-    batch row b, the c-th group of G heads — and q the [G, block_q, 1]
-    row statistics of the same heads in a `[B·H, t_q, 1]` array."""
+    batch row b, the c-th group of G heads — O/V the same of a
+    `[B, T, H·Dv]` array (out and dO along q; v and dv along kv), and q
+    the [G, block_q, 1] row statistics of the same heads in a
+    `[B·H, t_q, 1]` array."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -609,14 +627,17 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
                                causal=causal, block_q=bq, block_k=bk,
                                **static)
     assert math.prod(grid) == geom.grid_steps, (grid, geom)
-    _record_geometry(kernel, geom, lanes=G * D, copies=copies)
+    _record_geometry(kernel, geom, lanes=G * D, value_lanes=G * Dv,
+                     copies=copies)
+    q_side = lambda b, c, *t: (b, qtile(*t), c)  # noqa: E731
+    kv_side = lambda b, c, *t: (b, ktile(*t), c)  # noqa: E731
     blocks = {
-        "Q": pl.BlockSpec((1, bq, G * D),
-                          lambda b, c, *t: (b, qtile(*t), c)),
+        "Q": pl.BlockSpec((1, bq, G * D), q_side),
+        "O": pl.BlockSpec((1, bq, G * Dv), q_side),
         "q": pl.BlockSpec((G, bq, 1),
                           lambda b, c, *t: (b * nc + c, qtile(*t), 0)),
-        "K": pl.BlockSpec((1, bk, G * D),
-                          lambda b, c, *t: (b, ktile(*t), c))}
+        "K": pl.BlockSpec((1, bk, G * D), kv_side),
+        "V": pl.BlockSpec((1, bk, G * Dv), kv_side)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[blocks[c] for c in ins],
@@ -628,7 +649,7 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
 
 
 def _record_geometry(kernel: str, geom: FlashGeometry, *, lanes: int,
-                     copies: int) -> None:
+                     value_lanes: int, copies: int) -> None:
     """Say what engaged: Python at trace time, once a shape and
     compilation, no cost in the step.  The last newly traced call of
     each kernel stands."""
@@ -639,6 +660,7 @@ def _record_geometry(kernel: str, geom: FlashGeometry, *, lanes: int,
     obs_metrics.flash_block_k.set(geom.block_k, kernel=kernel)
     obs_metrics.flash_heads_per_step.set(geom.heads, kernel=kernel)
     obs_metrics.flash_lanes_per_step.set(lanes, kernel=kernel)
+    obs_metrics.flash_value_lanes_per_step.set(value_lanes, kernel=kernel)
     obs_metrics.flash_operand_copies.set(copies, kernel=kernel)
 
 
@@ -661,14 +683,15 @@ def _operand_copies(T: int, geom: FlashGeometry, q_side: int,
 def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
                interpret: bool, scale: float, repeated: int = 0):
     """The forward of unpadded operands in the projections' layout
-    ([B, T, H·D]) on `geom`: (out [B, t_q, H·D], lse [B·H, t_q, 1]).
+    (q and k [B, T, H·D], v [B, T, H·Dv]) on `geom`: (out
+    [B, t_q, H·Dv], lse [B·H, t_q, 1]).
     `repeated` says how many of them the caller copied ahead of this
     (a repeated k or v), for `iotml_flash_operand_copies`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HD = q.shape
-    D = HD // H
+    D, Dv = HD // H, v.shape[2] // H
     G, bq, Tq, Tk = geom.heads, geom.block_q, geom.t_q, geom.t_k
     if not causal and Tk != T:
         # padded keys are only excluded by the causal mask; non-causal
@@ -676,9 +699,9 @@ def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
         raise ValueError(
             f"non-causal flash attention needs T % {geom.block_k} == 0")
     fn, grid_spec, params, prefetch = _flash_grid(
-        "fwd", geom, _fwd_step, B=B, H=H, D=D, kv_outer=False,
-        causal=causal, ins="QKK", outs="Qq",
-        scratch=[pltpu.VMEM((bq, G * D), jnp.float32),
+        "fwd", geom, _fwd_step, B=B, H=H, D=D, Dv=Dv, kv_outer=False,
+        causal=causal, ins="QKV", outs="Oq",
+        scratch=[pltpu.VMEM((bq, G * Dv), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32)],
         copies=_operand_copies(T, geom, 1, repeated), scale=scale)
@@ -686,7 +709,7 @@ def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
     # (global positions) excludes them for every real query
     return pl.pallas_call(
         fn, name=FWD_KERNEL, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, Tq, HD), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, Tq, H * Dv), q.dtype),
                    jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32)],
         compiler_params=params, interpret=interpret,
     )(*prefetch, _pad_t(q, Tq), _pad_t(k, Tk), _pad_t(v, Tk))
@@ -696,16 +719,17 @@ def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
 def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
                    block_k: Optional[int], interpret: bool, scale: float,
                    repeated: int):
-    """Run the Pallas kernel; returns (out [B,T,H,D], lse [B,H,T]).
+    """Run the Pallas kernel; returns (out [B,T,H,Dv], lse [B,H,T]).
     Jitted, as `_flash_backward` is, so that a model of many equal
     layers traces and lowers the kernels once a shape and not once a
     layer (`_record_geometry` runs then, once)."""
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B, H, causal,
-                          block_q, block_k)
-    out, lse = _flash_fwd(*(x.reshape(B, T, H * D) for x in (q, k, v)), H,
+                          block_q, block_k, Dv)
+    out, lse = _flash_fwd(*(x.reshape(B, T, -1) for x in (q, k, v)), H,
                           causal, geom, interpret, scale, repeated)
-    return (out[:, :T].reshape(B, T, H, D),
+    return (out[:, :T].reshape(B, T, H, Dv),
             lse.reshape(B, H, -1)[:, :, :T])
 
 
@@ -725,25 +749,26 @@ def _bwd_operands(q, do, lse, delta, k, v, geom: FlashGeometry,
 def _flash_bwd_dkv(q, do, lse, delta, k, v, H: int, causal: bool,
                    geom: FlashGeometry, interpret: bool, scale: float,
                    repeated: int = 0):
-    """dK/dV of unpadded operands ([B, T, H·D]; lse and delta
-    [B·H, T, 1]) on `geom`: ([B, t_k, H·D],) × 2."""
+    """dK/dV of unpadded operands (q and k [B, T, H·D], dO and v
+    [B, T, H·Dv]; lse and delta [B·H, T, 1]) on `geom`:
+    ([B, t_k, H·D], [B, t_k, H·Dv])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HD = q.shape
-    D = HD // H
+    D, Dv = HD // H, v.shape[2] // H
     G, bk, Tk = geom.heads, geom.block_k, geom.t_k
     operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dkv", geom, _dkv_step, B=B, H=H, D=D, kv_outer=True,
-        causal=causal, ins="QQqqKK", outs="KK",
+        "bwd_dkv", geom, _dkv_step, B=B, H=H, D=D, Dv=Dv, kv_outer=True,
+        causal=causal, ins="QOqqKV", outs="KV",
         scratch=[pltpu.VMEM((bk, G * D), jnp.float32),
-                 pltpu.VMEM((bk, G * D), jnp.float32)],
+                 pltpu.VMEM((bk, G * Dv), jnp.float32)],
         copies=copies, scale=scale, t_real=T)
     return pl.pallas_call(
         fn, name=BWD_DKV_KERNEL, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Tk, HD), k.dtype),
-                   jax.ShapeDtypeStruct((B, Tk, HD), v.dtype)],
+                   jax.ShapeDtypeStruct((B, Tk, H * Dv), v.dtype)],
         compiler_params=params, interpret=interpret,
     )(*prefetch, *operands)
 
@@ -756,12 +781,12 @@ def _flash_bwd_dq(q, do, lse, delta, k, v, H: int, causal: bool,
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HD = q.shape
-    D = HD // H
+    D, Dv = HD // H, v.shape[2] // H
     G, bq, Tq = geom.heads, geom.block_q, geom.t_q
     operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dq", geom, _dq_step, B=B, H=H, D=D, kv_outer=False,
-        causal=causal, ins="QQqqKK", outs="Q",
+        "bwd_dq", geom, _dq_step, B=B, H=H, D=D, Dv=Dv, kv_outer=False,
+        causal=causal, ins="QOqqKV", outs="Q",
         scratch=[pltpu.VMEM((bq, G * D), jnp.float32)],
         copies=copies, scale=scale, t_real=T)
     (dq,) = pl.pallas_call(
@@ -784,16 +809,16 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     # rowwise D_i = sum_d dO_i·O_i (softmax-jacobian diagonal term)
     delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
-    flat = lambda x: x.reshape(B, T, H * D)  # noqa: E731
+    flat = lambda x: x.reshape(B, T, -1)  # noqa: E731
     operands = (flat(q), flat(do), lse.reshape(B * H, T, 1),
                 delta.reshape(B * H, T, 1), flat(k), flat(v))
     dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B, H, causal,
-                              block_q, block_k)
+                              block_q, block_k, v.shape[-1])
                for kernel in ("bwd_dkv", "bwd_dq"))
     dk, dv = _flash_bwd_dkv(*operands, H, causal, dkv, interpret, scale,
                             repeated)
     dq = _flash_bwd_dq(*operands, H, causal, dq, interpret, scale, repeated)
-    return tuple(x[:, :T].reshape(B, T, H, D) for x in (dq, dk, dv))
+    return tuple(x[:, :T].reshape(B, T, H, -1) for x in (dq, dk, dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -822,8 +847,11 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, interpret: bool = False,
                     scale: Optional[float] = None):
-    """Pallas flash attention. q: [B, T, H, D], k,v: [B, T, Hkv, D] with
-    Hkv dividing H → [B, T, H, D].
+    """Pallas flash attention. q: [B, T, H, D], k: [B, T, Hkv, D],
+    v: [B, T, Hkv, Dv] with Hkv dividing H → [B, T, H, Dv].  `Dv` may
+    differ from `D` (latent attention: rotary features ride q and k
+    only): v, out and their gradients are then column blocks of `G·Dv`
+    lanes beside q's and k's of `G·D`, one G for both.
 
     The kernels index that layout in place: each operand and each result
     is `[B, T, H·D]` to them (a free reshape), a grid step's heads one

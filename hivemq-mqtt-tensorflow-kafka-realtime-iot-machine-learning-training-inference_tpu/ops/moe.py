@@ -1,0 +1,231 @@
+"""Sparse-expert ops: rotary positions, the router, and the dropless
+dispatch of tokens to the experts held here.
+
+An expert layer routes every token over ALL `experts` of the model
+(sigmoid scores, a selection-only bias, the `top_k` largest, weights
+renormalised over the selected and scaled) and computes the part of the
+result that the experts it HOLDS give — a contiguous range
+`[first, first + held)`, one chip's share under expert parallelism.
+What the experts held elsewhere would add is left out; on one chip
+there is no exchange.
+
+Dropless under static shapes: no capacity and no token falls through.
+The assignments are sorted by expert, each held expert's group laid out
+from a tile's boundary (`dispatch_plan`), so every tile of `TILE` rows
+belongs to one expert.  The layout is built for the worst the router
+can produce — every token taking only experts held here — but only
+LIVE tiles run: `experts_apply` walks them in a loop whose trip count
+is data (gather a tile's tokens, the expert's gated MLP as two plain
+products, scale by the routing weights, add onto the tokens), and its
+backward walks them again.  Work and memory traffic follow the
+assignments that landed here, a tile's padding at most an expert.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+
+# --------------------------------------------------------------- rotary
+def rotary(x, theta: float):
+    """Rotary positions on the last axis of x [B, T, ..., R], positions
+    0 … T−1 along axis 1: neighbouring features (x[2i], x[2i+1]) are one
+    pair, turned by `t · theta^(−2i/R)`.  float32 angles.  (Pairs by a
+    reshape; taking a pair's partner by rolls of the whole 192-wide head
+    instead ran 36 ms a step slower in `km-train-backlog`: PERF.md §6,
+    PR 30.)"""
+    T, R = x.shape[1], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * freq     # [T, R/2]
+    shape = (1, T) + (1,) * (x.ndim - 3) + (R // 2,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    pairs = x.reshape(x.shape[:-1] + (R // 2, 2)).astype(jnp.float32)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+# --------------------------------------------------------------- router
+def route(u, gate, bias, top_k: int, scale: float):
+    """u [N, d] → (experts [N, top_k] int32, weights [N, top_k] float32).
+
+    Scores are sigmoids of a float32 product at `highest` (top-k is
+    discontinuous: a rounded score picks another expert).  `bias` moves
+    the SELECTION only — the weights are the selected scores without
+    it, over their sum, times `scale` — and no gradient reaches it."""
+    s = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), gate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    # the selected scores, by a masked sum (a gather of [N, top_k]
+    # scalars is the slower way on a chip)
+    picked = jnp.sum(jnp.where(
+        experts[..., None] == jnp.arange(s.shape[-1]), s[:, None, :], 0.0),
+        axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return experts, weights * scale
+
+
+# ------------------------------------------------------------- dispatch
+#: rows a tile of the dispatch holds: one expert's, so its two products
+#: are plain ones.  At 512 rows a float32 tile's products do about as
+#: many operations a byte of the expert's weights as a v5e's ridge asks.
+TILE = 512
+
+
+class Dispatch(NamedTuple):
+    """The assignments sorted by expert, and the tiles that walk the
+    groups of the experts held (`dispatch_plan`)."""
+    token: jax.Array        # [A + tile] the sorted assignments' tokens
+    weight: jax.Array       # [A + tile] their routing weights
+    tile_expert: jax.Array  # [tiles] the expert held (local) of a tile
+    tile_first: jax.Array   # [tiles] the sorted assignment it starts at
+    tile_rows: jax.Array    # [tiles] its live rows (the rest is padding)
+    live_tiles: jax.Array   # [] tiles that hold an assignment: the first
+    counts: jax.Array       # [experts] assignments to every expert
+
+
+def _tile(tokens: int) -> int:
+    return min(TILE, -(-tokens // 8) * 8)
+
+
+def dispatch_rows(tokens: int, top_k: int, held: int) -> int:
+    """The static rows a layer's dispatch is built for: the worst the
+    router can produce (a token's experts are distinct, so at most
+    min(top_k, held) of them are held here) in whole tiles, and a tile
+    of padding an expert."""
+    tile = _tile(tokens)
+    return -(-tokens * min(top_k, held) // tile) * tile + held * tile
+
+
+def dispatch_plan(experts, weights, first: int, held: int,
+                  n_experts: int) -> Dispatch:
+    """Sort the N·K assignments by expert (those held elsewhere last)
+    and cut the groups of the experts held into tiles, each group from
+    a tile of its own.  A group is one run of the sorted assignments, so
+    a tile is a slice of them: nothing is gathered row by row."""
+    N, K = experts.shape
+    tile = _tile(N)
+    n_tiles = dispatch_rows(N, K, held) // tile
+    flat = experts.reshape(-1)
+    local = flat - first
+    here = (local >= 0) & (local < held)
+    _, order = jax.lax.sort(
+        (jnp.where(here, local, held), jnp.arange(N * K, dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    counts = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype),
+                     axis=0, dtype=jnp.int32)
+    sizes = jax.lax.dynamic_slice_in_dim(counts, first, held)
+    tiles = -(-sizes // tile)                       # tiles a group takes
+    tile_end = jnp.cumsum(tiles)
+    c = jnp.arange(n_tiles)
+    e = jnp.minimum(jnp.searchsorted(tile_end, c, side="right"), held - 1)
+    within = (c - (tile_end - tiles)[e]) * tile     # rows of the group ahead
+    pad = lambda v, fill: jnp.concatenate(  # noqa: E731
+        [v, jnp.full((tile,), fill, v.dtype)])
+    return Dispatch(
+        pad(order // K, N), pad(weights.reshape(-1)[order], 0),
+        e.astype(jnp.int32),
+        ((jnp.cumsum(sizes) - sizes)[e] + within).astype(jnp.int32),
+        jnp.clip(sizes[e] - within, 0, tile).astype(jnp.int32),
+        tile_end[-1], counts)
+
+
+def _expert_tile(rows, w_in, w_out, weight):
+    """One tile through its expert: (silu(g) ⊙ u) W_out with
+    [g, u] = rows W_in, times the rows' routing weights."""
+    gate, up = jnp.split(rows @ w_in, 2, axis=-1)
+    return ((jax.nn.silu(gate) * up) @ w_out) * weight[:, None]
+
+
+def _tile_operands(c, x, w_in, w_out, weight, plan: Dispatch):
+    """Tile c: (its rows' tokens, its expert, which rows are live, the
+    operands of `_expert_tile`).  A row of padding takes token N: zeros
+    in, weight 0, nothing added back."""
+    tile = _tile(x.shape[0])
+    live = jnp.arange(tile) < plan.tile_rows[c]
+    cut = lambda v: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+        v, plan.tile_first[c], tile)
+    tokens = jnp.where(live, cut(plan.token), x.shape[0])
+    e = plan.tile_expert[c]
+    return tokens, e, live, (
+        x.at[tokens].get(mode="fill", fill_value=0), w_in[e], w_out[e],
+        jnp.where(live, cut(weight), 0).astype(x.dtype))
+
+
+def _add_rows(acc, tokens, rows):
+    # a token meets an expert once, so a tile's live rows are distinct
+    return acc.at[tokens].add(rows, mode="drop", unique_indices=True)
+
+
+@jax.custom_vjp
+def _experts(x, w_in, w_out, weight, plan: Dispatch):
+    def tile_step(c, out):
+        tokens, _, _, operands = _tile_operands(c, x, w_in, w_out, weight,
+                                                plan)
+        return _add_rows(out, tokens, _expert_tile(*operands))
+
+    return jax.lax.fori_loop(0, plan.live_tiles, tile_step,
+                             jnp.zeros_like(x))
+
+
+def _experts_fwd(x, w_in, w_out, weight, plan):
+    return _experts(x, w_in, w_out, weight, plan), \
+        (x, w_in, w_out, weight, plan)
+
+
+def _experts_bwd(res, d_out):
+    """The live tiles again: each recomputed and pulled back; an
+    expert's weight gradients accumulate in place."""
+    x, w_in, w_out, weight, plan = res
+
+    def tile_step(c, grads):
+        dx, d_in, d_outw, d_weight = grads
+        tokens, e, live, operands = _tile_operands(c, x, w_in, w_out, weight,
+                                                   plan)
+        _, pull = jax.vjp(_expert_tile, *operands)
+        d_rows, g_in, g_out, g_weight = pull(
+            d_out.at[tokens].get(mode="fill", fill_value=0))
+        at = plan.tile_first[c]
+        # a tile's padding lies over the next group's first assignments
+        kept = jax.lax.dynamic_slice_in_dim(d_weight, at, live.shape[0])
+        return (_add_rows(dx, tokens, d_rows), d_in.at[e].add(g_in),
+                d_outw.at[e].add(g_out),
+                jax.lax.dynamic_update_slice_in_dim(
+                    d_weight, jnp.where(live, g_weight.astype(kept.dtype),
+                                        kept), at, 0))
+
+    dx, d_in, d_outw, d_weight = jax.lax.fori_loop(
+        0, plan.live_tiles, tile_step,
+        (jnp.zeros_like(x), jnp.zeros_like(w_in), jnp.zeros_like(w_out),
+         jnp.zeros_like(weight)))
+    return dx, d_in, d_outw, d_weight, None
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def experts_apply(x, plan: Dispatch, w_in, w_out):
+    """Σ over the assignments held of `w · E_i(x)`, for x [N, d]:
+    `E_i(x) = (silu(x W_gate,i) ⊙ x W_up,i) W_down,i` with
+    `w_in[i] = [W_gate,i, W_up,i]` ([held, d, 2f]) and `w_out` [held, f,
+    d].  Dropless: every assignment held has its row."""
+    with jax.named_scope("experts"):
+        return _experts(x, w_in, w_out, plan.weight, plan)
+
+
+@functools.partial(jax.jit, static_argnames=("first", "held"))
+def experts_dense(x, experts, weights, w_in, w_out, first: int, held: int):
+    """The same sum with every expert held applied to every token and a
+    weight that is zero where it was not selected: the form the tests
+    hold the dispatch to (no sort, no grouped product)."""
+    ids = first + jnp.arange(held)
+    dense_w = jnp.sum(jnp.where(experts[..., None] == ids, weights[..., None],
+                                0.0), axis=1)                    # [N, held]
+    gate, up = jnp.split(jnp.einsum("nd,edf->enf", x, w_in), 2, axis=-1)
+    out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up, w_out)
+    return jnp.einsum("end,ne->nd", out, dense_w.astype(out.dtype))

@@ -79,9 +79,20 @@ def _keras_accuracy(pred, target, mask):
 
 def make_loss_fn(model, supervised: bool = False):
     """Loss closure.  Autoencoder mode targets the input itself
-    (zip(x, x), cardata-v3.py:218); supervised mode uses (x, y) windows."""
+    (zip(x, x), cardata-v3.py:218); supervised mode uses (x, y) windows.
+
+    A model that names `report_collections` (variable collections its
+    layers write data-dependent counts to: an expert layer's routing
+    load) has them returned as a third entry of the aux, and the fits
+    below carry them out beside the losses; any other model's loss is
+    the program it always was."""
+    reporting = list(getattr(model, "report_collections", ()))
 
     def loss_fn(params, x, y, mask):
+        if reporting and supervised:
+            pred, reports = model.apply({"params": params}, x,
+                                        mutable=reporting)
+            return _masked_mse(pred, y, mask), (pred, y, reports)
         out = model.apply({"params": params}, x, with_penalty=True) \
             if not supervised else (model.apply({"params": params}, x), 0.0)
         pred, penalty = out if isinstance(out, tuple) else (out, 0.0)
@@ -106,7 +117,7 @@ def make_raw_train_step(model, tx, supervised: bool = False,
     loss_fn = make_loss_fn(model, supervised)
 
     def iotml_train_step(state: TrainState, x, y, mask):
-        (loss, (pred, target)), grads = jax.value_and_grad(
+        (loss, (pred, target, *reports)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, x, y, mask)
         # the optimizer's operations carry `adam` in their metadata, as
         # the model's carry `attn`/`mlp`: a device trace tells them apart
@@ -115,6 +126,8 @@ def make_raw_train_step(model, tx, supervised: bool = False,
                                                  state.params)
             params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "accuracy": _keras_accuracy(pred, target, mask)}
+        if reports:
+            metrics["reports"] = reports[0]
         if row_loss:
             per_elem = jnp.square(pred - target)
             metrics["row_loss"] = jnp.mean(
@@ -138,6 +151,10 @@ def make_scanned_fit(model, tx, supervised: bool = False):
     step-per-dispatch loop is pure host/link latency.  Scanning the entire
     fit compiles once and runs N_epochs × N_batches updates in a single
     device program; numerically identical to the step loop.
+
+    Returns (state, (losses, accs)), each [epochs]; a model that reports
+    (`make_loss_fn`) adds a third entry, its reports with leading axes
+    [epochs, batches], which leave the device at the same sync.
     """
     raw = make_raw_train_step(model, tx, supervised)
 
@@ -145,11 +162,14 @@ def make_scanned_fit(model, tx, supervised: bool = False):
         def batch_step(st, inp):
             x, y, m = inp
             st, metrics = raw(st, x, y, m)
-            return st, (metrics["loss"], metrics["accuracy"])
+            return st, (metrics["loss"], metrics["accuracy"],
+                        *([metrics["reports"]] if "reports" in metrics
+                          else []))
 
         def epoch_step(st, _):
-            st, (losses, accs) = jax.lax.scan(batch_step, st, (xs, ys, masks))
-            return st, (jnp.mean(losses), jnp.mean(accs))
+            st, (losses, accs, *reports) = jax.lax.scan(
+                batch_step, st, (xs, ys, masks))
+            return st, (jnp.mean(losses), jnp.mean(accs), *reports)
 
         return jax.lax.scan(epoch_step, state, None, length=epochs)
 
@@ -439,6 +459,7 @@ class Trainer:
                 else:
                     xs, ys, masks = jax.device_put((xs, ys, masks))
             with tracing.phase("train", "dispatch"):
+                reports = ()   # a reporting model's, from the scanned fit
                 if use_fused:
                     self.state, losses, accs = fused_train.fused_fit(
                         self.state, xs, masks, epochs,
@@ -448,7 +469,7 @@ class Trainer:
                     scanned = scanned_fit_cached(
                         self.model, self.tx, self.supervised,
                         tx_key=self._tx_key)
-                    self.state, (losses, accs) = scanned(
+                    self.state, (losses, accs, *reports) = scanned(
                         self.state, xs, ys, masks, epochs)
             obs_metrics.records_trained.inc(records * epochs)
             if tracing.ENABLED and hasattr(batches, "take_traces"):
@@ -462,13 +483,18 @@ class Trainer:
                 # ONE sync for both metric vectors: each device_get
                 # blocks on the device, and the second would wait on
                 # nothing new
-                losses, accs = (np.asarray(a)
-                                for a in jax.device_get((losses, accs)))
+                losses, accs, *reports = jax.device_get(
+                    (losses, accs, *reports))
+                losses, accs = np.asarray(losses), np.asarray(accs)
+            if reports:
+                self.model.record_reports(reports[0])
         dt = time.perf_counter() - t0
         return {"loss": losses.tolist(), "accuracy": accs.tolist(),
                 "records": [records] * epochs, "seconds": [dt / epochs] * epochs,
                 "fit": "fused" if use_fused else "scanned",
-                "interpret": interpret}
+                "interpret": interpret,
+                # what a reporting model's layers said, [epochs, batches, …]
+                **({"reports": reports[0]} if reports else {})}
 
     def predict(self, batches, callbacks=(), params=None):
         """Batched jit inference; calls callbacks with (batch, outputs) for

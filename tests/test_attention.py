@@ -195,6 +195,8 @@ def test_flash_grad_transposes_nothing_and_copies_no_operand():
         lanes = got[f'iotml_flash_lanes_per_step{{kernel="{kernel}"}}']
         heads = got[f'iotml_flash_heads_per_step{{kernel="{kernel}"}}']
         assert lanes == heads * 64 and lanes % 128 == 0
+        assert got[
+            f'iotml_flash_value_lanes_per_step{{kernel="{kernel}"}}'] == lanes
     # a T pad and a repeated k, v are copies, and are counted
     q, k, v = _qkv(1, 300, 4, 64, kv_heads=2)
     jax.grad(lambda q, k, v: jnp.sum(flash_attention(
@@ -272,3 +274,71 @@ def test_flash_geometry_explicit_blocks_win(kernel):
                                  2048, 2048)
     want = 2048 if kernel == "fwd" else attention._MAX_BLOCK
     assert (g.block_q, g.block_k) == (want, want)
+
+
+@pytest.mark.parametrize("T", [100, 128, 200, 256, 300])
+@pytest.mark.parametrize("H,D,Dv", [(4, 48, 32),     # both under 128 lanes
+                                    (2, 192, 128)])  # the latent's heads
+def test_flash_attention_takes_value_heads_of_another_width(T, H, D, Dv):
+    """Latent attention's training form: query and key heads carry the
+    rotary features, value heads do not (192 beside 128 at the published
+    widths).  The kernels (interpreted) against the reference — out, dq,
+    dk, dv — over T under, at and over a block of 128, the padded
+    lengths included."""
+    rng = np.random.default_rng(T + D)
+    mk = lambda d: jnp.asarray(  # noqa: E731
+        rng.normal(size=(2, T, H, d)), jnp.float32)
+    q, k, v, w = mk(D), mk(D), mk(Dv), mk(Dv)
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(w * fn(q, k, v)), argnums=(0, 1, 2))(
+                q, k, v)
+
+    out = flash_attention(q, k, v, True, 128, 128, True)
+    assert out.shape == (2, T, H, Dv)
+    np.testing.assert_allclose(out, attention_reference(q, k, v, True),
+                               rtol=2e-5, atol=2e-5)
+    (got, grads), (want, wants) = both(
+        lambda q, k, v: flash_attention(q, k, v, True, 128, 128, True)), \
+        both(lambda q, k, v: attention_reference(q, k, v, True))
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for g, r, like in zip(grads, wants, (q, k, v)):
+        assert g.shape == like.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_geometry_by_both_widths():
+    """At the latent's shape a step takes two heads — 384 lanes of q and
+    k beside 256 of v and out — and the counted VMEM knows both widths;
+    equal widths derive what they derived before the second width
+    existed, and say so (`iotml_flash_value_lanes_per_step`)."""
+    from iotml.obs.metrics import default_registry
+
+    for kernel in attention.KERNELS:
+        geom = attention.flash_geometry(kernel, 8192, 192, 4, 1, 16, True,
+                                        Dv=128)
+        assert geom.heads * 192 % 128 == 0 and geom.heads * 128 % 128 == 0
+        narrow, wide = (attention._vmem_bytes(
+            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4, dv)
+            for dv in (128, 192))
+        assert narrow < wide == attention._vmem_bytes(
+            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4)
+        assert narrow <= attention._VMEM_BUDGET
+        for shape in ((1024, 64, 4, 4, 16, True), (4096, 64, 4, 1, 32, True)):
+            assert attention.flash_geometry(kernel, *shape) \
+                == attention.flash_geometry(kernel, *shape, Dv=shape[1])
+    assert attention._head_groups(16, 192, 128) == [2, 4, 8]
+    assert attention._head_groups(3, 192, 128) == [3]   # the whole width
+    jax.clear_caches()
+    q, k, v = _qkv(1, 256, 2, 192)
+    jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v[..., :128], interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    got = default_registry.collect()
+    for kernel in attention.KERNELS:
+        assert got[f'iotml_flash_lanes_per_step{{kernel="{kernel}"}}'] == 384
+        assert got[
+            f'iotml_flash_value_lanes_per_step{{kernel="{kernel}"}}'] == 256
+        # nothing padded, nothing repeated: v narrower than q costs no copy
+        assert got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}'] == 0

@@ -1,0 +1,375 @@
+"""The latent-attention, sparse-expert stack (`models.hybrid.SensorHybrid`
+with `mla` mixers and `moe_ffn` layers): the model and one compiled job
+against the benchmark's plain reference (loaded by path, as
+`benchmark/tests` loads it), the chip's-share cut (the shares add up to
+the uncut layer), the dropless dispatch under the worst imbalance
+against the dense-masked form, the selection-only bias, rotary
+positions, and what a fit says of the routing.  All at a tiny preset on
+the CPU."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from iotml.models.hybrid import SensorHybrid
+from iotml.models.latent_moe import ExpertLayer
+from iotml.ops import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = os.path.join(ROOT, "benchmark", "configs",
+                      "sensorformer-kimi-vl-a3b-instruct")
+#: width 64, 4 heads of 16 + 8 rotary beside 16, latent 32; 16 experts
+#: of 24, 3 a token, 4 held, one shared; a dense layer and two that route
+TINY = dict(hidden_size=64, num_attention_heads=4, intermediate_size=96,
+            moe_intermediate_size=24, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, num_hidden_layers=3,
+            n_routed_experts=4, num_experts_per_tok=3, n_shared_experts=1)
+
+
+def _reference(name, **sizes):
+    spec = importlib.util.spec_from_file_location(name, CONFIG + ".py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(CONFIG + ".json") as fh:
+        cfg = json.load(fh)
+    cfg.update(TINY)
+    cfg["published"] = dict(cfg["published"], n_routed_experts=16)
+    cfg["job"] = dict(cfg["job"], window=40)
+    cfg.update(sizes)
+    mod.use(cfg)
+    return mod, cfg
+
+
+@pytest.fixture(scope="module")
+def ref():
+    """The configuration's plain reference at the tiny preset."""
+    return _reference("bench_kimi_reference")
+
+
+def _batch(B=2, T=40, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
+            jnp.ones((B,), jnp.float32))
+
+
+def _close(got, want, rtol=2e-4):
+    """Within `rtol` of the reference's largest entry, leaf by leaf."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(float(jnp.abs(w).max()), 1e-30)
+        assert float(jnp.abs(g - w).max()) <= rtol * scale
+
+
+# --------------------------------------------- the model and the reference
+@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
+def test_model_matches_the_plain_reference(ref, mode):
+    """Loss and every gradient leaf, the reference's dense-masked
+    experts against the program's tiles, from the same seeded weights;
+    the bias of the router gets no gradient on either side."""
+    from iotml.train.loop import make_loss_fn
+
+    mod, cfg = ref
+    x, y, mask = _batch()
+    params = mod.init_params(3)
+    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
+    assert jax.tree.map(jnp.shape, params) == jax.tree.map(
+        jnp.shape, jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                  x)["params"])
+    loss = make_loss_fn(model, supervised=True)
+    with jax.default_matmul_precision("highest"):
+        (got, aux), grads = jax.jit(jax.value_and_grad(
+            loss, has_aux=True))(params, x, y, mask)
+        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
+            params, x, y, mask)
+    assert float(abs(got - want)) <= 1e-5 * float(want)
+    _close(grads, wants)
+    for i in (1, 2):
+        assert not np.asarray(grads[f"layer{i}"]["moe"]["router_bias"]).any()
+        assert np.asarray(grads[f"layer{i}"]["moe"]["router"]).any()
+    # what the layers reported: every assignment of every token
+    counts = jax.tree.leaves(aux[2])
+    assert [int(c.sum()) for c in counts] == [2 * 40 * 3] * 2
+
+
+def test_two_step_fit_matches_the_reference(ref):
+    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
+    epoch, against the reference's fit written out: parameters, both
+    moments, losses — and the assignments it made, read back with them."""
+    from iotml.data.dataset import Batch
+    from iotml.train.loop import Trainer
+
+    mod, cfg = ref
+    batches = [_batch(seed=s) for s in (1, 2)]
+    params = mod.init_params(5)
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
+                      learning_rate=1e-3)
+    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
+    try:
+        trainer._ensure_state(batches[0][0])
+        # the fit donates its state: it gets a copy of the weights
+        trainer.state = trainer.state.replace(
+            params=jax.tree.map(jnp.array, params))
+        with jax.default_matmul_precision("highest"):
+            history = trainer.fit_compiled(
+                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+                       first_index=0) for x, y, _ in batches], epochs=2)
+            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(
+                params, *(jnp.stack(v) for v in zip(*batches)))
+    finally:
+        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
+    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
+    adam = trainer.state.opt_state[0]
+    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
+           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
+    _close(adam.mu, mu)
+    _close(adam.nu, nu)
+    counts = jax.tree.leaves(history["reports"])
+    assert [c.shape for c in counts] == [(2, 2, 16)] * 2
+    assert all(int(c.sum()) == 2 * 2 * 2 * 40 * 3 for c in counts)
+
+
+def test_the_seeded_weights_keep_the_labelling_the_file_states(ref):
+    """The experts held are the contiguous range the file states, of
+    seeded weights as they come: `init_params` is one seeded call, and
+    nothing relabels the router's outputs or moves b afterwards."""
+    mod, cfg = ref
+    assert mod._held() == (0, 4, 16)
+    seeded = jax.jit(lambda k: mod._init(k))(jax.random.PRNGKey(7))
+    made = mod.init_params(7)
+    assert jax.tree.structure(made) == jax.tree.structure(seeded)
+    for a, b in zip(jax.tree.leaves(made), jax.tree.leaves(seeded)):
+        assert np.array_equal(a, b)
+    assert made["layer1"]["moe"]["router"].shape == (64, 16)
+    assert made["layer1"]["moe"]["experts_in"].shape[0] == 4
+
+
+# ------------------------------------------------------- the chip's share
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Sixteen experts, three a token, one shared expert, four shares
+    of four: the routed parts the four shares give, and the shared part
+    counted once, are what the uncut reference layer gives."""
+    whole, cfg = _reference("bench_kimi_uncut", n_routed_experts=16)
+    rng = np.random.default_rng(11)
+    u = jnp.asarray(rng.normal(size=(2, 40, 64)), jnp.float32)
+    p = jax.jit(lambda k: whole._init(k))(jax.random.PRNGKey(11))[
+        "layer1"]["moe"]
+    with jax.default_matmul_precision("highest"):
+        want, counts = whole._experts_layer(p, u)
+        shared = whole._gated(u, p["shared_in"]["kernel"],
+                              p["shared_out"]["kernel"])
+        total = jnp.zeros_like(u)
+        for first in (0, 4, 8, 12):
+            share = dict(p, experts_in=p["experts_in"][first:first + 4],
+                         experts_out=p["experts_out"][first:first + 4])
+            layer = ExpertLayer(whole.hybrid_config(dict(
+                cfg, n_routed_experts=4, experts_held={"first": first})))
+            out, reports = layer.apply({"params": share}, u,
+                                       mutable=["reports"])
+            # every share routes over all sixteen, and alike
+            assert np.array_equal(
+                reports["reports"]["expert_counts"], counts)
+            total = total + (out - shared)
+    whole.use(cfg)
+    assert int(counts.sum()) == 2 * 40 * 3
+    _close(total + shared, want, rtol=1e-5)
+
+
+# -------------------------------------------------------------- dropless
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 64 rows, so that a few hundred tokens are many tiles and
+    an expert's group ends inside one."""
+    monkeypatch.setattr(moe, "TILE", 64)
+
+
+@pytest.mark.parametrize("forced,per_token", [
+    ("all_held", 3),     # every token takes only experts held: the worst
+    ("none_held", 0),    # no token takes one: no live tile at all
+    ("one_expert", 1),   # every token takes the same expert held
+    ("seeded", None),    # whatever the seeded router does
+])
+def test_dispatch_is_dropless_under_the_worst_imbalance(small_tiles, forced,
+                                                        per_token):
+    """No capacity and no token falls through: the tiles' result and
+    every gradient against the dense-masked form (each expert held
+    applied to every token, weighted by zero where it was not chosen)."""
+    N, d, f, E, K, first, held = 300, 16, 8, 16, 3, 4, 4
+    rng = np.random.default_rng(2)
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, gate, w_in, w_out, w = arr(N, d), arr(d, E), arr(held, d, 2 * f), \
+        arr(held, f, d), arr(N, d)
+    here = (np.arange(E) >= first) & (np.arange(E) < first + held)
+    bias = {"all_held": np.where(here, 10.0, 0.0),
+            "none_held": np.where(here, -10.0, 0.0),
+            "one_expert": np.where(np.arange(E) == first + 2, 10.0,
+                                   np.where(here, -10.0, 0.0)),
+            "seeded": np.zeros(E)}[forced]
+    bias = jnp.asarray(bias, jnp.float32)
+
+    def tiles(x, gate, w_in, w_out):
+        plan = moe.dispatch_plan(*moe.route(x, gate, bias, K, 2.5),
+                                 first, held, E)
+        return jnp.sum(w * moe.experts_apply(x, plan, w_in, w_out)), plan
+
+    def dense(x, gate, w_in, w_out):
+        e, r = moe.route(x, gate, bias, K, 2.5)
+        return jnp.sum(w * moe.experts_dense(x, e, r, w_in, w_out,
+                                             first, held))
+
+    with jax.default_matmul_precision("highest"):
+        (got, plan), grads = jax.jit(jax.value_and_grad(
+            tiles, argnums=(0, 1, 2, 3), has_aux=True))(x, gate, w_in, w_out)
+        want, wants = jax.jit(jax.value_and_grad(
+            dense, argnums=(0, 1, 2, 3)))(x, gate, w_in, w_out)
+    landed = int(plan.counts[first:first + held].sum())
+    if per_token is not None:
+        assert landed == N * per_token
+    # every assignment held has a row of a live tile, and no other has
+    live = int(plan.live_tiles)
+    assert live == int(
+        np.ceil(np.asarray(plan.counts[first:first + held]) / 64).sum())
+    assert int(plan.tile_rows[:live].sum()) == landed
+    assert not np.asarray(plan.tile_rows[live:]).any()
+    assert plan.tile_rows.shape[0] * 64 == moe.dispatch_rows(N, K, held)
+    rows = np.concatenate([
+        np.asarray(plan.token)[f:f + r] for f, r in
+        zip(np.asarray(plan.tile_first[:live]),
+            np.asarray(plan.tile_rows[:live]))] + [np.zeros(0, np.int32)])
+    chosen = np.asarray(moe.route(x, gate, bias, K, 2.5)[0])
+    assert np.array_equal(np.sort(rows), np.sort(np.nonzero(
+        (chosen >= first) & (chosen < first + held))[0]))
+    assert float(abs(got - want)) <= 2e-4 * max(float(abs(want)), 1.0)
+    _close(grads, wants)
+
+
+def test_a_dropped_tile_shows(small_tiles):
+    """The walk is the live tiles and every one of them is needed: a
+    plan that says one tile fewer loses that tile's assignments from
+    the result, those and no others."""
+    N, d, f, E, K, first, held = 300, 16, 8, 16, 3, 4, 4
+    rng = np.random.default_rng(3)
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, gate, w_in, w_out = arr(N, d), arr(d, E), arr(held, d, 2 * f), \
+        arr(held, f, d)
+    bias = jnp.zeros((E,), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        plan = moe.dispatch_plan(*moe.route(x, gate, bias, K, 2.5),
+                                 first, held, E)
+        whole = moe.experts_apply(x, plan, w_in, w_out)
+        live = int(plan.live_tiles)
+        assert live >= 2
+        short = moe.experts_apply(
+            x, plan._replace(live_tiles=plan.live_tiles - 1), w_in, w_out)
+    at, rows = int(plan.tile_first[live - 1]), int(plan.tile_rows[live - 1])
+    lost = np.zeros(N, bool)
+    lost[np.asarray(plan.token)[at:at + rows]] = True
+    moved = np.asarray(jnp.abs(whole - short).max(axis=1)) > 0
+    assert rows > 0 and np.array_equal(moved, lost)
+
+
+def test_the_bias_moves_the_selection_and_not_the_weights():
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(50, 16)), jnp.float32)
+    gate = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
+    none = jnp.zeros((8,), jnp.float32)
+    push = none.at[5].set(10.0).at[0].set(-10.0)
+    s = jax.nn.sigmoid(jnp.dot(x, gate, precision="highest"))
+    for bias in (none, push):
+        experts, weights = moe.route(x, gate, bias, 3, 2.446)
+        picked = np.take_along_axis(np.asarray(s), np.asarray(experts), 1)
+        # the weights are the selected scores WITHOUT the bias
+        np.testing.assert_allclose(
+            weights, 2.446 * picked / picked.sum(1, keepdims=True),
+            rtol=1e-5)
+        np.testing.assert_allclose(weights.sum(1), 2.446, rtol=1e-5)
+    pushed = np.asarray(moe.route(x, gate, push, 3, 2.446)[0])
+    assert (pushed == 5).any(1).all() and not (pushed == 0).any()
+    assert not (np.asarray(moe.route(x, gate, none, 3, 2.446)[0])
+                == 5).any(1).all()
+    # and no gradient reaches it
+    g = jax.grad(lambda b: jnp.sum(moe.route(x, gate, b, 3, 2.446)[1] ** 2))(
+        push)
+    assert not np.asarray(g).any()
+
+
+def test_rotary_turns_neighbouring_pairs_by_position(ref):
+    mod, _ = ref
+    x = jnp.asarray(np.random.default_rng(6).normal(size=(2, 9, 3, 8)),
+                    jnp.float32)
+    got = moe.rotary(x, 800000.0)
+    np.testing.assert_allclose(got, mod._rotary(x), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got[:, 0], x[:, 0], rtol=1e-6)   # t = 0
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    # scores depend on the distance alone
+    q, k = moe.rotary(jnp.broadcast_to(x[:, :1], x.shape), 800000.0), \
+        moe.rotary(jnp.broadcast_to(x[:, 1:2], x.shape), 800000.0)
+    dots = jnp.einsum("bthd,bshd->bhts", q, k)
+    np.testing.assert_allclose(dots[..., 3, 1], dots[..., 7, 5], rtol=1e-4,
+                               atol=1e-5)
+
+
+# ------------------------------------------------------- what engaged
+def test_a_tiny_fit_says_what_engaged(ref):
+    """The trace-time counters after a fit — the layers by kind, the
+    experts held and routed over, the rows the dispatch is built for —
+    and the data read back with the losses: the assignments of the job
+    by where they landed, and the load of the busiest expert held."""
+    from iotml.data.dataset import Batch
+    from iotml.obs.metrics import default_registry
+    from iotml.train.loop import Trainer
+
+    mod, cfg = ref
+    jax.clear_caches()
+    before = default_registry.collect()
+    x, y, _ = _batch()
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
+                      learning_rate=1e-5)
+    history = trainer.fit_compiled(
+        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+               first_index=0)] * 3, epochs=2)
+    got = default_registry.collect()
+    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
+    assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
+            ("mla", "attention", "mamba", "dense_ffn", "moe_ffn")] \
+        == [3, 0, 0, 1, 2]
+    assert got["iotml_remat_blocks"] == 3
+    assert got["iotml_latent_assembled_operands"] == 1   # k, by the mixer
+    assert got['iotml_moe_experts{kind="held"}'] == 4
+    assert got['iotml_moe_experts{kind="routed_over"}'] == 16
+    assert got["iotml_moe_top_k"] == 3
+    assert got["iotml_moe_dispatch_rows"] == moe.dispatch_rows(80, 3, 4)
+    moved = {k: got[f'iotml_moe_assignments_total{{kind="{k}"}}']
+             - before.get(f'iotml_moe_assignments_total{{kind="{k}"}}', 0.0)
+             for k in ("held", "elsewhere")}
+    # two expert layers x 80 tokens x 3 a token x 3 steps x 2 epochs
+    assert moved["held"] + moved["elsewhere"] == 2 * 80 * 3 * 3 * 2
+    counts = np.sum([np.asarray(c).reshape(-1, 16).sum(0)
+                     for c in jax.tree.leaves(history["reports"])], axis=0)
+    assert moved["held"] == counts[:4].sum() > 0
+    assert got["iotml_moe_expert_load_max_over_mean"] == pytest.approx(
+        counts[:4].max() / counts[:4].mean())
+
+
+def test_a_model_that_reports_nothing_fits_the_program_it_had():
+    """No expert layer, no report: the scanned fit returns its two
+    vectors and its jaxpr holds no trace of the collection."""
+    import optax
+
+    from iotml.models.hybrid import HybridConfig
+    from iotml.train.loop import TrainState, make_scanned_fit
+
+    model = SensorHybrid(HybridConfig(layer_types=("mla", "attention")))
+    assert model.report_collections == ()
+    x, y, m = _batch()
+    tx = optax.adam(1e-3)
+    state = TrainState.create(model, jax.random.PRNGKey(0), x, tx=tx)
+    _, out = make_scanned_fit(model, tx, supervised=True)(
+        state, x[None], y[None], m[None], epochs=2)
+    assert len(out) == 2 and out[0].shape == (2,)

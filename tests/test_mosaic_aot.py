@@ -99,6 +99,21 @@ def test_flash_attention_grouped_heads_lower_for_v5e(v5e):
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
 
 
+def test_flash_attention_latent_heads_lower_for_v5e(v5e):
+    """`km-train-backlog`'s attention, exactly, forward and backward:
+    16 heads of 192 query/key features beside 128 value features at
+    T = 8,192 in float32 — column blocks of 384 and 256 lanes a step."""
+    qk = jax.ShapeDtypeStruct((1, 8192, 16, 192), jnp.float32, sharding=v5e)
+    v = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.float32, sharding=v5e)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 @pytest.mark.parametrize("shape,splits,K,copies", [
     # `gh-train-backlog`, exactly: the convolved stream as the mixer
     # hands it over, out as x, B and C (the B and C runs read from row
