@@ -15,7 +15,8 @@ batches.  The design here:
   jitted step never sees a new shape and never recompiles.
 
 `SensorBatches` mirrors the reference knobs (batch_size, take, skip) and its
-per-epoch re-read semantics via `reset()`.
+per-epoch re-read semantics via `reset()`.  A bounded take re-entered on
+one cursor (a loop of jobs) reads one take ahead: see `_take_ended`.
 """
 
 from __future__ import annotations
@@ -72,6 +73,11 @@ class SensorBatches:
       pad_tail: zero-pad the final ragged batch (True) or drop it (False —
         the reference's drop_remainder-free batch() keeps ragged tails; we
         pad by default because static shapes are the TPU contract).
+
+    A bounded take (`take` set) re-entered on one cursor reads one take
+    ahead: from the second full take in a row on, the consumer fetches
+    the next take's records while the caller trains on this one's
+    (`_take_ended`); its positions stay the delivered ones.
     """
 
     def __init__(self, consumer: StreamConsumer,
@@ -133,6 +139,13 @@ class SensorBatches:
         # be skipped for good.  Updated by __iter__ between chunks, read
         # by the poll loop to cap each fetch.
         self._need_rows: Optional[int] = None
+        # The `max_messages` of the current bounded iteration's
+        # `poll_decoded` calls (None outside one, and from the first
+        # consumer call of another kind on), and how many bounded
+        # iterations in a row emitted their whole `take`: what
+        # `_take_ended` hands the consumer's read-ahead.
+        self._take_polls: Optional[list] = None
+        self._full_takes = 0
         # Trace contexts FORKED from consumed record headers, marked
         # `consume` at decode and held (bounded drop-oldest) for the
         # pipeline closer — the train step / scorer calls take_traces()
@@ -199,6 +212,23 @@ class SensorBatches:
         if self._need_rows is None:
             return self.poll_chunk
         return max(1, min(self.poll_chunk, self._need_rows))
+
+    def _take_ended(self, full: bool) -> None:
+        """An iteration is over; `full`: it emitted its whole `take`.
+        From the second full take in a row on this object — a loop of
+        jobs on one cursor, not a one-shot job, which must not fetch a
+        record it will not train — the consumer is asked to run the
+        polls this take made once more, ahead of the next take and
+        while the caller computes (`StreamConsumer.read_ahead`: an exact
+        replay below `positions()`, so what is delivered, committed and
+        checkpointed is what it was).  Unbounded drains never ask."""
+        polls, self._take_polls = self._take_polls, None
+        self._need_rows = None
+        self._full_takes = self._full_takes + 1 if full else 0
+        ahead = getattr(self.consumer, "read_ahead", None)
+        if polls and self._full_takes >= 2 and ahead is not None:
+            ahead(polls, self._native, strip=5,
+                  with_keys=self._capture_keys)
 
     def _columnar_ready(self) -> bool:
         """Whether the zero-copy raw-batch path applies to this broker:
@@ -290,6 +320,7 @@ class SensorBatches:
 
     def _poll_msgs(self):
         """One message-list poll, as a `fetch` phase."""
+        self._take_polls = None  # not a take of poll_decoded calls alone
         with tracing.phase(None, "fetch"):
             return self.consumer.poll(self._poll_limit())
 
@@ -400,11 +431,13 @@ class SensorBatches:
             from ..stream.broker import SchemaIdMismatchError
 
             while True:
+                limit = self._poll_limit()
+                if self._take_polls is not None:
+                    self._take_polls.append(limit)
                 try:
                     with tracing.phase(None, "fetch"):
                         res = self.consumer.poll_decoded(
-                            self._native, strip=5,
-                            max_messages=self._poll_limit(),
+                            self._native, strip=5, max_messages=limit,
                             with_keys=self._capture_keys)
                 except SchemaIdMismatchError:
                     msgs = self._poll_msgs()
@@ -486,6 +519,8 @@ class SensorBatches:
                          keys=ks)  # first_index patched by caller
 
         chunks = self._filtered_chunks()
+        full = False
+        self._take_polls = [] if self.take else None
         try:
             while True:
                 if self.take:
@@ -515,6 +550,7 @@ class SensorBatches:
                         emitted += 1
                         index += B
                         if self.take and emitted >= self.take:
+                            full = True
                             return
                     lo += B
                 if lo < len(xs):
@@ -528,7 +564,7 @@ class SensorBatches:
                 b.first_index = index
                 yield b
         finally:
-            self._need_rows = None
+            self._take_ended(full)
 
     def _windowed_iter(self) -> Iterator[Batch]:
         """Sliding windows x=[B,T,F] with next-step targets y=[B,1,F].
@@ -562,6 +598,8 @@ class SensorBatches:
             return Batch(x, n_valid, 0, y=y)
 
         chunks = self._filtered_chunks()
+        full = False
+        self._take_polls = [] if self.take else None
         try:
             while True:
                 if self.take:
@@ -612,6 +650,7 @@ class SensorBatches:
                         emitted += 1
                         index += B
                         if self.take and emitted >= self.take:
+                            full = True
                             return
                     lo += B
                 if lo < len(wx):
@@ -625,7 +664,7 @@ class SensorBatches:
                 b.first_index = index
                 yield b
         finally:
-            self._need_rows = None
+            self._take_ended(full)
 
     # ----------------------------------------------------------- tracing
     def take_traces(self) -> List["tracing.TraceContext"]:
@@ -645,6 +684,7 @@ class SensorBatches:
         self.consumer.seek_to_start()
         self.records_seen = 0
         self._skipped = 0
+        self._full_takes = 0  # a re-read of the slice, not the next take
 
     def epochs(self, n: int):
         """Yield epoch iterators with automatic rewind between them."""

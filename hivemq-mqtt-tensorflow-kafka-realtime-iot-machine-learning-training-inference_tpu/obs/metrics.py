@@ -241,6 +241,14 @@ consumer_autoresets = default_registry.counter(
     "iotml_consumer_autoresets_total",
     "consumer cursors auto-reset to earliest after retention trimmed "
     "past them (OffsetOutOfRange), by topic")
+# the consumer's read-ahead of one take (stream/consumer.py
+# `read_ahead`): rows a poll_decoded took from it (hit), rows a poll
+# that found it unusable fetched in the foreground instead (miss), and
+# rows fetched ahead and never delivered (dropped: a seek, a rewind, a
+# poll of another size) — they stayed in the log, behind the cursors
+consumer_readahead_rows = default_registry.counter(
+    "iotml_consumer_readahead_rows_total",
+    "rows of the consumer's read-ahead by result (hit | miss | dropped)")
 replica_sync_rounds = default_registry.counter(
     "iotml_replica_sync_rounds_total", "follower replication rounds")
 replica_copied = default_registry.counter(
@@ -583,6 +591,7 @@ DECLARED_METRIC_LABELS = {
     "conv_grid_steps": ("kernel",),
     "conv_operand_copies": ("kernel",),
     "consumer_lag_records": ("group", "partition", "topic"),
+    "consumer_readahead_rows": ("result",),
     "dlq_total": ("source",),
     "flash_block_k": ("kernel",),
     "flash_block_q": ("kernel",),

@@ -97,6 +97,9 @@ _BUFFER_BOUND = 65536
 
 #: per-thread ring of phase spans: round-granular, so hours of a loop
 _PHASE_BOUND = 4096
+#: buffers registered before a new thread's registration prunes those of
+#: exited threads (see `_Collector.buffer`)
+_THREADS_BOUND = 256
 
 #: loop label of a phase opened with no loop and no parent (a batcher
 #: iterated outside any loop's phase)
@@ -164,6 +167,16 @@ class _Collector:
             buf = _Buf(threading.current_thread())
             self._tls.buf = buf
             with self._reg_lock:
+                if len(self._buffers) >= _THREADS_BOUND:
+                    # a process nobody drains (no scrape, no span log)
+                    # that starts short-lived threads — the consumer's
+                    # read-ahead is one a take — must not keep a ring a
+                    # thread for good: the exited threads' drained
+                    # buffers go, their phase spans with them (the
+                    # histogram has them; `drain` prunes the logged ones
+                    # sooner)
+                    self._buffers = [b for b in self._buffers
+                                     if b.q or b.thread.is_alive()]
                 self._buffers.append(buf)
         return buf
 

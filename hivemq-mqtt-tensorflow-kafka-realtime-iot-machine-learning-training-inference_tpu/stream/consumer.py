@@ -10,11 +10,18 @@ python-scripts/README.md:114-117).
 (emulator or native engine) and adds what the reference lacked: explicit
 multi-partition specs, committed-offset resume, and a `seek` for epoch
 re-reads without reconstructing the pipeline.
+
+Positions are DELIVERED positions.  `read_ahead` may fetch the next
+take's records while the caller computes, as Kafka's own consumer does
+below `position()`; a record fetched and not yet delivered is invisible
+to `positions()`, `commit()`, `record_lag()` and every checkpoint.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+import copy
+import threading
+from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -36,8 +43,37 @@ def parse_spec(spec: str) -> tuple:
     return parts[0], int(parts[1]), int(parts[2])
 
 
+class _Ahead(NamedTuple):
+    """One poll run ahead: the cursor state (`StreamConsumer._where`) it
+    started from and left behind, and what it asked for and got."""
+
+    before: tuple
+    max_messages: int
+    out: tuple  # the arrays `poll_decoded` returned
+    after: tuple
+
+
+class _ReadAhead:
+    """One take's polls, run ahead of the caller on a copy of the
+    cursors: `entries` holds them oldest first.  The fetching thread
+    appends under `lock` until `dropped`; the consumer's thread joins
+    it, pops the head, or drops the lot."""
+
+    __slots__ = ("key", "entries", "thread", "lock", "dropped")
+
+    def __init__(self, key: tuple):
+        self.key = key  # (codec, strip, with_keys) of every entry
+        self.entries: List[_Ahead] = []
+        self.thread: Optional[threading.Thread] = None
+        self.lock = threading.Lock()
+        self.dropped = False
+
+
 class StreamConsumer:
     """Cursor over one or more (topic, partition) logs.
+
+    Its positions are delivered positions: what `read_ahead` has fetched
+    and no poll has returned yet moved no cursor.
 
     Args:
       broker: broker duck-type (`fetch`, `end_offset`, `commit`, `committed`).
@@ -47,6 +83,9 @@ class StreamConsumer:
            (reference eof=True batch-mode); if False, callers may poll again
            as data arrives (continuous scoring mode).
     """
+
+    #: the take `read_ahead` fetched and no poll has returned yet
+    _ahead: Optional[_ReadAhead] = None
 
     def __init__(self, broker: Broker, specs: Sequence[str],
                  group: str = "iotml", eof: bool = True):
@@ -90,6 +129,7 @@ class StreamConsumer:
         processing round aborts mid-chunk: `poll` has already advanced the
         cursors, so without a rewind the failed records would be silently
         skipped; rewinding retries them next round (at-least-once)."""
+        self._drop_ahead()
         for i, cur in enumerate(self._cursors):
             topic, part, _ = cur
             off = self.broker.committed(self.group, topic, part)
@@ -189,6 +229,7 @@ class StreamConsumer:
             except OffsetOutOfRangeError as e:
                 off = max(e.earliest, self.broker.begin_offset(topic, part))
                 obs_metrics.consumer_autoresets.inc(topic=topic)
+                self._drop_ahead()
         # chronically outrun by retention (it trimmed past every reset):
         # an empty batch with the cursor parked at the last-known
         # earliest keeps the documented contract — poll() never raises
@@ -245,12 +286,39 @@ class StreamConsumer:
         [n, S] bytes) — with `with_keys`, (numeric, labels, keys [n]
         bytes) — or None when this broker has no native decode path (for
         with_keys that includes brokers without `fetch_decode_keys`);
-        n == 0 signals the same end-of-poll as an empty `poll()`."""
-        fd = getattr(self.broker,
-                     "fetch_decode_keys" if with_keys else "fetch_decode",
-                     None)
+        n == 0 signals the same end-of-poll as an empty `poll()`.
+
+        A poll that `read_ahead` already ran from this very cursor state
+        returns that run's arrays and adopts its cursors: rows, order
+        and positions after every poll are the unbuffered consumer's."""
+        fd = self._fused_leg(with_keys)
         if fd is None:
             return None
+        out = result = None
+        if self._ahead is not None:
+            out = self._take_ahead((codec, strip, with_keys), max_messages)
+            result = "miss" if out is None else "hit"
+        if out is None:
+            out = self._poll_decoded(fd, codec, strip, max_messages,
+                                     with_keys)
+        if result:
+            obs_metrics.consumer_readahead_rows.inc(len(out[0]),
+                                                    result=result)
+        self.record_lag(cached_only=True)
+        return out
+
+    def _fused_leg(self, with_keys: bool):
+        """The broker's fused fetch + decode call, or None without one."""
+        return getattr(self.broker,
+                       "fetch_decode_keys" if with_keys else "fetch_decode",
+                       None)
+
+    def _poll_decoded(self, fd, codec, strip: int, max_messages: int,
+                      with_keys: bool, autoreset: bool = True):
+        """`poll_decoded`'s round over the partitions, on this object's
+        cursors and `_rr`: the consumer's own, or the copy a read-ahead
+        runs on (`autoreset` False there: a cursor below the retained
+        base is the foreground poll's to reset and count)."""
         nums, labs, keys = [], [], []
         got = 0
         n = len(self._cursors)
@@ -264,6 +332,8 @@ class StreamConsumer:
                 res = fd(topic, part, off, codec, strip=strip,
                          max_rows=max_messages - got)
             except OffsetOutOfRangeError as e:
+                if not autoreset:
+                    raise
                 # same documented auto-reset-to-earliest as poll(): the
                 # fused native path must not turn a retention trim into
                 # a crashed trainer/scorer loop
@@ -290,7 +360,6 @@ class StreamConsumer:
                     keys.append(res[2])
                 got += len(numeric)
                 attempts = 0
-        self.record_lag(cached_only=True)
         if not nums:
             from .native import LABEL_STRIDE
 
@@ -299,6 +368,107 @@ class StreamConsumer:
             return empty + (np.zeros((0,), "S1"),) if with_keys else empty
         out = (np.concatenate(nums), np.concatenate(labs))
         return out + (np.concatenate(keys),) if with_keys else out
+
+    # --------------------------------------------------------- read-ahead
+    def _where(self) -> tuple:
+        """The cursor state a poll starts from and leaves behind."""
+        return tuple(c[2] for c in self._cursors), self._rr
+
+    def read_ahead(self, requests: Sequence[int], codec, strip: int = 5,
+                   with_keys: bool = False) -> None:
+        """Fetch the next take while the caller computes: ONE short-lived
+        thread runs `poll_decoded`'s round once for each of `requests`
+        (the `max_messages` of the polls to come, in order) on a COPY of
+        the cursors and keeps what each returned.  No cursor of this
+        consumer moves; `poll_decoded` hands an entry on only to the
+        very poll it replays (`_take_ahead`), so what is delivered, and
+        in what order, is what it would have been.  An empty result is
+        never kept (it means end of stream to the caller, who asks
+        again), and whatever the thread raises ends it silently: the
+        foreground poll meets the same condition and handles it as
+        documented.  At most one take is held."""
+        fd = self._fused_leg(with_keys)
+        if fd is None or not requests:
+            return
+        from ..supervise.registry import register_thread
+
+        self._drop_ahead()
+        ahead = _ReadAhead((codec, strip, with_keys))
+        shadow = copy.copy(self)
+        shadow._cursors = [list(c) for c in self._cursors]
+        shadow._ahead = None
+        ahead.thread = register_thread(threading.Thread(
+            target=self._fetch_ahead,
+            args=(ahead, shadow, fd, list(requests)),
+            name="iotml-consumer-read-ahead", daemon=True))
+        ahead.thread.start()
+        self._ahead = ahead
+
+    @staticmethod
+    def _fetch_ahead(ahead: _ReadAhead, shadow: "StreamConsumer", fd,
+                     requests: List[int]) -> None:
+        """The read-ahead thread: `shadow` is a copy of the consumer
+        whose cursors only this thread moves."""
+        codec, strip, with_keys = ahead.key
+        late = 0  # rows that arrived after the drop
+        try:
+            for max_messages in requests:
+                if ahead.dropped:
+                    break
+                before = shadow._where()
+                # outside any loop's phase: iotml.stream.fetch — the
+                # trainer's own loop reads only what IT still waits for
+                with tracing.phase(None, "fetch"):
+                    out = shadow._poll_decoded(fd, codec, strip,
+                                               max_messages, with_keys,
+                                               autoreset=False)
+                if not len(out[0]):
+                    break
+                with ahead.lock:
+                    if ahead.dropped:
+                        late = len(out[0])
+                        break
+                    ahead.entries.append(
+                        _Ahead(before, max_messages, out, shadow._where()))
+        except Exception:  # noqa: BLE001 - the foreground poll meets it
+            pass
+        if late:
+            obs_metrics.consumer_readahead_rows.inc(late, result="dropped")
+
+    def _take_ahead(self, key: tuple, max_messages: int):
+        """The head entry's arrays if this poll is the one it replays —
+        same codec, strip and keys, same `max_messages`, and the cursors
+        and `_rr` it started from — else None, with the rest dropped."""
+        ahead = self._ahead
+        ahead.thread.join()
+        head = ahead.entries[0] if ahead.entries else None
+        if head is None or key != ahead.key or \
+                head.max_messages != max_messages or \
+                head.before != self._where():
+            self._drop_ahead()
+            return None
+        del ahead.entries[0]
+        if not ahead.entries:
+            self._ahead = None
+        offsets, self._rr = head.after
+        for cur, off in zip(self._cursors, offsets):
+            cur[2] = off
+        return head.out
+
+    def _drop_ahead(self) -> None:
+        """Forget what was fetched ahead, and whatever a fetch still in
+        flight brings: called wherever a cursor moves other than by a
+        delivery.  The records stay in the log and the cursors never
+        passed them, so nothing is lost; the rows are counted."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        with ahead.lock:
+            ahead.dropped = True
+            rows = sum(len(e.out[0]) for e in ahead.entries)
+            ahead.entries.clear()
+        if rows:
+            obs_metrics.consumer_readahead_rows.inc(rows, result="dropped")
 
     def poll_into(self, decoder, out_numeric, out_labels, out_keys=None,
                   max_rows: int = 4096, max_bytes: int = 1 << 20):
@@ -353,6 +523,7 @@ class StreamConsumer:
                               self.broker.begin_offset(topic, part))
                     cur[2] = off
                     obs_metrics.consumer_autoresets.inc(topic=topic)
+                    self._drop_ahead()
             if raw is None:
                 continue
             got, next_off, flags, _skipped = decoder.decode_into(
@@ -445,6 +616,7 @@ class StreamConsumer:
     # ------------------------------------------------------------- cursor
     def seek_to_start(self):
         """Rewind to the construction offsets (per-epoch stream re-read)."""
+        self._drop_ahead()
         for cur, off in zip(self._cursors, self._start):
             cur[2] = off
 
@@ -456,10 +628,12 @@ class StreamConsumer:
         oft = getattr(self.broker, "offset_for_timestamp", None)
         if oft is None:
             return
+        self._drop_ahead()
         for cur in self._cursors:
             cur[2] = oft(cur[0], cur[1], timestamp_ms)
 
     def seek(self, topic: str, partition: int, offset: int):
+        self._drop_ahead()
         for cur in self._cursors:
             if cur[0] == topic and cur[1] == partition:
                 cur[2] = offset
@@ -469,10 +643,13 @@ class StreamConsumer:
     def positions(self) -> List[tuple]:
         """Current (topic, partition, next_offset) cursor state — this tuple
         is the stream-side resume checkpoint (SURVEY §5 'offset is the resume
-        cursor')."""
+        cursor').  Delivered positions: a record `read_ahead` fetched and
+        no poll returned has moved nothing here."""
         return [tuple(c) for c in self._cursors]
 
     def commit(self):
+        """Commit the delivered positions (`positions()`), never what
+        `read_ahead` holds: a crash after it re-reads those records."""
         # commit is the drain boundary — the batch-granular spot to
         # refresh the first-class lag gauge (ISSUE 13 satellite)
         self.record_lag()

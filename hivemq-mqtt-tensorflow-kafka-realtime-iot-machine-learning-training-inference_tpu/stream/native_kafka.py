@@ -369,6 +369,13 @@ class NativeKafkaBroker(ProducePartitionMixin):
                                    value, key, int(ts[i])))
             return out
 
+    def _open(self) -> None:
+        """Raise where `close()` has run: the consumer's read-ahead
+        thread may still hold this client then, and the engine
+        dereferences the handle it is given."""
+        if not self._h:
+            raise ConnectionError("native Kafka client is closed")
+
     def fetch_decode(self, topic: str, partition: int, offset: int,
                      codec: NativeCodec, strip: int = 5,
                      max_rows: int = 4096
@@ -376,6 +383,7 @@ class NativeKafkaBroker(ProducePartitionMixin):
         """Fused native poll → (numeric [n, F] float64, labels [n, S] bytes,
         next_offset).  n == 0 means no data at `offset`."""
         with self._lock:
+            self._open()
             numeric = np.empty((max_rows, codec.n_numeric), np.float64)
             labels = np.zeros((max_rows, max(codec.n_strings, 1)),
                               f"S{LABEL_STRIDE}")
@@ -417,6 +425,7 @@ class NativeKafkaBroker(ProducePartitionMixin):
         the record's routing identity (car id via the MQTT-topic key) —
         what per-entity consumers (car-health detection) join on."""
         with self._lock:
+            self._open()
             numeric = np.empty((max_rows, codec.n_numeric), np.float64)
             labels = np.zeros((max_rows, max(codec.n_strings, 1)),
                               f"S{LABEL_STRIDE}")

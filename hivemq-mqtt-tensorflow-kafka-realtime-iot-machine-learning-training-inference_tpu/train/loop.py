@@ -405,8 +405,14 @@ class Trainer:
         # transfer the program waits on has a fixed completion latency,
         # and a round's slice is small.  A chunked double-buffered
         # variant (device_put per 32 batches overlapping the stream
-        # decode) exists on the multi-chip path (DevicePrefetcher);
-        # whether it would pay here on the current chip: not measured.
+        # decode) exists on the multi-chip path (DevicePrefetcher).
+        # Here the overlap is the consumer's: from a loop's second job
+        # on, `StreamConsumer.read_ahead` fetches the NEXT job's records
+        # while this one's fit runs (`SensorBatches._take_ended`), below
+        # `positions()`, so the cursors a checkpoint saves and a commit
+        # writes after this call are still the trained ones.  Pulling
+        # the next take here, between dispatch and sync, would move them
+        # past what has been trained.
         #
         # Iterate via .epochs(1) when the source has it: for a cache=True
         # SensorBatches that's what populates the replay cache (a bare

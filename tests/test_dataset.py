@@ -1,10 +1,12 @@
 """Stream → fixed-shape batch pipeline (decode, filter, pad, window)."""
 
 import numpy as np
+import pytest
 
 from iotml.core.schema import KSQL_CAR_SCHEMA
 from iotml.data.dataset import SensorBatches
 from iotml.gen.simulator import FleetGenerator, FleetScenario
+from iotml.stream import native as native_mod
 from iotml.stream.broker import Broker
 from iotml.stream.consumer import StreamConsumer
 
@@ -99,3 +101,82 @@ def test_ksql_schema_is_default():
     _, consumer, _ = make_stream(num_cars=5, ticks=2)
     bs = SensorBatches(consumer)
     assert bs.schema is KSQL_CAR_SCHEMA
+
+
+# ------------------------------------------- read-ahead hint (ISSUE 31)
+class _TakesConsumer:
+    """A consumer duck-type with a fused decode leg and `rows` records
+    to give, which keeps what the batcher asked of it."""
+
+    fetch_decode = staticmethod(lambda *a, **kw: None)  # "has the leg"
+
+    def __init__(self, rows=10 ** 9):
+        self.broker = self
+        self.rows = rows
+        self.polls, self.asked = [], []
+
+    def poll_decoded(self, codec, strip=5, max_messages=4096,
+                     with_keys=False):
+        n = min(max_messages, self.rows)
+        self.rows -= n
+        self.polls.append(max_messages)
+        return (np.zeros((n, codec.n_numeric)),
+                np.full((n, codec.n_strings), b"false", "S16"))
+
+    def read_ahead(self, requests, codec, strip=5, with_keys=False):
+        self.asked.append(list(requests))
+
+    def seek_to_start(self):
+        pass
+
+
+needs_native = pytest.mark.skipif(not native_mod.available(),
+                                  reason="C++ engine not built")
+
+
+@needs_native
+@pytest.mark.parametrize("kw,polls", [
+    (dict(batch_size=100, take=3), [300]),
+    (dict(batch_size=100, take=3, poll_chunk=256), [256, 44]),
+    (dict(batch_size=4, take=4, window=64), [80]),
+    (dict(batch_size=1, take=4, window=4096), [4096, 4]),
+])
+def test_a_bounded_take_arms_on_its_second_end_in_a_row(kw, polls):
+    cons = _TakesConsumer()
+    batches = SensorBatches(cons, **kw)
+    assert len(list(batches)) == kw["take"]
+    assert cons.asked == []            # a one-shot job reads nothing ahead
+    assert len(list(batches)) == kw["take"]
+    assert cons.asked == [polls]       # a loop of jobs on one cursor does
+    assert len(list(batches)) == kw["take"]
+    assert cons.asked == [polls, polls]
+    assert cons.polls == polls * 3
+    # an epoch re-read is not the next take: the count starts again
+    batches.reset()
+    list(batches)
+    assert cons.asked == [polls, polls]
+
+
+@needs_native
+@pytest.mark.parametrize("window", [None, 8])
+def test_an_unbounded_drain_and_a_short_take_never_arm(window):
+    cons = _TakesConsumer(rows=1000)
+    drain = SensorBatches(cons, batch_size=100, window=window)
+    for _ in range(3):
+        list(drain)
+        cons.rows = 1000
+    assert cons.asked == []
+    # a take the stream's end cut short ends the run of full takes
+    cons = _TakesConsumer(rows=250)
+    batches = SensorBatches(cons, batch_size=100, take=2, window=window)
+    assert len(list(batches)) == 2
+    assert len(list(batches)) < 2
+    cons.rows = 10 ** 9
+    assert len(list(batches)) == 2
+    assert cons.asked == []
+    assert len(list(batches)) == 2
+    assert len(cons.asked) == 1
+    # and one abandoned midway does too
+    next(iter(batches))
+    assert len(list(batches)) == 2
+    assert len(cons.asked) == 1

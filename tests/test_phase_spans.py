@@ -212,6 +212,75 @@ def test_batcher_fetch_and_decode_are_phases_of_the_callers_loop():
     assert len([s for s in inner if s.name.endswith(".decode")]) == 2
 
 
+@needs_native
+def test_the_read_aheads_fetch_is_the_streams_and_the_hit_the_trainers():
+    """The consumer's read-ahead thread (ISSUE 31) opens its consumer
+    calls outside any loop's phase — `iotml.stream.fetch` — so
+    `loop="train"` reads only what the trainer's thread still waits
+    for: its own poll, served from the buffer."""
+    from iotml.stream.native_kafka import NativeKafkaBroker
+
+    broker = Broker()
+    _fill(broker, n=400)
+    with KafkaWireServer(broker) as srv:
+        nb = NativeKafkaBroker(f"127.0.0.1:{srv.port}")
+        cons = StreamConsumer(nb, ["T:0:0"], group="ahead")
+        batches = SensorBatches(cons, batch_size=10, take=3, window=16,
+                                poll_chunk=32)
+        reg0 = obs_metrics.default_registry.collect()
+        hosts = []
+        for job in range(3):
+            with tracing.phase("train", "host_pipeline", round=job) as h:
+                assert len(list(batches)) == 3
+            hosts.append(h.id)
+            # armed on the second end, not on the first
+            assert (cons._ahead is None) == (job == 0)
+        cons._ahead.thread.join(60)
+        reg1 = obs_metrics.default_registry.collect()
+        nb.close()
+
+    def count(reg, loop):
+        return reg.get("iotml_step_seconds_count"
+                       f'{{loop="{loop}",phase="fetch"}}', 0.0)
+
+    # a take is 46 rows in polls of 32 and 14: two read-aheads of two
+    # consumer calls each under `stream`, three takes' own under `train`
+    assert count(reg1, "stream") - count(reg0, "stream") == 4
+    assert count(reg1, "train") - count(reg0, "train") == 6
+    hit = 'iotml_consumer_readahead_rows_total{result="hit"}'
+    assert reg1.get(hit, 0.0) - reg0.get(hit, 0.0) == 46
+    spans = tracing.phases()
+    ahead = [s for s in spans if s.name == "iotml.stream.fetch"]
+    assert len(ahead) == 4 and all(
+        s.parent is None and s.thread == "iotml-consumer-read-ahead"
+        for s in ahead)
+    # the third job's polls came out of the buffer and are still its own
+    # `fetch` phases, on its own thread, inside its host pipeline
+    mine = [s for s in spans if s.parent == hosts[2]]
+    assert [s.name for s in mine] == ["iotml.train.fetch"] * 2
+    assert all(s.thread != "iotml-consumer-read-ahead" for s in mine)
+
+
+def test_exited_threads_do_not_keep_a_ring_each_for_good(monkeypatch):
+    """A thread a read-ahead, in a process nobody drains: a new thread's
+    registration prunes the exited threads' buffers past the bound."""
+    import threading
+
+    monkeypatch.setattr(tracing, "_THREADS_BOUND", 8)
+
+    def one():
+        with tracing.phase(None, "fetch"):
+            pass
+
+    for _ in range(40):
+        t = threading.Thread(target=one, name="short-lived", daemon=True)
+        t.start()
+        t.join()
+    assert len(tracing._collector.buffers()) <= 9
+    # the newest spans are still there to read; the histogram has all
+    assert any(s.thread == "short-lived" for s in tracing.phases())
+
+
 def test_scorer_drain_span_tree():
     import jax
 
