@@ -24,8 +24,16 @@ The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
 `attention`, or `mla`, the latent attention of `models.latent_moe`) and
 the feed-forward part (`ffn_types`: `dense_ffn`, the gated MLP above, or
 `moe_ffn`, that file's sparse-expert layer; left empty, every layer is
-`dense_ffn`).  Both are data of the model, as published configurations
-state them.
+`dense_ffn`).  Either may be `none`: a layer is then ONE part alone,
+`h ← h + r · part(RMSNorm(h))`, and builds the norm and the residual of
+the part it has and no other (a Nemotron-H-shaped stack: every layer a
+mixer or a feed-forward part).  Both are data of the model, as
+published configurations state them.
+
+The heads a layer holds may be a share of the model's (one chip's,
+where the model's mixers are divided over chips): `num_heads` and
+`ssm_heads` count the heads held, `head_dim` and `ssm_head_dim` state
+their width, and `d_model` is the stream's whatever share is held.
 
 The recurrent state (ssm_heads × ssm_head_dim × ssm_state a layer and
 sequence) starts at zero at the window's start; carrying it from window
@@ -53,6 +61,7 @@ from .latent_moe import normal as _normal
 
 KINDS = ("mamba", "attention", "mla")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
+NONE = "none"   # a layer without that part
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +73,8 @@ class HybridConfig:
     layer_types: Tuple[str, ...] = ("mamba", "attention", "mamba")
     num_heads: int = 4
     num_kv_heads: int = 2
+    # an attention head's width; 0: d_model // num_heads, every head held
+    head_dim: int = 0
     mlp_dim: int = 128
     ssm_heads: int = 4
     ssm_head_dim: int = 16
@@ -93,9 +104,16 @@ class HybridConfig:
     expert_dim: int = 32
     shared_dim: int = 32
     routed_scale: float = 1.0
+    # the experts' form (`ops.moe.EXPERT_FORMS`), routed and shared, and
+    # the latent the routed ones act in (0: at the stream's width)
+    expert_form: str = "gated_silu"
+    moe_latent: int = 0
 
     def ffn_kinds(self) -> Tuple[str, ...]:
         return self.ffn_types or ("dense_ffn",) * len(self.layer_types)
+
+    def attn_head_dim(self) -> int:
+        return self.head_dim or self.d_model // self.num_heads
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -155,8 +173,7 @@ class GroupedAttention(nn.Module):
     def __call__(self, u):
         m = self.cfg
         B, T, _ = u.shape
-        H, G = m.num_heads, m.num_kv_heads
-        D = m.d_model // H
+        H, G, D = m.num_heads, m.num_kv_heads, m.attn_head_dim()
         q = _dense(H * D, "q")(u).reshape(B, T, H, D)
         k = _dense(G * D, "k")(u).reshape(B, T, G, D)
         v = _dense(G * D, "v")(u).reshape(B, T, G, D)
@@ -181,22 +198,24 @@ class HybridBlock(nn.Module):
     @nn.compact
     def __call__(self, h):
         m = self.cfg
-        u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
-        if self.kind == "mamba":
-            mixed = MambaMixer(m, name="mixer")(u)
-        else:
-            attention = LatentAttention if self.kind == "mla" \
-                else GroupedAttention
-            with jax.named_scope("attn"):
-                mixed = attention(m, self.attn_mode, name="mixer")(u)
-        h = h + m.residual_multiplier * mixed
+        if self.kind != NONE:
+            u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
+            if self.kind == "mamba":
+                mixed = MambaMixer(m, name="mixer")(u)
+            else:
+                attention = LatentAttention if self.kind == "mla" \
+                    else GroupedAttention
+                with jax.named_scope("attn"):
+                    mixed = attention(m, self.attn_mode, name="mixer")(u)
+            h = h + m.residual_multiplier * mixed
+        if self.ffn == NONE:
+            return h
+        u = nn.RMSNorm(epsilon=m.eps, name="norm2")(h)
         if self.ffn == "moe_ffn":
-            out = ExpertLayer(m, name="moe")(
-                nn.RMSNorm(epsilon=m.eps, name="norm2")(h))
+            out = ExpertLayer(m, name="moe")(u)
         else:
             with jax.named_scope("mlp"):
-                out = gated_mlp(nn.RMSNorm(epsilon=m.eps, name="norm2")(h),
-                                m.mlp_dim, m.d_model)
+                out = gated_mlp(u, m.mlp_dim, m.d_model)
         return h + m.residual_multiplier * out
 
 
@@ -222,13 +241,14 @@ class SensorHybrid(nn.Module):
     def __call__(self, x):
         m = self.cfg
         ffns = m.ffn_kinds()
-        unknown = (set(m.layer_types) - set(KINDS)) \
-            | (set(ffns) - set(FFN_KINDS))
-        if unknown or len(ffns) != len(m.layer_types):
+        unknown = (set(m.layer_types) - set(KINDS + (NONE,))) \
+            | (set(ffns) - set(FFN_KINDS + (NONE,)))
+        if unknown or len(ffns) != len(m.layer_types) \
+                or (NONE, NONE) in zip(m.layer_types, ffns):
             raise ValueError(
                 f"layer_types {m.layer_types} and ffn_types {m.ffn_types}: "
                 f"known kinds are {KINDS} and {FFN_KINDS}, one of each a "
-                f"layer")
+                f"layer, of which one may be {NONE!r}")
         # what engaged, at trace time (as the flash geometry is said)
         for kind in KINDS:
             obs_metrics.model_layers.set(m.layer_types.count(kind),

@@ -21,6 +21,15 @@ and nothing standing in for them), and a shared expert every token
 takes, computed whole.  Dropless (`ops.moe.experts_apply`).  It reports
 the assignments every expert got, a step, through the `reports`
 collection: data, not shape, so it comes back with the losses.
+
+Two more things of the layer are data.  The experts' **form**
+(`expert_form`, `ops.moe.EXPERT_FORMS`: the gated SiLU above or the
+non-gated squared ReLU), the routed and the shared expert's alike.  And
+the width the routed experts act at: with `moe_latent` set,
+`y = (Σ_k w_k · E_{i_k}(u W_down)) W_back + S(u)` — the router still
+reads the full-width stream, the tiles gather, compute and scatter rows
+of the latent's width, the sum is projected back once, and the shared
+expert stays at full width.
 """
 
 from __future__ import annotations
@@ -45,11 +54,13 @@ def dense(features: int, name: str):
     return nn.Dense(features, use_bias=False, kernel_init=normal, name=name)
 
 
-def gated_mlp(u, width: int, d_model: int, name: str = "mlp"):
-    """(silu(g) ⊙ v) W_out with [g, v] = u W_in: `<name>_in`, `<name>_out`
-    in the calling module's scope."""
-    gate, value = jnp.split(dense(2 * width, f"{name}_in")(u), 2, axis=-1)
-    return dense(d_model, f"{name}_out")(nn.silu(gate) * value)
+def gated_mlp(u, width: int, d_model: int, name: str = "mlp",
+              form: str = "gated_silu"):
+    """(silu(g) ⊙ v) W_out with [g, v] = u W_in — or, under another
+    `form` of `ops.moe.EXPERT_FORMS`, that one: `<name>_in`,
+    `<name>_out` in the calling module's scope."""
+    hidden = dense(moe.EXPERT_FORMS[form] * width, f"{name}_in")(u)
+    return dense(d_model, f"{name}_out")(moe.expert_hidden(hidden, form))
 
 
 class LatentAttention(nn.Module):
@@ -101,9 +112,15 @@ class ExpertLayer(nn.Module):
         if not 0 <= first < first + held <= m.experts:
             raise ValueError(f"experts_held {m.experts_held} is no range of "
                              f"the {m.experts} experts routed over")
+        if not 0 < m.top_k <= m.experts:
+            raise ValueError(f"top_k {m.top_k} of {m.experts} experts: a "
+                             f"token's experts are distinct")
+        width = m.moe_latent or d   # what the routed experts read and give
+        wide = moe.EXPERT_FORMS[m.expert_form]
         obs_metrics.moe_experts.set(held, kind="held")
         obs_metrics.moe_experts.set(m.experts, kind="routed_over")
         obs_metrics.moe_top_k.set(m.top_k)
+        obs_metrics.moe_latent_dim.set(m.moe_latent)
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
         x = u.reshape(B * T, d)
@@ -115,12 +132,20 @@ class ExpertLayer(nn.Module):
             plan = moe.dispatch_plan(experts, weights, first, held, m.experts)
         self.sow(REPORTS, "expert_counts", plan.counts,
                  reduce_fn=lambda _, new: new, init_fn=lambda: 0)
+        if m.moe_latent:
+            with jax.named_scope("latent_proj"):
+                x = dense(width, "latent_in")(x)
         routed = moe.experts_apply(
             x, plan,
-            self.param("experts_in", normal, (held, d, 2 * m.expert_dim)),
-            self.param("experts_out", normal, (held, m.expert_dim, d)))
+            self.param("experts_in", normal,
+                       (held, width, wide * m.expert_dim)),
+            self.param("experts_out", normal, (held, m.expert_dim, width)),
+            m.expert_form)
+        if m.moe_latent:
+            with jax.named_scope("latent_proj"):
+                routed = dense(d, "latent_out")(routed)
         with jax.named_scope("shared"):
-            shared = gated_mlp(u, m.shared_dim, d, "shared")
+            shared = gated_mlp(u, m.shared_dim, d, "shared", m.expert_form)
         return routed.reshape(B, T, d) + shared
 
 
@@ -134,9 +159,19 @@ def record_reports(cfg, reports) -> None:
     if not counts:
         return
     first, held = cfg.experts_held
-    per_expert = np.sum([np.asarray(c, np.int64).reshape(-1, cfg.experts)
-                         .sum(axis=0) for c in counts], axis=0)
+    # [steps of every expert layer, experts]
+    steps = np.concatenate([np.asarray(c, np.int64).reshape(-1, cfg.experts)
+                            for c in counts])
+    per_expert = steps.sum(axis=0)
     here = per_expert[first:first + held]
+    # a step's live tiles (`ops.moe.dispatch_plan`): each expert held
+    # takes whole tiles, so the rows walked are its assignments and the
+    # padding that fills its last tile
+    walked = moe.walked_rows(steps[:, first:first + held],
+                             int(steps[0].sum()) // cfg.top_k)
+    obs_metrics.moe_tile_rows.inc(float(here.sum()), kind="live")
+    obs_metrics.moe_tile_rows.inc(float(walked.sum() - here.sum()),
+                                  kind="padding")
     obs_metrics.moe_assignments.inc(float(here.sum()), kind="held")
     obs_metrics.moe_assignments.inc(
         float(per_expert.sum() - here.sum()), kind="elsewhere")

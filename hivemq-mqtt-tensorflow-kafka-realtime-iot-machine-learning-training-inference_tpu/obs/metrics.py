@@ -515,6 +515,10 @@ moe_experts = default_registry.gauge(
     "here | routed_over: the router's outputs)")
 moe_top_k = default_registry.gauge(
     "iotml_moe_top_k", "experts a token is routed to")
+moe_latent_dim = default_registry.gauge(
+    "iotml_moe_latent_dim",
+    "width of the latent the last traced expert layer's routed experts "
+    "act in (0: at the stream's full width)")
 moe_dispatch_rows = default_registry.gauge(
     "iotml_moe_dispatch_rows",
     "static rows an expert layer's dispatch is built for: the worst the "
@@ -523,6 +527,11 @@ moe_assignments = default_registry.counter(
     "iotml_moe_assignments_total",
     "token-to-expert assignments of the fits so far, all expert layers, "
     "by kind (held: to an expert computed here | elsewhere: left out)")
+moe_tile_rows = default_registry.counter(
+    "iotml_moe_tile_rows_total",
+    "rows of the live tiles the expert layers' dispatch walked in the "
+    "fits so far, by kind (live: a row that holds an assignment | "
+    "padding: the rest of an expert's last tile)")
 moe_expert_load = default_registry.gauge(
     "iotml_moe_expert_load_max_over_mean",
     "the busiest expert held over the mean of the experts held, by "
@@ -606,6 +615,7 @@ DECLARED_METRIC_LABELS = {
     "model_layers": ("kind",),
     "moe_assignments": ("kind",),
     "moe_experts": ("kind",),
+    "moe_tile_rows": ("kind",),
     "model_offsets_lag": ("component",),
     "model_version": ("component",),
     "online_adaptations": ("action",),

@@ -15,10 +15,16 @@ from a tile's boundary (`dispatch_plan`), so every tile of `TILE` rows
 belongs to one expert.  The layout is built for the worst the router
 can produce — every token taking only experts held here — but only
 LIVE tiles run: `experts_apply` walks them in a loop whose trip count
-is data (gather a tile's tokens, the expert's gated MLP as two plain
+is data (gather a tile's tokens, the expert's MLP as two plain
 products, scale by the routing weights, add onto the tokens), and its
 backward walks them again.  Work and memory traffic follow the
 assignments that landed here, a tile's padding at most an expert.
+
+An expert's form is data (`EXPERT_FORMS`): `gated_silu`,
+`(silu(g) ⊙ v) W_out` with `[g, v] = x W_in` (`w_in` `[held, d, 2f]`),
+or `relu2`, the non-gated `relu(x W_in)² W_out` (`[held, d, f]`).  The
+rows may be narrower than the stream: a layer that computes its experts
+in a latent hands the projected rows in and projects the sum back.
 """
 
 from __future__ import annotations
@@ -102,6 +108,13 @@ def dispatch_rows(tokens: int, top_k: int, held: int) -> int:
     return -(-tokens * min(top_k, held) // tile) * tile + held * tile
 
 
+def walked_rows(held_counts, tokens: int):
+    """The rows of the live tiles a step of `tokens` walks, by expert
+    held, of its assignments to them: every group in whole tiles."""
+    tile = _tile(tokens)
+    return -(-held_counts // tile) * tile
+
+
 def dispatch_plan(experts, weights, first: int, held: int,
                   n_experts: int) -> Dispatch:
     """Sort the N·K assignments by expert (those held elsewhere last)
@@ -135,11 +148,25 @@ def dispatch_plan(experts, weights, first: int, held: int,
         tile_end[-1], counts)
 
 
-def _expert_tile(rows, w_in, w_out, weight):
-    """One tile through its expert: (silu(g) ⊙ u) W_out with
-    [g, u] = rows W_in, times the rows' routing weights."""
-    gate, up = jnp.split(rows @ w_in, 2, axis=-1)
-    return ((jax.nn.silu(gate) * up) @ w_out) * weight[:, None]
+#: an expert's form: the activation between its two products, and how
+#: many times the expert's width `w_in` is wide
+EXPERT_FORMS = {"gated_silu": 2, "relu2": 1}
+
+
+def expert_hidden(h, form: str):
+    """What an expert's second product reads, of its first's result h:
+    `silu(g) ⊙ v` with `[g, v] = h`, or `relu(h)²`."""
+    if form == "gated_silu":
+        gate, value = jnp.split(h, 2, axis=-1)
+        return jax.nn.silu(gate) * value
+    if form == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    raise ValueError(f"unknown expert form {form!r}: {tuple(EXPERT_FORMS)}")
+
+
+def _expert_tile(form, rows, w_in, w_out, weight):
+    """One tile through its expert, times the rows' routing weights."""
+    return (expert_hidden(rows @ w_in, form) @ w_out) * weight[:, None]
 
 
 def _tile_operands(c, x, w_in, w_out, weight, plan: Dispatch):
@@ -162,23 +189,23 @@ def _add_rows(acc, tokens, rows):
     return acc.at[tokens].add(rows, mode="drop", unique_indices=True)
 
 
-@jax.custom_vjp
-def _experts(x, w_in, w_out, weight, plan: Dispatch):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(form, x, w_in, w_out, weight, plan: Dispatch):
     def tile_step(c, out):
         tokens, _, _, operands = _tile_operands(c, x, w_in, w_out, weight,
                                                 plan)
-        return _add_rows(out, tokens, _expert_tile(*operands))
+        return _add_rows(out, tokens, _expert_tile(form, *operands))
 
     return jax.lax.fori_loop(0, plan.live_tiles, tile_step,
                              jnp.zeros_like(x))
 
 
-def _experts_fwd(x, w_in, w_out, weight, plan):
-    return _experts(x, w_in, w_out, weight, plan), \
+def _experts_fwd(form, x, w_in, w_out, weight, plan):
+    return _experts(form, x, w_in, w_out, weight, plan), \
         (x, w_in, w_out, weight, plan)
 
 
-def _experts_bwd(res, d_out):
+def _experts_bwd(form, res, d_out):
     """The live tiles again: each recomputed and pulled back; an
     expert's weight gradients accumulate in place."""
     x, w_in, w_out, weight, plan = res
@@ -187,7 +214,7 @@ def _experts_bwd(res, d_out):
         dx, d_in, d_outw, d_weight = grads
         tokens, e, live, operands = _tile_operands(c, x, w_in, w_out, weight,
                                                    plan)
-        _, pull = jax.vjp(_expert_tile, *operands)
+        _, pull = jax.vjp(functools.partial(_expert_tile, form), *operands)
         d_rows, g_in, g_out, g_weight = pull(
             d_out.at[tokens].get(mode="fill", fill_value=0))
         at = plan.tile_first[c]
@@ -209,23 +236,25 @@ def _experts_bwd(res, d_out):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def experts_apply(x, plan: Dispatch, w_in, w_out):
+def experts_apply(x, plan: Dispatch, w_in, w_out, form: str = "gated_silu"):
     """Σ over the assignments held of `w · E_i(x)`, for x [N, d]:
     `E_i(x) = (silu(x W_gate,i) ⊙ x W_up,i) W_down,i` with
-    `w_in[i] = [W_gate,i, W_up,i]` ([held, d, 2f]) and `w_out` [held, f,
-    d].  Dropless: every assignment held has its row."""
+    `w_in[i] = [W_gate,i, W_up,i]` ([held, d, 2f]), or under `relu2`
+    `relu(x W_up,i)² W_down,i` with `w_in` [held, d, f]; `w_out` [held,
+    f, d].  Dropless: every assignment held has its row."""
     with jax.named_scope("experts"):
-        return _experts(x, w_in, w_out, plan.weight, plan)
+        return _experts(form, x, w_in, w_out, plan.weight, plan)
 
 
-@functools.partial(jax.jit, static_argnames=("first", "held"))
-def experts_dense(x, experts, weights, w_in, w_out, first: int, held: int):
+@functools.partial(jax.jit, static_argnames=("first", "held", "form"))
+def experts_dense(x, experts, weights, w_in, w_out, first: int, held: int,
+                  form: str = "gated_silu"):
     """The same sum with every expert held applied to every token and a
     weight that is zero where it was not selected: the form the tests
     hold the dispatch to (no sort, no grouped product)."""
     ids = first + jnp.arange(held)
     dense_w = jnp.sum(jnp.where(experts[..., None] == ids, weights[..., None],
                                 0.0), axis=1)                    # [N, held]
-    gate, up = jnp.split(jnp.einsum("nd,edf->enf", x, w_in), 2, axis=-1)
-    out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up, w_out)
+    hidden = expert_hidden(jnp.einsum("nd,edf->enf", x, w_in), form)
+    out = jnp.einsum("enf,efd->end", hidden, w_out)
     return jnp.einsum("end,ne->nd", out, dense_w.astype(out.dtype))

@@ -188,22 +188,34 @@ def small_tiles(monkeypatch):
     monkeypatch.setattr(moe, "TILE", 64)
 
 
-@pytest.mark.parametrize("forced,per_token", [
-    ("all_held", 3),     # every token takes only experts held: the worst
-    ("none_held", 0),    # no token takes one: no live tile at all
-    ("one_expert", 1),   # every token takes the same expert held
-    ("seeded", None),    # whatever the seeded router does
+@pytest.mark.parametrize("forced,per_token,form,latent", [
+    # every token takes only experts held, the worst: both forms of an
+    # expert, at the stream's width and in a latent
+    ("all_held", 3, "gated_silu", 0),
+    ("all_held", 3, "relu2", 0),
+    ("all_held", 3, "gated_silu", 8),
+    ("all_held", 3, "relu2", 8),
+    ("none_held", 0, "gated_silu", 0),   # no live tile at all
+    ("one_expert", 1, "relu2", 8),       # every token the same expert held
+    ("seeded", None, "gated_silu", 0),   # whatever the seeded router does
+    ("seeded", None, "relu2", 8),
 ])
 def test_dispatch_is_dropless_under_the_worst_imbalance(small_tiles, forced,
-                                                        per_token):
+                                                        per_token, form,
+                                                        latent):
     """No capacity and no token falls through: the tiles' result and
     every gradient against the dense-masked form (each expert held
-    applied to every token, weighted by zero where it was not chosen)."""
+    applied to every token, weighted by zero where it was not chosen).
+    In a latent the router reads x, the experts x W_down, and the sum
+    goes back through W_back: both projections' gradients are held too."""
     N, d, f, E, K, first, held = 300, 16, 8, 16, 3, 4, 4
+    width = latent or d
     rng = np.random.default_rng(2)
     arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    x, gate, w_in, w_out, w = arr(N, d), arr(d, E), arr(held, d, 2 * f), \
-        arr(held, f, d), arr(N, d)
+    x, gate, w_in, w_out, w = arr(N, d), arr(d, E), \
+        arr(held, width, moe.EXPERT_FORMS[form] * f), arr(held, f, width), \
+        arr(N, d)
+    down, back = arr(d, width), arr(width, d)
     here = (np.arange(E) >= first) & (np.arange(E) < first + held)
     bias = {"all_held": np.where(here, 10.0, 0.0),
             "none_held": np.where(here, -10.0, 0.0),
@@ -212,21 +224,31 @@ def test_dispatch_is_dropless_under_the_worst_imbalance(small_tiles, forced,
             "seeded": np.zeros(E)}[forced]
     bias = jnp.asarray(bias, jnp.float32)
 
-    def tiles(x, gate, w_in, w_out):
+    def around(apply, x, down, back):
+        """The experts at the stream's width, or in the latent."""
+        return apply(x @ down) @ back if latent else apply(x)
+
+    def tiles(x, gate, w_in, w_out, down, back):
         plan = moe.dispatch_plan(*moe.route(x, gate, bias, K, 2.5),
                                  first, held, E)
-        return jnp.sum(w * moe.experts_apply(x, plan, w_in, w_out)), plan
+        out = around(lambda z: moe.experts_apply(z, plan, w_in, w_out, form),
+                     x, down, back)
+        return jnp.sum(w * out), plan
 
-    def dense(x, gate, w_in, w_out):
+    def dense(x, gate, w_in, w_out, down, back):
         e, r = moe.route(x, gate, bias, K, 2.5)
-        return jnp.sum(w * moe.experts_dense(x, e, r, w_in, w_out,
-                                             first, held))
+        return jnp.sum(w * around(
+            lambda z: moe.experts_dense(z, e, r, w_in, w_out, first, held,
+                                        form), x, down, back))
 
+    args = (x, gate, w_in, w_out, down, back)
     with jax.default_matmul_precision("highest"):
         (got, plan), grads = jax.jit(jax.value_and_grad(
-            tiles, argnums=(0, 1, 2, 3), has_aux=True))(x, gate, w_in, w_out)
+            tiles, argnums=tuple(range(6)), has_aux=True))(*args)
         want, wants = jax.jit(jax.value_and_grad(
-            dense, argnums=(0, 1, 2, 3)))(x, gate, w_in, w_out)
+            dense, argnums=tuple(range(6))))(*args)
+    if latent and forced != "none_held":   # the projections are in the path
+        assert np.asarray(grads[4]).any() and np.asarray(grads[5]).any()
     landed = int(plan.counts[first:first + held].sum())
     if per_token is not None:
         assert landed == N * per_token
@@ -237,6 +259,8 @@ def test_dispatch_is_dropless_under_the_worst_imbalance(small_tiles, forced,
     assert int(plan.tile_rows[:live].sum()) == landed
     assert not np.asarray(plan.tile_rows[live:]).any()
     assert plan.tile_rows.shape[0] * 64 == moe.dispatch_rows(N, K, held)
+    assert int(moe.walked_rows(plan.counts[first:first + held], N).sum()) \
+        == live * 64
     rows = np.concatenate([
         np.asarray(plan.token)[f:f + r] for f, r in
         zip(np.asarray(plan.tile_first[:live]),

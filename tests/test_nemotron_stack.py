@@ -1,0 +1,377 @@
+"""The one-part-a-layer stack (`models.hybrid.SensorHybrid` with `none`
+for a layer's mixer or its feed-forward part, a share of the heads, and
+experts in a latent): the model and one compiled job against the
+benchmark's plain reference (loaded by path, as `benchmark/tests` loads
+it), the chip's-share cut of all three kinds of layer (the shares add up
+to the uncut layer, which has grouped B and C and the group norm), the
+accepted configurations' parameter trees, and what a fit says of the
+latent and the tiles.  All at a tiny preset on the CPU."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from iotml.models import hybrid
+from iotml.models.hybrid import HybridConfig, SensorHybrid
+from iotml.models.latent_moe import ExpertLayer
+from iotml.ops import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIGS = os.path.join(ROOT, "benchmark", "configs")
+CONFIG = os.path.join(CONFIGS, "sensorformer-nemotron-3-super-120b-a12b")
+#: width 64; the share held: 4 state heads of 8 in one group, state 8;
+#: 2 query heads of 16 on one key/value head; 16 experts of 24 in a
+#: latent of 32, 5 a token, 4 held, a shared expert of 48; `M E * E M`
+TINY = dict(hidden_size=64, mamba_num_heads=4, mamba_head_dim=8,
+            ssm_state_size=8, n_groups=1, chunk_size=8,
+            num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+            moe_latent_size=32, moe_intermediate_size=24,
+            moe_shared_expert_intermediate_size=48, n_routed_experts=4,
+            num_experts_per_tok=5, num_hidden_layers=5,
+            hybrid_override_pattern="ME*EM")
+
+
+def _load(name, path):
+    spec = importlib.util.spec_from_file_location(name, path + ".py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(path + ".json") as fh:
+        return mod, json.load(fh)
+
+
+def _reference(name, **sizes):
+    mod, cfg = _load(name, CONFIG)
+    cfg.update(TINY)
+    cfg["published"] = dict(cfg["published"], n_routed_experts=16)
+    cfg["job"] = dict(cfg["job"], window=40)
+    cfg.update(sizes)
+    mod.use(cfg)
+    return mod, cfg
+
+
+@pytest.fixture(scope="module")
+def ref():
+    """The configuration's plain reference at the tiny preset."""
+    return _reference("bench_nemotron_reference")
+
+
+def _batch(B=2, T=40, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
+            jnp.ones((B,), jnp.float32))
+
+
+def _close(got, want, rtol=2e-4):
+    """Within `rtol` of the reference's largest entry, leaf by leaf."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(float(jnp.abs(w).max()), 1e-30)
+        assert float(jnp.abs(g - w).max()) <= rtol * scale
+
+
+# --------------------------------------------- the model and the reference
+def test_a_layer_builds_the_norm_of_the_part_it_has(ref):
+    """`M E * E M`: a mixer's layer holds `norm1` and `mixer`, an expert
+    layer `norm2` and `moe` with the two latent projections and non-gated
+    experts of the latent's width — the reference's tree, shape by shape."""
+    mod, cfg = ref
+    model = SensorHybrid(mod.hybrid_config(cfg))
+    shapes = jax.tree.map(jnp.shape, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), _batch()[0])["params"])
+    assert shapes == jax.tree.map(jnp.shape, mod.init_params(3))
+    assert [sorted(shapes[f"layer{i}"]) for i in range(5)] == [
+        ["mixer", "norm1"], ["moe", "norm2"], ["mixer", "norm1"],
+        ["moe", "norm2"], ["mixer", "norm1"]]
+    assert shapes["layer1"]["moe"]["experts_in"] == (4, 32, 24)
+    assert shapes["layer1"]["moe"]["experts_out"] == (4, 24, 32)
+    assert shapes["layer1"]["moe"]["latent_in"]["kernel"] == (64, 32)
+    assert shapes["layer1"]["moe"]["shared_in"]["kernel"] == (64, 48)
+    # the heads held are a share: their width is the file's, not 64 / 2
+    assert shapes["layer2"]["mixer"]["q"]["kernel"] == (64, 32)
+    assert shapes["layer2"]["mixer"]["k"]["kernel"] == (64, 16)
+    with pytest.raises(ValueError, match="one of each a layer"):
+        SensorHybrid(HybridConfig(layer_types=("none",),
+                                  ffn_types=("none",))).init(
+            jax.random.PRNGKey(0), _batch()[0])
+    with pytest.raises(ValueError, match="experts are distinct"):
+        ExpertLayer(HybridConfig(experts=4, experts_held=(0, 4),
+                                 top_k=5)).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, 64)))
+
+
+@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
+def test_model_matches_the_plain_reference(ref, mode):
+    """Loss and every gradient leaf from the same seeded weights: the
+    chunked scan against the stepped recurrence, the tiles in the latent
+    against the dense-masked experts."""
+    from iotml.train.loop import make_loss_fn
+
+    mod, cfg = ref
+    x, y, mask = _batch()
+    params = mod.init_params(3)
+    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
+    loss = make_loss_fn(model, supervised=True)
+    with jax.default_matmul_precision("highest"):
+        (got, aux), grads = jax.jit(jax.value_and_grad(
+            loss, has_aux=True))(params, x, y, mask)
+        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
+            params, x, y, mask)
+    assert float(abs(got - want)) <= 1e-5 * float(want)
+    _close(grads, wants)
+    for i in (1, 3):
+        assert not np.asarray(grads[f"layer{i}"]["moe"]["router_bias"]).any()
+        assert np.asarray(grads[f"layer{i}"]["moe"]["latent_in"]
+                          ["kernel"]).any()
+    assert [int(c.sum()) for c in jax.tree.leaves(aux[2])] == [2 * 40 * 5] * 2
+
+
+def test_two_step_fit_matches_the_reference(ref):
+    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
+    epoch, against the reference's fit written out: losses, updated
+    parameters, both moments — and the expert counts read back with
+    them against the reference's router."""
+    from iotml.data.dataset import Batch
+    from iotml.train.loop import Trainer
+
+    mod, cfg = ref
+    batches = [_batch(seed=s) for s in (1, 2)]
+    params = mod.init_params(5)
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
+                      learning_rate=1e-3)
+    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
+    stacked = [jnp.stack(v) for v in zip(*batches)]
+    try:
+        trainer._ensure_state(batches[0][0])
+        trainer.state = trainer.state.replace(
+            params=jax.tree.map(jnp.array, params))
+        with jax.default_matmul_precision("highest"):
+            history = trainer.fit_compiled(
+                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+                       first_index=0) for x, y, _ in batches], epochs=2)
+            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(params, *stacked)
+            # the first step's assignments, by the reference's router
+            _, first = mod._km._loss_counts(params, *(v[0] for v in stacked))
+    finally:
+        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
+    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
+    adam = trainer.state.opt_state[0]
+    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
+           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
+    _close(adam.mu, mu)
+    _close(adam.nu, nu)
+    layers = history["reports"]["reports"]
+    counts = [np.asarray(jax.tree.leaves(layers[k])[0])
+              for k in ("layer1", "layer3")]
+    assert [c.shape for c in counts] == [(2, 2, 16)] * 2
+    assert all(int(c.sum()) == 2 * 2 * 2 * 40 * 5 for c in counts)
+    assert np.array_equal(np.stack([c[0, 0] for c in counts]), first)
+
+
+# ------------------------------------------------------- the chip's share
+def test_the_shares_add_up_to_the_uncut_layers():
+    """The model at a small size — 2 B/C groups, 2 key/value heads, 8
+    experts — and its cut into 2 x 2 shares: each Mamba group's partial
+    `W_out` product, each head group's partial `W_o` product, each expert
+    range's routed term through `W_back`, with the shared expert, the
+    router and the latent projections counted once, add up to the uncut
+    reference's layer, which has grouped B and C and the group norm."""
+    whole, cfg = _reference(
+        "bench_nemotron_uncut", mamba_num_heads=8, n_groups=2,
+        num_attention_heads=4, num_key_value_heads=2, n_routed_experts=8,
+        hybrid_override_pattern="M*E", num_hidden_layers=3)
+    cfg["published"]["n_routed_experts"] = 8
+    whole.use(cfg)
+    rng = np.random.default_rng(11)
+    u = jnp.asarray(rng.normal(size=(2, 40, 64)), jnp.float32)
+    p = jax.jit(lambda k: whole._init(k))(jax.random.PRNGKey(11))
+    share_cfg = dict(cfg, mamba_num_heads=4, n_groups=1,
+                     num_attention_heads=2, num_key_value_heads=1,
+                     n_routed_experts=4)
+    program = whole.hybrid_config(dict(share_cfg, experts_held={"first": 0}))
+    whole.use(cfg)
+
+    def cut(v, sizes, g, axis=-1):
+        """Group g's part of each run of `sizes` along `axis`."""
+        runs = jnp.split(v, np.cumsum(sizes)[:-1], axis=axis)
+        return jnp.concatenate(
+            [jnp.split(r, 2, axis=axis)[g] for r in runs], axis=axis)
+
+    with jax.default_matmul_precision("highest"):
+        # M: in_proj's columns are [z, x, B_0 B_1, C_0 C_1, dt]
+        m = p["layer0"]["mixer"]
+        want = whole._mamba(m, u)
+        total = jnp.zeros_like(u)
+        for g in (0, 1):
+            share = {
+                "in_proj": {"kernel": cut(m["in_proj"]["kernel"],
+                                          (64, 64, 16, 16, 8), g)},
+                "conv_kernel": cut(m["conv_kernel"], (64, 16, 16), g),
+                "conv_bias": cut(m["conv_bias"], (64, 16, 16), g),
+                "dt_bias": cut(m["dt_bias"], (8,), g),
+                "A_log": cut(m["A_log"], (8,), g), "D": cut(m["D"], (8,), g),
+                "norm": {"scale": cut(m["norm"]["scale"], (64,), g)},
+                "out_proj": {"kernel": cut(m["out_proj"]["kernel"], (64,), g,
+                                           axis=0)}}
+            total = total + hybrid.MambaMixer(program).apply(
+                {"params": share}, u)
+        _close(total, want, rtol=1e-5)
+
+        # *: query heads 2g, 2g+1 read key/value head g
+        a = p["layer1"]["mixer"]
+        want = whole._attention(a, u)
+        total = jnp.zeros_like(u)
+        for g in (0, 1):
+            share = {"q": {"kernel": cut(a["q"]["kernel"], (64,), g)},
+                     "k": {"kernel": cut(a["k"]["kernel"], (32,), g)},
+                     "v": {"kernel": cut(a["v"]["kernel"], (32,), g)},
+                     "o": {"kernel": cut(a["o"]["kernel"], (64,), g, axis=0)}}
+            total = total + hybrid.GroupedAttention(program, "dense").apply(
+                {"params": share}, u)
+        _close(total, want, rtol=1e-5)
+
+        # E: every share routes over all eight, and alike
+        e = p["layer2"]["moe"]
+        want, counts = whole._latent_moe(e, u)
+        shared = whole._relu2(u, e["shared_in"]["kernel"],
+                              e["shared_out"]["kernel"])
+        total = jnp.zeros_like(u)
+        for first in (0, 4):
+            share = dict(e, experts_in=e["experts_in"][first:first + 4],
+                         experts_out=e["experts_out"][first:first + 4])
+            layer = ExpertLayer(whole.hybrid_config(dict(
+                share_cfg, experts_held={"first": first})))
+            whole.use(cfg)
+            out, reports = layer.apply({"params": share}, u,
+                                       mutable=["reports"])
+            assert np.array_equal(
+                reports["reports"]["expert_counts"], counts)
+            total = total + (out - shared)
+        assert int(counts.sum()) == 2 * 40 * 5
+        _close(total + shared, want, rtol=1e-5)
+
+
+# ----------------------------------------- the accepted configurations
+GRANITE_TREE = {
+    "mamba": {"A_log": (64,), "D": (64,), "conv_bias": (4352,),
+              "conv_kernel": (4, 4352), "dt_bias": (64,),
+              "in_proj": {"kernel": (2048, 8512)}, "norm": {"scale": (4096,)},
+              "out_proj": {"kernel": (4096, 2048)}},
+    "attention": {"q": {"kernel": (2048, 2048)}, "k": {"kernel": (2048, 512)},
+                  "v": {"kernel": (2048, 512)}, "o": {"kernel": (2048, 2048)}}}
+KIMI_MIXER = {"q": {"kernel": (2048, 3072)}, "kv_a": {"kernel": (2048, 576)},
+              "kv_norm": {"scale": (512,)}, "kv_b": {"kernel": (512, 4096)},
+              "o": {"kernel": (2048, 2048)}}
+KIMI_MOE = {"router": (2048, 64), "router_bias": (64,),
+            "experts_in": (8, 2048, 2816), "experts_out": (8, 1408, 2048),
+            "shared_in": {"kernel": (2048, 5632)},
+            "shared_out": {"kernel": (2816, 2048)}}
+
+
+@pytest.mark.parametrize("name,parameters", [
+    ("sensorformer-granite-4.0-h-micro", 746_546_130),
+    ("sensorformer-kimi-vl-a3b-instruct", 585_080_146)])
+def test_the_accepted_configurations_trees_are_what_they_were(name,
+                                                              parameters):
+    """Path by path and shape by shape, at the published widths: the
+    layers that have a mixer AND a feed-forward part build what they
+    built before a layer could be one part, a head a share, an expert
+    another form."""
+    mod, cfg = _load("bench_tree_" + name.split("-")[1],
+                     os.path.join(CONFIGS, name))
+    mod.use(cfg)
+    model = SensorHybrid(mod.hybrid_config(cfg))
+    assert model.cfg.head_dim == 0 and model.cfg.moe_latent == 0 \
+        and model.cfg.expert_form == "gated_silu"
+    tree = jax.tree.map(jnp.shape, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 16, 18)))["params"])
+    norm = {"scale": (2048,)}
+    want = {"embed": {"kernel": (18, 2048), "bias": (2048,)},
+            "head": {"kernel": (2048, 18), "bias": (18,)}, "norm_f": norm}
+    if "granite" in name:
+        for i, kind in enumerate(cfg["layer_types"][:10]):
+            want[f"layer{i}"] = {
+                "norm1": norm, "norm2": norm, "mixer": GRANITE_TREE[kind],
+                "mlp_in": {"kernel": (2048, 16384)},
+                "mlp_out": {"kernel": (8192, 2048)}}
+    else:
+        for i in range(6):
+            want[f"layer{i}"] = {"norm1": norm, "norm2": norm,
+                                 "mixer": KIMI_MIXER}
+            want[f"layer{i}"].update(
+                {"moe": KIMI_MOE} if i else
+                {"mlp_in": {"kernel": (2048, 22528)},
+                 "mlp_out": {"kernel": (11264, 2048)}})
+    assert tree == want
+    assert sum(int(np.prod(s)) for s in jax.tree.leaves(
+        tree, is_leaf=lambda s: isinstance(s, tuple))) == parameters
+    assert tree == jax.tree.map(jnp.shape, jax.eval_shape(
+        lambda: mod.init_params(0)))
+
+
+# ------------------------------------------------------- what engaged
+def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
+    """The trace-time counters after a fit — the layers by kind, the
+    latent's width — the `latent_proj` scope in the fit's program, and
+    the data read back with the losses at the fit's one sync: the rows of
+    the live tiles by whether they hold an assignment."""
+    from iotml.data.dataset import Batch
+    from iotml.obs.metrics import default_registry
+    from iotml.train import loop
+    from iotml.train.loop import Trainer
+
+    mod, cfg = ref
+    monkeypatch.setattr(moe, "TILE", 16)
+    jax.clear_caches()
+    before = default_registry.collect()
+    gets = []
+    device_get = jax.device_get
+    monkeypatch.setattr(loop.jax, "device_get",
+                        lambda t: gets.append(1) or device_get(t))
+    x, y, _ = _batch()
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
+                      learning_rate=1e-5)
+    history = trainer.fit_compiled(
+        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+               first_index=0)] * 3, epochs=2)
+    got = default_registry.collect()
+    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
+    assert len(gets) == 1          # the reports came back with the losses
+    assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
+            ("mamba", "attention", "mla", "dense_ffn", "moe_ffn")] \
+        == [2, 1, 0, 0, 2]
+    assert got["iotml_remat_blocks"] == 5
+    assert got["iotml_moe_latent_dim"] == 32
+    assert got['iotml_moe_experts{kind="held"}'] == 4
+    assert got['iotml_moe_experts{kind="routed_over"}'] == 16
+    assert got["iotml_moe_top_k"] == 5
+    assert got["iotml_moe_dispatch_rows"] == moe.dispatch_rows(80, 5, 4)
+    moved = lambda name, kind: got[f'{name}{{kind="{kind}"}}'] \
+        - before.get(f'{name}{{kind="{kind}"}}', 0.0)  # noqa: E731
+    steps = np.concatenate([np.asarray(c).reshape(-1, 16)[:, :4]
+                            for c in jax.tree.leaves(history["reports"])])
+    live = moved("iotml_moe_tile_rows_total", "live")
+    assert live == steps.sum() == moved("iotml_moe_assignments_total", "held")
+    assert live > 0
+    # every expert held walks whole tiles of 16 rows, a step and layer
+    assert moved("iotml_moe_tile_rows_total", "padding") \
+        == (-(-steps // 16) * 16).sum() - live > 0
+    # the scope rides the program's operations
+    model = SensorHybrid(mod.hybrid_config(cfg))
+    text = jax.jit(lambda p: model.apply(
+        {"params": p}, x, mutable=["reports"])[0]).lower(
+            mod.init_params(1)).as_text(debug_info=True)
+    for scope in ("latent_proj", "router", "experts", "shared", "ssm_proj",
+                  "ssd", "conv", "attn"):
+        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+    # a layer that acts at full width says 0
+    jax.clear_caches()
+    ExpertLayer(HybridConfig()).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8, 64)))
+    assert default_registry.collect()["iotml_moe_latent_dim"] == 0
