@@ -18,7 +18,18 @@ out, where a language model has its vocabulary.
 
 Every block is recomputed in the backward pass (`nn.remat`): a window of
 thousands of positions keeps one [T, d_model] input a block instead of
-each block's projections, decay tiles and MLP activations.
+each block's projections, decay tiles and MLP activations.  That is the
+right trade for what is large and cheap to make again, and the wrong
+one for what is small and dear: what a kernel or the router made.  So
+the recomputation keeps what goes by one of the names in `KEPT` — the
+flash kernel's `out` and log-sum-exp, latent attention's rotated q and
+assembled k, the router's selection, its selected scores and the
+dispatch's plan (integers; a top-k and a sort), the routed sum ahead of
+a latent's back-projection (its weight gradient reads it) — tens of MiB
+a layer against a second run of the forward kernel, of rotary's pads
+and copies, of the `highest` product and of the tiles' walk.  A name
+exists where the part that makes it exists, so the one policy serves
+every stack, and outside a recomputation a name is the identity.
 
 The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
 `attention`, or `mla`, the latent attention of `models.latent_moe`) and
@@ -53,12 +64,18 @@ import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
 from ..ops.attention import attention_reference, flash_attention
+from ..ops.moe import plan_kept_bytes
 from ..ops.ssd import causal_conv1d_silu, ssd_scan
 from . import latent_moe
 from .latent_moe import ExpertLayer, LatentAttention, gated_mlp
 from .latent_moe import dense as _dense
 from .latent_moe import normal as _normal
 
+#: what a block's recomputation keeps, by the names the parts give:
+#: `ops.attention._flash_fwd_rule`, `models.latent_moe.LatentAttention`,
+#: `ops.moe.route` and `dispatch_plan`, `models.latent_moe.ExpertLayer`
+KEPT = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
+        "route_picked", "dispatch_plan", "routed_sum")
 KINDS = ("mamba", "attention", "mla")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
 NONE = "none"   # a layer without that part
@@ -237,6 +254,26 @@ class SensorHybrid(nn.Module):
     def record_reports(self, reports) -> None:
         latent_moe.record_reports(self.cfg, reports)
 
+    def _kept_bytes(self, x) -> dict:
+        """The bytes a step of x [B, T, features] the blocks keep by
+        name (`KEPT`), by the part that makes them."""
+        m = self.cfg
+        tokens, size = x.shape[0] * x.shape[1], x.dtype.itemsize
+        flash = {"attention": m.attn_head_dim(), "mla": m.v_dim} \
+            if self.attn_mode != "dense" else {}
+        expert_layers = m.ffn_kinds().count("moe_ffn")
+        return {
+            # out [B, T, H, Dv] and a float32 lse [B, H, T]
+            "flash": sum(tokens * m.num_heads * (flash[kind] * size + 4)
+                         for kind in m.layer_types if kind in flash),
+            # latent attention's q and k, [B, T, H, nope + rope] each
+            "latent_qk": m.layer_types.count("mla") * 2 * tokens
+            * m.num_heads * (m.nope_dim + m.rope_dim) * size,
+            "router": expert_layers * plan_kept_bytes(
+                tokens, m.top_k, m.experts_held[1], m.experts),
+            "experts": expert_layers * tokens * m.moe_latent * size,
+        }
+
     @nn.compact
     def __call__(self, x):
         m = self.cfg
@@ -256,9 +293,12 @@ class SensorHybrid(nn.Module):
         for kind in FFN_KINDS:
             obs_metrics.model_layers.set(ffns.count(kind), kind=kind)
         obs_metrics.remat_blocks.set(len(m.layer_types))
+        for kind, kept in self._kept_bytes(x).items():
+            obs_metrics.remat_kept_bytes.set(kept, kind=kind)
         h = m.embedding_multiplier * nn.Dense(
             m.d_model, kernel_init=_normal, name="embed")(x)
-        block = nn.remat(HybridBlock)
+        block = nn.remat(HybridBlock, policy=jax.checkpoint_policies
+                         .save_only_these_names(*KEPT))
         for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
             h = block(kind, m, self.attn_mode, ffn, name=f"layer{i}")(h)
         h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)

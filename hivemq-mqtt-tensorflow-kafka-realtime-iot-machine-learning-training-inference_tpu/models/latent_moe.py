@@ -40,6 +40,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs import metrics as obs_metrics
 from ..ops import moe
@@ -88,6 +89,9 @@ class LatentAttention(nn.Module):
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, H, rope))],
                 axis=-1)
+        # kept by name from a recomputation: making them again is the
+        # rotary's pads and slices and the copy that assembles k
+        q, k = checkpoint_name(q, "mla_q"), checkpoint_name(k, "mla_k")
         v = kv[..., nope:]
         scale = 1.0 / math.sqrt(nope + rope)
         if self.attn_mode == "dense":
@@ -123,6 +127,14 @@ class ExpertLayer(nn.Module):
         obs_metrics.moe_latent_dim.set(m.moe_latent)
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
+        # the shared expert first: with it after the routed path, XLA's
+        # memory-space assignment moved another of its weights into VMEM
+        # once the recomputation kept the router's results, and its
+        # fusions ran 5.8 ms a step slower in `ns-train-backlog`
+        # (PERF.md §6, PR 33: compile the whole fit and read which
+        # operands of `shared` carry `S(1)` before moving this)
+        with jax.named_scope("shared"):
+            shared = gated_mlp(u, m.shared_dim, d, "shared", m.expert_form)
         x = u.reshape(B * T, d)
         with jax.named_scope("router"):
             experts, weights = moe.route(
@@ -142,10 +154,13 @@ class ExpertLayer(nn.Module):
             self.param("experts_out", normal, (held, m.expert_dim, width)),
             m.expert_form)
         if m.moe_latent:
+            # `latent_out`'s weight gradient reads the routed sum: kept
+            # by name, a recomputed forward does not walk the tiles for
+            # it (at the stream's width nothing reads it, and XLA drops
+            # the recomputed walk: PERF.md §6, PR 33)
+            routed = checkpoint_name(routed, "routed_sum")
             with jax.named_scope("latent_proj"):
                 routed = dense(d, "latent_out")(routed)
-        with jax.named_scope("shared"):
-            shared = gated_mlp(u, m.shared_dim, d, "shared", m.expert_form)
         return routed.reshape(B, T, d) + shared
 
 

@@ -539,6 +539,13 @@ moe_expert_load = default_registry.gauge(
 remat_blocks = default_registry.gauge(
     "iotml_remat_blocks",
     "blocks of the last traced model recomputed in the backward pass")
+remat_kept_bytes = default_registry.gauge(
+    "iotml_remat_kept_bytes",
+    "bytes a step the last traced model's recomputed blocks keep from "
+    "the forward pass by name, by kind (flash: the kernel's out and lse "
+    "| latent_qk: latent attention's rotated q and assembled k | "
+    "router: the selection, the selected scores and the plan | "
+    "experts: the routed sum in a latent)")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "
@@ -622,6 +629,7 @@ DECLARED_METRIC_LABELS = {
     "online_drifts": ("detector",),
     "prefetch_occupancy": ("loop",),
     "quorum_hwm_lag": ("partition", "topic"),
+    "remat_kept_bytes": ("kind",),
     "replica_lag": ("topic",),
     "rest_request_seconds": ("route",),
     "rest_requests": ("route", "code"),

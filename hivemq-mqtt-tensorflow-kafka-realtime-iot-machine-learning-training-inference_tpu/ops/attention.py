@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
@@ -830,7 +831,14 @@ def _flash(q, k, v, causal, block_q, block_k, interpret, scale, repeated):
 
 
 def _flash_fwd_rule(q, k, v, *static):
+    """The kernel's two results go by name: a caller that recomputes its
+    forward in the backward pass (`jax.checkpoint`) can keep them with a
+    policy of names and spare the kernel's second run — q, k and v it
+    makes again with its projections.  Outside any recomputation a name
+    is the identity."""
     out, lse = _flash_forward(q, k, v, *static)
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -34,6 +34,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 # --------------------------------------------------------------- rotary
@@ -56,24 +57,66 @@ def rotary(x, theta: float):
 
 
 # --------------------------------------------------------------- router
+def _mask(experts, n_experts: int):
+    # the selection as [N, top_k, experts]: one masked sum takes the
+    # selected entries out of [N, experts] or lays [N, top_k] out over
+    # them (a gather or scatter of [N, top_k] scalars is the slower way
+    # on a chip)
+    return experts[..., None] == jnp.arange(n_experts)
+
+
+def _route_weights(picked, scale: float):
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
+
+
+def _highest(a, b):
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def route(u, gate, bias, top_k: int, scale: float):
     """u [N, d] → (experts [N, top_k] int32, weights [N, top_k] float32).
 
     Scores are sigmoids of a float32 product at `highest` (top-k is
     discontinuous: a rounded score picks another expert).  `bias` moves
     the SELECTION only — the weights are the selected scores without
-    it, over their sum, times `scale` — and no gradient reaches it."""
-    s = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), gate.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
-    # the selected scores, by a masked sum (a gather of [N, top_k]
-    # scalars is the slower way on a chip)
-    picked = jnp.sum(jnp.where(
-        experts[..., None] == jnp.arange(s.shape[-1]), s[:, None, :], 0.0),
-        axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
-    return experts, weights * scale
+    it, over their sum, times `scale` — and no gradient reaches it.
+
+    The backward pass is written out, from the selection and the
+    selected scores alone (`route_experts`, `route_picked` by name): a
+    sigmoid's derivative at a selected entry is `picked · (1 − picked)`
+    and no other entry of [N, experts] has a gradient, so a caller that
+    recomputes its forward (`jax.checkpoint` with a policy of names)
+    runs the product, the sigmoid and top-k once.  The two products of
+    the backward are float32 at `highest`, as the forward's is."""
+    return _route_fwd(u, gate, bias, top_k, scale)[0]
+
+
+def _route_fwd(u, gate, bias, top_k, scale):
+    s = jax.nn.sigmoid(_highest(u.astype(jnp.float32),
+                                gate.astype(jnp.float32)))
+    _, experts = jax.lax.top_k(s + bias, top_k)
+    experts = checkpoint_name(experts, "route_experts")
+    picked = checkpoint_name(
+        jnp.sum(jnp.where(_mask(experts, s.shape[-1]), s[:, None, :], 0.0),
+                axis=-1), "route_picked")
+    return (experts, _route_weights(picked, scale)), \
+        (u, gate, bias, experts, picked)
+
+
+def _route_bwd(top_k, scale, res, cotangents):
+    u, gate, bias, experts, picked = res
+    _, pull = jax.vjp(lambda p: _route_weights(p, scale), picked)
+    (d_picked,) = pull(cotangents[1])
+    d_logits = jnp.sum(jnp.where(
+        _mask(experts, gate.shape[-1]),
+        (d_picked * picked * (1.0 - picked))[..., None], 0.0), axis=1)
+    return (_highest(d_logits, gate.astype(jnp.float32).T).astype(u.dtype),
+            _highest(u.astype(jnp.float32).T, d_logits).astype(gate.dtype),
+            jnp.zeros_like(bias))
+
+
+route.defvjp(_route_fwd, _route_bwd)
 
 
 # ------------------------------------------------------------- dispatch
@@ -120,18 +163,26 @@ def dispatch_plan(experts, weights, first: int, held: int,
     """Sort the N·K assignments by expert (those held elsewhere last)
     and cut the groups of the experts held into tiles, each group from
     a tile of its own.  A group is one run of the sorted assignments, so
-    a tile is a slice of them: nothing is gathered row by row."""
+    a tile is a slice of them: nothing is gathered row by row.
+
+    The sorted order, the tiles' fields and `counts` go by one name,
+    `dispatch_plan`: integers a recomputed forward would sort and count
+    a second time, and a policy of names keeps (`weight` it gathers
+    again, by the order kept)."""
     N, K = experts.shape
     tile = _tile(N)
     n_tiles = dispatch_rows(N, K, held) // tile
+    keep = functools.partial(checkpoint_name, name="dispatch_plan")
     flat = experts.reshape(-1)
     local = flat - first
     here = (local >= 0) & (local < held)
     _, order = jax.lax.sort(
         (jnp.where(here, local, held), jnp.arange(N * K, dtype=jnp.int32)),
         num_keys=1, is_stable=True)
-    counts = jnp.sum(flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype),
-                     axis=0, dtype=jnp.int32)
+    order = keep(order)
+    counts = keep(jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype),
+        axis=0, dtype=jnp.int32))
     sizes = jax.lax.dynamic_slice_in_dim(counts, first, held)
     tiles = -(-sizes // tile)                       # tiles a group takes
     tile_end = jnp.cumsum(tiles)
@@ -142,10 +193,19 @@ def dispatch_plan(experts, weights, first: int, held: int,
         [v, jnp.full((tile,), fill, v.dtype)])
     return Dispatch(
         pad(order // K, N), pad(weights.reshape(-1)[order], 0),
-        e.astype(jnp.int32),
-        ((jnp.cumsum(sizes) - sizes)[e] + within).astype(jnp.int32),
-        jnp.clip(sizes[e] - within, 0, tile).astype(jnp.int32),
-        tile_end[-1], counts)
+        keep(e.astype(jnp.int32)),
+        keep(((jnp.cumsum(sizes) - sizes)[e] + within).astype(jnp.int32)),
+        keep(jnp.clip(sizes[e] - within, 0, tile).astype(jnp.int32)),
+        keep(tile_end[-1]), counts)
+
+
+def plan_kept_bytes(tokens: int, top_k: int, held: int,
+                    n_experts: int) -> int:
+    """What a layer's router and plan keep by name, a step: the
+    selection and the selected scores, the sorted order, three fields a
+    tile, the live tiles' count and `counts` — four bytes each."""
+    tiles = dispatch_rows(tokens, top_k, held) // _tile(tokens)
+    return 4 * (3 * tokens * top_k + 3 * tiles + 1 + n_experts)
 
 
 #: an expert's form: the activation between its two products, and how

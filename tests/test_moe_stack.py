@@ -364,6 +364,13 @@ def test_a_tiny_fit_says_what_engaged(ref):
             ("mla", "attention", "mamba", "dense_ffn", "moe_ffn")] \
         == [3, 0, 0, 1, 2]
     assert got["iotml_remat_blocks"] == 3
+    # what the blocks' recomputation keeps, a step: two layers' selection
+    # and plan, three layers' q and k [2, 40, 4, 16 + 8]; no kernel ran
+    # (`dense` attention) and no latent is here
+    assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
+            ("router", "latent_qk", "flash", "experts")] \
+        == [2 * moe.plan_kept_bytes(80, 3, 4, 16),
+            3 * 2 * 2 * 40 * 4 * 24 * 4, 0, 0]
     assert got["iotml_latent_assembled_operands"] == 1   # k, by the mixer
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
@@ -379,6 +386,12 @@ def test_a_tiny_fit_says_what_engaged(ref):
     assert moved["held"] == counts[:4].sum() > 0
     assert got["iotml_moe_expert_load_max_over_mean"] == pytest.approx(
         counts[:4].max() / counts[:4].mean())
+    # with the kernels, three layers' out [2, 40, 4, 16] and lse [2, 4, 40]
+    jax.eval_shape(SensorHybrid(mod.hybrid_config(cfg),
+                                attn_mode="flash_interpret").init,
+                   jax.random.PRNGKey(0), x)
+    assert default_registry.collect()['iotml_remat_kept_bytes{kind="flash"}'] \
+        == 3 * (2 * 40 * 4 * 16 * 4 + 2 * 4 * 40 * 4)
 
 
 def test_a_model_that_reports_nothing_fits_the_program_it_had():

@@ -153,29 +153,14 @@ def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
             said['iotml_conv_operand_copies{kernel="bwd"}']) == copies
 
 
-def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
-    """What `iotml_conv_operand_copies` cannot see: the kernels work on
-    [B, channels, T] because that is how XLA lays the mixer's tensors
-    out in the compiled fit, so the `swapaxes` around the calls are
-    bitcasts.  A transposing copy at an edge has a channel-minor array
-    on one side, and on [B, T, channels] rows the cell's fit ran 5%
-    slower than with no kernel at all (PERF.md §6, PR 29): so the
-    scanned fit of one Mamba block at `gh-train-backlog`'s widths, Adam
-    and all, compiled for the described v5e, holds no array of the
-    stream's size — `in_proj`'s [1, 4096, 8512] product, the
-    [1, 4096, 4352] the convolution reads and writes, their cotangents
-    — with the channels fastest."""
+def _compiled_fit(v5e, model, T: int) -> str:
+    """The scanned fit of `model` — four steps of one window of T
+    positions, two epochs, Adam and all — compiled for the described
+    v5e from shapes alone: its text."""
     import optax
 
-    from iotml.models.hybrid import HybridConfig, SensorHybrid
     from iotml.train.loop import TrainState, make_scanned_fit
 
-    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
-    T = 4096
-    model = SensorHybrid(HybridConfig(
-        d_model=2048, layer_types=("mamba",), num_heads=32, num_kv_heads=8,
-        mlp_dim=8192, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
-        conv_width=4, chunk=256))
     tx = optax.adam(1e-5)
 
     def fresh(rng, x):
@@ -193,8 +178,31 @@ def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
     xs, ys, masks = described(tuple(
         jax.ShapeDtypeStruct(s, jnp.float32)
         for s in ((4, 1, T, 18), (4, 1, 1, 18), (4, 1))))
-    text = make_scanned_fit(model, tx, supervised=True).lower(
+    return make_scanned_fit(model, tx, supervised=True).lower(
         state, xs, ys, masks, epochs=2).compile().as_text()
+
+
+def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
+    """What `iotml_conv_operand_copies` cannot see: the kernels work on
+    [B, channels, T] because that is how XLA lays the mixer's tensors
+    out in the compiled fit, so the `swapaxes` around the calls are
+    bitcasts.  A transposing copy at an edge has a channel-minor array
+    on one side, and on [B, T, channels] rows the cell's fit ran 5%
+    slower than with no kernel at all (PERF.md §6, PR 29): so the
+    scanned fit of one Mamba block at `gh-train-backlog`'s widths, Adam
+    and all, compiled for the described v5e, holds no array of the
+    stream's size — `in_proj`'s [1, 4096, 8512] product, the
+    [1, 4096, 4352] the convolution reads and writes, their cotangents
+    — with the channels fastest."""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    T = 4096
+    model = SensorHybrid(HybridConfig(
+        d_model=2048, layer_types=("mamba",), num_heads=32, num_kv_heads=8,
+        mlp_dim=8192, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+        conv_width=4, chunk=256))
+    text = _compiled_fit(v5e, model, T)
     assert "iotml_conv_fwd" in text and "iotml_conv_bwd" in text
     # minor-to-major {1,2,0} of [1, T, C] and {2,1,0} of [1, C, T] are
     # the same bytes: time fastest
@@ -202,3 +210,32 @@ def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
                           r"\]\{[\d,]+", text))
     assert laid and laid <= {"f32[1,4096,4352]{1,2,0", "f32[1,4352,4096]{2,1,0",
                              "f32[1,4096,8512]{1,2,0", "f32[1,8512,4096]{2,1,0"}
+
+
+def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):
+    """What `tests/test_remat_policy.py` counts in the gradient's jaxpr,
+    held on the compiled program: the scanned fit of one block of latent
+    attention and experts at `km-train-backlog`'s widths, Adam and all,
+    compiled for the described v5e, calls `iotml_flash_fwd` once — the
+    block's recomputation keeps the kernel's `out` and log-sum-exp
+    (`models.hybrid.KEPT`) and reads them where it ran the kernel a
+    second time — and sorts the layer's assignments twice, top-k's sort
+    and the plan's, where it sorted them four times."""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    T = 8192
+    model = SensorHybrid(HybridConfig(
+        d_model=2048, layer_types=("mla",), ffn_types=("moe_ffn",),
+        num_heads=16, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        rope_theta=800000.0, experts=64, experts_held=(0, 8), top_k=6,
+        expert_dim=1408, shared_dim=2816, routed_scale=2.446,
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        logits_scaling=1.0), attn_mode="flash")
+    lines = _compiled_fit(v5e, model, T).splitlines()
+    calls = [line for line in lines if " custom-call(" in line]
+    for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
+                   "iotml_flash_bwd_dq"):
+        assert sum(kernel in line for line in calls) == 1, kernel
+    assert sum(re.search(r" = .* sort\(", line) is not None
+               for line in lines) == 2

@@ -347,6 +347,12 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
             ("mamba", "attention", "mla", "dense_ffn", "moe_ffn")] \
         == [2, 1, 0, 0, 2]
     assert got["iotml_remat_blocks"] == 5
+    # what the blocks' recomputation keeps, a step: two layers' selection
+    # and plan and their routed sums [80, 32]; `dense` attention ran no
+    # kernel, and no latent attention is here
+    assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
+            ("router", "experts", "flash", "latent_qk")] \
+        == [2 * moe.plan_kept_bytes(80, 5, 4, 16), 2 * 80 * 32 * 4, 0, 0]
     assert got["iotml_moe_latent_dim"] == 32
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
