@@ -30,4 +30,10 @@ The package directory on disk is
 
 __version__ = "0.1.0"
 
-from . import core  # noqa: F401
+# first of all, before anything heavy is imported: from here on an
+# import of 0.1 s or more is an `iotml.start.import` span (obs/tracing.py)
+from .obs import tracing as _tracing
+
+_tracing.time_imports()
+
+from . import core  # noqa: F401,E402

@@ -179,6 +179,15 @@ def run_streaming_app(argv, *, prog: str, usage: str, make_model: Callable,
                   "trained, nothing stored")
             return 0
         print(f"Training complete, final loss {history['loss'][-1]:.6f}")
+        # restart-per-job: every job is a start, so say where its seconds
+        # went (the spans of obs/tracing.py's `start` loop beside JAX's
+        # own compile counters) — what an operator reads after a deploy
+        from ..obs import tracing
+        from ..utils.device import compile_report
+
+        started = tracing.start_report()
+        if started:
+            print(tracing.start_line(started, compile_report()))
         # unique dir: concurrent jobs on one host must not trample each other
         ckpt_dir = tempfile.mkdtemp(prefix=f"iotml_{prog}_ckpt_")
         if model_file.endswith(".h5"):

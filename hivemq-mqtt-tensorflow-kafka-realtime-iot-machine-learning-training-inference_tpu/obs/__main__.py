@@ -25,7 +25,11 @@ crossed the wire through N processes and ``--show-trace`` prints that
 journey (stages, offset ranges, which process ran what).  Where the
 log holds the loops' phase spans (`tracing.phase`, written whenever a
 path is set) it also prints self time per phase — phases nest, so their
-totals must not be summed — and the slowest round with its phases.
+totals must not be summed — the slowest round with its phases, and each
+process's start: the seconds from its first import to the end of its
+first fit by `iotml.start.*` span (import by module, backend, state
+init) and that fit's dispatch and run, as `cli/_app.py` prints them at
+readiness.
 
 ``fleet`` is the metrics federation collector (ISSUE 13): scrape every
 endpoint in the manifest (processes auto-join it via
@@ -125,7 +129,8 @@ def load_spans_traces(path: str, phases: Dict[str, list] = None):
                 phases.setdefault(proc, []).append(PhaseSpan(
                     doc["name"], start, start + int(doc["dur_us"]),
                     doc.get("parent"), doc.get("round"),
-                    doc.get("thread", "?"), int(doc["id"])))
+                    doc.get("thread", "?"), int(doc["id"]),
+                    doc.get("note")))
     return stages, e2e, traces
 
 
@@ -133,7 +138,7 @@ def summarize_phases(by_proc: Dict[str, list]) -> dict:
     """Per phase name: count, total and SELF time (its duration less
     its children's cover — phases nest, so totals must not be summed),
     and per root name the slowest round with its phases."""
-    from .tracing import self_seconds
+    from .tracing import self_seconds, start_report
 
     rows: Dict[str, dict] = {}
     slowest: Dict[str, tuple] = {}
@@ -166,11 +171,19 @@ def summarize_phases(by_proc: Dict[str, list]) -> dict:
                 kids.get(s.id, ()), key=lambda k: -k.start)]
         rounds.append({"root": name, "round": root.round, "proc": proc,
                        "ms": ms, "phases": tree})
+    # each process's start (tracing's `start` loop beside its first fit):
+    # the split `cli/_app.py` prints at readiness, here without JAX's
+    # compile counters, which a span log does not carry
+    starts = {proc: start_report(spans, unit=1e-6)
+              for proc, spans in sorted(by_proc.items())}
     return {"phases": sorted(rows.values(), key=lambda r: -r["self_ms"]),
-            "slowest_rounds": rounds}
+            "slowest_rounds": rounds,
+            "starts": {proc: r for proc, r in starts.items() if r}}
 
 
 def print_phase_table(summary: dict) -> None:
+    from .tracing import start_line
+
     hdr = f"{'phase':<30} {'count':>8} {'total_ms':>11} {'self_ms':>11} " \
           f"{'max_ms':>10}"
     print("\nphase spans (self = duration less the children's cover):")
@@ -185,6 +198,8 @@ def print_phase_table(summary: dict) -> None:
         for ph in rd["phases"]:
             print(f"  {'  ' * ph['depth']}{ph['phase']:<{30 - 2 * ph['depth']}}"
                   f" {ph['ms']:>10.3f} ms  (self {ph['self_ms']:.3f})")
+    for proc, report in summary["starts"].items():
+        print(f"\n[{proc}] {start_line(report)}")
 
 
 def best_cross_process_trace(traces: Dict[str, dict]):

@@ -422,7 +422,9 @@ consumer_lag_records = default_registry.gauge(
 # item 3 (multi-chip training) starts from.
 step_seconds = default_registry.histogram(
     "iotml_step_seconds",
-    "hot-loop wall time by loop (train|score|online|stream) and phase; "
+    "wall time by loop (train|score|online|stream, and start: a "
+    "process's way to its first fit — import, backend, engine, "
+    "state_init, once each or once a slow import) and phase; "
     "phases NEST, so never sum the family: train fit > host_pipeline "
     "(> fetch, decode), stack, device_compute (> transfer, dispatch, "
     "sync); train round > fit, publish, checkpoint, commit; score "
@@ -443,7 +445,8 @@ compile_seconds = default_registry.histogram(
     buckets=(0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 20.0, 60.0, 180.0))
 compile_cache = default_registry.counter(
     "iotml_compile_cache_total",
-    "persistent compile cache lookups by result (hit | miss)")
+    "persistent compile cache lookups by result (hit | miss) and "
+    "program")
 # the flash kernels' step geometry (ops/attention.py `flash_geometry`),
 # set at trace time by each pallas_call site: what the last compiled
 # call of each kernel (fwd | bwd_dkv | bwd_dq) engaged.
@@ -601,7 +604,7 @@ DECLARED_METRIC_LABELS = {
     "checkpoint_seconds": ("phase",),
     "cluster_shard_epoch": ("shard",),
     "cluster_shard_failovers": ("shard",),
-    "compile_cache": ("result",),
+    "compile_cache": ("program", "result"),
     "compile_seconds": ("program", "stage"),
     "consumer_autoresets": ("topic",),
     "conv_grid_steps": ("kernel",),

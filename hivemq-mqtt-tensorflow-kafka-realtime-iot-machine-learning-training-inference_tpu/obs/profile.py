@@ -16,7 +16,14 @@ Usage:
 
 The program's own phases (`iotml.train.fit`, `.host_pipeline`, `.fetch`,
 `.decode`, `.dispatch`, ... — `obs.tracing.phase`) are already spans of
-that capture's host plane; `annotate` adds a caller's own.
+that capture's host plane, and so are a start's where the capture is
+open that early: `iotml.start.backend`, `.state_init`, `.engine` and,
+one per import of 0.1 s or more, `.import` (its module in the
+annotation's `note`); `annotate` adds a caller's own.
+
+`jax` is imported where a capture starts, not with this module: the
+`obs` package stays importable ahead of everything heavy, which is what
+lets `tracing.time_imports()` see the heavy imports.
 """
 
 from __future__ import annotations
@@ -25,14 +32,14 @@ import contextlib
 import os
 from typing import Iterator, Optional
 
-import jax
-
 from . import tracing
 
 
 @contextlib.contextmanager
 def trace(logdir: str = "./logs") -> Iterator[None]:
     """Capture a profiler trace window into `logdir` (TensorBoard layout)."""
+    import jax
+
     os.makedirs(logdir, exist_ok=True)
     jax.profiler.start_trace(logdir)
     try:

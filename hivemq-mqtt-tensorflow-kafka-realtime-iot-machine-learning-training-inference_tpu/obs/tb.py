@@ -3,9 +3,9 @@
 The reference wires TensorBoard callbacks into fit and commits the resulting
 event files (SURVEY §5 'tracing/profiling').  Here: a `ScalarLogger` that
 writes TensorBoard event files via tensorboardX when present (it is in this
-image) and always mirrors to a plain JSONL file (grep-able, no reader dep),
-plus a `JaxProfiler` wrapper over `jax.profiler` trace sessions — the
-XLA-level equivalent of the reference's committed TF profiler traces.
+image) and always mirrors to a plain JSONL file (grep-able, no reader dep).
+The XLA-level equivalent of the reference's committed TF profiler traces
+is `obs.profile.trace`.
 """
 
 from __future__ import annotations
@@ -52,23 +52,3 @@ class ScalarLogger:
         if self._tb is not None:
             self._tb.close()
 
-
-class JaxProfiler:
-    """jax.profiler trace session → TensorBoard-loadable trace directory.
-
-    Thin class wrapper over `obs.profile.trace` (which also offers
-    `annotate` spans and `maybe_trace` for env-driven capture)."""
-
-    def __init__(self, log_dir: str):
-        self.log_dir = log_dir
-        self._cm = None
-
-    def __enter__(self):
-        from .profile import trace
-
-        self._cm = trace(self.log_dir)
-        self._cm.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._cm.__exit__(*exc)

@@ -37,6 +37,14 @@ where `jax` is already imported: this module never imports it.  Phase
 spans are unconditional and round-granular (per round, per consumer
 call; never per record, never inside a compiled step).
 
+The loop ``start`` is a process's way to its first fit: ``backend``
+(`utils.device.claim_device`), ``state_init`` (`Trainer._ensure_state`),
+``engine`` (the native engine's build, where it builds) and ``import`` —
+one span per OUTERMOST import of 0.1 s or more, its module in the
+span's `note`, timed by a finder `time_imports()` puts ahead of
+``sys.meta_path`` (the package's ``__init__`` calls it first of all).
+`start_report()` lays them beside the first ``iotml.train.fit``.
+
 The record level is off by default, zero-ish cost: every instrumentation
 site guards on the module flag (`tracing.ENABLED`, which gates the
 record-level contexts only) and allocates nothing when it is False.
@@ -104,6 +112,10 @@ _THREADS_BOUND = 256
 #: loop label of a phase opened with no loop and no parent (a batcher
 #: iterated outside any loop's phase)
 _NO_LOOP = "stream"
+
+#: an outermost import shorter than this makes no span: a start imports
+#: a few thousand modules, and the ring holds 4,096 spans a thread
+IMPORT_FLOOR_S = 0.1
 
 #: the module's one wall-clock anchor: a phase span carries monotonic
 #: times, and (monotonic - anchor) + wall anchor lays a span log over a
@@ -397,7 +409,10 @@ class PhaseSpan(NamedTuple):
     """One phase of one round of a loop.  `start`/`end` are monotonic
     seconds (`wall_ns()` maps them onto the wall clock through the
     module's anchor); `parent` is the enclosing phase's `id` on this
-    thread, None for a root; ids count up per process."""
+    thread, None for a root; ids count up per process.  `note` says
+    which one of a phase's kind this was where the name cannot (an
+    import's module): it rides the ring and the span log and is no
+    label of the histogram, whose label set stays closed."""
 
     name: str
     start: float
@@ -406,6 +421,7 @@ class PhaseSpan(NamedTuple):
     round: Optional[int]
     thread: str
     id: int
+    note: Optional[str] = None
 
     @property
     def seconds(self) -> float:
@@ -441,14 +457,19 @@ class phase:
     `loop` and `round` are inherited from the enclosing phase of this
     thread where None.  Unconditional, and round-granular by contract:
     open one per round or per consumer call, never per record and never
-    inside a jitted function."""
+    inside a jitted function.  `note` names the instance (`PhaseSpan`).
+    A phase that ends before `floor` seconds leaves nothing behind, no
+    span and no observation: for work that is worth a span only where
+    it turns out long (an import, a build that found nothing to do)."""
 
-    __slots__ = ("loop", "phase", "round", "id", "name", "_buf",
-                 "_parent", "_note", "_t0")
+    __slots__ = ("loop", "phase", "round", "note", "floor", "id", "name",
+                 "_buf", "_parent", "_note", "_t0")
 
     def __init__(self, loop: Optional[str], phase: str,
-                 round: Optional[int] = None):
+                 round: Optional[int] = None, note: Optional[str] = None,
+                 floor: float = 0.0):
         self.loop, self.phase, self.round = loop, phase, round
+        self.note, self.floor = note, floor
 
     def __enter__(self) -> "phase":
         buf = self._buf = _collector.buffer()
@@ -461,8 +482,10 @@ class phase:
         self.id = next(_phase_ids)
         buf.stack.append(self)
         name = self.name = f"iotml.{self.loop}.{self.phase}"
-        self._note = annotation(name) if self.round is None \
-            else annotation(name, round=self.round)
+        kw = {} if self.round is None else {"round": self.round}
+        if self.note is not None:
+            kw["note"] = self.note
+        self._note = annotation(name, **kw)
         if self._note is not None:
             self._note.__enter__()
         self._t0 = time.monotonic()
@@ -474,11 +497,13 @@ class phase:
             self._note.__exit__(*exc)
         buf = self._buf
         buf.stack.pop()
+        if t1 - self._t0 < self.floor:
+            return
         _metrics.step_seconds.observe(t1 - self._t0, loop=self.loop,
                                       phase=self.phase)
         buf.phases.append(PhaseSpan(
             self.name, self._t0, t1, self._parent, self.round,
-            buf.thread.name, self.id))
+            buf.thread.name, self.id, self.note))
 
 
 def phases() -> List[PhaseSpan]:
@@ -507,6 +532,192 @@ def self_seconds(spans) -> Dict[int, float]:
                 cover, edge = cover + (b - a), b
         out[s.id] = (s.end - s.start) - cover
     return out
+
+
+# ------------------------------------------------------ a process's start
+def _nested(tls, name: str) -> bool:
+    """Whether an import of `name` by this thread, now, is the outermost
+    import's own time: a module of its package, or anything under a
+    module of another package that is being timed already."""
+    own = getattr(tls, "own", None)
+    return own is not None and (tls.abroad
+                                or name.partition(".")[0] == own)
+
+
+class _TimedLoader:
+    """Stands in for a module's loader until the module runs: the
+    outermost `exec_module` of a thread is a `start.import` phase with a
+    floor.  What the outermost's `note` says beside its own name is
+    where its seconds went: the imports of OTHER packages it pulled in
+    first-hand, by seconds (`iotml.train (orbax.checkpoint 2.58, optax
+    0.36)`), which are the only nested imports that meet this object.
+    The module gets its own loader back before it runs, so nothing that
+    reads `__loader__` or `__spec__.loader` later meets it either."""
+
+    __slots__ = ("_inner",)
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def create_module(self, spec):
+        return self._inner.create_module(spec)
+
+    def exec_module(self, module) -> None:
+        inner, tls = self._inner, _importing
+        spec = getattr(module, "__spec__", None)
+        if spec is not None and spec.loader is self:
+            spec.loader = inner
+        if getattr(module, "__loader__", None) is self:
+            module.__loader__ = inner
+        name = module.__name__
+        if _nested(tls, name):
+            # (found outside an import and run inside one: as it was)
+            return inner.exec_module(module)
+        if getattr(tls, "own", None) is not None:
+            # pulled in first-hand from another package: timed, no span
+            tls.abroad, t0 = True, time.monotonic()
+            try:
+                return inner.exec_module(module)
+            finally:
+                tls.abroad = False
+                tls.pulled.append((time.monotonic() - t0, name))
+        tls.own, tls.abroad, tls.pulled = name.partition(".")[0], False, []
+        span = phase("start", "import", note=name, floor=IMPORT_FLOOR_S)
+        span.__enter__()
+        try:
+            inner.exec_module(module)
+        finally:
+            tls.own = None
+            heavy = [f"{n} {s:.2f}" for s, n in
+                     sorted(tls.pulled, reverse=True)[:3]
+                     if s >= IMPORT_FLOOR_S / 2]
+            if heavy:
+                span.note = f"{name} ({', '.join(heavy)})"
+            span.__exit__(*sys.exc_info())
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+class _ImportTimer:
+    """A finder ahead of `sys.meta_path` that finds nothing of its own.
+    Outside any import it asks the finders behind it and hands their
+    spec on with the loader wrapped; inside one it does the same only
+    for a module the outermost pulls in first-hand from another package,
+    and answers None for every other — most of a start's two thousand —
+    so that those are found and loaded as if it were not here: a
+    wrapped loader is one more interpreter frame a level of nesting for
+    as long as the module runs, and a dozen of them moved a loop deep
+    inside `orbax.checkpoint`'s import (`google.api_core`'s scan of the
+    installed distributions) across a boundary of CPython's frame
+    stack, where every call allocates a chunk and frees it again: five
+    times as long on the chip's host (PR 34).  The import
+    system consults `sys.meta_path` only for a module that is not in
+    `sys.modules`, so an import statement that finds its module there —
+    every one after the first — never comes here."""
+
+    def find_spec(self, name, path=None, target=None):
+        if _nested(_importing, name):
+            return None
+        for finder in sys.meta_path:
+            find = getattr(finder, "find_spec", None)
+            if finder is self or find is None:
+                continue
+            spec = find(name, path, target)
+            if spec is not None:
+                break
+        else:
+            return None
+        _ImportTimer.seen += 1
+        if hasattr(spec.loader, "exec_module"):
+            spec.loader = _TimedLoader(spec.loader)
+        return spec
+
+    #: modules handed on with a wrapped loader: what the bookkeeping
+    #: beyond one thread-local read was paid for
+    seen = 0
+
+
+#: per thread: `own`, the top-level package of the outermost import in
+#: progress (None outside any), `abroad`, whether a nested import of
+#: another package is running, and `pulled`, those imports' seconds
+_importing = threading.local()
+
+
+def time_imports() -> None:
+    """From here on every outermost import of this process that takes
+    `IMPORT_FLOOR_S` or more is an `iotml.start.import` span in the
+    importing thread's ring, the module's name in its `note`.  Once a
+    process; the package's `__init__` calls it before it imports
+    anything heavy."""
+    if not any(isinstance(f, _ImportTimer) for f in sys.meta_path):
+        sys.meta_path.insert(0, _ImportTimer())
+
+
+def start_report(spans=None, unit: float = 1.0) -> dict:
+    """A trainer's start from its spans: `start`, the seconds of each
+    `iotml.start.*` phase up to the end of the first `iotml.train.fit`;
+    that fit (`first_fit_s`) with its `dispatch_s` (tracing, lowering,
+    the cache read or the compilation) and `sync_s` (the first
+    execution); `imports`, the five slowest by module; `ready_s`, the
+    fit's end.  {} before a first fit has ended.  `spans` defaults to
+    this process's rings; `unit` is a second in the spans' time unit (a
+    span log's are microseconds since the writer's anchor, which is
+    where `ready_s` counts from: the process's first import of this
+    package)."""
+    own = spans is None
+    spans = phases() if own else sorted(spans, key=lambda s: s.id)
+    fit = next((s for s in spans if s.name == "iotml.train.fit"), None)
+    if fit is None:
+        return {}
+    out = {"ready_s": (fit.end - (_ANCHOR_MONO if own else 0)) * unit,
+           "first_fit_s": (fit.end - fit.start) * unit,
+           "dispatch_s": 0.0, "sync_s": 0.0, "start": {}}
+    imports = []
+    for s in spans:
+        if s.start > fit.end:
+            continue
+        loop, _, what = s.name.partition(".")[2].partition(".")
+        seconds = (s.end - s.start) * unit
+        if loop == "start":
+            out["start"][what] = out["start"].get(what, 0.0) + seconds
+            if what == "import":
+                imports.append((seconds, s.note or "?"))
+        elif s.name in ("iotml.train.dispatch", "iotml.train.sync") \
+                and s.thread == fit.thread and s.start >= fit.start \
+                and not out[what + "_s"]:
+            out[what + "_s"] = seconds
+    out["imports"] = [(name, sec) for sec, name in
+                      sorted(imports, reverse=True)[:5]]
+    return out
+
+
+def start_line(report: dict, compiles: Optional[dict] = None) -> str:
+    """One line of `start_report()` for an operator: where the seconds
+    from the first import to a trained job went.  `compiles` is
+    `utils.device.compile_report()`'s dict where the process listened
+    to JAX's compilations: what tracing, lowering and building or
+    loading the program's own programs took, and which of them the
+    persistent cache served."""
+    if not report:
+        return "start: no fit yet"
+    by_phase = dict(report["start"])
+    parts = [f"import {by_phase.pop('import', 0.0):.2f}" + "".join(
+        f"; {name} {sec:.2f}" for name, sec in report["imports"])]
+    parts += [f"{what.replace('_', ' ')} {sec:.2f}"
+              for what, sec in sorted(by_phase.items())]
+    parts.append(f"first fit {report['first_fit_s']:.2f} (dispatch "
+                 f"{report['dispatch_s']:.2f}, run {report['sync_s']:.2f})")
+    if compiles is not None:
+        by = compiles["seconds"]
+        parts.append(
+            f"the program's own programs: trace {by['trace']:.2f}, lower "
+            f"{by['lower']:.2f}, build or load {by['backend']:.2f} (cache "
+            f"read {by['cache_read']:.2f}); from the cache: "
+            f"{', '.join(compiles['hit']) or 'none'}; compiled (cache "
+            f"miss): {', '.join(compiles['missed']) or 'none'}")
+    return (f"start: ready {report['ready_s']:.2f} s after the first "
+            f"import | " + " | ".join(parts))
 
 
 def mark_batch(ctx: Optional[TraceContext], stage: str,
@@ -582,7 +793,9 @@ def flush() -> Dict[str, int]:
                  "parent": s.parent, "round": s.round, "thread": s.thread,
                  "start_us": int((s.start - _ANCHOR_MONO) * 1e6),
                  "dur_us": int((s.end - s.start) * 1e6),
-                 "wall0_ns": _ANCHOR_WALL_NS, "proc": proc}) for s in new]
+                 "wall0_ns": _ANCHOR_WALL_NS, "proc": proc,
+                 **({} if s.note is None else {"note": s.note})})
+                for s in new]
     for e in entries:
         if e[0] == "span":
             _, tid, stage, start_s, dur_s, wall0_ns, t_mark = e

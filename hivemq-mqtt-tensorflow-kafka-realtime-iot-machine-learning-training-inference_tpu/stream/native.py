@@ -24,6 +24,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..core.schema import RecordSchema
+from ..obs import tracing
 
 _TYPE_CODE = {"float": 0, "double": 1, "int": 2, "long": 3, "string": 4,
               "boolean": 5}
@@ -71,7 +72,10 @@ def load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    _lib = _load()
+    # a span where the engine had to be BUILT (`make`, seconds, in a new
+    # checkout or after an edit); loading a built one is milliseconds
+    with tracing.phase("start", "engine", floor=0.5):
+        _lib = _load()
     if _lib is None:
         print("iotml: native stream engine unavailable (build or load of "
               f"{_SO_PATH} failed); using the pure-Python codecs",

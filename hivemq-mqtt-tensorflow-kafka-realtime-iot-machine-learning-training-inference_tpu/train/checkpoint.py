@@ -27,7 +27,6 @@ from typing import Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
 from ..store import fsync_dir
 
@@ -38,6 +37,13 @@ class CheckpointManager:
     def __init__(self, directory: str):
         self.directory = os.path.abspath(directory)
         os.makedirs(self.directory, exist_ok=True)
+        # imported where a checkpoint is first written or read, not with
+        # the package: it pulls in google.cloud.logging, whose version
+        # check scans every installed distribution — 12 s of every
+        # start of a trainer on the chip's host (PERF.md §6, PR 34), and
+        # a trainer that never checkpoints never needs it
+        import orbax.checkpoint as ocp
+
         self._ckpt = ocp.PyTreeCheckpointer()
         #: torn/corrupt step dirs skipped by the last restore() walk
         self.skipped_torn = 0

@@ -318,9 +318,17 @@ class Trainer:
 
     def _ensure_state(self, sample_x):
         if self.state is None:
-            self.state = TrainState.create(self.model, self.rng, sample_x,
-                                           tx=self.tx, tx_key=self._tx_key)
-            self._step = make_train_step(self.model, self.tx, self.supervised)
+            # once a Trainer, and a start's second-largest part where the
+            # model is large: `iotml_state_init` traced, lowered, read
+            # from the cache or compiled, and run — the span ends when
+            # the state is on the device, which the first fit would
+            # wait for in any case
+            with tracing.phase("start", "state_init"):
+                self.state = jax.block_until_ready(TrainState.create(
+                    self.model, self.rng, sample_x, tx=self.tx,
+                    tx_key=self._tx_key))
+                self._step = make_train_step(self.model, self.tx,
+                                             self.supervised)
 
     def fit(self, batches, epochs: int = 1, verbose: bool = False,
             callbacks=()) -> dict:

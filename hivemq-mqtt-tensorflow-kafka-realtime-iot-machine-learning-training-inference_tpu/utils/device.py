@@ -3,11 +3,13 @@
 A chip belongs to one process at a time, and JAX falls back to the CPU
 without a word when it cannot start an accelerator.  Entry points that
 own a device call `claim_device()` once, before any other JAX work: it
-initialises the backend, refuses a CPU nobody asked for, places the
-persistent compile cache, starts counting compilations
-(`iotml_compile_seconds`), and returns what the process got so banners
-and stats lines can name it.  `python -m iotml.utils.device` prints that
-report plus the installed versions as one JSON line.
+initialises the backend (the span `iotml.start.backend`), refuses a CPU
+nobody asked for, places the persistent compile cache, starts counting
+compilations (`iotml_compile_seconds`, `iotml_compile_cache_total`),
+and returns what the process got so banners and stats lines can name
+it; `compile_report()` is those counters for a readiness line.
+`python -m iotml.utils.device` prints that report plus the installed
+versions as one JSON line.
 """
 
 from __future__ import annotations
@@ -67,13 +69,13 @@ _listening = False
 def listen_for_compiles() -> None:
     """Book what JAX reports of its own compilations into
     `iotml_compile_seconds{stage,program}` and
-    `iotml_compile_cache_total{result}`; registered once a process (JAX
-    has no unregister).  `program` is the jitted function's name where
-    it is one of the program's own (`iotml_*`, train/loop.py), else
-    "other", so the label set is bounded.  JAX times a cache read
-    without naming the program; the read happens inside that program's
-    `backend` event, on the same thread, so it is held until that event
-    names it."""
+    `iotml_compile_cache_total{result,program}`; registered once a
+    process (JAX has no unregister).  `program` is the jitted function's
+    name where it is one of the program's own (`iotml_*`,
+    train/loop.py), else "other", so the label set is bounded.  JAX
+    times a cache read, and reports a hit or a miss, without naming the
+    program; each happens inside that program's `backend` event, on the
+    same thread, so it is held until that event names it."""
     global _listening
     if _listening:
         return
@@ -104,16 +106,22 @@ def listen_for_compiles() -> None:
         program = program_of(kw)
         obs_metrics.compile_seconds.observe(seconds, stage=stage,
                                             program=program)
+        if stage != "backend":
+            return
         read_s = getattr(pending, "read_s", None)
-        if stage == "backend" and read_s is not None:
+        if read_s is not None:
             pending.read_s = None
             obs_metrics.compile_seconds.observe(read_s, stage="cache_read",
                                                 program=program)
+        result = getattr(pending, "result", None)
+        if result is not None:
+            pending.result = None
+            obs_metrics.compile_cache.inc(result=result, program=program)
 
     def on_event(event: str, **kw) -> None:
         result = _CACHE_RESULTS.get(event)
         if result is not None:
-            obs_metrics.compile_cache.inc(result=result)
+            pending.result = result
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
@@ -127,7 +135,10 @@ def claim_device() -> dict:
     the chip must not run on the host unnoticed."""
     import jax
 
-    devices = jax.devices()
+    from ..obs import tracing
+
+    with tracing.phase("start", "backend"):
+        devices = jax.devices()
     first = devices[0]
     if first.platform == "cpu" and \
             "cpu" not in (jax.config.jax_platforms or ""):
@@ -138,6 +149,33 @@ def claim_device() -> dict:
     return {"platform": first.platform, "device_kind": first.device_kind,
             "count": len(devices),
             "compile_cache_dir": enable_compile_cache()}
+
+
+def compile_report() -> dict:
+    """What `listen_for_compiles()` has booked for the program's own
+    programs so far: `seconds` by stage (`cache_read` is part of
+    `backend`), `hit`, the programs the persistent cache served, and
+    `missed`, those it did not hold — the ones a start compiled (a
+    process without a cache, as one pinned to the CPU, names neither)."""
+    import re
+
+    from ..obs import metrics as obs_metrics
+
+    seconds = dict.fromkeys(("trace", "lower", "backend", "cache_read"), 0.0)
+    cached = {"hit": [], "miss": []}
+    series = re.compile(r'iotml_compile_(seconds_sum|cache_total)'
+                        r'\{program="(iotml_\w+)",(?:stage|result)="(\w+)"\}')
+    for key, value in obs_metrics.default_registry.collect().items():
+        found = series.fullmatch(key)
+        if found is None:
+            continue
+        family, program, what = found.groups()
+        if family == "seconds_sum":
+            seconds[what] += value
+        elif value:
+            cached[what].append(program)
+    return {"seconds": seconds, "hit": sorted(cached["hit"]),
+            "missed": sorted(cached["miss"])}
 
 
 def device_text(report: dict) -> str:

@@ -140,8 +140,13 @@ def test_fit_compiled_leaves_one_span_tree_per_call():
     fits = [s for s in spans if s.name == "iotml.train.fit"]
     assert [f.round for f in fits] == [1, 2] and trainer.fits == 2
     assert all(f.parent is None for f in fits)
+    # a Trainer's first fit is also where its state is made (ISSUE 34:
+    # the `start` loop's spans lie where the work happens)
+    assert [s.parent for s in spans
+            if s.name == "iotml.start.state_init"] == [fits[0].id]
     for fit in fits:
-        kids = {s.name: s for s in spans if s.parent == fit.id}
+        kids = {s.name: s for s in spans if s.parent == fit.id
+                and not s.name.startswith("iotml.start.")}
         assert set(kids) == {"iotml.train.host_pipeline",
                              "iotml.train.stack",
                              "iotml.train.device_compute"}
