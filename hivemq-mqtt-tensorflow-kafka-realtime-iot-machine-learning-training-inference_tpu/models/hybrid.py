@@ -24,8 +24,9 @@ one for what is small and dear: what a kernel or the router made.  So
 the recomputation keeps what goes by one of the names in `KEPT` — the
 flash kernel's `out` and log-sum-exp, latent attention's rotated q and
 assembled k, the router's selection, its selected scores and the
-dispatch's plan (integers; a top-k and a sort), the routed sum ahead of
-a latent's back-projection (its weight gradient reads it) — tens of MiB
+dispatch's plan (the sorted order, the routing weights in that order
+and the tiles' integers; a top-k and a sort), the routed sum ahead of a
+latent's back-projection (its weight gradient reads it) — tens of MiB
 a layer against a second run of the forward kernel, of rotary's pads
 and copies, of the `highest` product and of the tiles' walk.  A name
 exists where the part that makes it exists, so the one policy serves

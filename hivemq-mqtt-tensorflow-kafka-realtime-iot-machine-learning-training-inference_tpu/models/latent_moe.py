@@ -127,6 +127,7 @@ class ExpertLayer(nn.Module):
         obs_metrics.moe_latent_dim.set(m.moe_latent)
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
+        obs_metrics.moe_plan_sorted_operands.set(moe.PLAN_SORTED_OPERANDS)
         # the shared expert first: with it after the routed path, XLA's
         # memory-space assignment moved another of its weights into VMEM
         # once the recomputation kept the router's results, and its

@@ -526,6 +526,11 @@ moe_dispatch_rows = default_registry.gauge(
     "iotml_moe_dispatch_rows",
     "static rows an expert layer's dispatch is built for: the worst the "
     "router can produce, tokens x min(top_k, experts held)")
+moe_plan_sorted_operands = default_registry.gauge(
+    "iotml_moe_plan_sorted_operands",
+    "operands the sort of an expert layer's dispatch plan carries (3: the "
+    "key, the index and the routing weights, which so reach their sorted "
+    "places, and their cotangents come back, without a gather or a scatter)")
 moe_assignments = default_registry.counter(
     "iotml_moe_assignments_total",
     "token-to-expert assignments of the fits so far, all expert layers, "

@@ -158,29 +158,63 @@ def walked_rows(held_counts, tokens: int):
     return -(-held_counts // tile) * tile
 
 
+#: the name the plan's parts go by under a policy of names
+_keep = functools.partial(checkpoint_name, name="dispatch_plan")
+#: the operands the plan's sort carries: the key, the index that
+#: becomes the order, and the routing weights
+PLAN_SORTED_OPERANDS = 3
+
+
+@jax.custom_vjp
+def _sort_plan(key, weights):
+    """The N·K assignments sorted by `key`, stably: (order [A] int32,
+    the flat `weights` in that order).  The weights ride the sort as a
+    third operand, so nothing gathers them by `order`; and `order` is a
+    permutation, so their cotangents' way back is a second sort, by
+    `order` itself, where autodiff's rule for a sort gathers by the
+    permutation and transposes that into a scatter-add of scalars."""
+    return _sort_plan_fwd(key, weights)[0]
+
+
+def _sort_plan_fwd(key, weights):
+    _, order, weight = jax.lax.sort(
+        (key, jnp.arange(key.shape[0], dtype=jnp.int32), weights),
+        num_keys=1, is_stable=True)
+    order = _keep(order)
+    return (order, _keep(weight)), order
+
+
+def _sort_plan_bwd(order, cotangents):
+    # every key is another: nothing for a stable sort to keep in order
+    _, d_weights = jax.lax.sort((order, cotangents[1]), num_keys=1,
+                                is_stable=False)
+    return None, d_weights
+
+
+_sort_plan.defvjp(_sort_plan_fwd, _sort_plan_bwd)
+
+
 def dispatch_plan(experts, weights, first: int, held: int,
                   n_experts: int) -> Dispatch:
     """Sort the N·K assignments by expert (those held elsewhere last)
     and cut the groups of the experts held into tiles, each group from
     a tile of its own.  A group is one run of the sorted assignments, so
-    a tile is a slice of them: nothing is gathered row by row.
+    a tile is a slice of them: nothing is gathered row by row, and the
+    routing weights reach their sorted places inside the sort
+    (`_sort_plan`).
 
-    The sorted order, the tiles' fields and `counts` go by one name,
-    `dispatch_plan`: integers a recomputed forward would sort and count
-    a second time, and a policy of names keeps (`weight` it gathers
-    again, by the order kept)."""
+    The sorted order and weights, the tiles' fields and `counts` go by
+    one name, `dispatch_plan`: what a recomputed forward would sort and
+    count a second time, and a policy of names keeps."""
     N, K = experts.shape
     tile = _tile(N)
     n_tiles = dispatch_rows(N, K, held) // tile
-    keep = functools.partial(checkpoint_name, name="dispatch_plan")
     flat = experts.reshape(-1)
     local = flat - first
     here = (local >= 0) & (local < held)
-    _, order = jax.lax.sort(
-        (jnp.where(here, local, held), jnp.arange(N * K, dtype=jnp.int32)),
-        num_keys=1, is_stable=True)
-    order = keep(order)
-    counts = keep(jnp.sum(
+    order, weight = _sort_plan(jnp.where(here, local, held),
+                               weights.reshape(-1))
+    counts = _keep(jnp.sum(
         flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype),
         axis=0, dtype=jnp.int32))
     sizes = jax.lax.dynamic_slice_in_dim(counts, first, held)
@@ -192,20 +226,21 @@ def dispatch_plan(experts, weights, first: int, held: int,
     pad = lambda v, fill: jnp.concatenate(  # noqa: E731
         [v, jnp.full((tile,), fill, v.dtype)])
     return Dispatch(
-        pad(order // K, N), pad(weights.reshape(-1)[order], 0),
-        keep(e.astype(jnp.int32)),
-        keep(((jnp.cumsum(sizes) - sizes)[e] + within).astype(jnp.int32)),
-        keep(jnp.clip(sizes[e] - within, 0, tile).astype(jnp.int32)),
-        keep(tile_end[-1]), counts)
+        pad(order // K, N), pad(weight, 0),
+        _keep(e.astype(jnp.int32)),
+        _keep(((jnp.cumsum(sizes) - sizes)[e] + within).astype(jnp.int32)),
+        _keep(jnp.clip(sizes[e] - within, 0, tile).astype(jnp.int32)),
+        _keep(tile_end[-1]), counts)
 
 
 def plan_kept_bytes(tokens: int, top_k: int, held: int,
                     n_experts: int) -> int:
     """What a layer's router and plan keep by name, a step: the
-    selection and the selected scores, the sorted order, three fields a
-    tile, the live tiles' count and `counts` — four bytes each."""
+    selection and the selected scores, the sorted order and the sorted
+    weights, three fields a tile, the live tiles' count and `counts` —
+    four bytes each."""
     tiles = dispatch_rows(tokens, top_k, held) // _tile(tokens)
-    return 4 * (3 * tokens * top_k + 3 * tiles + 1 + n_experts)
+    return 4 * (4 * tokens * top_k + 3 * tiles + 1 + n_experts)
 
 
 #: an expert's form: the activation between its two products, and how

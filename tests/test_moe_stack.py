@@ -297,6 +297,87 @@ def test_a_dropped_tile_shows(small_tiles):
     assert rows > 0 and np.array_equal(moved, lost)
 
 
+def _plan_by_gather(experts, weights, first, held, n_experts):
+    """`ops.moe.dispatch_plan` as it stood before the weights rode the
+    sort: the (key, index) pairs sorted, the flat weights gathered by
+    the sorted order — autodiff's way back is that gather's transpose, a
+    scatter-add of scalars.  The reference the sort form is held to."""
+    N, K = experts.shape
+    local = experts.reshape(-1) - first
+    _, order = jax.lax.sort(
+        (jnp.where((local >= 0) & (local < held), local, held),
+         jnp.arange(N * K, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    plan = moe.dispatch_plan(experts, jax.lax.stop_gradient(weights), first,
+                             held, n_experts)
+    return plan._replace(weight=jnp.concatenate(
+        [weights.reshape(-1)[order],
+         jnp.zeros((moe._tile(N),), weights.dtype)]))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("K,first,held,idle", [
+    (3, 4, 4, None),     # the shape of the tests above
+    (6, 2, 3, None),     # top_k > held: a token's experts held are fewer
+    (5, 0, 8, 5),        # an expert held that no token takes
+    (2, 15, 1, None),    # one expert held, the last routed over
+])
+def test_the_routing_weights_ride_the_plans_sort(small_tiles, seed, K, first,
+                                                 held, idle):
+    """`Dispatch.weight` is the sort's own output and its cotangents
+    come back through a sort by the order: bit for bit the gather form's
+    weights and gradient (a sort moves values; a permutation's
+    scatter-add adds nothing to anything), the dense-masked form's
+    gradient to the file's tolerance — and no gather and no scatter-add
+    of the N·K scalars in the gradient's jaxpr, where the gather form
+    has one of each."""
+    N, d, f, E = 300, 16, 8, 16
+    rng = np.random.default_rng(seed)
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, gate, w_in, w_out, w = arr(N, d), arr(d, E), arr(held, d, 2 * f), \
+        arr(held, f, d), arr(N, d)
+    bias = jnp.zeros((E,), jnp.float32) if idle is None \
+        else jnp.where(jnp.arange(E) == idle, -10.0, 0.0)
+    experts, weights = moe.route(x, gate, bias, K, 2.5)
+    if idle is not None:
+        assert not (experts == idle).any()
+
+    def through(plan_of):
+        def loss(weights):
+            plan = plan_of(experts, weights, first, held, E)
+            return jnp.sum(w * moe.experts_apply(x, plan, w_in, w_out)), plan
+        return loss
+
+    def dense(weights):
+        return jnp.sum(w * moe.experts_dense(x, experts, weights, w_in,
+                                             w_out, first, held))
+
+    with jax.default_matmul_precision("highest"):
+        (got, plan), grad = jax.jit(jax.value_and_grad(
+            through(moe.dispatch_plan), has_aux=True))(weights)
+        (want, by_gather), grad_by_gather = jax.jit(jax.value_and_grad(
+            through(_plan_by_gather), has_aux=True))(weights)
+        grad_dense = jax.jit(jax.grad(dense))(weights)
+    assert np.array_equal(plan.weight, by_gather.weight)
+    assert np.array_equal(plan.token, by_gather.token)
+    assert float(got) == float(want)
+    assert np.array_equal(grad, grad_by_gather)
+    assert np.asarray(grad).any()
+    # an assignment held elsewhere moves nothing here
+    here = np.asarray((experts >= first) & (experts < first + held))
+    assert not np.asarray(grad)[~here].any()
+    _close(grad, grad_dense)
+    # the gradient's jaxpr, counted as `test_remat_policy.py` counts it
+    from tests.test_remat_policy import _count, _what
+
+    def moves(plan_of):
+        counts = _count(jax.make_jaxpr(jax.grad(
+            through(plan_of), has_aux=True))(weights).jaxpr, _what(N * K), {})
+        return counts.get("gather", 0), counts.get("scatter-add", 0)
+
+    assert moves(moe.dispatch_plan) == (0, 0)
+    assert moves(_plan_by_gather) == (1, 1)
+
+
 def test_the_bias_moves_the_selection_and_not_the_weights():
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(50, 16)), jnp.float32)
@@ -365,8 +446,12 @@ def test_a_tiny_fit_says_what_engaged(ref):
         == [3, 0, 0, 1, 2]
     assert got["iotml_remat_blocks"] == 3
     # what the blocks' recomputation keeps, a step: two layers' selection
-    # and plan, three layers' q and k [2, 40, 4, 16 + 8]; no kernel ran
-    # (`dense` attention) and no latent is here
+    # and plan (four [80, 3] arrays — the selection, the selected scores,
+    # the sorted order, the sorted weights — three fields of seven tiles
+    # of 80 rows, the live tiles' count, `counts`), three layers' q and k
+    # [2, 40, 4, 16 + 8]; no kernel ran (`dense` attention) and no latent
+    # is here
+    assert moe.plan_kept_bytes(80, 3, 4, 16) == 4 * (4 * 240 + 3 * 7 + 1 + 16)
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
             ("router", "latent_qk", "flash", "experts")] \
         == [2 * moe.plan_kept_bytes(80, 3, 4, 16),
@@ -376,6 +461,8 @@ def test_a_tiny_fit_says_what_engaged(ref):
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
     assert got["iotml_moe_top_k"] == 3
     assert got["iotml_moe_dispatch_rows"] == moe.dispatch_rows(80, 3, 4)
+    # the plan's sort carries key, index and routing weights
+    assert got["iotml_moe_plan_sorted_operands"] == 3
     moved = {k: got[f'iotml_moe_assignments_total{{kind="{k}"}}']
              - before.get(f'iotml_moe_assignments_total{{kind="{k}"}}', 0.0)
              for k in ("held", "elsewhere")}

@@ -219,8 +219,11 @@ def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):
     compiled for the described v5e, calls `iotml_flash_fwd` once — the
     block's recomputation keeps the kernel's `out` and log-sum-exp
     (`models.hybrid.KEPT`) and reads them where it ran the kernel a
-    second time — and sorts the layer's assignments twice, top-k's sort
-    and the plan's, where it sorted them four times."""
+    second time — and sorts the layer's assignments three times, top-k's
+    sort, the plan's (the routing weights its third operand) and the one
+    that brings the weights' cotangents back, where it sorted them four
+    times and gathered and scatter-added the 49,152 weights a scalar at
+    a time."""
     from iotml.models.hybrid import HybridConfig, SensorHybrid
 
     monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
@@ -237,5 +240,8 @@ def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):
     for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
                    "iotml_flash_bwd_dq"):
         assert sum(kernel in line for line in calls) == 1, kernel
-    assert sum(re.search(r" = .* sort\(", line) is not None
-               for line in lines) == 2
+    sorts = [line for line in lines if re.search(r" = .* sort\(", line)]
+    assert len(sorts) == 3
+    assert sum("f32[49152]" in line.split(" sort(")[0] for line in sorts) == 2
+    assert not [line for line in lines if re.search(
+        r" = f32\[49152\]\S* (gather|scatter)\(", line)]

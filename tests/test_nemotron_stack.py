@@ -348,8 +348,11 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
         == [2, 1, 0, 0, 2]
     assert got["iotml_remat_blocks"] == 5
     # what the blocks' recomputation keeps, a step: two layers' selection
-    # and plan and their routed sums [80, 32]; `dense` attention ran no
-    # kernel, and no latent attention is here
+    # and plan (four [80, 5] arrays with the sorted weights, three fields
+    # of 24 tiles of 16 rows, the live tiles' count, `counts`) and their
+    # routed sums [80, 32]; `dense` attention ran no kernel, and no
+    # latent attention is here
+    assert moe.plan_kept_bytes(80, 5, 4, 16) == 4 * (4 * 400 + 3 * 24 + 1 + 16)
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
             ("router", "experts", "flash", "latent_qk")] \
         == [2 * moe.plan_kept_bytes(80, 5, 4, 16), 2 * 80 * 32 * 4, 0, 0]
@@ -358,6 +361,7 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
     assert got["iotml_moe_top_k"] == 5
     assert got["iotml_moe_dispatch_rows"] == moe.dispatch_rows(80, 5, 4)
+    assert got["iotml_moe_plan_sorted_operands"] == 3
     moved = lambda name, kind: got[f'{name}{{kind="{kind}"}}'] \
         - before.get(f'{name}{{kind="{kind}"}}', 0.0)  # noqa: E731
     steps = np.concatenate([np.asarray(c).reshape(-1, 16)[:, :4]

@@ -7,8 +7,10 @@ trains (Mamba + grouped-query attention; latent attention + experts at
 the stream's width; one-part layers + experts in a latent), at a tiny
 preset on the CPU: the policy changes no gradient and no report; the
 router's hand-written backward is autodiff of its forward; and in the
-gradient's jaxpr the kernel, top-k and the sort appear once a layer and
-the `highest` product three times."""
+gradient's jaxpr the kernel and top-k appear once a layer, the `highest`
+product three times and a sort twice (the plan's, and the one that
+brings the routing weights' cotangents back) — with no gather and no
+scatter-add of the routing weights' scalars."""
 
 import flax.linen as nn
 import jax
@@ -127,45 +129,63 @@ def _count(jaxpr, found, counts):
     return counts
 
 
-def _what(eqn):
-    name = eqn.primitive.name
-    if name == "pallas_call":
-        return eqn.params["name"]
-    if name == "dot_general":
-        precision = eqn.params["precision"]
-        return "highest" if precision is not None and all(
-            p == jax.lax.Precision.HIGHEST for p in precision) else None
-    return name if name in ("top_k", "sort") else None
+def _what(assignments):
+    """What `_count` counts of an equation; a `gather` or `scatter-add`
+    only where its operand is a float vector of `assignments` entries:
+    a layer's routing weights, or their cotangents, a scalar at a time."""
+    def found(eqn):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            return eqn.params["name"]
+        if name == "dot_general":
+            precision = eqn.params["precision"]
+            return "highest" if precision is not None and all(
+                p == jax.lax.Precision.HIGHEST for p in precision) else None
+        if name in ("gather", "scatter-add"):
+            aval = eqn.invars[0].aval
+            return name if aval.shape == (assignments,) and jnp.issubdtype(
+                aval.dtype, jnp.floating) else None
+        return name if name in ("top_k", "sort") else None
+    return found
 
 
 @pytest.mark.parametrize("stack", STACKS)
 def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
                                                               stack):
     """In the gradient's jaxpr: `iotml_flash_fwd` once an attention
-    layer (as often as the backward kernel), `top_k` and `sort` once an
-    expert layer, the `highest` product three times (the forward's and
-    the backward's two) — and under plain `nn.remat`, the recomputed
-    forward's beside them."""
+    layer (as often as the backward kernel), `top_k` once an expert
+    layer, `sort` twice (the plan's, which carries the routing weights
+    to their sorted places, and the one by the sorted order that brings
+    their cotangents back), the `highest` product three times (the
+    forward's and the backward's two), and the routing weights neither
+    gathered nor scatter-added — and under plain `nn.remat`, the
+    recomputed forward's top-k, sort and product beside them."""
     cfg, attention, routed = STACKS[stack]
     model = SensorHybrid(cfg, attn_mode="flash_interpret")
     batch = _batch()
     params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
     loss = make_loss_fn(model, supervised=True)
+    what = _what(batch[0].shape[0] * batch[0].shape[1] * cfg.top_k)
 
     def counted():
         jax.clear_caches()
         return _count(jax.make_jaxpr(jax.grad(loss, has_aux=True))(
-            params, *batch).jaxpr, _what, {})
+            params, *batch).jaxpr, what, {})
+
+    def of(counts, *names):
+        return tuple(counts.get(name, 0) for name in names)
 
     kept = counted()
     assert kept.get("iotml_flash_fwd", 0) == attention \
         == kept.get("iotml_flash_bwd_dkv", 0)
-    assert (kept.get("top_k", 0), kept.get("sort", 0),
-            kept.get("highest", 0)) == (routed, routed, 3 * routed)
+    assert of(kept, "top_k", "sort", "highest") \
+        == (routed, 2 * routed, 3 * routed)
+    assert of(kept, "gather", "scatter-add") == (0, 0)
 
     plain = nn.remat
     monkeypatch.setattr(hybrid.nn, "remat", lambda cls, policy: plain(cls))
     again = counted()
     assert again.get("iotml_flash_fwd", 0) == 2 * attention
-    assert (again.get("top_k", 0), again.get("sort", 0),
-            again.get("highest", 0)) == (2 * routed, 2 * routed, 4 * routed)
+    assert of(again, "top_k", "sort", "highest") \
+        == (2 * routed, 3 * routed, 4 * routed)
+    assert of(again, "gather", "scatter-add") == (0, 0)
