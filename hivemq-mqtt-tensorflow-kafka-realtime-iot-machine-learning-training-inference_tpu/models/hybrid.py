@@ -33,14 +33,26 @@ exists where the part that makes it exists, so the one policy serves
 every stack, and outside a recomputation a name is the identity.
 
 The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
-`attention`, or `mla`, the latent attention of `models.latent_moe`) and
-the feed-forward part (`ffn_types`: `dense_ffn`, the gated MLP above, or
+`attention`, `mla`, the latent attention of `models.latent_moe`, or
+`short_conv`, the gated short convolution below) and the feed-forward
+part (`ffn_types`: `dense_ffn`, the gated MLP above, or
 `moe_ffn`, that file's sparse-expert layer; left empty, every layer is
 `dense_ffn`).  Either may be `none`: a layer is then ONE part alone,
 `h ← h + r · part(RMSNorm(h))`, and builds the norm and the residual of
 the part it has and no other (a Nemotron-H-shaped stack: every layer a
 mixer or a feed-forward part).  Both are data of the model, as
 published configurations state them.
+
+Two more mixers' parts are data.  The **gated short convolution**
+(`short_conv`, LFM2's): `[b, c, x] = u W_in`, `y = conv(b ⊙ x)` — a
+depthwise causal convolution of `short_conv_width` taps a channel
+with no bias and no activation, the state-space mixer's kernels told
+so (`ops.ssd.causal_conv1d_fused`) — and `(c ⊙ y) W_out`: two gates
+around a few taps, order carried by the taps alone.  And grouped
+attention may **norm and turn** its queries and keys: `qk_norm` a
+weight-only RMSNorm over a head's features, one weight vector for
+all query heads and one for all key heads; `attn_rope_theta` (0: no
+positions) rotary positions over the whole head, after the norms.
 
 The heads a layer holds may be a share of the model's (one chip's,
 where the model's mixers are divided over chips): `num_heads` and
@@ -64,9 +76,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
+from ..ops import moe
 from ..ops.attention import attention_reference, flash_attention
-from ..ops.moe import plan_kept_bytes
-from ..ops.ssd import causal_conv1d_silu, ssd_scan
+from ..ops.ssd import causal_conv1d_fused, causal_conv1d_silu, ssd_scan
 from . import latent_moe
 from .latent_moe import ExpertLayer, LatentAttention, gated_mlp
 from .latent_moe import dense as _dense
@@ -77,7 +89,7 @@ from .latent_moe import normal as _normal
 #: `ops.moe.route` and `dispatch_plan`, `models.latent_moe.ExpertLayer`
 KEPT = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
         "route_picked", "dispatch_plan", "routed_sum")
-KINDS = ("mamba", "attention", "mla")
+KINDS = ("mamba", "attention", "mla", "short_conv")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
 NONE = "none"   # a layer without that part
 
@@ -99,6 +111,12 @@ class HybridConfig:
     ssm_state: int = 8
     conv_width: int = 4
     chunk: int = 8
+    # a gated short convolution's taps a channel (`short_conv`)
+    short_conv_width: int = 3
+    # grouped attention (`attention`): a per-head RMSNorm of q and k, and
+    # rotary positions over the whole head (0: none)
+    qk_norm: bool = False
+    attn_rope_theta: float = 0.0
     eps: float = 1e-5
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
@@ -183,6 +201,26 @@ class MambaMixer(nn.Module):
             return _dense(m.d_model, "out_proj")(y)
 
 
+class ShortConvMixer(nn.Module):
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        d = m.d_model
+        with jax.named_scope("conv_proj"):
+            b, c, x = jnp.split(_dense(3 * d, "in_proj")(u), 3, axis=-1)
+        with jax.named_scope("short_conv"):
+            # the gates are XLA's multiplies around the kernels' call
+            (y,) = causal_conv1d_fused(
+                b * x, self.param("conv_kernel", _normal,
+                                  (m.short_conv_width, d)),
+                activation="none")
+            y = c * y
+        with jax.named_scope("conv_proj"):
+            return _dense(d, "out_proj")(y)
+
+
 class GroupedAttention(nn.Module):
     cfg: HybridConfig
     attn_mode: str   # dense | flash | flash_interpret
@@ -195,6 +233,16 @@ class GroupedAttention(nn.Module):
         q = _dense(H * D, "q")(u).reshape(B, T, H, D)
         k = _dense(G * D, "k")(u).reshape(B, T, G, D)
         v = _dense(G * D, "v")(u).reshape(B, T, G, D)
+        obs_metrics.attn_qk_norm.set(int(m.qk_norm))
+        obs_metrics.attn_rotary_dim.set(D if m.attn_rope_theta else 0)
+        if m.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = nn.RMSNorm(epsilon=m.eps, name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=m.eps, name="k_norm")(k)
+        if m.attn_rope_theta:
+            with jax.named_scope("rope"):
+                q = moe.rotary(q, m.attn_rope_theta)
+                k = moe.rotary(k, m.attn_rope_theta)
         if self.attn_mode == "dense":
             o = attention_reference(q, k, v, causal=True,
                                     scale=m.attention_multiplier)
@@ -220,6 +268,8 @@ class HybridBlock(nn.Module):
             u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
             if self.kind == "mamba":
                 mixed = MambaMixer(m, name="mixer")(u)
+            elif self.kind == "short_conv":
+                mixed = ShortConvMixer(m, name="mixer")(u)
             else:
                 attention = LatentAttention if self.kind == "mla" \
                     else GroupedAttention
@@ -270,7 +320,7 @@ class SensorHybrid(nn.Module):
             # latent attention's q and k, [B, T, H, nope + rope] each
             "latent_qk": m.layer_types.count("mla") * 2 * tokens
             * m.num_heads * (m.nope_dim + m.rope_dim) * size,
-            "router": expert_layers * plan_kept_bytes(
+            "router": expert_layers * moe.plan_kept_bytes(
                 tokens, m.top_k, m.experts_held[1], m.experts),
             "experts": expert_layers * tokens * m.moe_latent * size,
         }

@@ -18,9 +18,11 @@ are serving's and are not here.
 HELD here (`experts_held`, a contiguous range: one chip's share under
 expert parallelism; the others' terms are left out, with no exchange
 and nothing standing in for them), and a shared expert every token
-takes, computed whole.  Dropless (`ops.moe.experts_apply`).  It reports
-the assignments every expert got, a step, through the `reports`
-collection: data, not shape, so it comes back with the losses.
+takes, computed whole (`shared_dim` 0: the model has none, and the
+layer is `Σ_k w_k · E_{i_k}(u)` alone).  Dropless
+(`ops.moe.experts_apply`).  It reports the assignments every expert
+got, a step, through the `reports` collection: data, not shape, so it
+comes back with the losses.
 
 Two more things of the layer are data.  The experts' **form**
 (`expert_form`, `ops.moe.EXPERT_FORMS`: the gated SiLU above or the
@@ -125,6 +127,7 @@ class ExpertLayer(nn.Module):
         obs_metrics.moe_experts.set(m.experts, kind="routed_over")
         obs_metrics.moe_top_k.set(m.top_k)
         obs_metrics.moe_latent_dim.set(m.moe_latent)
+        obs_metrics.moe_shared_dim.set(m.shared_dim)
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
         obs_metrics.moe_plan_sorted_operands.set(moe.PLAN_SORTED_OPERANDS)
@@ -134,8 +137,11 @@ class ExpertLayer(nn.Module):
         # fusions ran 5.8 ms a step slower in `ns-train-backlog`
         # (PERF.md §6, PR 33: compile the whole fit and read which
         # operands of `shared` carry `S(1)` before moving this)
-        with jax.named_scope("shared"):
-            shared = gated_mlp(u, m.shared_dim, d, "shared", m.expert_form)
+        shared = None   # `shared_dim` 0: the layer is its routed sum alone
+        if m.shared_dim:
+            with jax.named_scope("shared"):
+                shared = gated_mlp(u, m.shared_dim, d, "shared",
+                                   m.expert_form)
         x = u.reshape(B * T, d)
         with jax.named_scope("router"):
             experts, weights = moe.route(
@@ -162,7 +168,8 @@ class ExpertLayer(nn.Module):
             routed = checkpoint_name(routed, "routed_sum")
             with jax.named_scope("latent_proj"):
                 routed = dense(d, "latent_out")(routed)
-        return routed.reshape(B, T, d) + shared
+        routed = routed.reshape(B, T, d)
+        return routed if shared is None else routed + shared
 
 
 def record_reports(cfg, reports) -> None:

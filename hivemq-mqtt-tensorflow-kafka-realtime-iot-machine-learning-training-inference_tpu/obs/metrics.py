@@ -499,6 +499,13 @@ conv_block_t = default_registry.gauge(
     "iotml_conv_block_t", "positions a block of the convolution kernels holds")
 conv_block_c = default_registry.gauge(
     "iotml_conv_block_c", "channels a block of the convolution kernels holds")
+conv_taps = default_registry.gauge(
+    "iotml_conv_taps", "taps a channel of the last traced convolution has")
+conv_activation_fused = default_registry.gauge(
+    "iotml_conv_activation_fused",
+    "1 where the last traced convolution's kernels applied an activation "
+    "(SiLU) to the sum, 0 where they stored it as it stood (a gated short "
+    "convolution, whose gates are its caller's)")
 conv_operand_copies = default_registry.gauge(
     "iotml_conv_operand_copies",
     "operands of the convolution kernels copied ahead of them by their "
@@ -507,8 +514,18 @@ conv_operand_copies = default_registry.gauge(
 model_layers = default_registry.gauge(
     "iotml_model_layers",
     "layers of the last traced hybrid model, by the kind of their mixer "
-    "(mamba | attention | mla) and of their feed-forward part "
-    "(dense_ffn | moe_ffn)")
+    "(mamba | attention | mla | short_conv) and of their feed-forward "
+    "part (dense_ffn | moe_ffn)")
+# grouped attention's two optional parts (models/hybrid.py
+# `GroupedAttention`), at trace time: what the last traced layer applied
+attn_rotary_dim = default_registry.gauge(
+    "iotml_attn_rotary_dim",
+    "features of a head the last traced grouped-attention layer turned by "
+    "rotary positions (the whole head, or 0: no positions)")
+attn_qk_norm = default_registry.gauge(
+    "iotml_attn_qk_norm",
+    "1 where the last traced grouped-attention layer normed its queries "
+    "and keys a head (RMSNorm), else 0")
 # the sparse-expert layer (models/latent_moe.py, ops/moe.py).  Shape at
 # trace time, as above; the assignments are DATA, read back with a
 # fit's losses at its one sync (`Trainer.fit_compiled`).
@@ -522,6 +539,10 @@ moe_latent_dim = default_registry.gauge(
     "iotml_moe_latent_dim",
     "width of the latent the last traced expert layer's routed experts "
     "act in (0: at the stream's full width)")
+moe_shared_dim = default_registry.gauge(
+    "iotml_moe_shared_dim",
+    "width of the shared expert every token of the last traced expert "
+    "layer takes beside its routed experts (0: the layer has none)")
 moe_dispatch_rows = default_registry.gauge(
     "iotml_moe_dispatch_rows",
     "static rows an expert layer's dispatch is built for: the worst the "

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 def causal_conv1d(x, kernel, bias):
     """Depthwise causal convolution over time, the plain form the tests
-    hold `causal_conv1d_silu` to: x [B, T, C], kernel [K, C], bias [C]
+    hold `causal_conv1d_fused` to: x [B, T, C], kernel [K, C], bias [C]
     → [B, T, C], with y_t = bias + Σ_k kernel[k] · x_{t-(K-1)+k} and
     x_t = 0 for t < 0 (left-padded with zeros).
 
@@ -75,6 +75,10 @@ _LANES = 128
 _CONV_BLOCK_BYTES = 2 ** 21
 #: the most positions a block holds
 _CONV_MAX_T = 4096
+#: what the kernels may apply to the convolution's sum before they store
+#: it: `silu` (Mamba-2's) or nothing (`none`: a gated short convolution's,
+#: whose gates are its caller's)
+CONV_ACTIVATIONS = ("silu", "none")
 #: what an iteration of the kernels' loops takes: so many channels
 #: (whole sublane tiles) by so many lane tiles.  The chain from a load
 #: through the rolls, the exponential and the reciprocal to the store is
@@ -155,14 +159,14 @@ def _row_constants(k_ref, b_ref, r, n: int):
             jnp.broadcast_to(b_ref[r, :].astype(f32), (n, _LANES)))
 
 
-def _conv_fwd_step(*refs, K: int, halo: bool):
-    """One [block_c, block_t] block of silu(bias + Σ_k kernel[k] ·
-    x_{t-(K-1)+k}): a sublane tile of channels at a time, lane tile by
-    lane tile, a tile's taps, sum and SiLU in registers.  The K-1
-    positions before a lane tile are the end of the tile before it —
-    kept, rolled, from tile to tile; for a block's first tile `h_ref`
-    (the tile before the block, through its own index map; zeros before
-    the first block, so no padded x exists)."""
+def _conv_fwd_step(*refs, K: int, halo: bool, activation: str):
+    """One [block_c, block_t] block of act(bias + Σ_k kernel[k] ·
+    x_{t-(K-1)+k}), act SiLU or nothing: a sublane tile of channels at
+    a time, lane tile by lane tile, a tile's taps, sum and activation
+    in registers.  The K-1 positions before a lane tile are the end of
+    the tile before it — kept, rolled, from tile to tile; for a block's
+    first tile `h_ref` (the tile before the block, through its own
+    index map; zeros before the first block, so no padded x exists)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -191,8 +195,9 @@ def _conv_fwd_step(*refs, K: int, halo: bool):
                 pre = bias + cur * w[K - 1]
                 for s, rolled, was in zip(shifts, mine, beside):
                     pre = pre + _moved(rolled, was, s, lane) * w[K - 1 - s]
-                o_ref[0, r, at] = (pre * jax.nn.sigmoid(pre)).astype(
-                    o_ref.dtype)
+                if activation == "silu":
+                    pre = pre * jax.nn.sigmoid(pre)
+                o_ref[0, r, at] = pre.astype(o_ref.dtype)
                 beside = mine
             return beside
 
@@ -203,10 +208,11 @@ def _conv_fwd_step(*refs, K: int, halo: bool):
     jax.lax.fori_loop(0, bc // n, rows, 0)
 
 
-def _conv_bwd_step(*refs, K: int, halo: bool):
+def _conv_bwd_step(*refs, K: int, halo: bool, activation: str):
     """One block of the backward, time blocks and a block's lane tiles
     taken LAST first: the pre-activation again from x (nothing else was
-    kept), g = dy · silu′(pre), dx_t = Σ_k kernel[k] · g_{t+(K-1)-k} —
+    kept), g = dy · silu′(pre) — g = dy where nothing was applied, and
+    no pre-activation is formed — dx_t = Σ_k kernel[k] · g_{t+(K-1)-k} —
     the K-1 positions of g after a tile are the start of the tile
     handled just before it, kept rolled from tile to tile and in
     `g_after` from block to block — and the tap and bias gradients
@@ -256,12 +262,15 @@ def _conv_bwd_step(*refs, K: int, halo: bool):
                 taps = [cur[u + 1]] + [
                     _moved(rolled[u + 1][s - 1], rolled[u][s - 1], s, lane)
                     for s in shifts]
-                pre = bias
-                for back, tap in enumerate(taps):
-                    pre = pre + tap * w[K - 1 - back]
-                sg = jax.nn.sigmoid(pre)
-                g = dy_ref[0, r, at[u]].astype(f32) * (
-                    sg * (1.0 + pre * (1.0 - sg)))
+                if activation == "silu":
+                    pre = bias
+                    for back, tap in enumerate(taps):
+                        pre = pre + tap * w[K - 1 - back]
+                    sg = jax.nn.sigmoid(pre)
+                    g = dy_ref[0, r, at[u]].astype(f32) * (
+                        sg * (1.0 + pre * (1.0 - sg)))
+                else:
+                    g = dy_ref[0, r, at[u]].astype(f32)
                 mine = [pltpu.roll(g, _LANES - s, 1) for s in shifts]
                 dx = g * w[K - 1]
                 for s, m, was in zip(shifts, mine, after):
@@ -289,15 +298,20 @@ def _conv_bwd_step(*refs, K: int, halo: bool):
     jax.lax.fori_loop(0, bc // n, rows, 0)
 
 
-def _conv_cost(elements: int, K: int, itemsize: int, passes: int):
+def _conv_cost(elements: int, K: int, itemsize: int, passes: int,
+               activation: str):
     """What a call moves and computes, for XLA's scheduler: `passes`
     tensors of `elements` through HBM (x and y; x, dy and dx), 2K
     operations an element for the taps — again for dx and for the tap
-    gradients in the backward — and one exponential."""
+    gradients in the backward, and a third time there for the
+    pre-activation where an activation was applied — and the
+    activation's own eight with one exponential."""
     from jax.experimental import pallas as pl
 
-    return pl.CostEstimate(flops=(2 * K * (2 * passes - 3) + 8) * elements,
-                           transcendentals=elements,
+    silu = activation == "silu"
+    rounds = 1 if passes == 2 else 2 + silu
+    return pl.CostEstimate(flops=(2 * K * rounds + 8 * silu) * elements,
+                           transcendentals=elements * silu,
                            bytes_accessed=passes * elements * itemsize)
 
 
@@ -337,10 +351,11 @@ def _conv_specs(K: int, geom: ConvGeometry, first: int, at):
         pl.BlockSpec((bc, 1), lambda b, c, t: (c, 0)))
 
 
-@functools.partial(jax.jit, static_argnames=("column", "geom", "interpret"))
+@functools.partial(jax.jit, static_argnames=("column", "geom", "interpret",
+                                             "activation"))
 def _conv_forward(xt, kernel, bias, column: int, geom: ConvGeometry,
-                  interpret: bool):
-    """silu(conv) of the `kernel.shape[1]` channels of xt [B, channels,
+                  interpret: bool, activation: str):
+    """act(conv) of the `kernel.shape[1]` channels of xt [B, channels,
     T] from `column` on: [B, width, T].  Jitted, as the flash calls
     are: a stack of equal layers traces and lowers it once."""
     from jax.experimental import pallas as pl
@@ -352,23 +367,26 @@ def _conv_forward(xt, kernel, bias, column: int, geom: ConvGeometry,
     halo = t_pad > bt
     block, ahead, own, taps, col = _conv_specs(K, geom, first, lambda t: t)
     out = pl.pallas_call(
-        functools.partial(_conv_fwd_step, K=K, halo=halo),
+        functools.partial(_conv_fwd_step, K=K, halo=halo,
+                          activation=activation),
         grid=(B, c_pad // bc, t_pad // bt),
         in_specs=[block] + [ahead] * halo + [taps, col],
         out_specs=own,
         out_shape=jax.ShapeDtypeStruct((B, c_pad, t_pad), xt.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
-        cost_estimate=_conv_cost(B * t_pad * c_pad, K, xt.dtype.itemsize, 2),
+        cost_estimate=_conv_cost(B * t_pad * c_pad, K, xt.dtype.itemsize, 2,
+                                 activation),
         interpret=interpret, name="iotml_conv_fwd",
     )(*[xt] * (1 + halo), _whole(kernel.T, geom),
       _whole(bias[:, None], geom))
     return out[:, :width, :T]
 
 
-@functools.partial(jax.jit, static_argnames=("column", "geom", "interpret"))
+@functools.partial(jax.jit, static_argnames=("column", "geom", "interpret",
+                                             "activation"))
 def _conv_backward(xt, dyt, kernel, bias, column: int, geom: ConvGeometry,
-                   interpret: bool):
+                   interpret: bool, activation: str):
     """(dx [B, width, T], dkernel [K, width], dbias [width]) of
     `_conv_forward` at the cotangent dyt [B, width, T]."""
     from jax.experimental import pallas as pl
@@ -383,7 +401,8 @@ def _conv_backward(xt, dyt, kernel, bias, column: int, geom: ConvGeometry,
     block, ahead, own, taps, col = _conv_specs(
         K, geom, first, lambda t: nt - 1 - t)    # the last block first
     dx, dk, db = pl.pallas_call(
-        functools.partial(_conv_bwd_step, K=K, halo=halo),
+        functools.partial(_conv_bwd_step, K=K, halo=halo,
+                          activation=activation),
         grid=(B, c_pad // bc, nt),
         in_specs=[block] + [ahead] * halo + [own, taps, col],
         out_specs=[own,
@@ -395,7 +414,8 @@ def _conv_backward(xt, dyt, kernel, bias, column: int, geom: ConvGeometry,
         scratch_shapes=[pltpu.VMEM((bc, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        cost_estimate=_conv_cost(B * t_pad * c_pad, K, xt.dtype.itemsize, 3),
+        cost_estimate=_conv_cost(B * t_pad * c_pad, K, xt.dtype.itemsize, 3,
+                                 activation),
         interpret=interpret, name="iotml_conv_bwd",
     )(*[xt] * (1 + halo), dyt, _whole(kernel.T, geom),
       _whole(bias[:, None], geom))
@@ -411,13 +431,14 @@ def _runs(x, splits):
             for c, w in zip(starts, splits)]
 
 
-def _record_conv(kernel: str, B: int, runs) -> None:
+def _record_conv(kernel: str, B: int, runs, K: int, activation: str) -> None:
     """Say what engaged, as `_record` below does: the steps of the runs'
-    calls together, the blocks of the widest run, and how many operands
-    the wrapper copied ahead of the kernels — where a run is not read
-    in place x, sliced out and padded, and in the backward dy, padded
-    with it.  Copies XLA makes of its own around a call, to turn an
-    operand's layout, are not the wrapper's and are not counted:
+    calls together, the blocks of the widest run, the taps, whether the
+    kernels applied an activation, and how many operands the wrapper
+    copied ahead of the kernels — where a run is not read in place x,
+    sliced out and padded, and in the backward dy, padded with it.
+    Copies XLA makes of its own around a call, to turn an operand's
+    layout, are not the wrapper's and are not counted:
     `tests/test_mosaic_aot.py` reads the layouts off a compiled fit."""
     from ..obs import metrics as obs_metrics
 
@@ -428,51 +449,57 @@ def _record_conv(kernel: str, B: int, runs) -> None:
     _, _, widest = max(runs, key=lambda r: r[1])
     obs_metrics.conv_block_t.set(widest.block_t)
     obs_metrics.conv_block_c.set(widest.block_c)
+    obs_metrics.conv_taps.set(K)
+    obs_metrics.conv_activation_fused.set(int(activation != "none"))
     obs_metrics.conv_operand_copies.set(
         sum((not g.in_place) * (1 + (kernel == "bwd")) for g in geoms),
         kernel=kernel)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _conv_silu(x, kernel, bias, splits):
-    return _conv_silu_fwd(x, kernel, bias, splits)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_fused(x, kernel, bias, splits, activation):
+    return _conv_fused_fwd(x, kernel, bias, splits, activation)[0]
 
 
-def _conv_silu_fwd(x, kernel, bias, splits):
+def _conv_fused_fwd(x, kernel, bias, splits, activation):
     from .fused_train import interpret_mode
 
     runs = _runs(x, splits)
-    _record_conv("fwd", x.shape[0], runs)
+    _record_conv("fwd", x.shape[0], runs, kernel.shape[0], activation)
     xt = jnp.swapaxes(x, 1, 2)
     out = tuple(jnp.swapaxes(_conv_forward(
         xt, kernel[:, c:c + w], bias[c:c + w], column=c, geom=g,
-        interpret=interpret_mode()), 1, 2) for c, w, g in runs)
+        interpret=interpret_mode(), activation=activation), 1, 2)
+        for c, w, g in runs)
     return out, (x, kernel, bias)
 
 
-def _conv_silu_bwd(splits, kept, dys):
+def _conv_fused_bwd(splits, activation, kept, dys):
     from .fused_train import interpret_mode
 
     x, kernel, bias = kept
     runs = _runs(x, splits)
-    _record_conv("bwd", x.shape[0], runs)
+    _record_conv("bwd", x.shape[0], runs, kernel.shape[0], activation)
     xt = jnp.swapaxes(x, 1, 2)
     dxs, dks, dbs = zip(*(
         _conv_backward(xt, jnp.swapaxes(dy, 1, 2), kernel[:, c:c + w],
                        bias[c:c + w], column=c, geom=g,
-                       interpret=interpret_mode())
+                       interpret=interpret_mode(), activation=activation)
         for (c, w, g), dy in zip(runs, dys)))
     return (jnp.swapaxes(jnp.concatenate(dxs, axis=1), 1, 2),
             jnp.concatenate(dks, axis=1), jnp.concatenate(dbs))
 
 
-_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+_conv_fused.defvjp(_conv_fused_fwd, _conv_fused_bwd)
 
 
-def causal_conv1d_silu(x, kernel, bias, *, splits=None):
-    """silu(causal_conv1d(x, kernel, bias)) for x [B, T, C], kernel
-    [K, C] and bias [C], split along the channels into runs of `splits`
-    widths (one run of C where None): a tuple of [B, T, width].
+def causal_conv1d_fused(x, kernel, bias=None, *, splits=None,
+                        activation: str = "silu"):
+    """act(causal_conv1d(x, kernel, bias)) for x [B, T, C], kernel
+    [K, C] and bias [C] (None: no bias, zeros to the kernels), split
+    along the channels into runs of `splits` widths (one run of C where
+    None): a tuple of [B, T, width].  `activation` is static, one of
+    `CONV_ACTIVATIONS`: `silu`, or `none` for the sum as it stands.
 
     One Pallas kernel a direction (`iotml_conv_fwd`, `iotml_conv_bwd`,
     once a run) on the transposed arrays, time on the lanes: a forward
@@ -492,7 +519,17 @@ def causal_conv1d_silu(x, kernel, bias, *, splits=None):
     if K - 1 > _LANES:
         raise ValueError(f"{K} taps reach past the {_LANES} positions "
                          "ahead of a block")
-    return _conv_silu(x, kernel, bias, splits)
+    if activation not in CONV_ACTIVATIONS:
+        raise ValueError(f"activation {activation!r} is none of "
+                         f"{CONV_ACTIVATIONS}")
+    if bias is None:
+        bias = jnp.zeros((C,), kernel.dtype)
+    return _conv_fused(x, kernel, bias, splits, activation)
+
+
+def causal_conv1d_silu(x, kernel, bias, *, splits=None):
+    """`causal_conv1d_fused` with the SiLU: Mamba-2's convolution."""
+    return causal_conv1d_fused(x, kernel, bias, splits=splits)
 
 
 def ssd_scan(x, dt, a, b, c, chunk: int):

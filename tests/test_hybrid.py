@@ -18,7 +18,8 @@ import pytest
 from iotml.models.hybrid import HybridConfig, SensorHybrid
 from iotml.ops.attention import attention_reference, flash_attention
 from iotml.ops import ssd
-from iotml.ops.ssd import causal_conv1d, causal_conv1d_silu, ssd_scan
+from iotml.ops.ssd import (causal_conv1d, causal_conv1d_fused,
+                           causal_conv1d_silu, ssd_scan)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = os.path.join(ROOT, "benchmark", "configs",
@@ -128,13 +129,18 @@ def test_causal_conv_matches_the_grouped_convolution(form, short_blocks):
 
 
 @pytest.mark.parametrize("B", [1, 3])
-@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("K,activation", [
+    (1, "silu"), (4, "silu"),     # Mamba-2's: four taps under a SiLU
+    (3, "none")])                 # the gated short convolution's: three
+#                                   taps and the sum as it stands
 @pytest.mark.parametrize("T", [5, 256, 200])   # under a block of 128, a
 @pytest.mark.parametrize("C", [80, 256, 384])  # multiple, a ragged tail
-def test_conv_kernels_match_the_plain_form(C, T, K, B, short_blocks):
-    """Output, dx, dkernel and dbias of `causal_conv1d_silu` against
-    silu(causal_conv1d) and its `jax.grad`, in three runs (the second
-    and the third read from a later row block of x); and which operands
+def test_conv_kernels_match_the_plain_form(C, T, K, activation, B,
+                                           short_blocks):
+    """Output, dx, dkernel and dbias of `causal_conv1d_fused` against
+    act(causal_conv1d) and its `jax.grad`, in three runs (the second
+    and the third read from a later row block of x), under either
+    activation; what the kernels say they applied; and which operands
     the wrapper had to copy."""
     from iotml.obs.metrics import default_registry
 
@@ -146,11 +152,14 @@ def test_conv_kernels_match_the_plain_form(C, T, K, B, short_blocks):
     weights = tuple(f32(B, T, w) for w in splits)
 
     def plain(x, kernel, bias):
-        y = jax.nn.silu(causal_conv1d(x, kernel, bias))
+        y = causal_conv1d(x, kernel, bias)
+        if activation == "silu":
+            y = jax.nn.silu(y)
         return jnp.split(y, [splits[0], splits[0] + n], axis=-1)
 
     def kernels(x, kernel, bias):
-        return causal_conv1d_silu(x, kernel, bias, splits=splits)
+        return causal_conv1d_fused(x, kernel, bias, splits=splits,
+                                   activation=activation)
 
     got, want = (jax.value_and_grad(
         lambda *a: sum(jnp.sum(w * y) for w, y in zip(weights, f(*a))),
@@ -167,6 +176,25 @@ def test_conv_kernels_match_the_plain_form(C, T, K, B, short_blocks):
     assert said['iotml_conv_operand_copies{kernel="bwd"}'] == 2 * copied
     assert said["iotml_conv_block_t"] == 128
     assert said["iotml_conv_block_c"] == {80: 32, 256: 88, 384: 128}[C]
+    assert said["iotml_conv_taps"] == K
+    assert said["iotml_conv_activation_fused"] == (activation == "silu")
+
+
+def test_conv_without_a_bias_is_the_conv_with_a_zero_one():
+    """`bias` None: zeros to the same kernels, no second path — and the
+    SiLU's entry point is the fused one's default."""
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(size=(2, 130, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 16)), jnp.float32)
+    zero = jnp.zeros((16,), jnp.float32)
+    for activation in ("none", "silu"):
+        np.testing.assert_array_equal(
+            causal_conv1d_fused(x, w, activation=activation)[0],
+            causal_conv1d_fused(x, w, zero, activation=activation)[0])
+    np.testing.assert_array_equal(causal_conv1d_silu(x, w, zero)[0],
+                                  causal_conv1d_fused(x, w, zero)[0])
+    _close(causal_conv1d_fused(x, w, activation="none")[0],
+           causal_conv1d(x, w, zero), rtol=1e-6)
 
 
 def test_conv_kernels_refuse_what_they_cannot_place():
@@ -177,6 +205,8 @@ def test_conv_kernels_refuse_what_they_cannot_place():
         causal_conv1d_silu(jnp.zeros((1, 16, 32)), jnp.zeros((4, 24)), bias)
     with pytest.raises(ValueError, match="reach past"):
         causal_conv1d_silu(x, jnp.zeros((130, 24)), bias)
+    with pytest.raises(ValueError, match="is none of"):
+        causal_conv1d_fused(x, jnp.zeros((4, 24)), bias, activation="gelu")
 
 
 # ------------------------------------------------------------ the model

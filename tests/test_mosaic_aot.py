@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from iotml.ops import fused_train
 from iotml.ops.attention import flash_attention
-from iotml.ops.ssd import causal_conv1d_silu
+from iotml.ops.moe import rotary
+from iotml.ops.ssd import causal_conv1d_fused
 
 
 @pytest.fixture(scope="module")
@@ -114,20 +115,44 @@ def test_flash_attention_latent_heads_lower_for_v5e(v5e):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
-@pytest.mark.parametrize("shape,splits,K,copies", [
+def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e):
+    """`lf-train-backlog`'s one attention layer, exactly, forward and
+    backward: two windows of 8,192 positions, 32 query heads over 8
+    key/value heads of 64 (`gh-train-backlog`'s head shape at twice the
+    window), queries and keys turned by rotary positions over the whole
+    head ahead of the kernels, scores x 64^-1/2."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.float32, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.float32, sharding=v5e)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(rotary(q, 1e6), rotary(k, 1e6), v,
+                                       causal=True, scale=0.125))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("shape,splits,K,activation,copies", [
     # `gh-train-backlog`, exactly: the convolved stream as the mixer
     # hands it over, out as x, B and C (the B and C runs read from row
     # blocks 32 and 33 of it)
-    ((1, 4096, 4352), (4096, 128, 128), 4, (0, 0)),
+    ((1, 4096, 4352), (4096, 128, 128), 4, "silu", (0, 0)),
+    # `lf-train-backlog`, exactly: the gated stream of a short
+    # convolution, two windows of 8,192 positions in blocks of 4,096 (a
+    # lane tile ahead of each second block), three taps, the sum stored
+    # as it stands
+    ((2, 8192, 2048), (2048,), 3, "none", (0, 0)),
     # blocks with a lane tile ahead of them: T past the longest block
-    ((2, 16384, 512), (512,), 4, (0, 0)),
+    ((2, 16384, 512), (512,), 4, "silu", (0, 0)),
     # sublanes that divide nothing and positions that fill no lane
     # tile: every run sliced out and padded ahead of the kernels
-    ((2, 203, 256), (200, 56), 4, (2, 4)),
-    ((3, 5, 80), (80,), 2, (1, 2)),
+    ((2, 203, 256), (200, 56), 4, "silu", (2, 4)),
+    ((2, 203, 256), (200, 56), 3, "none", (2, 4)),
+    ((3, 5, 80), (80,), 2, "silu", (1, 2)),
 ])
 def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
-                                    copies):
+                                    activation, copies):
     """`iotml_conv_fwd` and `iotml_conv_bwd` through the entry point and
     `jax.grad`: a lane roll or a dynamic slice Mosaic cannot place, a
     block `conv_geometry` sized past scoped VMEM or a row block it
@@ -141,8 +166,8 @@ def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
                        for s in (shape, (K, C), (C,)))
 
     def loss(x, kernel, bias):
-        return sum(jnp.sum(y * y) for y in causal_conv1d_silu(
-            x, kernel, bias, splits=splits))
+        return sum(jnp.sum(y * y) for y in causal_conv1d_fused(
+            x, kernel, bias, splits=splits, activation=activation))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, kernel, bias).compile().as_text()
@@ -151,10 +176,12 @@ def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
     said = default_registry.collect()
     assert (said['iotml_conv_operand_copies{kernel="fwd"}'],
             said['iotml_conv_operand_copies{kernel="bwd"}']) == copies
+    assert (said["iotml_conv_taps"], said["iotml_conv_activation_fused"]) \
+        == (K, activation == "silu")
 
 
-def _compiled_fit(v5e, model, T: int) -> str:
-    """The scanned fit of `model` — four steps of one window of T
+def _compiled_fit(v5e, model, T: int, B: int = 1) -> str:
+    """The scanned fit of `model` — four steps of B windows of T
     positions, two epochs, Adam and all — compiled for the described
     v5e from shapes alone: its text."""
     import optax
@@ -174,10 +201,10 @@ def _compiled_fit(v5e, model, T: int) -> str:
             a.shape, a.dtype, sharding=v5e), tree)
 
     state = described(jax.eval_shape(fresh, jax.random.PRNGKey(0),
-                                     jnp.zeros((1, T, 18))))
+                                     jnp.zeros((B, T, 18))))
     xs, ys, masks = described(tuple(
         jax.ShapeDtypeStruct(s, jnp.float32)
-        for s in ((4, 1, T, 18), (4, 1, 1, 18), (4, 1))))
+        for s in ((4, B, T, 18), (4, B, 1, 18), (4, B))))
     return make_scanned_fit(model, tx, supervised=True).lower(
         state, xs, ys, masks, epochs=2).compile().as_text()
 
@@ -210,6 +237,35 @@ def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
                           r"\]\{[\d,]+", text))
     assert laid and laid <= {"f32[1,4096,4352]{1,2,0", "f32[1,4352,4096]{2,1,0",
                              "f32[1,4096,8512]{1,2,0", "f32[1,8512,4096]{2,1,0"}
+
+
+def test_the_fit_keeps_the_short_convolutions_stream_time_minor(
+        v5e, monkeypatch):
+    """The gated short convolution hands the kernels `b ⊙ x` and takes
+    y back through the same `swapaxes` as the Mamba mixer: in the
+    scanned fit of `lf-train-backlog`'s first layer (the mixer and the
+    dense MLP at the published widths, two windows of 8,192, Adam and
+    all) compiled for the described v5e, `in_proj`'s [2, 8192, 6144]
+    product and its cotangent exist time-fastest only, and every
+    convolution call reads and writes [2, 2048, 8192] row-major — so no
+    transposing copy stands at a call's edge."""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    model = SensorHybrid(HybridConfig(
+        d_model=2048, layer_types=("short_conv",), mlp_dim=11776,
+        short_conv_width=3, embedding_multiplier=1.0,
+        residual_multiplier=1.0, logits_scaling=1.0))
+    text = _compiled_fit(v5e, model, 8192, B=2)
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "iotml_conv_" in line]
+    assert sum("iotml_conv_fwd" in c for c in calls) == 2   # and recomputed
+    assert sum("iotml_conv_bwd" in c for c in calls) == 1
+    for call in calls:
+        assert "f32[2,2048,8192]{2,1,0" in call.split(" custom-call(")[0]
+    laid = set(re.findall(r"f32\[2,(?:8192,6144|6144,8192)\]\{[\d,]+", text))
+    assert laid and laid <= {"f32[2,8192,6144]{1,2,0",
+                             "f32[2,6144,8192]{2,1,0"}
 
 
 def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):

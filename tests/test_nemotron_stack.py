@@ -273,28 +273,57 @@ KIMI_MOE = {"router": (2048, 64), "router_bias": (64,),
             "shared_out": {"kernel": (2816, 2048)}}
 
 
+NEMOTRON_TREE = {
+    "M": {"norm1": {"scale": (4096,)}, "mixer": {
+        "A_log": (16,), "D": (16,), "conv_bias": (1280,),
+        "conv_kernel": (4, 1280), "dt_bias": (16,),
+        "in_proj": {"kernel": (4096, 2320)}, "norm": {"scale": (1024,)},
+        "out_proj": {"kernel": (1024, 4096)}}},
+    "*": {"norm1": {"scale": (4096,)}, "mixer": {
+        "q": {"kernel": (4096, 512)}, "k": {"kernel": (4096, 128)},
+        "v": {"kernel": (4096, 128)}, "o": {"kernel": (512, 4096)}}},
+    "E": {"norm2": {"scale": (4096,)}, "moe": {
+        "router": (4096, 512), "router_bias": (512,),
+        "latent_in": {"kernel": (4096, 1024)},
+        "latent_out": {"kernel": (1024, 4096)},
+        "experts_in": (8, 1024, 2688), "experts_out": (8, 2688, 1024),
+        "shared_in": {"kernel": (4096, 5376)},
+        "shared_out": {"kernel": (5376, 4096)}}}}
+
+
 @pytest.mark.parametrize("name,parameters", [
     ("sensorformer-granite-4.0-h-micro", 746_546_130),
-    ("sensorformer-kimi-vl-a3b-instruct", 585_080_146)])
+    ("sensorformer-kimi-vl-a3b-instruct", 585_080_146),
+    ("sensorformer-nemotron-3-super-120b-a12b", 566_799_362)])
 def test_the_accepted_configurations_trees_are_what_they_were(name,
                                                               parameters):
     """Path by path and shape by shape, at the published widths: the
     layers that have a mixer AND a feed-forward part build what they
     built before a layer could be one part, a head a share, an expert
-    another form."""
+    another form — and all three what they built before grouped
+    attention could norm and turn its heads, a mixer be a gated short
+    convolution, an expert layer go without its shared expert."""
     mod, cfg = _load("bench_tree_" + name.split("-")[1],
                      os.path.join(CONFIGS, name))
     mod.use(cfg)
     model = SensorHybrid(mod.hybrid_config(cfg))
-    assert model.cfg.head_dim == 0 and model.cfg.moe_latent == 0 \
-        and model.cfg.expert_form == "gated_silu"
+    assert not model.cfg.qk_norm and model.cfg.attn_rope_theta == 0 \
+        and "short_conv" not in model.cfg.layer_types
+    if "nemotron" not in name:
+        assert model.cfg.head_dim == 0 and model.cfg.moe_latent == 0 \
+            and model.cfg.expert_form == "gated_silu"
     tree = jax.tree.map(jnp.shape, jax.eval_shape(
         model.init, jax.random.PRNGKey(0),
         jnp.zeros((1, 16, 18)))["params"])
-    norm = {"scale": (2048,)}
-    want = {"embed": {"kernel": (18, 2048), "bias": (2048,)},
-            "head": {"kernel": (2048, 18), "bias": (18,)}, "norm_f": norm}
-    if "granite" in name:
+    width = cfg["hidden_size"]
+    norm = {"scale": (width,)}
+    want = {"embed": {"kernel": (18, width), "bias": (width,)},
+            "head": {"kernel": (width, 18), "bias": (18,)}, "norm_f": norm}
+    if "nemotron" in name:
+        assert model.cfg.shared_dim == 5376
+        for i, letter in enumerate(cfg["hybrid_override_pattern"]):
+            want[f"layer{i}"] = NEMOTRON_TREE[letter]
+    elif "granite" in name:
         for i, kind in enumerate(cfg["layer_types"][:10]):
             want[f"layer{i}"] = {
                 "norm1": norm, "norm2": norm, "mixer": GRANITE_TREE[kind],
