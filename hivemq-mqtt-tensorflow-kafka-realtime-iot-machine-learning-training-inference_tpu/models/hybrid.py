@@ -19,9 +19,11 @@ out, where a language model has its vocabulary.
 Every block is recomputed in the backward pass (`nn.remat`): a window of
 thousands of positions keeps one [T, d_model] input a block instead of
 each block's projections, decay tiles and MLP activations.  That is the
-right trade for what is large and cheap to make again, and the wrong
-one for what is small and dear: what a kernel or the router made.  So
-the recomputation keeps what goes by one of the names in `KEPT` — the
+right trade for what is large and cheap to make again, the wrong one
+for what is small and dear — what a kernel or the router made — and a
+question of room for what is large AND dear: the feed-forward part's
+first product.  So the recomputation keeps what goes by one of the
+names in `KEPT` — the
 flash kernel's `out` and log-sum-exp, latent attention's rotated q and
 assembled k, the router's selection, its selected scores and the
 dispatch's plan (the sorted order, the routing weights in that order
@@ -31,6 +33,22 @@ a layer against a second run of the forward kernel, of rotary's pads
 and copies, of the `highest` product and of the tiles' walk.  A name
 exists where the part that makes it exists, so the one policy serves
 every stack, and outside a recomputation a name is the identity.
+
+The third case has a name too (`FFN_KEPT`, given by `gated_mlp` to
+`mlp_in`'s and `shared_in`'s output, the pre-activation): hundreds of
+MiB a layer against one of that product's four runs a step — the
+activation is made again from it, elementwise, inside the products
+that read it.  Hundreds of MiB a layer is a question of room, so a
+byte budget decides in WHICH layers the policy lists the name
+(`remat_budget`: a third of what the device's memory holds beyond the
+state — the parameters and Adam's two moments — the second copy of
+the parameters a start holds while that state is built from seeded or
+restored weights, and what `KEPT` keeps; the other two thirds are the
+program's other temporaries' and the allocator's room to spare), from
+the last layer down (`kept_layers`: its value lives shortest between
+the forward pass and the backward).  One rule whose parameter
+is bytes: a stack with more tokens a step or more dense layers keeps
+the product in fewer of them, and never less than `KEPT`.
 
 The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
 `attention`, `mla`, the latent attention of `models.latent_moe`, or
@@ -89,6 +107,13 @@ from .latent_moe import normal as _normal
 #: `ops.moe.route` and `dispatch_plan`, `models.latent_moe.ExpertLayer`
 KEPT = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
         "route_picked", "dispatch_plan", "routed_sum")
+#: and, in the layers the byte budget takes, what
+#: `models.latent_moe.gated_mlp` names: the feed-forward part's first
+#: product, the dense MLP's and the shared expert's alike
+FFN_KEPT = latent_moe.FFN_HIDDEN
+#: a device's memory where the backend reports no `bytes_limit` (the
+#: CPU): a TPU v5e's
+DEVICE_BYTES = 16 * 2 ** 30
 KINDS = ("mamba", "attention", "mla", "short_conv")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
 NONE = "none"   # a layer without that part
@@ -150,6 +175,45 @@ class HybridConfig:
 
     def attn_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+
+def ffn_hidden_bytes(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
+    """The bytes of each layer's first feed-forward product, `mlp_in`'s
+    or `shared_in`'s `[tokens, wide × width]`; 0 for a layer that has
+    none (no feed-forward part, or experts without a shared one)."""
+    width = {"dense_ffn": moe.EXPERT_FORMS["gated_silu"] * cfg.mlp_dim,
+             "moe_ffn": moe.EXPERT_FORMS[cfg.expert_form] * cfg.shared_dim}
+    return tuple(tokens * width.get(ffn, 0) * itemsize
+                 for ffn in cfg.ffn_kinds())
+
+
+def device_bytes() -> int:
+    """What one device's memory holds, by the backend's own count."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit", DEVICE_BYTES)
+
+
+def remat_budget(limit: int, held_bytes: int, kept_bytes: int) -> int:
+    """The bytes a step the large names may keep on a device of `limit`
+    bytes: a third of what a trainer's arrays (`held_bytes`) and
+    `KEPT`'s names leave — the program's other temporaries (1.6-3.8 GB
+    in the benchmark's cells) and room to spare take the rest."""
+    return max(0, limit - held_bytes - kept_bytes) // 3
+
+
+def kept_layers(candidates, budget: int) -> tuple:
+    """The layers whose candidate (bytes a layer, 0: none) is kept:
+    from the LAST down while their sum stays within `budget`, in the
+    stack's order."""
+    kept, total = [], 0
+    for layer in reversed(range(len(candidates))):
+        if not candidates[layer]:
+            continue
+        total += candidates[layer]
+        if total > budget:
+            break
+        kept.append(layer)
+    return tuple(reversed(kept))
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -306,7 +370,7 @@ class SensorHybrid(nn.Module):
         latent_moe.record_reports(self.cfg, reports)
 
     def _kept_bytes(self, x) -> dict:
-        """The bytes a step of x [B, T, features] the blocks keep by
+        """The bytes a step of x [B, T, features] every block keeps by
         name (`KEPT`), by the part that makes them."""
         m = self.cfg
         tokens, size = x.shape[0] * x.shape[1], x.dtype.itemsize
@@ -344,14 +408,32 @@ class SensorHybrid(nn.Module):
         for kind in FFN_KINDS:
             obs_metrics.model_layers.set(ffns.count(kind), kind=kind)
         obs_metrics.remat_blocks.set(len(m.layer_types))
-        for kind, kept in self._kept_bytes(x).items():
+        kept_bytes = self._kept_bytes(x)
+        # what a trainer holds beside the fit's temporaries: the
+        # parameters, Adam's two moments, and at its start a second copy
+        # of the parameters (the seeded or restored weights the state is
+        # built from) — no parameters yet while they are made, and
+        # nothing is recomputed then
+        held = 4 * sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(
+            self.variables.get("params", {})))
+        ffn_bytes = ffn_hidden_bytes(m, x.shape[0] * x.shape[1],
+                                     x.dtype.itemsize)
+        keeps_ffn = kept_layers(ffn_bytes, remat_budget(
+            device_bytes(), held, sum(kept_bytes.values())))
+        kept_bytes["ffn"] = sum(ffn_bytes[i] for i in keeps_ffn)
+        for kind, kept in kept_bytes.items():
             obs_metrics.remat_kept_bytes.set(kept, kind=kind)
+        obs_metrics.remat_kept_layers.set(len(keeps_ffn), kind="ffn")
+        obs_metrics.remat_keepable_layers.set(
+            sum(b > 0 for b in ffn_bytes), kind="ffn")
         h = m.embedding_multiplier * nn.Dense(
             m.d_model, kernel_init=_normal, name="embed")(x)
-        block = nn.remat(HybridBlock, policy=jax.checkpoint_policies
-                         .save_only_these_names(*KEPT))
+        names = jax.checkpoint_policies.save_only_these_names
+        block = {False: nn.remat(HybridBlock, policy=names(*KEPT)),
+                 True: nn.remat(HybridBlock, policy=names(*KEPT, FFN_KEPT))}
         for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
-            h = block(kind, m, self.attn_mode, ffn, name=f"layer{i}")(h)
+            h = block[i in keeps_ffn](kind, m, self.attn_mode, ffn,
+                                      name=f"layer{i}")(h)
         h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)
         return nn.Dense(self.features, kernel_init=_normal,
                         name="head")(h) / m.logits_scaling

@@ -49,6 +49,8 @@ from ..ops import moe
 from ..ops.attention import attention_reference, flash_attention
 
 normal = nn.initializers.normal(0.02)
+#: the name `gated_mlp` gives its first product's output
+FFN_HIDDEN = "ffn_hidden"
 #: the variable collection a layer's data-dependent counts ride out in
 REPORTS = "reports"
 
@@ -61,8 +63,13 @@ def gated_mlp(u, width: int, d_model: int, name: str = "mlp",
               form: str = "gated_silu"):
     """(silu(g) ⊙ v) W_out with [g, v] = u W_in — or, under another
     `form` of `ops.moe.EXPERT_FORMS`, that one: `<name>_in`,
-    `<name>_out` in the calling module's scope."""
-    hidden = dense(moe.EXPERT_FORMS[form] * width, f"{name}_in")(u)
+    `<name>_out` in the calling module's scope.  The first product's
+    output goes by a name: a block's recomputation keeps it in the
+    layers whose policy lists the name (`models.hybrid`: a byte budget
+    decides) and reads it there in place of a second `u W_in`; the
+    activation is elementwise and is made again from it."""
+    hidden = checkpoint_name(
+        dense(moe.EXPERT_FORMS[form] * width, f"{name}_in")(u), FFN_HIDDEN)
     return dense(d_model, f"{name}_out")(moe.expert_hidden(hidden, form))
 
 

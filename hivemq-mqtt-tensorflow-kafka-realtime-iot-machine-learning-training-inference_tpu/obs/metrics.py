@@ -574,7 +574,18 @@ remat_kept_bytes = default_registry.gauge(
     "the forward pass by name, by kind (flash: the kernel's out and lse "
     "| latent_qk: latent attention's rotated q and assembled k | "
     "router: the selection, the selected scores and the plan | "
-    "experts: the routed sum in a latent)")
+    "experts: the routed sum in a latent | ffn: the feed-forward part's "
+    "first product, mlp_in's and shared_in's output, in the layers a "
+    "byte budget takes)")
+remat_kept_layers = default_registry.gauge(
+    "iotml_remat_kept_layers",
+    "layers of the last traced model whose recomputation keeps a large "
+    "value under the byte budget, by kind (ffn: the feed-forward part's "
+    "first product)")
+remat_keepable_layers = default_registry.gauge(
+    "iotml_remat_keepable_layers",
+    "layers of the last traced model that make such a value, kept or "
+    "not, by kind (ffn: a dense MLP or a shared expert)")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "
@@ -658,7 +669,9 @@ DECLARED_METRIC_LABELS = {
     "online_drifts": ("detector",),
     "prefetch_occupancy": ("loop",),
     "quorum_hwm_lag": ("partition", "topic"),
+    "remat_keepable_layers": ("kind",),
     "remat_kept_bytes": ("kind",),
+    "remat_kept_layers": ("kind",),
     "replica_lag": ("topic",),
     "rest_request_seconds": ("route",),
     "rest_requests": ("route", "code"),

@@ -309,6 +309,10 @@ def test_a_tiny_fit_says_what_engaged():
     assert got['iotml_model_layers{kind="mamba"}'] == 3
     assert got['iotml_model_layers{kind="attention"}'] == 1
     assert got["iotml_remat_blocks"] == 4
+    # every layer's MLP keeps its first product [42, 2 x 128] here
+    assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 4 * 42 * 256 * 4
+    assert got['iotml_remat_kept_layers{kind="ffn"}'] \
+        == got['iotml_remat_keepable_layers{kind="ffn"}'] == 4
 
 
 # ----------------------------- grouped heads and a scale through the kernels

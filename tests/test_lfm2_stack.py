@@ -351,6 +351,11 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
             ("router", "experts", "flash", "latent_qk")] \
         == [4 * moe.plan_kept_bytes(80, 3, 4, 16), 0, 0, 0]
+    # and under the byte budget the one dense MLP's first product
+    # [80, 2 x 96]: an expert layer without a shared expert makes none
+    assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 80 * 192 * 4
+    assert got['iotml_remat_kept_layers{kind="ffn"}'] \
+        == got['iotml_remat_keepable_layers{kind="ffn"}'] == 1
     # the scopes ride the program's operations
     model = SensorHybrid(mod.hybrid_config(cfg))
     text = jax.jit(lambda p: model.apply(

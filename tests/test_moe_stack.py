@@ -456,6 +456,12 @@ def test_a_tiny_fit_says_what_engaged(ref):
             ("router", "latent_qk", "flash", "experts")] \
         == [2 * moe.plan_kept_bytes(80, 3, 4, 16),
             3 * 2 * 2 * 40 * 4 * 24 * 4, 0, 0]
+    # and under the byte budget, here in every layer that makes one: the
+    # dense MLP's first product [80, 2 x 96], two shared experts' [80, 2 x 24]
+    assert got['iotml_remat_kept_bytes{kind="ffn"}'] \
+        == 80 * (192 + 2 * 48) * 4
+    assert got['iotml_remat_kept_layers{kind="ffn"}'] \
+        == got['iotml_remat_keepable_layers{kind="ffn"}'] == 3
     assert got["iotml_latent_assembled_operands"] == 1   # k, by the mixer
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16

@@ -385,6 +385,11 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
             ("router", "experts", "flash", "latent_qk")] \
         == [2 * moe.plan_kept_bytes(80, 5, 4, 16), 2 * 80 * 32 * 4, 0, 0]
+    # and under the byte budget the two shared experts' first product,
+    # [80, 48] each (non-gated: one product's width)
+    assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 2 * 80 * 48 * 4
+    assert got['iotml_remat_kept_layers{kind="ffn"}'] \
+        == got['iotml_remat_keepable_layers{kind="ffn"}'] == 2
     assert got["iotml_moe_latent_dim"] == 32
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16

@@ -1,16 +1,26 @@
 """What a block's recomputation keeps (`models.hybrid.KEPT`): the flash
 kernel's `out` and log-sum-exp, the router's selection and plan, the
-routed sum in a latent — by name, under `nn.remat`'s policy.
+routed sum in a latent — by name, under `nn.remat`'s policy — and, in
+the layers a byte budget takes (`FFN_KEPT`, `kept_layers`), the
+feed-forward part's first product.
 
-Three properties, each over the three shapes of stack the benchmark
-trains (Mamba + grouped-query attention; latent attention + experts at
-the stream's width; one-part layers + experts in a latent), at a tiny
-preset on the CPU: the policy changes no gradient and no report; the
+Three properties, each over the four shapes of stack the benchmark
+trains with recomputed blocks (Mamba + grouped-query attention; latent
+attention + experts at the stream's width; one-part layers + experts in
+a latent; gated short convolutions + experts without a shared one), at
+a tiny preset on the CPU, with the budget held to what keeps that product in
+every layer that makes one, in the last of them and in none: the policy
+changes no gradient and no report; the
 router's hand-written backward is autodiff of its forward; and in the
 gradient's jaxpr the kernel and top-k appear once a layer, the `highest`
 product three times and a sort twice (the plan's, and the one that
 brings the routing weights' cotangents back) — with no gather and no
-scatter-add of the routing weights' scalars."""
+scatter-add of the routing weights' scalars — and `mlp_in`'s or
+`shared_in`'s product three times in a layer that keeps it, four times
+in one that does not.  Then the counter beside the arrays the policy
+saves, and the budget's rule itself, a pure function."""
+
+import re
 
 import flax.linen as nn
 import jax
@@ -36,8 +46,14 @@ STACKS = {
         ffn_types=("none", "moe_ffn", "none", "moe_ffn", "none"),
         num_heads=2, num_kv_heads=1, head_dim=16, moe_latent=32,
         expert_form="relu2", **dict(_EXPERTS, top_k=5)), 1, 2),
+    "short_conv_no_share": (HybridConfig(
+        layer_types=("short_conv", "attention", "short_conv"),
+        ffn_types=("dense_ffn", "moe_ffn", "moe_ffn"), qk_norm=True,
+        attn_rope_theta=10000.0, **dict(_EXPERTS, shared_dim=0)), 1, 2),
 }
 MODES = ("flash_interpret", "dense")
+#: the layers whose feed-forward product the budget is held to take
+KEEPS = ("all", "last", "none")
 
 
 def _batch(B=2, T=40, seed=0):
@@ -47,6 +63,21 @@ def _batch(B=2, T=40, seed=0):
             jnp.ones((B,), jnp.float32))
 
 
+def _hold_budget(monkeypatch, cfg, x, keep) -> tuple:
+    """The byte budget set to what keeps the feed-forward part's first
+    product as `keep` says; the layers that then keep it, and the
+    layers that make one."""
+    candidates = hybrid.ffn_hidden_bytes(cfg, x.shape[0] * x.shape[1],
+                                         x.dtype.itemsize)
+    makes = tuple(i for i, b in enumerate(candidates) if b)
+    budget = {"all": sum(candidates), "none": 0,
+              "last": candidates[makes[-1]]}[keep]
+    monkeypatch.setattr(hybrid, "remat_budget", lambda *sizes: budget)
+    keeps = hybrid.kept_layers(candidates, budget)
+    assert keeps == {"all": makes, "last": makes[-1:], "none": ()}[keep]
+    return keeps, makes
+
+
 def _grads_and_reports(model, params, batch):
     loss = make_loss_fn(model, supervised=True)
     (_, aux), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(
@@ -54,16 +85,19 @@ def _grads_and_reports(model, params, batch):
     return grads, aux[2:]
 
 
+@pytest.mark.parametrize("keep", KEEPS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("stack", STACKS)
 def test_the_policy_changes_no_gradient_and_no_report(monkeypatch, stack,
-                                                      mode):
+                                                      mode, keep):
     """The kept values are the ones the recomputation would make: the
     loss's gradients and the expert layers' `reports` with the policy
-    equal those of the same model under plain `nn.remat`."""
+    equal those of the same model under plain `nn.remat`, whichever
+    layers keep their feed-forward product."""
     model = SensorHybrid(STACKS[stack][0], attn_mode=mode)
     batch = _batch()
     params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
+    _hold_budget(monkeypatch, STACKS[stack][0], batch[0], keep)
     kept, kept_reports = _grads_and_reports(model, params, batch)
 
     plain = nn.remat
@@ -138,6 +172,12 @@ def _what(assignments):
         if name == "pallas_call":
             return eqn.params["name"]
         if name == "dot_general":
+            # a feed-forward part's first product, forward, recomputed
+            # or backward, by the names its module and layer trace under
+            where = str(eqn.source_info.name_stack)
+            first = re.search(r"\b(?:mlp|shared)_in\b", where)
+            if first:
+                return "ffn_in:" + re.search(r"\blayer(\d+)\b", where)[1]
             precision = eqn.params["precision"]
             return "highest" if precision is not None and all(
                 p == jax.lax.Precision.HIGHEST for p in precision) else None
@@ -149,21 +189,26 @@ def _what(assignments):
     return found
 
 
+@pytest.mark.parametrize("keep", KEEPS)
 @pytest.mark.parametrize("stack", STACKS)
 def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
-                                                              stack):
+                                                              stack, keep):
     """In the gradient's jaxpr: `iotml_flash_fwd` once an attention
     layer (as often as the backward kernel), `top_k` once an expert
     layer, `sort` twice (the plan's, which carries the routing weights
     to their sorted places, and the one by the sorted order that brings
     their cotangents back), the `highest` product three times (the
     forward's and the backward's two), and the routing weights neither
-    gathered nor scatter-added — and under plain `nn.remat`, the
-    recomputed forward's top-k, sort and product beside them."""
+    gathered nor scatter-added; `mlp_in`'s or `shared_in`'s product
+    three times in a layer that keeps its output (forward and the
+    backward's two) and four times in one that makes it again — and
+    under plain `nn.remat`, the recomputed forward's top-k, sort and
+    products beside them."""
     cfg, attention, routed = STACKS[stack]
     model = SensorHybrid(cfg, attn_mode="flash_interpret")
     batch = _batch()
     params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
+    keeps, makes = _hold_budget(monkeypatch, cfg, batch[0], keep)
     loss = make_loss_fn(model, supervised=True)
     what = _what(batch[0].shape[0] * batch[0].shape[1] * cfg.top_k)
 
@@ -181,6 +226,8 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     assert of(kept, "top_k", "sort", "highest") \
         == (routed, 2 * routed, 3 * routed)
     assert of(kept, "gather", "scatter-add") == (0, 0)
+    assert {k: n for k, n in kept.items() if k.startswith("ffn_in:")} \
+        == {f"ffn_in:{i}": 3 if i in keeps else 4 for i in makes}
 
     plain = nn.remat
     monkeypatch.setattr(hybrid.nn, "remat", lambda cls, policy: plain(cls))
@@ -189,3 +236,93 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     assert of(again, "top_k", "sort", "highest") \
         == (2 * routed, 3 * routed, 4 * routed)
     assert of(again, "gather", "scatter-add") == (0, 0)
+    assert of(again, *(f"ffn_in:{i}" for i in makes)) == (4,) * len(makes)
+
+
+def _saved(jaxpr, name, found):
+    """The avals of the values named `name` that a recomputation in
+    `jaxpr` reads back from the forward pass: the ones the policy saved
+    (jax hands a saved residual on through a `reduce_precision`)."""
+    read = {id(v) for eqn in jaxpr.eqns
+            if eqn.primitive.name in ("remat2", "checkpoint")
+            for v in eqn.invars}
+    through = {id(eqn.invars[0]): id(eqn.outvars[0]) for eqn in jaxpr.eqns
+               if eqn.primitive.name == "reduce_precision"}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name" and eqn.params["name"] == name:
+            out = id(eqn.outvars[0])
+            if through.get(out, out) in read:
+                found.append(eqn.outvars[0].aval)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _saved(sub, name, found)
+    return found
+
+
+@pytest.mark.parametrize("keep", KEEPS)
+@pytest.mark.parametrize("stack", STACKS)
+def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
+                                                     keep):
+    """`iotml_remat_kept_bytes{kind="ffn"}` is the bytes of the arrays
+    named `FFN_KEPT` that the gradient's recomputations read back from
+    the forward pass, `iotml_remat_kept_layers` their count, and
+    `iotml_remat_keepable_layers` the layers that make one."""
+    from iotml.obs.metrics import default_registry
+
+    cfg = STACKS[stack][0]
+    model = SensorHybrid(cfg, attn_mode="flash_interpret")
+    batch = _batch()
+    params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
+    keeps, makes = _hold_budget(monkeypatch, cfg, batch[0], keep)
+    jax.clear_caches()
+    saved = _saved(jax.make_jaxpr(jax.grad(
+        make_loss_fn(model, supervised=True), has_aux=True))(
+            params, *batch).jaxpr, hybrid.FFN_KEPT, [])
+    said = default_registry.collect()
+    assert said['iotml_remat_kept_bytes{kind="ffn"}'] \
+        == sum(a.size * a.dtype.itemsize for a in saved)
+    assert said['iotml_remat_kept_layers{kind="ffn"}'] == len(saved) \
+        == len(keeps)
+    assert said['iotml_remat_keepable_layers{kind="ffn"}'] == len(makes)
+    assert bool(saved) == (keep != "none")
+
+
+@pytest.mark.parametrize("candidates, budget, kept", [
+    ((40, 40, 40), 39, ()),               # nothing fits: no layer
+    ((40, 40, 40), 80, (1, 2)),           # the last two, in the stack's order
+    ((40, 40, 40), 119, (1, 2)),          # between two sums: still two
+    ((40, 40, 40), 120, (0, 1, 2)),       # all fit: all
+    ((90, 20, 20), 100, (1, 2)),          # stops at the first that does not
+    ((0, 40, 0, 40, 0), 80, (1, 3)),      # a layer without one is no candidate
+    ((0, 40, 0, 40, 0), 79, (3,)),
+    ((), 10, ()), ((0, 0), 10, ()), ((40,), 0, ()),
+])
+def test_the_budget_takes_the_last_layers_that_fit(candidates, budget, kept):
+    assert hybrid.kept_layers(candidates, budget) == kept
+
+
+@pytest.mark.parametrize("overrides, tokens, want", [
+    ({}, 80, (80 * 256 * 4,) * 3),        # gated: [g, v] of mlp_dim each
+    (dict(ffn_types=("dense_ffn", "none", "moe_ffn"), shared_dim=48), 10,
+     (10 * 256 * 4, 0, 10 * 96 * 4)),     # `none`: no candidate
+    (dict(ffn_types=("moe_ffn",) * 3, shared_dim=0), 80, (0, 0, 0)),
+    (dict(ffn_types=("moe_ffn",) * 3, shared_dim=48, expert_form="relu2"),
+     80, (80 * 48 * 4,) * 3),             # non-gated: one product's width
+])
+def test_a_layer_without_a_first_product_is_no_candidate(overrides, tokens,
+                                                         want):
+    cfg = HybridConfig(**overrides)
+    assert hybrid.ffn_hidden_bytes(cfg, tokens, 4) == want
+    assert hybrid.kept_layers(want, sum(want)) \
+        == tuple(i for i, b in enumerate(want) if b)
+
+
+@pytest.mark.parametrize("limit, held, kept, budget", [
+    (1000, 400, 300, 100),    # a third of what the arrays and the names leave
+    (1000, 1200, 0, 0),       # arrays past the device's memory: nothing
+    (hybrid.DEVICE_BYTES, 2 ** 30, 0, 5 * 2 ** 30),
+])
+def test_the_budget_is_a_third_of_what_the_arrays_leave(limit, held, kept,
+                                                        budget):
+    assert hybrid.remat_budget(limit, held, kept) == budget
+    # this backend reports no `bytes_limit`: the constant stands in
+    assert hybrid.device_bytes() == hybrid.DEVICE_BYTES
