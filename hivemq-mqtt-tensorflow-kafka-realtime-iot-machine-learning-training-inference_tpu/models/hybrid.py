@@ -72,6 +72,22 @@ weight-only RMSNorm over a head's features, one weight vector for
 all query heads and one for all key heads; `attn_rope_theta` (0: no
 positions) rotary positions over the whole head, after the norms.
 
+A stack may be a **loop** (`loop_steps` > 1, a looped language
+model's): ONE set of layers run `loop_steps` times a step, the final
+norm closing every pass — its output is what the next pass starts from
+— and one head and one exit gate reading every pass's output, so the
+model has `loop_steps` outputs and as many gate values a position, and
+names its own objective (`expected_loss`: the passes' losses under the
+exit distribution the gates define, less β times its entropy), which
+`train.loop.make_loss_fn` takes in the masked mean squared error's
+place.  The passes are a scan over one pass's program (`nn.scan`, the
+parameters broadcast): what the blocks keep by name comes back stacked
+a pass, a shared leaf's gradient is the backward scan's carry — whole
+only when the FIRST pass's backward ends, so every gradient lives
+through the backward pass — and every byte count below is a step's,
+over all passes.  Its blocks may norm each part's OUTPUT as well
+(`post_norms`: sandwich norms, `h + N(part(N(h)))`).
+
 The heads a layer holds may be a share of the model's (one chip's,
 where the model's mixers are divided over chips): `num_heads` and
 `ssm_heads` count the heads held, `head_dim` and `ssm_head_dim` state
@@ -86,12 +102,14 @@ to window, and one-step decoding against it, is the scorer's later work
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs import metrics as obs_metrics
 from ..ops import moe
@@ -117,6 +135,10 @@ DEVICE_BYTES = 16 * 2 ** 30
 KINDS = ("mamba", "attention", "mla", "short_conv")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
 NONE = "none"   # a layer without that part
+#: what a model's own objective reports, beside its layers' collections
+#: (`train.loop.make_loss_fn`), and a looped stack's two entries there
+OBJECTIVE = "objective"
+PASS_LOSS, EXIT_MASS = "pass_loss", "exit_mass"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +191,13 @@ class HybridConfig:
     # the latent the routed ones act in (0: at the stream's width)
     expert_form: str = "gated_silu"
     moe_latent: int = 0
+    # a looped stack: the passes a step makes over its one set of layers
+    # (1: none, and no exit gate), whether a block norms each part's
+    # OUTPUT ahead of the residual add (sandwich norms), and the weight
+    # of the exit distribution's entropy in the expected loss
+    loop_steps: int = 1
+    post_norms: bool = False
+    exit_entropy_weight: float = 0.1
 
     def ffn_kinds(self) -> Tuple[str, ...]:
         return self.ffn_types or ("dense_ffn",) * len(self.layer_types)
@@ -178,12 +207,14 @@ class HybridConfig:
 
 
 def ffn_hidden_bytes(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
-    """The bytes of each layer's first feed-forward product, `mlp_in`'s
-    or `shared_in`'s `[tokens, wide × width]`; 0 for a layer that has
+    """The bytes a step of each layer's first feed-forward product,
+    `mlp_in`'s or `shared_in`'s `[tokens, wide × width]` in every pass
+    the stack makes over the layer (a policy is a layer's: it keeps the
+    product in all its applications or in none); 0 for a layer that has
     none (no feed-forward part, or experts without a shared one)."""
     width = {"dense_ffn": moe.EXPERT_FORMS["gated_silu"] * cfg.mlp_dim,
              "moe_ffn": moe.EXPERT_FORMS[cfg.expert_form] * cfg.shared_dim}
-    return tuple(tokens * width.get(ffn, 0) * itemsize
+    return tuple(cfg.loop_steps * tokens * width.get(ffn, 0) * itemsize
                  for ffn in cfg.ffn_kinds())
 
 
@@ -328,6 +359,8 @@ class HybridBlock(nn.Module):
     @nn.compact
     def __call__(self, h):
         m = self.cfg
+        obs_metrics.model_post_norms.set(
+            m.post_norms * ((self.kind != NONE) + (self.ffn != NONE)))
         if self.kind != NONE:
             u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
             if self.kind == "mamba":
@@ -339,7 +372,7 @@ class HybridBlock(nn.Module):
                     else GroupedAttention
                 with jax.named_scope("attn"):
                     mixed = attention(m, self.attn_mode, name="mixer")(u)
-            h = h + m.residual_multiplier * mixed
+            h = h + m.residual_multiplier * self._post_norm(mixed, 1)
         if self.ffn == NONE:
             return h
         u = nn.RMSNorm(epsilon=m.eps, name="norm2")(h)
@@ -348,7 +381,17 @@ class HybridBlock(nn.Module):
         else:
             with jax.named_scope("mlp"):
                 out = gated_mlp(u, m.mlp_dim, m.d_model)
-        return h + m.residual_multiplier * out
+        return h + m.residual_multiplier * self._post_norm(out, 2)
+
+    def _post_norm(self, part, which: int):
+        """A sandwich block norms a part's output too, ahead of the
+        residual add: `post_norm1` the mixer's, `post_norm2` the
+        feed-forward part's."""
+        if not self.cfg.post_norms:
+            return part
+        with jax.named_scope("post_norm"):
+            return nn.RMSNorm(epsilon=self.cfg.eps,
+                              name=f"post_norm{which}")(part)
 
 
 class SensorHybrid(nn.Module):
@@ -362,22 +405,48 @@ class SensorHybrid(nn.Module):
     def report_collections(self) -> Tuple[str, ...]:
         """The variable collections this model's layers report data in
         (`Trainer` reads them back with the losses); none without an
-        expert layer, and the fit is then the program it always was."""
+        expert layer.  A model with neither this nor an `objective`
+        reports nothing, and its fit is the program it always was."""
         return (latent_moe.REPORTS,) if "moe_ffn" in self.cfg.ffn_kinds() \
             else ()
 
+    @property
+    def objective(self):
+        """A looped stack's own loss over `(outputs, y, mask)` — the
+        expectation of the passes' losses under the exit distribution,
+        less its entropy (`expected_loss`) — which `train.loop
+        .make_loss_fn` takes in place of the masked mean squared error
+        of one output; None where the stack makes one pass."""
+        if self.cfg.loop_steps == 1:
+            return None
+        return functools.partial(expected_loss,
+                                 beta=self.cfg.exit_entropy_weight)
+
     def record_reports(self, reports) -> None:
-        latent_moe.record_reports(self.cfg, reports)
+        """What a fit's reports say, into the registry: the expert
+        layers' collection and, from a looped stack's objective, the
+        passes' losses and exit masses, `[epochs, batches, passes]`."""
+        latent_moe.record_reports(self.cfg,
+                                  reports.get(latent_moe.REPORTS, {}))
+        said = reports.get(OBJECTIVE, {})
+        for name, gauge in ((PASS_LOSS, obs_metrics.loop_pass_loss),
+                            (EXIT_MASS, obs_metrics.loop_exit_mass)):
+            if name in said:
+                means = np.asarray(said[name], np.float64).reshape(
+                    -1, self.cfg.loop_steps).mean(axis=0)
+                for t, value in enumerate(means):
+                    gauge.set(float(value), kind=f"pass{t + 1}")
 
     def _kept_bytes(self, x) -> dict:
-        """The bytes a step of x [B, T, features] every block keeps by
-        name (`KEPT`), by the part that makes them."""
+        """The bytes a step of x [B, T, features] the blocks keep by
+        name (`KEPT`) over all the stack's passes, by the part that
+        makes them."""
         m = self.cfg
         tokens, size = x.shape[0] * x.shape[1], x.dtype.itemsize
         flash = {"attention": m.attn_head_dim(), "mla": m.v_dim} \
             if self.attn_mode != "dense" else {}
         expert_layers = m.ffn_kinds().count("moe_ffn")
-        return {
+        once = {
             # out [B, T, H, Dv] and a float32 lse [B, H, T]
             "flash": sum(tokens * m.num_heads * (flash[kind] * size + 4)
                          for kind in m.layer_types if kind in flash),
@@ -388,9 +457,21 @@ class SensorHybrid(nn.Module):
                 tokens, m.top_k, m.experts_held[1], m.experts),
             "experts": expert_layers * tokens * m.moe_latent * size,
         }
+        kept = {kind: m.loop_steps * kept for kind, kept in once.items()}
+        # and what the passes' scan stacks beside the names, a pass:
+        # every block's input and the closing's — in a stack that is no
+        # loop a block's input is one of the program's other
+        # temporaries, which `remat_budget` leaves room for
+        kept["loop_inputs"] = (m.loop_steps > 1) * m.loop_steps \
+            * (len(m.layer_types) + 1) * tokens * m.d_model * size
+        return kept
 
     @nn.compact
     def __call__(self, x):
+        """→ the next record's prediction at every position
+        `[B, T, features]`; from a looped stack `(predictions
+        [passes, B, T, features], exit-gate logits [passes, B, T])`,
+        every pass's through the one head and the one gate."""
         m = self.cfg
         ffns = m.ffn_kinds()
         unknown = (set(m.layer_types) - set(KINDS + (NONE,))) \
@@ -401,21 +482,29 @@ class SensorHybrid(nn.Module):
                 f"layer_types {m.layer_types} and ffn_types {m.ffn_types}: "
                 f"known kinds are {KINDS} and {FFN_KINDS}, one of each a "
                 f"layer, of which one may be {NONE!r}")
+        if m.loop_steps < 1:
+            raise ValueError(f"loop_steps {m.loop_steps}: a stack makes at "
+                             f"least one pass over its layers")
         # what engaged, at trace time (as the flash geometry is said)
         for kind in KINDS:
             obs_metrics.model_layers.set(m.layer_types.count(kind),
                                          kind=kind)
         for kind in FFN_KINDS:
             obs_metrics.model_layers.set(ffns.count(kind), kind=kind)
+        obs_metrics.model_loop_steps.set(m.loop_steps)
         obs_metrics.remat_blocks.set(len(m.layer_types))
         kept_bytes = self._kept_bytes(x)
         # what a trainer holds beside the fit's temporaries: the
         # parameters, Adam's two moments, and at its start a second copy
         # of the parameters (the seeded or restored weights the state is
-        # built from) — no parameters yet while they are made, and
-        # nothing is recomputed then
-        held = 4 * sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(
-            self.variables.get("params", {})))
+        # built from) — and under a loop every gradient, whole only when
+        # the backward of the FIRST pass ends, where a leaf used once a
+        # step is updated and dropped as its gradient arrives; no
+        # parameters yet while they are made, and nothing is recomputed
+        # then
+        held = (4 + (m.loop_steps > 1)) * sum(
+            p.size * p.dtype.itemsize for p in jax.tree.leaves(
+                self.variables.get("params", {})))
         ffn_bytes = ffn_hidden_bytes(m, x.shape[0] * x.shape[1],
                                      x.dtype.itemsize)
         keeps_ffn = kept_layers(ffn_bytes, remat_budget(
@@ -431,9 +520,73 @@ class SensorHybrid(nn.Module):
         names = jax.checkpoint_policies.save_only_these_names
         block = {False: nn.remat(HybridBlock, policy=names(*KEPT)),
                  True: nn.remat(HybridBlock, policy=names(*KEPT, FFN_KEPT))}
-        for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
-            h = block[i in keeps_ffn](kind, m, self.attn_mode, ffn,
-                                      name=f"layer{i}")(h)
-        h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)
-        return nn.Dense(self.features, kernel_init=_normal,
-                        name="head")(h) / m.logits_scaling
+
+        def layers(stack, h):
+            # the modules are `stack`'s: this model's, or its stand-in
+            # under a lifted loop
+            for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
+                h = block[i in keeps_ffn](kind, m, stack.attn_mode, ffn,
+                                          name=f"layer{i}")(h)
+            return h
+
+        def closing(stack, h):
+            """The final norm, which closes a pass, and the head."""
+            h = nn.RMSNorm(epsilon=m.eps, name="norm_f")(h)
+            return h, nn.Dense(stack.features, kernel_init=_normal,
+                               name="head")(h) / m.logits_scaling
+
+        if m.loop_steps == 1:
+            return closing(self, layers(self, h))[1]
+
+        def gated_closing(stack, h):
+            h, pred = closing(stack, h)
+            with jax.named_scope("exit_gate"):
+                gate = nn.Dense(1, kernel_init=_normal, name="exit_gate")(h)
+            return h, (pred, gate[..., 0])
+
+        def looped_pass(stack, h, _):
+            # what a pass leaves is what the next one starts from; its
+            # closing is recomputed too: kept, the norm's four values of
+            # the stream's size would be stacked a pass for one input
+            return nn.remat(gated_closing)(stack, layers(stack, h))
+
+        # ONE set of parameters, `loop_steps` passes: a scan whose body
+        # is one pass — the program of an L-layer stack; what the blocks
+        # keep by name comes back stacked a pass, and a shared leaf's
+        # gradient is the backward scan's carry
+        _, outputs = nn.scan(
+            looped_pass, variable_broadcast="params",
+            variable_axes={latent_moe.REPORTS: 0},
+            split_rngs={"params": False}, length=m.loop_steps)(self, h, None)
+        return outputs
+
+
+def exit_log_probs(gates):
+    """log p of the exit distribution a position: from the gates' logits
+    `[passes, …]` with λ = sigmoid, `p_t = λ_t ∏_{j<t} (1 − λ_j)` and
+    the last pass takes what is left, `p_R = ∏_{j<R} (1 − λ_j)`."""
+    stay = jax.nn.log_sigmoid(-gates)
+    before = jnp.cumsum(stay, axis=0) - stay     # Σ_{j<t} log(1 − λ_j)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(gates[:-1]) + before[:-1], before[-1:]])
+
+
+def expected_loss(outputs, y, mask, beta: float):
+    """A looped stack's objective: the masked mean over windows and
+    positions of `Σ_t p_t ℓ_t − β H(p)`, ℓ_t pass t's squared error
+    against the target (a mean over the fields), p the exit
+    distribution and H its entropy → (loss, the last pass's prediction,
+    {the passes' mean losses, the passes' mean exit masses})."""
+    preds, gates = outputs                        # [R, B, T, F], [R, B, T]
+    per_pass = jnp.mean(jnp.square(preds - y), axis=-1)
+    log_p = exit_log_probs(gates.astype(jnp.float32))
+    p = jnp.exp(log_p)
+    entropy = -jnp.sum(p * log_p, axis=0)
+    m = mask[:, None]
+    denom = jnp.maximum(jnp.sum(m) * per_pass.shape[-1], 1.0)
+
+    def mean(v):   # over the valid windows' positions
+        return jnp.sum(v * m, axis=(-2, -1)) / denom
+
+    loss = mean(jnp.sum(p * per_pass, axis=0) - beta * entropy)
+    return loss, preds[-1], {PASS_LOSS: mean(per_pass), EXIT_MASS: mean(p)}

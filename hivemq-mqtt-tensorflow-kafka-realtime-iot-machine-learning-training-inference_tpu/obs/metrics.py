@@ -516,6 +516,24 @@ model_layers = default_registry.gauge(
     "layers of the last traced hybrid model, by the kind of their mixer "
     "(mamba | attention | mla | short_conv) and of their feed-forward "
     "part (dense_ffn | moe_ffn)")
+model_loop_steps = default_registry.gauge(
+    "iotml_model_loop_steps",
+    "passes a step the last traced hybrid model makes over its one set of "
+    "layers (1: a stack that is no loop)")
+model_post_norms = default_registry.gauge(
+    "iotml_model_post_norms",
+    "norms the last traced hybrid block applied to its parts' OUTPUTS "
+    "ahead of the residual adds (sandwich norms: one a part, else 0)")
+# a looped stack's objective (models/hybrid.py `expected_loss`): DATA,
+# read back with a fit's losses at its one sync, the last fit's means
+loop_exit_mass = default_registry.gauge(
+    "iotml_loop_exit_mass",
+    "mean mass the exit distribution gave each pass over the last fit's "
+    "valid positions, by kind (pass1 | pass2 | ...: they sum to 1)")
+loop_pass_loss = default_registry.gauge(
+    "iotml_loop_pass_loss",
+    "mean squared error of each pass's own output over the last fit's "
+    "valid positions, by kind (pass1 | pass2 | ...)")
 # grouped attention's two optional parts (models/hybrid.py
 # `GroupedAttention`), at trace time: what the last traced layer applied
 attn_rotary_dim = default_registry.gauge(
@@ -659,6 +677,8 @@ DECLARED_METRIC_LABELS = {
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),
+    "loop_exit_mass": ("kind",),
+    "loop_pass_loss": ("kind",),
     "model_layers": ("kind",),
     "moe_assignments": ("kind",),
     "moe_experts": ("kind",),

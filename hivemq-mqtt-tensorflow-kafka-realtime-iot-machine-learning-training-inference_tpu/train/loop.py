@@ -84,15 +84,25 @@ def make_loss_fn(model, supervised: bool = False):
     A model that names `report_collections` (variable collections its
     layers write data-dependent counts to: an expert layer's routing
     load) has them returned as a third entry of the aux, and the fits
-    below carry them out beside the losses; any other model's loss is
-    the program it always was."""
+    below carry them out beside the losses.  A model may also name its
+    own `objective` over `(outputs, y, mask)` → (loss, the prediction
+    the accuracy reads, what it reports) — a looped stack's expectation
+    over its passes' outputs — which stands in the masked mean squared
+    error's place, and whose reports ride out under `objective` beside
+    the collections.  Any other model's loss is the program it always
+    was."""
     reporting = list(getattr(model, "report_collections", ()))
+    objective = getattr(model, "objective", None)
 
     def loss_fn(params, x, y, mask):
-        if reporting and supervised:
-            pred, reports = model.apply({"params": params}, x,
-                                        mutable=reporting)
-            return _masked_mse(pred, y, mask), (pred, y, reports)
+        if (reporting or objective) and supervised:
+            out, reports = model.apply(
+                {"params": params}, x, mutable=reporting) if reporting \
+                else (model.apply({"params": params}, x), {})
+            if objective is None:
+                return _masked_mse(out, y, mask), (out, y, reports)
+            loss, pred, said = objective(out, y, mask)
+            return loss, (pred, y, {**reports, "objective": said})
         out = model.apply({"params": params}, x, with_penalty=True) \
             if not supervised else (model.apply({"params": params}, x), 0.0)
         pred, penalty = out if isinstance(out, tuple) else (out, 0.0)

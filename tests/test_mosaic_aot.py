@@ -301,3 +301,39 @@ def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):
     assert sum("f32[49152]" in line.split(" sort(")[0] for line in sorts) == 2
     assert not [line for line in lines if re.search(
         r" = f32\[49152\]\S* (gather|scatter)\(", line)]
+
+
+def test_the_looped_fit_runs_the_flash_forward_once_an_application(
+        v5e, monkeypatch):
+    """A stack whose layers are run several times a step is ONE pass's
+    program under a scan: the scanned fit of two sandwich-normed layers
+    at `ou-train-backlog`'s widths (sixteen heads of 128 on sixteen
+    key/value heads, rotary over the whole head, an MLP of 5,632), two
+    passes, the expected loss and Adam and all, compiled for the
+    described v5e, holds each flash kernel once a LAYER — inside the
+    passes' loop, so once an application, the forward not again in the
+    backward's recomputation (its `out` and log-sum-exp come back
+    stacked a pass) — and hands the kernels q, k and v as the
+    projections made them: no operand copied."""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+    from iotml.obs.metrics import default_registry
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    jax.clear_caches()   # the geometry is said where a shape is traced
+    model = SensorHybrid(HybridConfig(
+        d_model=2048, layer_types=("attention",) * 2, num_heads=16,
+        num_kv_heads=16, head_dim=128, attention_multiplier=128 ** -0.5,
+        attn_rope_theta=1000000.0, mlp_dim=5632, eps=1e-6, loop_steps=2,
+        post_norms=True, embedding_multiplier=1.0, residual_multiplier=1.0,
+        logits_scaling=1.0), attn_mode="flash")
+    lines = _compiled_fit(v5e, model, 8192).splitlines()
+    calls = [line for line in lines if " custom-call(" in line]
+    for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
+                   "iotml_flash_bwd_dq"):
+        assert sum(kernel in line for line in calls) == 2, kernel
+    said = default_registry.collect()
+    assert [said[f'iotml_flash_operand_copies{{kernel="{k}"}}']
+            for k in ("fwd", "bwd_dkv", "bwd_dq")] == [0, 0, 0]
+    assert said["iotml_model_loop_steps"] == 2
+    # the passes' loop is in the program: the stacked kernel outputs
+    assert any(re.search(r"f32\[2,1,8192,16,128\]", line) for line in lines)

@@ -4,10 +4,12 @@ routed sum in a latent — by name, under `nn.remat`'s policy — and, in
 the layers a byte budget takes (`FFN_KEPT`, `kept_layers`), the
 feed-forward part's first product.
 
-Three properties, each over the four shapes of stack the benchmark
+Three properties, each over the five shapes of stack the benchmark
 trains with recomputed blocks (Mamba + grouped-query attention; latent
 attention + experts at the stream's width; one-part layers + experts in
-a latent; gated short convolutions + experts without a shared one), at
+a latent; gated short convolutions + experts without a shared one; a
+looped stack of sandwich-normed layers, whose passes are a scan: what a
+layer keeps it keeps in every pass, stacked), at
 a tiny preset on the CPU, with the budget held to what keeps that product in
 every layer that makes one, in the last of them and in none: the policy
 changes no gradient and no report; the
@@ -50,6 +52,9 @@ STACKS = {
         layer_types=("short_conv", "attention", "short_conv"),
         ffn_types=("dense_ffn", "moe_ffn", "moe_ffn"), qk_norm=True,
         attn_rope_theta=10000.0, **dict(_EXPERTS, shared_dim=0)), 1, 2),
+    "looped": (HybridConfig(
+        layer_types=("attention", "attention"), num_kv_heads=4,
+        attn_rope_theta=10000.0, loop_steps=3, post_norms=True), 2, 0),
 }
 MODES = ("flash_interpret", "dense")
 #: the layers whose feed-forward product the budget is held to take
@@ -101,7 +106,7 @@ def test_the_policy_changes_no_gradient_and_no_report(monkeypatch, stack,
     kept, kept_reports = _grads_and_reports(model, params, batch)
 
     plain = nn.remat
-    monkeypatch.setattr(hybrid.nn, "remat", lambda cls, policy: plain(cls))
+    monkeypatch.setattr(hybrid.nn, "remat", lambda target, policy=None: plain(target))
     again, again_reports = _grads_and_reports(model, params, batch)
 
     assert jax.tree.structure(kept) == jax.tree.structure(again)
@@ -110,7 +115,9 @@ def test_the_policy_changes_no_gradient_and_no_report(monkeypatch, stack,
         assert float(jnp.abs(got - want).max()) <= 2e-6 * scale
     assert jax.tree.all(jax.tree.map(
         lambda a, b: bool((a == b).all()), kept_reports, again_reports))
-    assert bool(kept_reports) == bool(STACKS[stack][2])
+    # expert layers report, and a looped stack's objective
+    assert bool(kept_reports) == bool(
+        STACKS[stack][2] or STACKS[stack][0].loop_steps > 1)
 
 
 def _route_by_autodiff(u, gate, bias, top_k, scale):
@@ -230,7 +237,7 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
         == {f"ffn_in:{i}": 3 if i in keeps else 4 for i in makes}
 
     plain = nn.remat
-    monkeypatch.setattr(hybrid.nn, "remat", lambda cls, policy: plain(cls))
+    monkeypatch.setattr(hybrid.nn, "remat", lambda target, policy=None: plain(target))
     again = counted()
     assert again.get("iotml_flash_fwd", 0) == 2 * attention
     assert of(again, "top_k", "sort", "highest") \
@@ -239,20 +246,55 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     assert of(again, *(f"ffn_in:{i}" for i in makes)) == (4,) * len(makes)
 
 
-def _saved(jaxpr, name, found):
-    """The avals of the values named `name` that a recomputation in
-    `jaxpr` reads back from the forward pass: the ones the policy saved
-    (jax hands a saved residual on through a `reduce_precision`)."""
-    read = {id(v) for eqn in jaxpr.eqns
+def _through(jaxpr) -> dict:
+    """jax hands a saved residual on through a `reduce_precision`."""
+    return {id(eqn.invars[0]): id(eqn.outvars[0]) for eqn in jaxpr.eqns
+            if eqn.primitive.name == "reduce_precision"}
+
+
+def _read_back(jaxpr) -> set:
+    """The values a recomputation in `jaxpr` reads."""
+    return {id(v) for eqn in jaxpr.eqns
             if eqn.primitive.name in ("remat2", "checkpoint")
             for v in eqn.invars}
-    through = {id(eqn.invars[0]): id(eqn.outvars[0]) for eqn in jaxpr.eqns
-               if eqn.primitive.name == "reduce_precision"}
+
+
+def _named(jaxpr, name):
+    """(the value, or what a `reduce_precision` made of it) of every
+    value named `name` in `jaxpr`."""
+    through = _through(jaxpr)
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "name" and eqn.params["name"] == name:
             out = id(eqn.outvars[0])
-            if through.get(out, out) in read:
-                found.append(eqn.outvars[0].aval)
+            yield eqn.outvars[0], through.get(out, out)
+
+
+def _saved(jaxpr, name, found):
+    """The avals of the values named `name` that a recomputation in
+    `jaxpr` reads back from the forward pass: the ones the policy
+    saved.  Where the passes of a loop are a scan, the forward scan
+    stacks what its body named and the backward scan's body reads a
+    pass's slice of it back: the stacked array is what was saved."""
+    read = _read_back(jaxpr)
+    for value, out in _named(jaxpr, name):
+        if out in read:
+            found.append(value.aval)
+    scans = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "scan"]
+    sliced = set()   # stacked arrays a backward body's recomputation reads
+    for eqn in scans:
+        body, first = eqn.params["jaxpr"].jaxpr, \
+            eqn.params["num_consts"] + eqn.params["num_carry"]
+        read = _read_back(body)
+        sliced |= {id(outer) for outer, inner in zip(
+            eqn.invars[first:], body.invars[first:]) if id(inner) in read}
+    for eqn in scans:
+        body = eqn.params["jaxpr"].jaxpr
+        place = {id(v): i for i, v in enumerate(body.outvars)}
+        for _, out in _named(body, name):
+            stacked = eqn.outvars[place[out]] if out in place else None
+            if stacked is not None and id(stacked) in sliced:
+                found.append(stacked.aval)
+    for eqn in jaxpr.eqns:
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _saved(sub, name, found)
     return found
@@ -284,6 +326,23 @@ def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
         == len(keeps)
     assert said['iotml_remat_keepable_layers{kind="ffn"}'] == len(makes)
     assert bool(saved) == (keep != "none")
+    if cfg.loop_steps > 1:
+        # every pass's, stacked: the kernel's out and lse a layer, and
+        # the stream-sized inputs the scan keeps beside the names
+        jaxpr = jax.make_jaxpr(jax.grad(
+            make_loss_fn(model, supervised=True), has_aux=True))(
+                params, *batch).jaxpr
+        flash = _saved(jaxpr, "flash_out", []) + _saved(jaxpr, "flash_lse", [])
+        assert all(a.shape[0] == cfg.loop_steps for a in saved + flash)
+        assert said['iotml_remat_kept_bytes{kind="flash"}'] \
+            == sum(a.size * a.dtype.itemsize for a in flash)
+        forward = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
+        stream = (cfg.loop_steps,) + batch[0].shape[:2] + (cfg.d_model,)
+        assert said['iotml_remat_kept_bytes{kind="loop_inputs"}'] == sum(
+            v.aval.size * v.aval.dtype.itemsize for v in forward.outvars
+            if v.aval.shape == stream)
+    else:
+        assert said['iotml_remat_kept_bytes{kind="loop_inputs"}'] == 0
 
 
 @pytest.mark.parametrize("candidates, budget, kept", [
@@ -307,6 +366,7 @@ def test_the_budget_takes_the_last_layers_that_fit(candidates, budget, kept):
     (dict(ffn_types=("moe_ffn",) * 3, shared_dim=0), 80, (0, 0, 0)),
     (dict(ffn_types=("moe_ffn",) * 3, shared_dim=48, expert_form="relu2"),
      80, (80 * 48 * 4,) * 3),             # non-gated: one product's width
+    (dict(loop_steps=4), 80, (4 * 80 * 256 * 4,) * 3),   # in every pass
 ])
 def test_a_layer_without_a_first_product_is_no_candidate(overrides, tokens,
                                                          want):
@@ -320,6 +380,11 @@ def test_a_layer_without_a_first_product_is_no_candidate(overrides, tokens,
     (1000, 400, 300, 100),    # a third of what the arrays and the names leave
     (1000, 1200, 0, 0),       # arrays past the device's memory: nothing
     (hybrid.DEVICE_BYTES, 2 ** 30, 0, 5 * 2 ** 30),
+    # a looped stack at the sixth cell's size: five times its 1.64 GB of
+    # parameters (the gradients live through the backward pass) and what
+    # 32 applications keep leave 1.37 GB, under a layer's four 369 MB
+    (16_909_336_064, 5 * 1_644_748_876, 2_164_260_864 + 2_415_919_104,
+     1_368_470_572),
 ])
 def test_the_budget_is_a_third_of_what_the_arrays_leave(limit, held, kept,
                                                         budget):
