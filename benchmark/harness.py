@@ -403,18 +403,54 @@ def tree_sub(a, b):
 
 
 # ------------------------------------------------------ profiler window
+TRACE_MARGIN = 1.05  # a job may outlast the longest seen by this much
+
+
+def trace_due_from(t_end: float, length_s: float, longest_s: float) -> float:
+    """The instant from which a call finds the profiler's window due:
+    `length_s` before `t_end` or, where the calls have lain further
+    apart than that, the longest stretch between two of them (and a
+    margin)."""
+    return t_end - max(length_s, TRACE_MARGIN * longest_s)
+
+
+def trace_due(now: float, t_end: float, length_s: float,
+              longest_s: float) -> bool:
+    """Is the profiler's window due at a call made at `now`?  From the
+    first call at which a job started now could be the window's last.
+    Calls lie no further apart than the longest stretch, so one falls
+    there; `length_s` is the traced stretch's floor, and its length
+    wherever the jobs are shorter."""
+    return now >= trace_due_from(t_end, length_s, longest_s)
+
+
 class TraceWindow:
-    """A profiler trace of the window's last seconds, stopped after the
+    """A profiler trace of the window's last whole job or jobs (its
+    last `length_s` seconds where they are shorter), stopped after the
     window has closed so that writing it costs the window nothing."""
 
     def __init__(self, run, length_s: float):
         self.run, self.length_s = run, length_s
         self.dir = run.path("trace")
         self.t0 = None
+        self.longest_s, self.calls = 0.0, 0
+        # the newest calls, in seconds of the window: what a run that
+        # took no trace has to say for itself
+        self.seen = collections.deque(maxlen=16)
+
+    def due(self, now: float, t_end: float) -> bool:
+        """One call from the driver's loop, at a boundary between jobs
+        (or a poll while it waits for records): keeps the longest
+        stretch between calls and says whether the trace starts here."""
+        at = now - (t_end - self.run.seconds)
+        if self.seen:
+            self.longest_s = max(self.longest_s, at - self.seen[-1])
+        self.seen.append(at)
+        self.calls += 1
+        return trace_due(now, t_end, self.length_s, self.longest_s)
 
     def maybe_start(self, now: float, t_end: float) -> None:
-        if self.t0 is None and self.run.trace and \
-                now >= t_end - self.length_s:
+        if self.t0 is None and self.run.trace and self.due(now, t_end):
             import jax
 
             jax.profiler.start_trace(self.dir)
@@ -429,23 +465,49 @@ class TraceWindow:
             self.run.spans.annotate = None
             self.window.__exit__(None, None, None)
 
+    def why_none(self, what: str) -> str:
+        due_from = trace_due_from(self.run.seconds, self.length_s,
+                                  self.longest_s)
+        return (f"a traced run with no trace to report: {what}.  The "
+                f"window's loop asked {self.calls} times whether the "
+                f"trace was due, last at "
+                f"{', '.join(f'{t:.3f}' for t in self.seen)} s of its "
+                f"{self.run.seconds:g} s; the longest stretch between "
+                f"two calls was {self.longest_s:.3f} s, so the trace "
+                f"was due from {due_from:.3f} s: the end less the "
+                f"larger of trace_seconds, {self.length_s:g}, and "
+                f"{TRACE_MARGIN:g} such stretches")
+
     def stop(self) -> dict:
         """Stop and reduce, once nothing is in flight any more; {} where
-        no trace was taken."""
+        no trace was asked for, and in a rehearsal on the CPU, which has
+        no device plane and no device metric.  On a chip a traced run
+        with no trace to reduce ends here and says why: a result line
+        without `busy_s` and `window_s` is no traced run's."""
         if self.t0 is None:
+            if self.run.trace and self.run.on_chip():
+                raise SystemExit(self.why_none(
+                    "the profiler never started"))
             return {}
         import jax
 
         from benchmark import trace_reduce
 
         jax.profiler.stop_trace()
-        pbs = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
-                                     "*.xplane.pb"))
-        # a rehearsal on the CPU has no device plane and no device metric
-        reduced = trace_reduce.reduce_file(pbs[0]) \
-            if self.run.on_chip() else {}
-        shutil.rmtree(self.dir, ignore_errors=True)
-        return reduced
+        try:
+            if not self.run.on_chip():
+                return {}
+            pbs = glob.glob(os.path.join(self.dir, "plugins", "profile",
+                                         "*", "*.xplane.pb"))
+            if not pbs:
+                raise SystemExit(self.why_none(
+                    f"the profiler wrote no .xplane.pb under {self.dir}"))
+            return trace_reduce.reduce_file(pbs[0])
+        except ValueError as e:  # the reduction found no device plane
+            raise SystemExit(self.why_none(
+                f"it started at {self.seen[-1]:.3f} s, but {e}")) from e
+        finally:
+            shutil.rmtree(self.dir, ignore_errors=True)
 
 
 def device_report(run, traced: dict) -> dict:

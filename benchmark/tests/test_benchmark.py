@@ -6,8 +6,10 @@ coming out as not correct."""
 
 import json
 import os
+import random
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -93,6 +95,171 @@ def test_trace_reduction_attributes_gaps_to_the_innermost_span():
         trace_reduce.reduce(planes[1:])
 
 
+# ---------------------------------------------------- the trace's start
+RUN_S, TRACE_S = 40.0, 3.0
+T0 = 86_400.25  # the window opens at some instant of the host's clock
+
+
+def old_due(now, t_end, length_s, _longest_s):
+    """The rule before PR 42, kept here so that the tests say what they
+    guard: the window's last `trace_seconds`, whatever a job's length."""
+    return now >= t_end - length_s
+
+
+def calls_of(first_s, period_s, jitter=None):
+    """The instants of the window at which a driver's loop asks whether
+    the trace is due: as it opens, then at each job's end inside it."""
+    out, t, k = [0.0], first_s, 0
+    while t < RUN_S:
+        out.append(t)
+        k += 1
+        t = first_s + k * period_s if jitter is None \
+            else t + period_s * jitter.uniform(0.98, 1.02)
+    return out
+
+
+def trace_start(calls, tmp_path):
+    """Walk `calls` through TraceWindow's own bookkeeping: (the index of
+    the call the trace starts at, the TraceWindow), None where none."""
+    tw = harness.TraceWindow(bare_run(tmp_path), TRACE_S)
+    for i, at in enumerate(calls):
+        if tw.due(T0 + at, T0 + RUN_S):
+            return i, tw
+    return None, tw
+
+
+def bare_run(tmp_path, platform="tpu", trace=True):
+    return types.SimpleNamespace(
+        seconds=RUN_S, trace=trace, path=lambda n: str(tmp_path / n),
+        on_chip=lambda: platform != "cpu",
+        spans=types.SimpleNamespace(annotate=None))
+
+
+@pytest.mark.parametrize("excess,phase,jittered", [
+    (0.0, 0.0, False), (0.03, 0.0, False), (0.10, 0.0, False),
+    (0.0, 0.37, False), (0.0, 0.81, False), (0.10, 0.50, False),
+    (0.0, 0.0, True)], ids=[
+    "even", "first_3pc", "first_10pc", "mid_job_37pc", "mid_job_81pc",
+    "first_10pc_mid_job_50pc", "jitter_2pc"])
+def test_a_trace_always_starts_whatever_the_jobs_length(
+        tmp_path, excess, phase, jittered):
+    """Job periods 0.2 to 13 s (`run_seconds` / 3) in steps of 0.01, a
+    first job up to 10% longer than the rest, a window that opens at
+    any phase of a job, periods that vary by 2%: the trace starts
+    inside the window, at a boundary from which a whole job is traced,
+    and no earlier than one longest stretch and `trace_seconds` before
+    the end."""
+    for n in range(20, 1301):
+        period = n / 100
+        jitter = random.Random(n) if jittered else None
+        calls = calls_of(period * (1 + excess) * (1 - phase), period, jitter)
+        i, tw = trace_start(calls, tmp_path)
+        assert i is not None, (period, calls[-3:], tw.longest_s)
+        assert calls[i] < RUN_S
+        assert calls[i] >= RUN_S - (tw.longest_s + TRACE_S), period
+        # where every job is shorter than trace_seconds (and the
+        # margin), the instant is the old rule's, call for call
+        if tw.longest_s * harness.TRACE_MARGIN <= TRACE_S:
+            assert calls[i - 1] - 1e-6 < RUN_S - TRACE_S <= calls[i] + 1e-6
+
+
+def test_the_old_rule_took_no_trace_where_no_boundary_fell_in_its_last_3_s(
+        tmp_path, monkeypatch):
+    """What PR 42 mends, named: with `now >= t_end - trace_seconds` a
+    cell whose job outlasts `trace_seconds` has a trace only where a
+    boundary happens to fall in the window's last 3 s."""
+    monkeypatch.setattr(harness, "trace_due", old_due)
+    missed = [n / 100 for n in range(20, 1301)
+              if trace_start(calls_of(n / 100, n / 100), tmp_path)[0]
+              is None]
+    assert missed and min(missed) > TRACE_S
+    # no k with 37 <= k * p < 40: the zones ISSUE 42 lists under 4 s,
+    # (3.077, 3.083), (3.333, 3.364) and (3.636, 3.700)
+    assert [p for p in missed if p < 4 and p not in (3.36, 3.7)] == [
+        3.08, 3.34, 3.35, 3.64, 3.65, 3.66, 3.67, 3.68, 3.69]
+
+
+# (first job, the rest) in seconds of the window.  `ou` on the accepted
+# tree (ledger, PR 40: fit 3,863.5 + batching 12.4 + rest 0.7 ms; the
+# first job fetches in the foreground) and under PR 41's change (its
+# builder's traced runs: `rounds: 3.6574 / 3.6584 / 3.7827 s`)
+OU_ACCEPTED, OU_PR41 = (3.99, 3.877), (3.7827, 3.6584)
+
+
+def test_ou_on_the_accepted_tree_starts_where_the_old_rule_started(
+        tmp_path, monkeypatch):
+    calls = calls_of(*OU_ACCEPTED)
+    i, tw = trace_start(calls, tmp_path)
+    assert i == 10 and calls[i] == pytest.approx(38.883)
+    assert tw.longest_s == pytest.approx(3.99)  # due from 35.81 s
+    assert calls[9] < RUN_S - harness.TRACE_MARGIN * 3.99 <= calls[10]
+    monkeypatch.setattr(harness, "trace_due", old_due)
+    assert trace_start(calls, tmp_path)[0] == 10
+
+
+def test_pr41s_boundaries_start_a_trace_that_the_old_rule_never_started(
+        tmp_path, monkeypatch):
+    calls = calls_of(*OU_PR41)
+    i, tw = trace_start(calls, tmp_path)
+    # the tenth boundary, 0.29 s short of the old rule's 37 s; the job
+    # traced ends at 40.37 s and closes the window
+    assert i == 10 and calls[i] == pytest.approx(36.7083)
+    assert calls[i] + OU_PR41[1] == pytest.approx(40.3667)
+    monkeypatch.setattr(harness, "trace_due", old_due)
+    assert trace_start(calls, tmp_path)[0] is None
+
+
+@pytest.mark.parametrize("cell,first_ms,rest_ms", [
+    # ledger, PR 40: the larger `fit_max_ms.train` of the pair as the
+    # first job, `fit_ms` + `batching_ms` + `round_rest_ms` as the rest
+    ("sf", 906.97, 676.62), ("gh", 1913.8, 1854.55),
+    ("km", 2472.1, 2268.8), ("ns", 2144.9, 1949.64),
+    ("lf", 2690.4, 2415.63)])
+def test_cells_whose_jobs_are_shorter_start_at_the_old_rules_call(
+        tmp_path, monkeypatch, cell, first_ms, rest_ms):
+    calls = calls_of(first_ms / 1e3, rest_ms / 1e3)
+    new, tw = trace_start(calls, tmp_path)
+    assert tw.longest_s * harness.TRACE_MARGIN < TRACE_S
+    monkeypatch.setattr(harness, "trace_due", old_due)
+    assert new == trace_start(calls, tmp_path)[0] is not None
+
+
+def test_a_traced_run_on_a_chip_that_took_no_trace_says_so(tmp_path):
+    tw = harness.TraceWindow(bare_run(tmp_path), TRACE_S)
+    for at in (0.0, 3.78, 7.44):  # then nothing: the loop died early
+        assert not tw.due(T0 + at, T0 + RUN_S)
+    with pytest.raises(SystemExit) as stopped:
+        tw.stop()
+    said = str(stopped.value)
+    assert stopped.value.code != 0 and "never started" in said
+    assert "asked 3 times" in said and "0.000, 3.780, 7.440 s" in said
+    assert "stretch between two calls was 3.780 s" in said
+    assert "due from 36.031 s" in said  # 40 - 1.05 * 3.78
+    # a rehearsal on the CPU has no trace to report, and an untraced
+    # run asked for none
+    assert harness.TraceWindow(bare_run(tmp_path, "cpu"), TRACE_S).stop() \
+        == {}
+    assert harness.TraceWindow(bare_run(tmp_path, trace=False),
+                               TRACE_S).stop() == {}
+
+
+def test_a_trace_without_a_device_plane_says_so_on_a_chip(tmp_path):
+    """The CPU's profiler writes no device plane: told it is a chip,
+    the run ends with the reduction's reason and the trace's start."""
+    import jax.numpy as jnp
+
+    tw = harness.TraceWindow(bare_run(tmp_path), TRACE_S)
+    tw.maybe_start(T0 + 38.5, T0 + RUN_S)
+    assert tw.t0 is not None
+    jnp.ones((8, 8)).sum().block_until_ready()
+    tw.close_window()
+    with pytest.raises(SystemExit) as stopped:
+        tw.stop()
+    said = str(stopped.value)
+    assert "no device plane" in said and "started at 38.500 s" in said
+    assert not os.path.exists(tw.dir)
+
+
 # ---------------------------------------------------------------- fleet
 def test_fleet_is_seeded_and_decodes_with_the_programs_codec():
     def records(seed):
@@ -149,11 +316,12 @@ def test_rehearsal_ends_in_the_contracts_line(capsys, workload):
 
 @pytest.mark.parametrize("workload", ["sf-train-backlog",
                                       "ae-train-backlog"])
-def test_traced_rehearsal_reports_the_cells_layer_metrics(capsys, workload):
+def test_traced_rehearsal_reports_the_cells_layer_metrics(
+        capsys, workload, rehearsed_layer_metrics):
     line, _ = rehearse(capsys, workload, trace=1)
     assert set(line) == RESULT_KEYS  # no device: no breakdown, no busy_s
-    assert set(line["metrics"]) == {"batching_ms.train", "fit_ms.train",
-                                    "round_rest_ms.train"}
+    assert set(line["metrics"]) == rehearsed_layer_metrics(
+        bench_of(workload), workload)
 
 
 def test_no_device_named_and_none_found_prints_no_result():

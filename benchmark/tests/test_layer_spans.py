@@ -40,16 +40,23 @@ def traced():
     return json.loads(lines[-1]), lines
 
 
-def test_the_traced_rehearsal_reports_every_span_metric(traced):
+def test_the_traced_rehearsal_reports_every_span_metric(
+        traced, rehearsed_layer_metrics):
     line, lines = traced
     assert line["correct"] is True, "\n".join(lines[-25:])
-    # train_mfu is a device metric: a rehearsal carries none
-    assert set(line["metrics"]) == OLD | NEW
+    # train_mfu is a device metric: a rehearsal carries none; what
+    # later PRs listed for every cell (the set-up's spans) is the
+    # cell's too
+    assert set(line["metrics"]) == rehearsed_layer_metrics(
+        harness.load_json(os.path.join(ROOT, "BENCHMARK.json")),
+        "sf-train-backlog") >= OLD | NEW
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert value["recompiles.train"] == 0
-    # the same stacking, timed from inside and by subtraction
+    # the same stacking, timed from inside and by subtraction (what is
+    # between them is the span's own cost under the profiler: 0.50-0.77
+    # ms a round on this sandbox's CPU at PR 42, on either tree)
     assert abs(value["stack_ms.train"]
-               - value["round_rest_ms.train"]) < 0.5
+               - value["round_rest_ms.train"]) < 1.0
     # the fit's three parts are the fit
     parts = value["transfer_ms.train"] + value["dispatch_ms.train"] \
         + value["sync_ms.train"]

@@ -57,15 +57,15 @@ def _rehearse(trace: int, seed: int):
 def test_the_cell_lists_eleven_layer_metrics_and_its_own_mfu():
     bench = _bench()
     cell = harness.find_cell(bench, CELL)
-    assert cell == bench["workloads"][-1] and cell["config"] == CONFIG
+    assert cell["config"] == CONFIG
     assert cell["chips"] == 1 and cell["traffic"] == "train_backlog"
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", [CELL])
               and m["moves"] == "train_tokens_per_s"}
     assert listed == SPAN_METRICS | {"train_mfu.moe"}
-    assert bench["per_layer"][-1]["name"] == "train_mfu.moe"
-    assert bench["per_layer"][-1]["workloads"] == [CELL]
-    for name, cells in (("train_mfu", ["sf-train-backlog"]),
+    # by name: later cells append their entries after this one's
+    for name, cells in (("train_mfu.moe", [CELL]),
+                        ("train_mfu", ["sf-train-backlog"]),
                         ("train_mfu.hybrid", ["gh-train-backlog"])):
         assert next(m for m in bench["per_layer"]
                     if m["name"] == name)["workloads"] == cells
@@ -169,11 +169,13 @@ def test_rehearsal_ends_in_the_contracts_line():
                and ln.endswith("-> ok") for ln in lines)
 
 
-def test_traced_rehearsal_reports_every_span_metric():
+def test_traced_rehearsal_reports_every_span_metric(
+        rehearsed_layer_metrics):
     line, lines = _rehearse(1, 30)
     assert line["correct"] is True, "\n".join(lines[-25:])
     # train_mfu.moe divides by a chip's peak: a rehearsal carries none
-    assert set(line["metrics"]) == SPAN_METRICS
+    assert set(line["metrics"]) == rehearsed_layer_metrics(_bench(), CELL) \
+        >= SPAN_METRICS
     assert line["metrics"]["recompiles.train"]["value"] == 0
 
 
