@@ -70,7 +70,12 @@ around a few taps, order carried by the taps alone.  And grouped
 attention may **norm and turn** its queries and keys: `qk_norm` a
 weight-only RMSNorm over a head's features, one weight vector for
 all query heads and one for all key heads; `attn_rope_theta` (0: no
-positions) rotary positions over the whole head, after the norms.
+positions) rotary positions over the whole head, after the norms —
+under the flash kernels, where the heads fill whole 128-lane tiles,
+by the Pallas call `iotml_rope` on the projections' own `[B, T, H·D]`
+(`ops.rope`, its tables made once a step by `rotary_tables`), else by
+XLA's pair form (`ops.moe.rotary`); `iotml_attn_rotary_kernel` says
+which.
 
 A stack may be a **loop** (`loop_steps` > 1, a looped language
 model's): ONE set of layers run `loop_steps` times a step, the final
@@ -112,7 +117,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as obs_metrics
-from ..ops import moe
+from ..ops import moe, rope
 from ..ops.attention import attention_reference, flash_attention
 from ..ops.ssd import causal_conv1d_fused, causal_conv1d_silu, ssd_scan
 from . import latent_moe
@@ -316,12 +321,27 @@ class ShortConvMixer(nn.Module):
             return _dense(d, "out_proj")(y)
 
 
+def rotary_tables(m: HybridConfig, attn_mode: str, T: int):
+    """`iotml_rope`'s tables for grouped attention's heads over T
+    positions — or None where the pair form turns them: no positions,
+    `dense` attention (the plain path), or query or key heads that fill
+    no whole 128-lane tiles.  `SensorHybrid` makes them once a step,
+    outside the passes' loop and the blocks' recomputation; a layer
+    applied on its own makes its own."""
+    D = m.attn_head_dim()
+    if not m.attn_rope_theta or attn_mode == "dense" or not all(
+            rope.lanes(n * D, D) for n in (m.num_heads, m.num_kv_heads)):
+        return None
+    with jax.named_scope("rope"):
+        return rope.tables(T, D, m.attn_rope_theta)
+
+
 class GroupedAttention(nn.Module):
     cfg: HybridConfig
     attn_mode: str   # dense | flash | flash_interpret
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, rope_tables=None):
         m = self.cfg
         B, T, _ = u.shape
         H, G, D = m.num_heads, m.num_kv_heads, m.attn_head_dim()
@@ -334,10 +354,20 @@ class GroupedAttention(nn.Module):
             with jax.named_scope("qk_norm"):
                 q = nn.RMSNorm(epsilon=m.eps, name="q_norm")(q)
                 k = nn.RMSNorm(epsilon=m.eps, name="k_norm")(k)
+        if rope_tables is None:
+            rope_tables = rotary_tables(m, self.attn_mode, T)
+        obs_metrics.attn_rotary_kernel.set(2 * (rope_tables is not None))
         if m.attn_rope_theta:
+            # in the flash kernels' own layout where they run and the
+            # lanes allow, else as XLA's pair form
             with jax.named_scope("rope"):
-                q = moe.rotary(q, m.attn_rope_theta)
-                k = moe.rotary(k, m.attn_rope_theta)
+                if rope_tables is None:
+                    q, k = (moe.rotary(a, m.attn_rope_theta) for a in (q, k))
+                else:
+                    q, k = (rope.rope(
+                        a, rope_tables,
+                        interpret=self.attn_mode == "flash_interpret")
+                        for a in (q, k))
         if self.attn_mode == "dense":
             o = attention_reference(q, k, v, causal=True,
                                     scale=m.attention_multiplier)
@@ -357,7 +387,7 @@ class HybridBlock(nn.Module):
     ffn: str = "dense_ffn"
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, rope_tables=None):
         m = self.cfg
         obs_metrics.model_post_norms.set(
             m.post_norms * ((self.kind != NONE) + (self.ffn != NONE)))
@@ -368,10 +398,13 @@ class HybridBlock(nn.Module):
             elif self.kind == "short_conv":
                 mixed = ShortConvMixer(m, name="mixer")(u)
             else:
-                attention = LatentAttention if self.kind == "mla" \
-                    else GroupedAttention
                 with jax.named_scope("attn"):
-                    mixed = attention(m, self.attn_mode, name="mixer")(u)
+                    if self.kind == "mla":
+                        mixed = LatentAttention(m, self.attn_mode,
+                                                name="mixer")(u)
+                    else:
+                        mixed = GroupedAttention(
+                            m, self.attn_mode, name="mixer")(u, rope_tables)
             h = h + m.residual_multiplier * self._post_norm(mixed, 1)
         if self.ffn == NONE:
             return h
@@ -521,12 +554,17 @@ class SensorHybrid(nn.Module):
         block = {False: nn.remat(HybridBlock, policy=names(*KEPT)),
                  True: nn.remat(HybridBlock, policy=names(*KEPT, FFN_KEPT))}
 
-        def layers(stack, h):
+        # grouped attention's rotary tables, once a step for every layer,
+        # pass and recomputation (None: no such layer, or the pair form)
+        tables = rotary_tables(m, self.attn_mode, x.shape[1]) \
+            if "attention" in m.layer_types else None
+
+        def layers(stack, h, tables):
             # the modules are `stack`'s: this model's, or its stand-in
             # under a lifted loop
             for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
                 h = block[i in keeps_ffn](kind, m, stack.attn_mode, ffn,
-                                          name=f"layer{i}")(h)
+                                          name=f"layer{i}")(h, tables)
             return h
 
         def closing(stack, h):
@@ -536,7 +574,7 @@ class SensorHybrid(nn.Module):
                                name="head")(h) / m.logits_scaling
 
         if m.loop_steps == 1:
-            return closing(self, layers(self, h))[1]
+            return closing(self, layers(self, h, tables))[1]
 
         def gated_closing(stack, h):
             h, pred = closing(stack, h)
@@ -544,11 +582,11 @@ class SensorHybrid(nn.Module):
                 gate = nn.Dense(1, kernel_init=_normal, name="exit_gate")(h)
             return h, (pred, gate[..., 0])
 
-        def looped_pass(stack, h, _):
+        def looped_pass(stack, h, tables):
             # what a pass leaves is what the next one starts from; its
             # closing is recomputed too: kept, the norm's four values of
             # the stream's size would be stacked a pass for one input
-            return nn.remat(gated_closing)(stack, layers(stack, h))
+            return nn.remat(gated_closing)(stack, layers(stack, h, tables))
 
         # ONE set of parameters, `loop_steps` passes: a scan whose body
         # is one pass — the program of an L-layer stack; what the blocks
@@ -557,7 +595,8 @@ class SensorHybrid(nn.Module):
         _, outputs = nn.scan(
             looped_pass, variable_broadcast="params",
             variable_axes={latent_moe.REPORTS: 0},
-            split_rngs={"params": False}, length=m.loop_steps)(self, h, None)
+            split_rngs={"params": False}, in_axes=nn.broadcast,
+            length=m.loop_steps)(self, h, tables)
         return outputs
 
 

@@ -540,6 +540,13 @@ attn_rotary_dim = default_registry.gauge(
     "iotml_attn_rotary_dim",
     "features of a head the last traced grouped-attention layer turned by "
     "rotary positions (the whole head, or 0: no positions)")
+attn_rotary_kernel = default_registry.gauge(
+    "iotml_attn_rotary_kernel",
+    "operands (q and k: 2, or 0) the last traced grouped-attention layer "
+    "turned by rotary positions inside the Pallas call iotml_rope, on the "
+    "projections' own [B, T, H*D]; 0 beside a non-zero "
+    "iotml_attn_rotary_dim: XLA's pair form (ops/moe.py rotary) ran, by "
+    "attn_mode dense or heads that fill no whole 128-lane tiles")
 attn_qk_norm = default_registry.gauge(
     "iotml_attn_qk_norm",
     "1 where the last traced grouped-attention layer normed its queries "

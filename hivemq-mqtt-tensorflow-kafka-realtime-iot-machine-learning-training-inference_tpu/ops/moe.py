@@ -44,7 +44,20 @@ def rotary(x, theta: float):
     pair, turned by `t · theta^(−2i/R)`.  float32 angles.  (Pairs by a
     reshape; taking a pair's partner by rolls of the whole 192-wide head
     instead ran 36 ms a step slower in `km-train-backlog`: PERF.md §6,
-    PR 30.)"""
+    PR 30.)
+
+    Who still calls it: `LatentAttention` (`km-train-backlog`: a 64-wide
+    slice of a 192-wide head and ONE shared key head, assembled into the
+    kernels' operands after the turn), and `GroupedAttention` under
+    `dense` attention (the plain path the tests compare against) or
+    where its heads fill no whole 128-lane tiles.  Where grouped
+    attention turns the WHOLE head under the flash kernels
+    (`lf-train-backlog`, `ou-train-backlog`) the same mathematics runs
+    as the Pallas call `iotml_rope` on the projections' own
+    `[B, T, H·D]` (`ops/rope.py`): XLA lays the `[…, R/2, 2]` array of
+    this form out with T on the lanes and pays padded, transposing
+    copies on both sides of the kernels to undo it (PERF.md §6,
+    PR 43)."""
     T, R = x.shape[1], x.shape[-1]
     freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
     angle = jnp.arange(T, dtype=jnp.float32)[:, None] * freq     # [T, R/2]

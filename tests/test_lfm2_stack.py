@@ -300,6 +300,44 @@ def test_two_step_fit_matches_the_reference(ref):
     assert np.array_equal(np.stack([c[0, 0] for c in counts]), first)
 
 
+@pytest.mark.parametrize("mode,in_kernel", [("flash_interpret", 2),
+                                            ("dense", 0)])
+def test_a_fit_at_heads_that_fill_the_lanes_says_which_form_turned(
+        mode, in_kernel):
+    """Eight normed heads of 16 on eight key/value heads at a width of
+    128 — `H·D = G·D` = one 128-lane tile, eight heads a chunk: under
+    the kernels q and k are turned by `iotml_rope` on `[B, T, H·D]`
+    after the heads' norms (`iotml_attn_rotary_kernel` 2), under `dense`
+    by the pair form (0), and the compiled job's losses are the
+    reference's either way."""
+    from iotml.data.dataset import Batch
+    from iotml.obs.metrics import default_registry
+    from iotml.train.loop import Trainer
+
+    mod, cfg = _reference("bench_lfm2_lanes_" + mode, hidden_size=128,
+                          num_attention_heads=8, num_key_value_heads=8)
+    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
+    jax.clear_caches()
+    batches = [_batch(seed=s) for s in (1, 2)]
+    params = mod.init_params(5)
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode),
+                      supervised=True, learning_rate=1e-3)
+    trainer._ensure_state(batches[0][0])
+    trainer.state = trainer.state.replace(
+        params=jax.tree.map(jnp.array, params))
+    with jax.default_matmul_precision("highest"):
+        history = trainer.fit_compiled(
+            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+                   first_index=0) for x, y, _ in batches], epochs=1)
+        *_, losses = mod.make_fit(mod.loss_fn, 1)(
+            params, *(jnp.stack(v) for v in zip(*batches)))
+    got = default_registry.collect()
+    assert got["iotml_attn_rotary_kernel"] == in_kernel
+    assert got["iotml_attn_rotary_dim"] == 16
+    assert got["iotml_attn_qk_norm"] == 1
+    np.testing.assert_allclose(history["loss"], losses, rtol=1e-4)
+
+
 # ------------------------------------------------------- what engaged
 def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     """The trace-time counters after a fit — the layers by kind, the
@@ -342,6 +380,9 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert (got['iotml_conv_operand_copies{kernel="fwd"}'],
             got['iotml_conv_operand_copies{kernel="bwd"}']) == (1, 2)
     assert got["iotml_attn_rotary_dim"] == 16
+    # `dense` attention (and four heads of 16 fill no 128-lane tile):
+    # XLA's pair form turned them
+    assert got["iotml_attn_rotary_kernel"] == 0
     assert got["iotml_attn_qk_norm"] == 1
     assert got["iotml_moe_shared_dim"] == 0
     assert got['iotml_moe_experts{kind="held"}'] == 4
@@ -370,7 +411,8 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     SensorHybrid(HybridConfig(ffn_types=("moe_ffn",) * 3)).init(
         jax.random.PRNGKey(0), x)
     got = default_registry.collect()
-    assert got["iotml_attn_rotary_dim"] == got["iotml_attn_qk_norm"] == 0
+    assert got["iotml_attn_rotary_dim"] == got["iotml_attn_qk_norm"] \
+        == got["iotml_attn_rotary_kernel"] == 0
     assert got["iotml_moe_shared_dim"] == 32
     assert got["iotml_conv_taps"] == 4
     assert got["iotml_conv_activation_fused"] == 1

@@ -427,11 +427,13 @@ def test_a_tiny_fit_says_what_engaged(ref):
     and the data read back with the losses: the assignments of the job
     by where they landed, and the load of the busiest expert held."""
     from iotml.data.dataset import Batch
+    from iotml.obs import metrics as obs_metrics
     from iotml.obs.metrics import default_registry
     from iotml.train.loop import Trainer
 
     mod, cfg = ref
     jax.clear_caches()
+    obs_metrics.attn_rotary_kernel.set(0)   # whatever a test before traced
     before = default_registry.collect()
     x, y, _ = _batch()
     trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
@@ -483,8 +485,12 @@ def test_a_tiny_fit_says_what_engaged(ref):
     jax.eval_shape(SensorHybrid(mod.hybrid_config(cfg),
                                 attn_mode="flash_interpret").init,
                    jax.random.PRNGKey(0), x)
-    assert default_registry.collect()['iotml_remat_kept_bytes{kind="flash"}'] \
+    got = default_registry.collect()
+    assert got['iotml_remat_kept_bytes{kind="flash"}'] \
         == 3 * (2 * 40 * 4 * 16 * 4 + 2 * 4 * 40 * 4)
+    # latent attention turns a 16-wide slice of its heads by `moe.rotary`
+    # under either mode, never by the call `iotml_rope`
+    assert got["iotml_attn_rotary_kernel"] == 0
 
 
 def test_a_model_that_reports_nothing_fits_the_program_it_had():

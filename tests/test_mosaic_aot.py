@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from iotml.ops import fused_train
+from iotml.ops import fused_train, rope
 from iotml.ops.attention import flash_attention
 from iotml.ops.moe import rotary
 from iotml.ops.ssd import causal_conv1d_fused
@@ -115,22 +115,94 @@ def test_flash_attention_latent_heads_lower_for_v5e(v5e):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
-def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e):
+@pytest.mark.parametrize("form,calls", [
+    ("pairs", 3),         # XLA's pair form: the three flash kernels
+    ("lanes", 3 + 4),     # and q's and k's turn, and their cotangents'
+])
+def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e, form, calls):
     """`lf-train-backlog`'s one attention layer, exactly, forward and
     backward: two windows of 8,192 positions, 32 query heads over 8
     key/value heads of 64 (`gh-train-backlog`'s head shape at twice the
     window), queries and keys turned by rotary positions over the whole
-    head ahead of the kernels, scores x 64^-1/2."""
+    head ahead of the kernels — by `iotml_rope` on `[B, T, H·D]`, as the
+    layer turns them under the kernels, or by the pair form — scores x
+    64^-1/2."""
     q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.float32, sharding=v5e)
     kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.float32, sharding=v5e)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(rotary(q, 1e6), rotary(k, 1e6), v,
-                                       causal=True, scale=0.125))
+        if form == "lanes":
+            cos_sin = rope.tables(8192, 64, 1e6)
+            q, k = rope.rope(q, cos_sin), rope.rope(k, cos_sin)
+        else:
+            q, k = rotary(q, 1e6), rotary(k, 1e6)
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=0.125))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 8192, 16, 128),    # `ou-train-backlog`'s q and k, exactly
+    (2, 8192, 32, 64),     # `lf-train-backlog`'s q: two heads a chunk
+    (2, 8192, 8, 64),      # and its k: 512 lanes, blocks of 1,024 rows
+    (1, 8200, 4, 256),     # a head of two tiles' lanes; a ragged block
+])
+def test_rope_lowers_for_v5e(v5e, shape):
+    """`iotml_rope` forward and backward (the same call turned back):
+    the lane rotations, the parity select and the loop over a block's
+    rows pass Mosaic, and the blocks fit scoped VMEM."""
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+
+    def loss(x):
+        cos_sin = rope.tables(shape[1], shape[3], 1e6)
+        return jnp.sum(rope.rope(x, cos_sin) ** 2)
+
+    text = jax.jit(jax.grad(loss)).lower(x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count(rope.ROPE_KERNEL) >= 2
+
+
+def test_a_turned_layer_keeps_its_stream_feature_minor(v5e):
+    """What `iotml_rope` exists to remove, held without a chip: one
+    attention layer at `ou-train-backlog`'s shape — the four
+    projections, q and k turned, the flash kernels, forward and backward
+    — compiled for the described v5e.  Under XLA's pair form the
+    program held `f32[1,8192,16,64,2]` arrays, which XLA lays out with T
+    on the lanes, and copies of the `[1, 8192, 2048]` stream into and
+    out of that layout on both sides of the kernels; now no array ends
+    in an axis of 2, and no `copy` makes the stream T-minor."""
+    from iotml.models.hybrid import GroupedAttention, HybridConfig
+
+    cfg = HybridConfig(d_model=2048, num_heads=16, num_kv_heads=16,
+                       head_dim=128, attn_rope_theta=1e6)
+    layer = GroupedAttention(cfg, "flash")
+    u = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), u))
+
+    def loss(p, u):
+        return jnp.sum(layer.apply(p, u) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    assert text.count(rope.ROPE_KERNEL) >= 4    # q, k: forward, backward
+    shaped = re.compile(r" = (\w+)\[([\d,]+)\]\{([\d,]+)[:}]")
+    pairs, t_minor = [], []
+    for line in text.splitlines():
+        found = shaped.search(line)
+        if not found:
+            continue
+        dims = [int(d) for d in found.group(2).split(",")]
+        order = [int(d) for d in found.group(3).split(",")]
+        if len(dims) > 2 and dims[-1] == 2 and 8192 in dims:
+            pairs.append(line.strip()[:120])
+        if " copy(" in line and dims == [1, 8192, 2048] and order[0] == 1:
+            t_minor.append(line.strip()[:120])
+    assert not pairs, pairs[:3]
+    assert not t_minor, t_minor[:3]
 
 
 @pytest.mark.parametrize("shape,splits,K,activation,copies", [
@@ -327,13 +399,24 @@ def test_the_looped_fit_runs_the_flash_forward_once_an_application(
         post_norms=True, embedding_multiplier=1.0, residual_multiplier=1.0,
         logits_scaling=1.0), attn_mode="flash")
     lines = _compiled_fit(v5e, model, 8192).splitlines()
-    calls = [line for line in lines if " custom-call(" in line]
+    # by the instruction's own name: a call's operands are named too
+    # (`iotml_rope` reads `%iotml_flash_bwd_dq.n`)
+    calls = [line.split(" = ")[0] for line in lines
+             if " custom-call(" in line]
     for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
                    "iotml_flash_bwd_dq"):
-        assert sum(kernel in line for line in calls) == 2, kernel
+        assert sum(kernel in name for name in calls) == 2, kernel
     said = default_registry.collect()
     assert [said[f'iotml_flash_operand_copies{{kernel="{k}"}}']
             for k in ("fwd", "bwd_dkv", "bwd_dq")] == [0, 0, 0]
     assert said["iotml_model_loop_steps"] == 2
+    # q and k turned in the kernels' layout: a layer's forward, its
+    # recomputation and its backward hold the call twice each — by
+    # tables made outside the passes' loop, not once an application
+    assert said["iotml_attn_rotary_kernel"] == 2
+    assert sum(rope.ROPE_KERNEL in name for name in calls) == 2 * 6
+    assert sum(" cosine(" in line for line in lines) <= 2
+    assert not [line for line in lines
+                if re.search(r"f32\[1,8192,16,64,2\]", line)]
     # the passes' loop is in the program: the stacked kernel outputs
     assert any(re.search(r"f32\[2,1,8192,16,128\]", line) for line in lines)

@@ -304,6 +304,46 @@ def test_two_step_fit_matches_the_reference(ref):
                                rtol=1e-5)
 
 
+@pytest.mark.parametrize("mode,in_kernel", [("flash_interpret", 2),
+                                            ("dense", 0)])
+def test_a_fit_at_heads_that_fill_the_lanes_says_which_form_turned(
+        mode, in_kernel):
+    """Eight heads of 16 at a width of 128 — `H·D` = one 128-lane tile:
+    under the kernels every application's q and k are turned by
+    `iotml_rope` on `[B, T, H·D]` (`iotml_attn_rotary_kernel` 2), under
+    `dense` by the pair form (0), and the compiled job's losses are the
+    reference's either way."""
+    from iotml.data.dataset import Batch
+    from iotml.obs.metrics import default_registry
+    from iotml.train.loop import Trainer
+
+    mod, cfg = _load("bench_ouro_lanes_" + mode, "sensorformer-ouro-2.6b")
+    cfg.update(TINY, hidden_size=128, num_attention_heads=8,
+               num_key_value_heads=8)
+    cfg["layer_types"] = cfg["layer_types"][:L]
+    cfg["job"] = dict(cfg["job"], window=40)
+    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
+    mod.use(cfg)
+    jax.clear_caches()
+    batches = [_batch(seed=s) for s in (1, 2)]
+    params = mod.init_params(5)
+    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode),
+                      supervised=True, learning_rate=1e-3)
+    trainer._ensure_state(batches[0][0])
+    trainer.state = trainer.state.replace(
+        params=jax.tree.map(jnp.array, params))
+    with jax.default_matmul_precision("highest"):
+        history = trainer.fit_compiled(
+            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
+                   first_index=0) for x, y, _ in batches], epochs=1)
+        *_, losses = mod.make_fit(mod.loss_fn, 1)(
+            params, *(jnp.stack(v) for v in zip(*batches)))
+    got = default_registry.collect()
+    assert got["iotml_attn_rotary_kernel"] == in_kernel
+    assert got["iotml_attn_rotary_dim"] == 16
+    np.testing.assert_allclose(history["loss"], losses, rtol=1e-4)
+
+
 # ------------------------------------------------------- what engaged
 def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     """The trace-time gauges after a fit — the passes, the norms on a
@@ -336,6 +376,9 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
             ("attention", "dense_ffn", "mamba", "moe_ffn")] == [L, L, 0, 0]
     assert got["iotml_remat_blocks"] == L      # the layers, not R x L
     assert got["iotml_attn_rotary_dim"] == 16
+    # `dense` attention (and four heads of 16 fill no 128-lane tile):
+    # XLA's pair form turned them
+    assert got["iotml_attn_rotary_kernel"] == 0
     assert got["iotml_attn_qk_norm"] == 0
     # over all passes: every block's input and a pass's closing's; the
     # MLP's first product [80, 2 x 96] in both layers' four
@@ -376,6 +419,9 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     got = default_registry.collect()
     assert got["iotml_model_loop_steps"] == 1
     assert got["iotml_model_post_norms"] == 0
+    # a stack without positions turns nothing, by either form
+    assert got["iotml_attn_rotary_dim"] == got["iotml_attn_rotary_kernel"] \
+        == 0
     assert got['iotml_remat_kept_bytes{kind="loop_inputs"}'] == 0
     assert plain.objective is None and plain.report_collections == ()
     assert "exit_gate" not in made and not any(
