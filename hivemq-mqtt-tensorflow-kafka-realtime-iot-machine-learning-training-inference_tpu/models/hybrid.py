@@ -34,21 +34,34 @@ and copies, of the `highest` product and of the tiles' walk.  A name
 exists where the part that makes it exists, so the one policy serves
 every stack, and outside a recomputation a name is the identity.
 
-The third case has a name too (`FFN_KEPT`, given by `gated_mlp` to
-`mlp_in`'s and `shared_in`'s output, the pre-activation): hundreds of
-MiB a layer against one of that product's four runs a step — the
-activation is made again from it, elementwise, inside the products
-that read it.  Hundreds of MiB a layer is a question of room, so a
-byte budget decides in WHICH layers the policy lists the name
-(`remat_budget`: a third of what the device's memory holds beyond the
-state — the parameters and Adam's two moments — the second copy of
-the parameters a start holds while that state is built from seeded or
-restored weights, and what `KEPT` keeps; the other two thirds are the
-program's other temporaries' and the allocator's room to spare), from
-the last layer down (`kept_layers`: its value lives shortest between
-the forward pass and the backward).  One rule whose parameter
-is bytes: a stack with more tokens a step or more dense layers keeps
-the product in fewer of them, and never less than `KEPT`.
+The third case has names too, and a byte budget decides in WHICH
+layers a policy lists them (`remat_budget`: a third of what the
+device's memory holds beyond the state — the parameters and Adam's two
+moments — the second copy of the parameters a start holds while that
+state is built from seeded or restored weights, and what `KEPT` keeps;
+the other two thirds are the program's other temporaries' and the
+allocator's room to spare).  The budget buys what is dearest to remake
+a byte (`budget_candidates`, `budget_takes`): a candidate is a name in
+a layer, its bytes a step and the operations a kept byte spares, twice
+the width its product contracts over by the bytes of an element.
+`FFN_KEPT` (given by `gated_mlp` to `mlp_in`'s and `shared_in`'s
+output, the pre-activation) spares one of that product's four runs a
+step, 2 × `d_model` operations an element — the activation is made
+again from it, elementwise, inside the products that read it.  In a
+sandwich block the post norm's backward reads the norm's INPUT, so the
+recomputation runs the part's last product again for it and for
+nothing else: `HybridBlock._post_norm` names that input (`FFN_OUT`,
+`MIXER_OUT`; without `post_norms` nothing reads it in the backward and
+no name is made), a value of the stream's size that spares 2 × the
+part's inner width an element — `mlp_dim` against `ffn_hidden`'s
+`d_model`, so the feed-forward part's output goes first.  Dearest
+first; among equals in the order listed (`ffn_hidden` first) and, within
+a name, from the last layer down (`kept_layers`: its value lives
+shortest between the forward pass and the backward) until one does not
+fit — which ends that name and not the cheaper ones behind it.  One
+rule whose parameters are bytes and widths the configuration states: a
+stack with more tokens a step or more dense layers keeps fewer of the
+large values, and never less than `KEPT`.
 
 The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
 `attention`, `mla`, the latent attention of `models.latent_moe`, or
@@ -109,12 +122,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs import metrics as obs_metrics
 from ..ops import moe, rope
@@ -134,6 +148,12 @@ KEPT = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
 #: `models.latent_moe.gated_mlp` names: the feed-forward part's first
 #: product, the dense MLP's and the shared expert's alike
 FFN_KEPT = latent_moe.FFN_HIDDEN
+#: and what `HybridBlock._post_norm` names in a sandwich block: the
+#: feed-forward part's output and the mixer's, ahead of their post norms
+FFN_OUT, MIXER_OUT = "ffn_out", "mixer_out"
+#: the names the byte budget decides, in the order equals are taken in,
+#: and the kind the registry counts each under
+BUDGETED = {FFN_KEPT: "ffn", FFN_OUT: "ffn_out", MIXER_OUT: "mixer_out"}
 #: a device's memory where the backend reports no `bytes_limit` (the
 #: CPU): a TPU v5e's
 DEVICE_BYTES = 16 * 2 ** 30
@@ -223,6 +243,53 @@ def ffn_hidden_bytes(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
                  for ffn in cfg.ffn_kinds())
 
 
+def part_inner(cfg: HybridConfig, part: str) -> int:
+    """The width the product that makes a part's output contracts over
+    (what remaking an element of that output costs, in multiply-adds):
+    the MLP's `mlp_dim`, an expert layer's shared width and a token's
+    routed ones (or the latent they act in), a mixer's heads × their
+    width; 0 for `none`."""
+    m = cfg
+    return {"dense_ffn": m.mlp_dim,
+            "moe_ffn": m.shared_dim + (m.moe_latent or m.top_k * m.expert_dim),
+            "attention": m.num_heads * m.attn_head_dim(),
+            "mla": m.num_heads * m.v_dim,
+            "mamba": m.ssm_heads * m.ssm_head_dim,
+            "short_conv": m.d_model}.get(part, 0)
+
+
+class Candidate(NamedTuple):
+    """A large value a layer's recomputation keeps if the byte budget
+    takes it: the name it goes by, its layer, its bytes a step over all
+    the stack's passes, and the operations a kept byte spares."""
+
+    name: str
+    layer: int
+    bytes: int
+    density: float
+
+
+def budget_candidates(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
+    """What the byte budget chooses among, in the order equals are taken
+    in: every layer's first feed-forward product (`ffn_hidden_bytes`;
+    remade by a product over `d_model`) and, in a sandwich block, the
+    feed-forward part's output and the mixer's `[tokens, d_model]`
+    ahead of their post norms (remade by a product over `part_inner`).
+    A layer without the part is listed with 0 bytes: no candidate."""
+    m = cfg
+    found = [Candidate(FFN_KEPT, layer, size, 2 * m.d_model / itemsize)
+             for layer, size in enumerate(
+                 ffn_hidden_bytes(m, tokens, itemsize))]
+    if m.post_norms:
+        stream = m.loop_steps * tokens * m.d_model * itemsize
+        for name, parts in ((FFN_OUT, m.ffn_kinds()),
+                            (MIXER_OUT, m.layer_types)):
+            found += [Candidate(name, layer, stream * (part != NONE),
+                                2 * part_inner(m, part) / itemsize)
+                      for layer, part in enumerate(parts)]
+    return tuple(found)
+
+
 def device_bytes() -> int:
     """What one device's memory holds, by the backend's own count."""
     stats = jax.local_devices()[0].memory_stats() or {}
@@ -250,6 +317,24 @@ def kept_layers(candidates, budget: int) -> tuple:
             break
         kept.append(layer)
     return tuple(reversed(kept))
+
+
+def budget_takes(candidates, budget: int) -> tuple:
+    """The candidates `budget` bytes buy: dearest to remake a byte
+    first; among equals a name at a time in the order listed, each from
+    the last layer down while it fits (`kept_layers`) — a name that
+    does not fit ends there, and what is left buys the ones behind it."""
+    groups = {}
+    for c in candidates:
+        groups.setdefault((c.density, c.name), []).append(c)
+    taken = []
+    # a stable sort by density alone: equals stay in the listed order
+    for _, group in sorted(groups.items(), key=lambda g: -g[0][0]):
+        fits = [group[i] for i in kept_layers(
+            [c.bytes for c in group], budget)]
+        budget -= sum(c.bytes for c in fits)
+        taken += fits
+    return tuple(taken)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -422,6 +507,9 @@ class HybridBlock(nn.Module):
         feed-forward part's."""
         if not self.cfg.post_norms:
             return part
+        # the norm's backward reads its input: by a name, a layer whose
+        # policy lists it does not run the part's last product again
+        part = checkpoint_name(part, (MIXER_OUT, FFN_OUT)[which - 1])
         with jax.named_scope("post_norm"):
             return nn.RMSNorm(epsilon=self.cfg.eps,
                               name=f"post_norm{which}")(part)
@@ -538,21 +626,28 @@ class SensorHybrid(nn.Module):
         held = (4 + (m.loop_steps > 1)) * sum(
             p.size * p.dtype.itemsize for p in jax.tree.leaves(
                 self.variables.get("params", {})))
-        ffn_bytes = ffn_hidden_bytes(m, x.shape[0] * x.shape[1],
-                                     x.dtype.itemsize)
-        keeps_ffn = kept_layers(ffn_bytes, remat_budget(
+        candidates = budget_candidates(m, x.shape[0] * x.shape[1],
+                                       x.dtype.itemsize)
+        taken = budget_takes(candidates, remat_budget(
             device_bytes(), held, sum(kept_bytes.values())))
-        kept_bytes["ffn"] = sum(ffn_bytes[i] for i in keeps_ffn)
+        for name, kind in BUDGETED.items():
+            kept_bytes[kind] = sum(c.bytes for c in taken if c.name == name)
+            obs_metrics.remat_kept_layers.set(
+                sum(c.name == name for c in taken), kind=kind)
+            obs_metrics.remat_keepable_layers.set(
+                sum(c.name == name and c.bytes > 0 for c in candidates),
+                kind=kind)
         for kind, kept in kept_bytes.items():
             obs_metrics.remat_kept_bytes.set(kept, kind=kind)
-        obs_metrics.remat_kept_layers.set(len(keeps_ffn), kind="ffn")
-        obs_metrics.remat_keepable_layers.set(
-            sum(b > 0 for b in ffn_bytes), kind="ffn")
         h = m.embedding_multiplier * nn.Dense(
             m.d_model, kernel_init=_normal, name="embed")(x)
+        # a layer's policy: `KEPT` and what the budget took in it; one
+        # recomputed block a distinct set
+        keeps = [tuple(c.name for c in taken if c.layer == layer)
+                 for layer in range(len(m.layer_types))]
         names = jax.checkpoint_policies.save_only_these_names
-        block = {False: nn.remat(HybridBlock, policy=names(*KEPT)),
-                 True: nn.remat(HybridBlock, policy=names(*KEPT, FFN_KEPT))}
+        block = {kept: nn.remat(HybridBlock, policy=names(*KEPT, *kept))
+                 for kept in dict.fromkeys(keeps)}
 
         # grouped attention's rotary tables, once a step for every layer,
         # pass and recomputation (None: no such layer, or the pair form)
@@ -563,8 +658,8 @@ class SensorHybrid(nn.Module):
             # the modules are `stack`'s: this model's, or its stand-in
             # under a lifted loop
             for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
-                h = block[i in keeps_ffn](kind, m, stack.attn_mode, ffn,
-                                          name=f"layer{i}")(h, tables)
+                h = block[keeps[i]](kind, m, stack.attn_mode, ffn,
+                                    name=f"layer{i}")(h, tables)
             return h
 
         def closing(stack, h):

@@ -599,18 +599,21 @@ remat_kept_bytes = default_registry.gauge(
     "the forward pass by name, by kind (flash: the kernel's out and lse "
     "| latent_qk: latent attention's rotated q and assembled k | "
     "router: the selection, the selected scores and the plan | "
-    "experts: the routed sum in a latent | ffn: the feed-forward part's "
-    "first product, mlp_in's and shared_in's output, in the layers a "
-    "byte budget takes)")
+    "experts: the routed sum in a latent | and in the layers a byte "
+    "budget takes, ffn: the feed-forward part's first product, mlp_in's "
+    "and shared_in's output | ffn_out, mixer_out: a sandwich block's "
+    "feed-forward output and mixer output ahead of their post norms)")
 remat_kept_layers = default_registry.gauge(
     "iotml_remat_kept_layers",
     "layers of the last traced model whose recomputation keeps a large "
     "value under the byte budget, by kind (ffn: the feed-forward part's "
-    "first product)")
+    "first product | ffn_out, mixer_out: a sandwich block's part outputs "
+    "ahead of their post norms)")
 remat_keepable_layers = default_registry.gauge(
     "iotml_remat_keepable_layers",
     "layers of the last traced model that make such a value, kept or "
-    "not, by kind (ffn: a dense MLP or a shared expert)")
+    "not, by kind (ffn: a dense MLP or a shared expert | ffn_out, "
+    "mixer_out: a part under a post norm)")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "

@@ -313,6 +313,10 @@ def test_a_tiny_fit_says_what_engaged():
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 4 * 42 * 256 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 4
+    # no post norms: a part's output is no candidate
+    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
+               for what in ("kept_bytes", "kept_layers", "keepable_layers")
+               for kind in ("ffn_out", "mixer_out"))
 
 
 # ----------------------------- grouped heads and a scale through the kernels

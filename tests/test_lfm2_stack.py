@@ -397,6 +397,10 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 80 * 192 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 1
+    # no post norms: a part's output is no candidate
+    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
+               for what in ("kept_bytes", "kept_layers", "keepable_layers")
+               for kind in ("ffn_out", "mixer_out"))
     # the scopes ride the program's operations
     model = SensorHybrid(mod.hybrid_config(cfg))
     text = jax.jit(lambda p: model.apply(

@@ -390,6 +390,10 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 2 * 80 * 48 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 2
+    # no post norms: a part's output is no candidate
+    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
+               for what in ("kept_bytes", "kept_layers", "keepable_layers")
+               for kind in ("ffn_out", "mixer_out"))
     assert got["iotml_moe_latent_dim"] == 32
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16

@@ -388,6 +388,13 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == R * L * 80 * 192 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == L
+    # a sandwich block: the budget buys the feed-forward part's output
+    # ahead of its post norm FIRST (a product over 96 against the first
+    # product's 64), [80, 64] an application — and at this size all fits
+    assert got['iotml_remat_kept_bytes{kind="ffn_out"}'] \
+        == R * L * 80 * 64 * 4
+    assert got['iotml_remat_kept_layers{kind="ffn_out"}'] \
+        == got['iotml_remat_keepable_layers{kind="ffn_out"}'] == L
     assert got['iotml_remat_kept_bytes{kind="flash"}'] == 0
     # DATA: the last fit's means, a value a pass
     said = history["reports"][hybrid.OBJECTIVE]

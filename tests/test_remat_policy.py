@@ -1,17 +1,21 @@
 """What a block's recomputation keeps (`models.hybrid.KEPT`): the flash
 kernel's `out` and log-sum-exp, the router's selection and plan, the
 routed sum in a latent — by name, under `nn.remat`'s policy — and, in
-the layers a byte budget takes (`FFN_KEPT`, `kept_layers`), the
-feed-forward part's first product.
+the layers a byte budget takes (`BUDGETED`, `budget_takes`), the
+feed-forward part's first product and, in a sandwich block, the parts'
+outputs ahead of their post norms.
 
 Three properties, each over the five shapes of stack the benchmark
 trains with recomputed blocks (Mamba + grouped-query attention; latent
 attention + experts at the stream's width; one-part layers + experts in
 a latent; gated short convolutions + experts without a shared one; a
 looped stack of sandwich-normed layers, whose passes are a scan: what a
-layer keeps it keeps in every pass, stacked), at
-a tiny preset on the CPU, with the budget held to what keeps that product in
-every layer that makes one, in the last of them and in none: the policy
+layer keeps it keeps in every pass, stacked) and a sandwich stack that
+is no loop, at
+a tiny preset on the CPU, with the budget held to what keeps every
+candidate, what keeps the first product in the last layer that makes
+one (and what is dearer than it: a sandwich block's feed-forward
+output, in every layer) and nothing: the policy
 changes no gradient and no report; the
 router's hand-written backward is autodiff of its forward; and in the
 gradient's jaxpr the kernel and top-k appear once a layer, the `highest`
@@ -19,9 +23,15 @@ product three times and a sort twice (the plan's, and the one that
 brings the routing weights' cotangents back) — with no gather and no
 scatter-add of the routing weights' scalars — and `mlp_in`'s or
 `shared_in`'s product three times in a layer that keeps it, four times
-in one that does not.  Then the counter beside the arrays the policy
-saves, and the budget's rule itself, a pure function."""
+in one that does not, and a sandwich block's `mlp_out` likewise by its
+output's name.  Then the counter beside the arrays the policy
+saves, and the budget's rule itself, pure functions: the order, the
+candidates of a configuration, and what the rule takes at the shapes of
+the benchmark's five hybrid cells."""
 
+import importlib.util
+import json
+import os
 import re
 
 import flax.linen as nn
@@ -31,7 +41,7 @@ import numpy as np
 import pytest
 
 from iotml.models import hybrid
-from iotml.models.hybrid import HybridConfig, SensorHybrid
+from iotml.models.hybrid import Candidate, HybridConfig, SensorHybrid
 from iotml.ops import moe
 from iotml.train.loop import make_loss_fn
 
@@ -55,6 +65,8 @@ STACKS = {
     "looped": (HybridConfig(
         layer_types=("attention", "attention"), num_kv_heads=4,
         attn_rope_theta=10000.0, loop_steps=3, post_norms=True), 2, 0),
+    "sandwich": (HybridConfig(
+        layer_types=("mamba", "attention"), post_norms=True), 1, 0),
 }
 MODES = ("flash_interpret", "dense")
 #: the layers whose feed-forward product the budget is held to take
@@ -70,17 +82,27 @@ def _batch(B=2, T=40, seed=0):
 
 def _hold_budget(monkeypatch, cfg, x, keep) -> tuple:
     """The byte budget set to what keeps the feed-forward part's first
-    product as `keep` says; the layers that then keep it, and the
-    layers that make one."""
-    candidates = hybrid.ffn_hidden_bytes(cfg, x.shape[0] * x.shape[1],
-                                         x.dtype.itemsize)
-    makes = tuple(i for i, b in enumerate(candidates) if b)
-    budget = {"all": sum(candidates), "none": 0,
-              "last": candidates[makes[-1]]}[keep]
+    product as `keep` says — `all`: every candidate; `last`: that
+    product in the last layer that makes one, after what is dearer than
+    it; `none`: nothing.  → the layers that then keep that product, the
+    layers that make one, and all the budget took."""
+    candidates = hybrid.budget_candidates(cfg, x.shape[0] * x.shape[1],
+                                          x.dtype.itemsize)
+    first = [c for c in candidates if c.name == hybrid.FFN_KEPT and c.bytes]
+    makes = tuple(c.layer for c in first)
+    dearer = [c for c in candidates if c.density > first[-1].density]
+    budget = {"all": sum(c.bytes for c in candidates), "none": 0,
+              "last": sum(c.bytes for c in dearer) + first[-1].bytes}[keep]
     monkeypatch.setattr(hybrid, "remat_budget", lambda *sizes: budget)
-    keeps = hybrid.kept_layers(candidates, budget)
+    taken = hybrid.budget_takes(candidates, budget)
+    keeps = tuple(c.layer for c in taken if c.name == hybrid.FFN_KEPT)
     assert keeps == {"all": makes, "last": makes[-1:], "none": ()}[keep]
-    return keeps, makes
+    assert set(taken) == {"all": {c for c in candidates if c.bytes},
+                          "last": set(dearer) | {first[-1]},
+                          "none": set()}[keep]
+    # a sandwich block's feed-forward output is dearer than the product
+    assert bool(dearer) == cfg.post_norms
+    return keeps, makes, taken
 
 
 def _grads_and_reports(model, params, batch):
@@ -185,6 +207,8 @@ def _what(assignments):
             first = re.search(r"\b(?:mlp|shared)_in\b", where)
             if first:
                 return "ffn_in:" + re.search(r"\blayer(\d+)\b", where)[1]
+            if re.search(r"\bmlp_out\b", where):
+                return "ffn_out:" + re.search(r"\blayer(\d+)\b", where)[1]
             precision = eqn.params["precision"]
             return "highest" if precision is not None and all(
                 p == jax.lax.Precision.HIGHEST for p in precision) else None
@@ -208,14 +232,18 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     forward's and the backward's two), and the routing weights neither
     gathered nor scatter-added; `mlp_in`'s or `shared_in`'s product
     three times in a layer that keeps its output (forward and the
-    backward's two) and four times in one that makes it again — and
+    backward's two) and four times in one that makes it again; in a
+    sandwich block `mlp_out`'s product likewise, three times where the
+    policy keeps the post norm's input and four where the norm's
+    backward has it made again (in a block without post norms nothing
+    reads it in the backward: three) — and
     under plain `nn.remat`, the recomputed forward's top-k, sort and
     products beside them."""
     cfg, attention, routed = STACKS[stack]
     model = SensorHybrid(cfg, attn_mode="flash_interpret")
     batch = _batch()
     params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
-    keeps, makes = _hold_budget(monkeypatch, cfg, batch[0], keep)
+    keeps, makes, taken = _hold_budget(monkeypatch, cfg, batch[0], keep)
     loss = make_loss_fn(model, supervised=True)
     what = _what(batch[0].shape[0] * batch[0].shape[1] * cfg.top_k)
 
@@ -235,6 +263,11 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     assert of(kept, "gather", "scatter-add") == (0, 0)
     assert {k: n for k, n in kept.items() if k.startswith("ffn_in:")} \
         == {f"ffn_in:{i}": 3 if i in keeps else 4 for i in makes}
+    dense = [i for i, ffn in enumerate(cfg.ffn_kinds()) if ffn == "dense_ffn"]
+    out_kept = {c.layer for c in taken if c.name == hybrid.FFN_OUT}
+    assert {k: n for k, n in kept.items() if k.startswith("ffn_out:")} \
+        == {f"ffn_out:{i}": 3 + (cfg.post_norms and i not in out_kept)
+            for i in dense}
 
     plain = nn.remat
     monkeypatch.setattr(hybrid.nn, "remat", lambda target, policy=None: plain(target))
@@ -244,6 +277,8 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
         == (2 * routed, 3 * routed, 4 * routed)
     assert of(again, "gather", "scatter-add") == (0, 0)
     assert of(again, *(f"ffn_in:{i}" for i in makes)) == (4,) * len(makes)
+    assert of(again, *(f"ffn_out:{i}" for i in dense)) \
+        == (3 + cfg.post_norms,) * len(dense)
 
 
 def _through(jaxpr) -> dict:
@@ -304,9 +339,11 @@ def _saved(jaxpr, name, found):
 @pytest.mark.parametrize("stack", STACKS)
 def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
                                                      keep):
-    """`iotml_remat_kept_bytes{kind="ffn"}` is the bytes of the arrays
-    named `FFN_KEPT` that the gradient's recomputations read back from
-    the forward pass, `iotml_remat_kept_layers` their count, and
+    """`iotml_remat_kept_bytes{kind=…}` of each budgeted name's kind
+    (`ffn`: `FFN_KEPT`; `ffn_out`, `mixer_out`: a sandwich block's
+    parts' outputs) is the bytes of the arrays of that name that the
+    gradient's recomputations read back from the forward pass,
+    `iotml_remat_kept_layers` their count, and
     `iotml_remat_keepable_layers` the layers that make one."""
     from iotml.obs.metrics import default_registry
 
@@ -314,31 +351,36 @@ def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
     model = SensorHybrid(cfg, attn_mode="flash_interpret")
     batch = _batch()
     params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
-    keeps, makes = _hold_budget(monkeypatch, cfg, batch[0], keep)
+    _, makes, taken = _hold_budget(monkeypatch, cfg, batch[0], keep)
     jax.clear_caches()
-    saved = _saved(jax.make_jaxpr(jax.grad(
+    jaxpr = jax.make_jaxpr(jax.grad(
         make_loss_fn(model, supervised=True), has_aux=True))(
-            params, *batch).jaxpr, hybrid.FFN_KEPT, [])
+            params, *batch).jaxpr
     said = default_registry.collect()
-    assert said['iotml_remat_kept_bytes{kind="ffn"}'] \
-        == sum(a.size * a.dtype.itemsize for a in saved)
-    assert said['iotml_remat_kept_layers{kind="ffn"}'] == len(saved) \
-        == len(keeps)
-    assert said['iotml_remat_keepable_layers{kind="ffn"}'] == len(makes)
+    sandwiched = len(cfg.layer_types) * cfg.post_norms
+    saved = []
+    for name, kind in hybrid.BUDGETED.items():
+        found = _saved(jaxpr, name, [])
+        assert said[f'iotml_remat_kept_bytes{{kind="{kind}"}}'] \
+            == sum(a.size * a.dtype.itemsize for a in found)
+        assert said[f'iotml_remat_kept_layers{{kind="{kind}"}}'] \
+            == len(found) == sum(c.name == name for c in taken)
+        assert said[f'iotml_remat_keepable_layers{{kind="{kind}"}}'] \
+            == (len(makes) if name == hybrid.FFN_KEPT else sandwiched)
+        saved += found
     assert bool(saved) == (keep != "none")
     if cfg.loop_steps > 1:
         # every pass's, stacked: the kernel's out and lse a layer, and
-        # the stream-sized inputs the scan keeps beside the names
-        jaxpr = jax.make_jaxpr(jax.grad(
-            make_loss_fn(model, supervised=True), has_aux=True))(
-                params, *batch).jaxpr
+        # the stream-sized inputs the scan keeps beside the names (a
+        # kept part's output is of the stream's size too)
         flash = _saved(jaxpr, "flash_out", []) + _saved(jaxpr, "flash_lse", [])
         assert all(a.shape[0] == cfg.loop_steps for a in saved + flash)
         assert said['iotml_remat_kept_bytes{kind="flash"}'] \
             == sum(a.size * a.dtype.itemsize for a in flash)
         forward = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
         stream = (cfg.loop_steps,) + batch[0].shape[:2] + (cfg.d_model,)
-        assert said['iotml_remat_kept_bytes{kind="loop_inputs"}'] == sum(
+        assert sum(said[f'iotml_remat_kept_bytes{{kind="{kind}"}}']
+                   for kind in ("loop_inputs", "ffn_out", "mixer_out")) == sum(
             v.aval.size * v.aval.dtype.itemsize for v in forward.outvars
             if v.aval.shape == stream)
     else:
@@ -357,6 +399,85 @@ def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
 ])
 def test_the_budget_takes_the_last_layers_that_fit(candidates, budget, kept):
     assert hybrid.kept_layers(candidates, budget) == kept
+
+
+def _c(spec: str) -> tuple:
+    """`"a:40@1 a:40@1 b:30@2"` → candidates: a name, its bytes and its
+    density, the layers counted a name in the order written."""
+    seen, out = {}, []
+    for word in spec.split():
+        name, rest = word.split(":")
+        size, density = rest.split("@")
+        layer = seen[name] = seen.get(name, -1) + 1
+        out.append(Candidate(name, layer, int(size), float(density)))
+    return tuple(out)
+
+
+@pytest.mark.parametrize("spec, budget, taken", [
+    # the dearest first, then the last layer of the cheaper name
+    ("a:40@1 a:40@1 b:30@2 b:30@2", 100, "b0 b1 a1"),
+    ("a:40@1 a:40@1 b:30@2 b:30@2", 59, "b1"),
+    # equals: in the order listed, each from its last layer down
+    ("a:40@1 a:40@1 b:30@1 b:30@1", 70, "a1 b1"),
+    ("b:30@1 b:30@1 a:40@1 a:40@1", 70, "b0 b1"),
+    # a name that does not fit does not stop a cheaper one behind it
+    ("a:90@2 a:90@2 b:20@1 b:20@1", 50, "b0 b1"),
+    ("a:90@1 b:20@1 b:20@1", 50, "b0 b1"),           # nor an equal one
+    # within a name it stops at the first that does not fit
+    ("a:20@1 a:90@1 b:30@1", 50, "b0"),
+    # one name, one density: today's rule (`kept_layers`)
+    ("a:40@1 a:40@1 a:40@1", 80, "a1 a2"),
+    ("a:40@1 a:0@1 a:40@1 a:0@1", 79, "a2"),         # 0 bytes: no candidate
+    # a name of two densities (two kinds of mixer): the dearer layers first
+    ("m:40@1 m:40@3 m:40@1 m:40@3", 120, "m1 m3 m2"),
+    ("", 10, ""), ("a:40@1", 0, ""),
+])
+def test_the_budget_buys_the_dearest_to_remake_a_byte_first(spec, budget,
+                                                           taken):
+    got = hybrid.budget_takes(_c(spec), budget)
+    assert " ".join(f"{c.name}{c.layer}" for c in got) == taken
+    assert sum(c.bytes for c in got) <= budget
+
+
+_STREAM = 80 * 64 * 4   # a [tokens, d_model] value of the default widths
+
+
+@pytest.mark.parametrize("overrides, want", [
+    # no post norms: the first products alone, a product over d_model 64
+    ({}, [("ffn_hidden", i, 80 * 256 * 4, 32.0) for i in range(3)]),
+    # a sandwich block: its parts' outputs beside them — the MLP's a
+    # product over mlp_dim 128, a mixer's over its heads' features
+    (dict(post_norms=True),
+     [("ffn_hidden", i, 80 * 256 * 4, 32.0) for i in range(3)]
+     + [("ffn_out", i, _STREAM, 64.0) for i in range(3)]
+     + [("mixer_out", i, _STREAM, 32.0) for i in range(3)]),
+    # in every pass of a loop; heads of a stated width
+    (dict(post_norms=True, loop_steps=4, layer_types=("attention",),
+          head_dim=48),
+     [("ffn_hidden", 0, 4 * 80 * 256 * 4, 32.0),
+      ("ffn_out", 0, 4 * _STREAM, 64.0),
+      ("mixer_out", 0, 4 * _STREAM, 96.0)]),
+    # a loop without post norms has no more candidates than a stack
+    (dict(loop_steps=4, layer_types=("attention",)),
+     [("ffn_hidden", 0, 4 * 80 * 256 * 4, 32.0)]),
+    # one-part layers: the part a layer lacks is no candidate (0 bytes);
+    # an expert layer's output contracts over its shared width and a
+    # token's routed ones, latent attention's over its value heads
+    (dict(post_norms=True, layer_types=("mla", "none", "short_conv"),
+          ffn_types=("none", "moe_ffn", "dense_ffn")),
+     [("ffn_hidden", 0, 0, 32.0), ("ffn_hidden", 1, 80 * 64 * 4, 32.0),
+      ("ffn_hidden", 2, 80 * 256 * 4, 32.0),
+      ("ffn_out", 0, 0, 0.0), ("ffn_out", 1, _STREAM, (32 + 2 * 32) / 2),
+      ("ffn_out", 2, _STREAM, 64.0),
+      ("mixer_out", 0, _STREAM, 4 * 16 / 2), ("mixer_out", 1, 0, 0.0),
+      ("mixer_out", 2, _STREAM, 32.0)]),
+])
+def test_a_sandwich_blocks_outputs_are_candidates(overrides, want):
+    """A candidate's bytes are a step's over all passes, its density
+    twice the width its product contracts over by an element's bytes."""
+    cfg = HybridConfig(**overrides)
+    assert list(hybrid.budget_candidates(cfg, 80, 4)) \
+        == [Candidate(*c) for c in want]
 
 
 @pytest.mark.parametrize("overrides, tokens, want", [
@@ -391,3 +512,57 @@ def test_the_budget_is_a_third_of_what_the_arrays_leave(limit, held, kept,
     assert hybrid.remat_budget(limit, held, kept) == budget
     # this backend reports no `bytes_limit`: the constant stands in
     assert hybrid.device_bytes() == hybrid.DEVICE_BYTES
+
+
+_CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs")
+#: the chip's `bytes_limit` (a TPU v5e's, as the benchmark's runs read it)
+_CHIP_BYTES = 16_909_336_064
+_NO_OUTS = {"ffn_out": (), "mixer_out": ()}
+
+
+@pytest.mark.parametrize("stem, first_product, outs", [
+    # without post norms: the first product alone, where it was (PR 39)
+    ("granite-4.0-h-micro", (4, 5, 6, 7, 8, 9), _NO_OUTS),
+    ("kimi-vl-a3b-instruct", (0, 1, 2, 3, 4, 5), _NO_OUTS),
+    ("nemotron-3-super-120b-a12b", (1, 3, 5, 8, 10), _NO_OUTS),
+    ("lfm2-24b-a2b", (0,), _NO_OUTS),
+    # a sandwich block under a loop: the MLP's output in all six layers;
+    # no room then for a first product of 1.476 GB in the 0.80 GB left
+    ("ouro-2.6b", (), {"ffn_out": (0, 1, 2, 3, 4, 5), "mixer_out": (4, 5)}),
+])
+def test_what_the_rule_takes_at_the_benchmarks_shapes(stem, first_product,
+                                                      outs):
+    """By arithmetic alone (shapes, no array): the five hybrid
+    configurations at their jobs' sizes on the chip's memory — the four
+    without post norms keep what `kept_layers` alone gave them, and
+    `ou` buys its feed-forward outputs first."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + re.sub(r"\W", "_", stem),
+        os.path.join(_CONFIGS, f"sensorformer-{stem}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(os.path.join(_CONFIGS, f"sensorformer-{stem}.json")) as fh:
+        cfg = json.load(fh)
+    job, m = cfg["job"], mod.hybrid_config(cfg)
+    model = SensorHybrid(m, features=cfg["model"]["features"],
+                         attn_mode=cfg["model"]["attn_mode"])
+    x = jax.ShapeDtypeStruct(
+        (job["batch_size"], job["window"], model.features), jnp.float32)
+    held = (4 + (m.loop_steps > 1)) * sum(
+        p.size * p.dtype.itemsize for p in jax.tree.leaves(
+            jax.eval_shape(model.init, jax.random.PRNGKey(0), x)))
+    budget = hybrid.remat_budget(
+        _CHIP_BYTES, held, sum(model._kept_bytes(x).values()))
+    tokens = x.shape[0] * x.shape[1]
+    taken = hybrid.budget_takes(
+        hybrid.budget_candidates(m, tokens, 4), budget)
+    got = {name: tuple(c.layer for c in taken if c.name == name)
+           for name in hybrid.BUDGETED}
+    assert got == dict(outs, ffn_hidden=first_product)
+    if not m.post_norms:
+        assert first_product == hybrid.kept_layers(
+            hybrid.ffn_hidden_bytes(m, tokens, 4), budget)
+    else:
+        assert sum(c.bytes for c in taken if c.name == "ffn_out") \
+            == 1_610_612_736
