@@ -209,8 +209,11 @@ def _run(seed: int, records: int, cars: int, n_shards: int,
         # compact the changelog mid-drill so the standby equality below
         # proves the shadow follows the COMPACTED log, not a convenient
         # full history
-        for p in range(partitions):
-            broker.store.log_for(CHANGELOG_TOPIC, p).roll()
+        # (under the broker's data lock, as every other mutation of a
+        # log the shards still append to)
+        with broker._lock:
+            for p in range(partitions):
+                broker.store.log_for(CHANGELOG_TOPIC, p).roll()
         broker.run_compaction(force=True)
         for _ in range(warm_ticks // 2):
             published += gen.publish(broker, IN_TOPIC, n_ticks=1,

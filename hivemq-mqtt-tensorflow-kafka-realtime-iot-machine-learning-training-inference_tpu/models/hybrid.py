@@ -1,120 +1,44 @@
-"""SensorHybrid: a layer stack of two kinds — Mamba-2 state-space mixers
-beside grouped-query attention — over long per-car sensor histories.
+"""SensorHybrid: a stack of layers whose two parts are data — a mixer
+(`layer_types`: `mamba`, Mamba-2's chunked state-space scan; `attention`,
+grouped-query attention that may norm and turn its heads; `mla`, the
+latent attention of `models.latent_moe`; `short_conv`, LFM2's gated
+short convolution) and a feed-forward part (`ffn_types`: `dense_ffn`, a
+gated-SiLU MLP, or `moe_ffn`, that file's sparse-expert layer; left
+empty, every layer is `dense_ffn`) — over long per-car sensor histories.
 
-The block is IBM Granite 4.0-H's (`model_type` granitemoehybrid, dense):
-`layer_types` names each layer's mixer in order, every layer is
+A layer is IBM Granite 4.0-H's block,
 
-    h ← h + r · mixer(RMSNorm(h))        h ← h + r · mlp(RMSNorm(h))
+    h ← h + r · mixer(RMSNorm(h))        h ← h + r · ffn(RMSNorm(h))
 
-with the residual multiplier r, a gated-SiLU MLP, weight-only RMSNorm and
-no bias on any projection.  The Mamba-2 mixer projects to a gate z, a
-convolved stream xBC and a step Δ per head, runs the selective
-state-space recurrence in its chunked form (`ops.ssd.ssd_scan`), gates,
-normalises and projects back; the attention mixer has fewer key/value
-heads than query heads, no positional encoding (the state-space layers
-carry order) and a softmax scale of its own.  One sensor record is one
-position: `Dense(features → d_model)` in, `Dense(d_model → features)`
-out, where a language model has its vocabulary.
+with the residual multiplier r, weight-only RMSNorm and no bias on any
+projection.  Either part may be `none`: the layer is then ONE part alone
+and builds the norm and the residual of the part it has.  A block may
+norm each part's OUTPUT too (`post_norms`: sandwich norms,
+`h + N(part(N(h)))`), and a stack may be a LOOP (`loop_steps` > 1): one
+set of layers run that many times a step as a scan over one pass's
+program, the final norm closing every pass, one head and one exit gate
+reading every pass's output, and the model naming its own objective
+(`expected_loss`).  The heads a layer holds may be a share of the
+model's (one chip's).  One sensor record is one position: `Dense(features
+→ d_model)` in, `Dense(d_model → features)` out.  The recurrent state
+starts at zero at the window's start (carrying it on is ROADMAP M2).
 
-Every block is recomputed in the backward pass (`nn.remat`): a window of
-thousands of positions keeps one [T, d_model] input a block instead of
-each block's projections, decay tiles and MLP activations.  That is the
-right trade for what is large and cheap to make again, the wrong one
-for what is small and dear — what a kernel or the router made — and a
-question of room for what is large AND dear: the feed-forward part's
-first product.  So the recomputation keeps what goes by one of the
-names in `KEPT` — the
-flash kernel's `out` and log-sum-exp, latent attention's rotated q and
-assembled k, the router's selection, its selected scores and the
-dispatch's plan (the sorted order, the routing weights in that order
-and the tiles' integers; a top-k and a sort), the routed sum ahead of a
-latent's back-projection (its weight gradient reads it) — tens of MiB
-a layer against a second run of the forward kernel, of rotary's pads
-and copies, of the `highest` product and of the tiles' walk.  A name
-exists where the part that makes it exists, so the one policy serves
-every stack, and outside a recomputation a name is the identity.
-
-The third case has names too, and a byte budget decides in WHICH
-layers a policy lists them (`remat_budget`: a third of what the
-device's memory holds beyond the state — the parameters and Adam's two
-moments — the second copy of the parameters a start holds while that
-state is built from seeded or restored weights, and what `KEPT` keeps;
-the other two thirds are the program's other temporaries' and the
-allocator's room to spare).  The budget buys what is dearest to remake
-a byte (`budget_candidates`, `budget_takes`): a candidate is a name in
-a layer, its bytes a step and the operations a kept byte spares, twice
-the width its product contracts over by the bytes of an element.
-`FFN_KEPT` (given by `gated_mlp` to `mlp_in`'s and `shared_in`'s
-output, the pre-activation) spares one of that product's four runs a
-step, 2 × `d_model` operations an element — the activation is made
-again from it, elementwise, inside the products that read it.  In a
-sandwich block the post norm's backward reads the norm's INPUT, so the
-recomputation runs the part's last product again for it and for
-nothing else: `HybridBlock._post_norm` names that input (`FFN_OUT`,
-`MIXER_OUT`; without `post_norms` nothing reads it in the backward and
-no name is made), a value of the stream's size that spares 2 × the
-part's inner width an element — `mlp_dim` against `ffn_hidden`'s
-`d_model`, so the feed-forward part's output goes first.  Dearest
-first; among equals in the order listed (`ffn_hidden` first) and, within
-a name, from the last layer down (`kept_layers`: its value lives
-shortest between the forward pass and the backward) until one does not
-fit — which ends that name and not the cheaper ones behind it.  One
-rule whose parameters are bytes and widths the configuration states: a
-stack with more tokens a step or more dense layers keeps fewer of the
-large values, and never less than `KEPT`.
-
-The stack is two choices a layer: the mixer (`layer_types`: `mamba`,
-`attention`, `mla`, the latent attention of `models.latent_moe`, or
-`short_conv`, the gated short convolution below) and the feed-forward
-part (`ffn_types`: `dense_ffn`, the gated MLP above, or
-`moe_ffn`, that file's sparse-expert layer; left empty, every layer is
-`dense_ffn`).  Either may be `none`: a layer is then ONE part alone,
-`h ← h + r · part(RMSNorm(h))`, and builds the norm and the residual of
-the part it has and no other (a Nemotron-H-shaped stack: every layer a
-mixer or a feed-forward part).  Both are data of the model, as
-published configurations state them.
-
-Two more mixers' parts are data.  The **gated short convolution**
-(`short_conv`, LFM2's): `[b, c, x] = u W_in`, `y = conv(b ⊙ x)` — a
-depthwise causal convolution of `short_conv_width` taps a channel
-with no bias and no activation, the state-space mixer's kernels told
-so (`ops.ssd.causal_conv1d_fused`) — and `(c ⊙ y) W_out`: two gates
-around a few taps, order carried by the taps alone.  And grouped
-attention may **norm and turn** its queries and keys: `qk_norm` a
-weight-only RMSNorm over a head's features, one weight vector for
-all query heads and one for all key heads; `attn_rope_theta` (0: no
-positions) rotary positions over the whole head, after the norms —
-under the flash kernels, where the heads fill whole 128-lane tiles,
-by the Pallas call `iotml_rope` on the projections' own `[B, T, H·D]`
-(`ops.rope`, its tables made once a step by `rotary_tables`), else by
-XLA's pair form (`ops.moe.rotary`); `iotml_attn_rotary_kernel` says
-which.
-
-A stack may be a **loop** (`loop_steps` > 1, a looped language
-model's): ONE set of layers run `loop_steps` times a step, the final
-norm closing every pass — its output is what the next pass starts from
-— and one head and one exit gate reading every pass's output, so the
-model has `loop_steps` outputs and as many gate values a position, and
-names its own objective (`expected_loss`: the passes' losses under the
-exit distribution the gates define, less β times its entropy), which
-`train.loop.make_loss_fn` takes in the masked mean squared error's
-place.  The passes are a scan over one pass's program (`nn.scan`, the
-parameters broadcast): what the blocks keep by name comes back stacked
-a pass, a shared leaf's gradient is the backward scan's carry — whole
-only when the FIRST pass's backward ends, so every gradient lives
-through the backward pass — and every byte count below is a step's,
-over all passes.  Its blocks may norm each part's OUTPUT as well
-(`post_norms`: sandwich norms, `h + N(part(N(h)))`).
-
-The heads a layer holds may be a share of the model's (one chip's,
-where the model's mixers are divided over chips): `num_heads` and
-`ssm_heads` count the heads held, `head_dim` and `ssm_head_dim` state
-their width, and `d_model` is the stream's whatever share is held.
-
-The recurrent state (ssm_heads × ssm_head_dim × ssm_state a layer and
-sequence) starts at zero at the window's start; carrying it from window
-to window, and one-step decoding against it, is the scorer's later work
-(ROADMAP M2).
+Every block is recomputed in the backward pass (`nn.remat`), and what
+that recomputation may KEEP from the forward pass is a row of `TABLE`:
+the names its part gives the value (`checkpoint_name`; outside a
+recomputation a name is the identity), the kind the registry counts it
+under, the part of a layer that makes it, and its bytes in one
+application of that part.  A row without `inner` is kept always — what a
+kernel or the router made: small, and dear to make again.  A row with
+`inner` is large, and a byte budget decides in WHICH layers a policy
+lists its name (`remat_budget`: a third of what the device's memory
+holds beyond a trainer's arrays and the rows kept always).  The budget
+buys what is dearest to remake a byte first (`budget_takes`): a kept
+element spares a product over `inner` features, 2 × `inner` operations
+by an element's bytes; among equals in the table's order, and within a
+name from the last layer down (its value lives shortest) until one does
+not fit — which ends that name and not the cheaper ones behind it.  What
+each row was measured to buy on the chip is `PERF.md` §6's.
 """
 
 from __future__ import annotations
@@ -122,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -132,28 +56,13 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs import metrics as obs_metrics
 from ..ops import moe, rope
-from ..ops.attention import attention_reference, flash_attention
 from ..ops.ssd import causal_conv1d_fused, causal_conv1d_silu, ssd_scan
 from . import latent_moe
-from .latent_moe import ExpertLayer, LatentAttention, gated_mlp
+from .latent_moe import (ExpertLayer, LatentAttention, causal_attention,
+                         gated_mlp)
 from .latent_moe import dense as _dense
 from .latent_moe import normal as _normal
 
-#: what a block's recomputation keeps, by the names the parts give:
-#: `ops.attention._flash_fwd_rule`, `models.latent_moe.LatentAttention`,
-#: `ops.moe.route` and `dispatch_plan`, `models.latent_moe.ExpertLayer`
-KEPT = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
-        "route_picked", "dispatch_plan", "routed_sum")
-#: and, in the layers the byte budget takes, what
-#: `models.latent_moe.gated_mlp` names: the feed-forward part's first
-#: product, the dense MLP's and the shared expert's alike
-FFN_KEPT = latent_moe.FFN_HIDDEN
-#: and what `HybridBlock._post_norm` names in a sandwich block: the
-#: feed-forward part's output and the mixer's, ahead of their post norms
-FFN_OUT, MIXER_OUT = "ffn_out", "mixer_out"
-#: the names the byte budget decides, in the order equals are taken in,
-#: and the kind the registry counts each under
-BUDGETED = {FFN_KEPT: "ffn", FFN_OUT: "ffn_out", MIXER_OUT: "mixer_out"}
 #: a device's memory where the backend reports no `bytes_limit` (the
 #: CPU): a TPU v5e's
 DEVICE_BYTES = 16 * 2 ** 30
@@ -231,31 +140,106 @@ class HybridConfig:
         return self.head_dim or self.d_model // self.num_heads
 
 
-def ffn_hidden_bytes(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
-    """The bytes a step of each layer's first feed-forward product,
-    `mlp_in`'s or `shared_in`'s `[tokens, wide × width]` in every pass
-    the stack makes over the layer (a policy is a layer's: it keeps the
-    product in all its applications or in none); 0 for a layer that has
-    none (no feed-forward part, or experts without a shared one)."""
-    width = {"dense_ffn": moe.EXPERT_FORMS["gated_silu"] * cfg.mlp_dim,
-             "moe_ffn": moe.EXPERT_FORMS[cfg.expert_form] * cfg.shared_dim}
-    return tuple(cfg.loop_steps * tokens * width.get(ffn, 0) * itemsize
-                 for ffn in cfg.ffn_kinds())
+class Kept(NamedTuple):
+    """A value a block's recomputation may keep: a row of `TABLE`."""
+
+    kind: str                # `iotml_remat_*{kind=}` counts it under this
+    names: Tuple[str, ...]   # as its part names it; (): the scan keeps it
+    # the parts that make it → (cfg, tokens, itemsize) → its bytes in
+    # ONE application of that part
+    bytes: dict
+    # bought by the budget: (cfg, part) → the width the product that
+    # remakes an element contracts over; None: kept always
+    inner: Optional[Callable] = None
+    kernels: bool = False    # attention's kernels make it: not `dense`
+    sandwich: bool = False   # named where a post norm reads it, only
 
 
-def part_inner(cfg: HybridConfig, part: str) -> int:
+def part_inner(m: HybridConfig, part: str) -> int:
     """The width the product that makes a part's output contracts over
     (what remaking an element of that output costs, in multiply-adds):
     the MLP's `mlp_dim`, an expert layer's shared width and a token's
     routed ones (or the latent they act in), a mixer's heads × their
     width; 0 for `none`."""
-    m = cfg
     return {"dense_ffn": m.mlp_dim,
             "moe_ffn": m.shared_dim + (m.moe_latent or m.top_k * m.expert_dim),
             "attention": m.num_heads * m.attn_head_dim(),
             "mla": m.num_heads * m.v_dim,
             "mamba": m.ssm_heads * m.ssm_head_dim,
             "short_conv": m.d_model}.get(part, 0)
+
+
+def _stream(m, tokens, size):
+    return tokens * m.d_model * size
+
+
+#: the feed-forward part's first product, as `models.latent_moe
+#: .gated_mlp` names it (the dense MLP's and the shared expert's alike),
+#: and a sandwich block's two outputs ahead of their post norms
+FFN_KEPT = latent_moe.FFN_HIDDEN
+FFN_OUT, MIXER_OUT = "ffn_out", "mixer_out"
+#: what the passes' scan stacks beside the names, a pass: every block's
+#: input and the closing's (`SensorHybrid._kept_bytes`' own term) — in
+#: a stack that is no loop a block's input is one of the program's
+#: other temporaries, which `remat_budget` leaves room for
+_SCAN = Kept("loop_inputs", (), {})
+#: every value the recomputation may keep.  Kept always, by the names of
+#: `ops.attention._flash_fwd_rule`, `models.latent_moe.LatentAttention`,
+#: `ops.moe.route` and `dispatch_plan`, `models.latent_moe.ExpertLayer`;
+#: then what the passes' scan stacks beside the names; then what the
+#: byte budget buys, in the order equals are taken in
+TABLE = (
+    # out [B, T, H, Dv] and a float32 lse [B, H, T]
+    Kept("flash", ("flash_out", "flash_lse"),
+         {"attention": lambda m, tokens, size: tokens * m.num_heads
+          * (m.attn_head_dim() * size + 4),
+          "mla": lambda m, tokens, size: tokens * m.num_heads
+          * (m.v_dim * size + 4)}, kernels=True),
+    # latent attention's rotated q and assembled k, [B, T, H, nope + rope]
+    Kept("latent_qk", ("mla_q", "mla_k"),
+         {"mla": lambda m, tokens, size: 2 * tokens * m.num_heads
+          * (m.nope_dim + m.rope_dim) * size}),
+    # the selection, the selected scores and the plan: a top-k and a sort
+    Kept("router", ("route_experts", "route_picked", "dispatch_plan"),
+         {"moe_ffn": lambda m, tokens, size: moe.plan_kept_bytes(
+             tokens, m.top_k, m.experts_held[1], m.experts)}),
+    # the routed sum ahead of a latent's back-projection
+    Kept("experts", ("routed_sum",),
+         {"moe_ffn": lambda m, tokens, size: tokens * m.moe_latent * size}),
+    _SCAN,
+    # `mlp_in`'s or `shared_in`'s `[tokens, wide × width]` (experts
+    # without a shared one: 0), remade by a product over `d_model`: the
+    # activation is made again from it, elementwise, in what reads it
+    Kept("ffn", (FFN_KEPT,),
+         {"dense_ffn": lambda m, tokens, size: tokens
+          * moe.EXPERT_FORMS["gated_silu"] * m.mlp_dim * size,
+          "moe_ffn": lambda m, tokens, size: tokens
+          * moe.EXPERT_FORMS[m.expert_form] * m.shared_dim * size},
+         inner=lambda m, part: m.d_model),
+    # the post norm's backward reads the norm's INPUT: kept, the part's
+    # last product is not run again for it
+    Kept(FFN_OUT, (FFN_OUT,), dict.fromkeys(FFN_KINDS, _stream), part_inner,
+         sandwich=True),
+    Kept(MIXER_OUT, (MIXER_OUT,), dict.fromkeys(KINDS, _stream), part_inner,
+         sandwich=True),
+)
+#: the names every layer's policy lists
+KEPT = tuple(name for row in TABLE if not row.inner for name in row.names)
+#: the names the byte budget decides, and each one's kind
+BUDGETED = {row.names[0]: row.kind for row in TABLE if row.inner}
+
+
+def row_bytes(row: Kept, m: HybridConfig, tokens: int, itemsize: int,
+              attn_mode: Optional[str] = None) -> tuple:
+    """(The part that makes a row's value, its bytes a step) a layer —
+    over ALL the stack's passes: a policy is a layer's, it keeps a value
+    in all the layer's applications or in none; (`none`, 0) where
+    neither part of the layer makes it."""
+    passes = m.loop_steps * (attn_mode != "dense" or not row.kernels)
+    parts = (next((part for part in layer if part in row.bytes), NONE)
+             for layer in zip(m.layer_types, m.ffn_kinds()))
+    return tuple((part, passes * row.bytes[part](m, tokens, itemsize)
+                  if part != NONE else 0) for part in parts)
 
 
 class Candidate(NamedTuple):
@@ -271,23 +255,20 @@ class Candidate(NamedTuple):
 
 def budget_candidates(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
     """What the byte budget chooses among, in the order equals are taken
-    in: every layer's first feed-forward product (`ffn_hidden_bytes`;
-    remade by a product over `d_model`) and, in a sandwich block, the
-    feed-forward part's output and the mixer's `[tokens, d_model]`
-    ahead of their post norms (remade by a product over `part_inner`).
-    A layer without the part is listed with 0 bytes: no candidate."""
-    m = cfg
-    found = [Candidate(FFN_KEPT, layer, size, 2 * m.d_model / itemsize)
-             for layer, size in enumerate(
-                 ffn_hidden_bytes(m, tokens, itemsize))]
-    if m.post_norms:
-        stream = m.loop_steps * tokens * m.d_model * itemsize
-        for name, parts in ((FFN_OUT, m.ffn_kinds()),
-                            (MIXER_OUT, m.layer_types)):
-            found += [Candidate(name, layer, stream * (part != NONE),
-                                2 * part_inner(m, part) / itemsize)
-                      for layer, part in enumerate(parts)]
-    return tuple(found)
+    in: the budgeted rows of `TABLE`, a candidate a layer (a layer
+    without the part is listed with 0 bytes: no candidate)."""
+    return tuple(
+        Candidate(row.names[0], layer, size,
+                  2 * row.inner(cfg, part) / itemsize)
+        for row in TABLE if row.inner and (cfg.post_norms or not row.sandwich)
+        for layer, (part, size) in enumerate(
+            row_bytes(row, cfg, tokens, itemsize)))
+
+
+def ffn_hidden_bytes(cfg: HybridConfig, tokens: int, itemsize: int) -> tuple:
+    """The bytes a step of each layer's first feed-forward product."""
+    return tuple(c.bytes for c in budget_candidates(cfg, tokens, itemsize)
+                 if c.name == FFN_KEPT)
 
 
 def device_bytes() -> int:
@@ -387,6 +368,10 @@ class MambaMixer(nn.Module):
 
 
 class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution: `[b, c, x] = u W_in`, `y = conv(b
+    ⊙ x)` — a depthwise causal convolution of `short_conv_width` taps a
+    channel, no bias, no activation — and `(c ⊙ y) W_out`."""
+
     cfg: HybridConfig
 
     @nn.compact
@@ -422,6 +407,12 @@ def rotary_tables(m: HybridConfig, attn_mode: str, T: int):
 
 
 class GroupedAttention(nn.Module):
+    """Fewer key/value heads than query heads, a softmax scale of its
+    own; `qk_norm`: a weight-only RMSNorm over a head's features, one
+    weight vector for all query heads and one for all key heads;
+    `attn_rope_theta` (0: none, the state-space layers carry order):
+    rotary positions over the whole head, after the norms."""
+
     cfg: HybridConfig
     attn_mode: str   # dense | flash | flash_interpret
 
@@ -453,15 +444,7 @@ class GroupedAttention(nn.Module):
                         a, rope_tables,
                         interpret=self.attn_mode == "flash_interpret")
                         for a in (q, k))
-        if self.attn_mode == "dense":
-            o = attention_reference(q, k, v, causal=True,
-                                    scale=m.attention_multiplier)
-        elif self.attn_mode in ("flash", "flash_interpret"):
-            o = flash_attention(
-                q, k, v, causal=True, scale=m.attention_multiplier,
-                interpret=self.attn_mode == "flash_interpret")
-        else:
-            raise ValueError(f"unknown attn_mode {self.attn_mode}")
+        o = causal_attention(q, k, v, self.attn_mode, m.attention_multiplier)
         return _dense(m.d_model, "o")(o.reshape(B, T, H * D))
 
 
@@ -559,32 +542,14 @@ class SensorHybrid(nn.Module):
                     gauge.set(float(value), kind=f"pass{t + 1}")
 
     def _kept_bytes(self, x) -> dict:
-        """The bytes a step of x [B, T, features] the blocks keep by
-        name (`KEPT`) over all the stack's passes, by the part that
-        makes them."""
-        m = self.cfg
-        tokens, size = x.shape[0] * x.shape[1], x.dtype.itemsize
-        flash = {"attention": m.attn_head_dim(), "mla": m.v_dim} \
-            if self.attn_mode != "dense" else {}
-        expert_layers = m.ffn_kinds().count("moe_ffn")
-        once = {
-            # out [B, T, H, Dv] and a float32 lse [B, H, T]
-            "flash": sum(tokens * m.num_heads * (flash[kind] * size + 4)
-                         for kind in m.layer_types if kind in flash),
-            # latent attention's q and k, [B, T, H, nope + rope] each
-            "latent_qk": m.layer_types.count("mla") * 2 * tokens
-            * m.num_heads * (m.nope_dim + m.rope_dim) * size,
-            "router": expert_layers * moe.plan_kept_bytes(
-                tokens, m.top_k, m.experts_held[1], m.experts),
-            "experts": expert_layers * tokens * m.moe_latent * size,
-        }
-        kept = {kind: m.loop_steps * kept for kind, kept in once.items()}
-        # and what the passes' scan stacks beside the names, a pass:
-        # every block's input and the closing's — in a stack that is no
-        # loop a block's input is one of the program's other
-        # temporaries, which `remat_budget` leaves room for
-        kept["loop_inputs"] = (m.loop_steps > 1) * m.loop_steps \
-            * (len(m.layer_types) + 1) * tokens * m.d_model * size
+        """kind → the bytes a step of x [B, T, features] the blocks keep
+        whatever the budget (`TABLE`'s rows without `inner`)."""
+        m, tokens, size = self.cfg, x.shape[0] * x.shape[1], x.dtype.itemsize
+        kept = {row.kind: sum(b for _, b in row_bytes(
+                    row, m, tokens, size, self.attn_mode))
+                for row in TABLE if not row.inner}
+        kept[_SCAN.kind] = (m.loop_steps > 1) * m.loop_steps \
+            * (len(m.layer_types) + 1) * _stream(m, tokens, size)
         return kept
 
     @nn.compact
@@ -614,7 +579,7 @@ class SensorHybrid(nn.Module):
             obs_metrics.model_layers.set(ffns.count(kind), kind=kind)
         obs_metrics.model_loop_steps.set(m.loop_steps)
         obs_metrics.remat_blocks.set(len(m.layer_types))
-        kept_bytes = self._kept_bytes(x)
+        kept = self._kept_bytes(x)
         # what a trainer holds beside the fit's temporaries: the
         # parameters, Adam's two moments, and at its start a second copy
         # of the parameters (the seeded or restored weights the state is
@@ -629,16 +594,16 @@ class SensorHybrid(nn.Module):
         candidates = budget_candidates(m, x.shape[0] * x.shape[1],
                                        x.dtype.itemsize)
         taken = budget_takes(candidates, remat_budget(
-            device_bytes(), held, sum(kept_bytes.values())))
+            device_bytes(), held, sum(kept.values())))
         for name, kind in BUDGETED.items():
-            kept_bytes[kind] = sum(c.bytes for c in taken if c.name == name)
+            kept[kind] = sum(c.bytes for c in taken if c.name == name)
             obs_metrics.remat_kept_layers.set(
                 sum(c.name == name for c in taken), kind=kind)
             obs_metrics.remat_keepable_layers.set(
                 sum(c.name == name and c.bytes > 0 for c in candidates),
                 kind=kind)
-        for kind, kept in kept_bytes.items():
-            obs_metrics.remat_kept_bytes.set(kept, kind=kind)
+        for kind, size in kept.items():
+            obs_metrics.remat_kept_bytes.set(size, kind=kind)
         h = m.embedding_multiplier * nn.Dense(
             m.d_model, kernel_init=_normal, name="embed")(x)
         # a layer's policy: `KEPT` and what the budget took in it; one
@@ -646,8 +611,8 @@ class SensorHybrid(nn.Module):
         keeps = [tuple(c.name for c in taken if c.layer == layer)
                  for layer in range(len(m.layer_types))]
         names = jax.checkpoint_policies.save_only_these_names
-        block = {kept: nn.remat(HybridBlock, policy=names(*KEPT, *kept))
-                 for kept in dict.fromkeys(keeps)}
+        block = {bought: nn.remat(HybridBlock, policy=names(*KEPT, *bought))
+                 for bought in dict.fromkeys(keeps)}
 
         # grouped attention's rotary tables, once a step for every layer,
         # pass and recomputation (None: no such layer, or the pair form)

@@ -73,6 +73,17 @@ def gated_mlp(u, width: int, d_model: int, name: str = "mlp",
     return dense(d_model, f"{name}_out")(moe.expert_hidden(hidden, form))
 
 
+def causal_attention(q, k, v, attn_mode: str, scale: float):
+    """Causal attention the way `attn_mode` says: `dense`, the plain
+    form, or the flash kernels (`flash`; `flash_interpret` off the chip)."""
+    if attn_mode == "dense":
+        return attention_reference(q, k, v, causal=True, scale=scale)
+    if attn_mode in ("flash", "flash_interpret"):
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               interpret=attn_mode == "flash_interpret")
+    raise ValueError(f"unknown attn_mode {attn_mode}")
+
+
 class LatentAttention(nn.Module):
     cfg: Any         # models.hybrid.HybridConfig
     attn_mode: str   # dense | flash | flash_interpret
@@ -103,14 +114,7 @@ class LatentAttention(nn.Module):
         q, k = checkpoint_name(q, "mla_q"), checkpoint_name(k, "mla_k")
         v = kv[..., nope:]
         scale = 1.0 / math.sqrt(nope + rope)
-        if self.attn_mode == "dense":
-            o = attention_reference(q, k, v, causal=True, scale=scale)
-        elif self.attn_mode in ("flash", "flash_interpret"):
-            o = flash_attention(
-                q, k, v, causal=True, scale=scale,
-                interpret=self.attn_mode == "flash_interpret")
-        else:
-            raise ValueError(f"unknown attn_mode {self.attn_mode}")
+        o = causal_attention(q, k, v, self.attn_mode, scale)
         return dense(m.d_model, "o")(o.reshape(B, T, H * dv))
 
 

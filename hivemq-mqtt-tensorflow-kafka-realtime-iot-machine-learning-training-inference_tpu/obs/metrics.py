@@ -593,27 +593,21 @@ moe_expert_load = default_registry.gauge(
 remat_blocks = default_registry.gauge(
     "iotml_remat_blocks",
     "blocks of the last traced model recomputed in the backward pass")
+# by kind: a row of `models.hybrid.TABLE`, which says what each one is
 remat_kept_bytes = default_registry.gauge(
     "iotml_remat_kept_bytes",
     "bytes a step the last traced model's recomputed blocks keep from "
-    "the forward pass by name, by kind (flash: the kernel's out and lse "
-    "| latent_qk: latent attention's rotated q and assembled k | "
-    "router: the selection, the selected scores and the plan | "
-    "experts: the routed sum in a latent | and in the layers a byte "
-    "budget takes, ffn: the feed-forward part's first product, mlp_in's "
-    "and shared_in's output | ffn_out, mixer_out: a sandwich block's "
-    "feed-forward output and mixer output ahead of their post norms)")
+    "the forward pass, by kind (the rows of models/hybrid.py TABLE: "
+    "kept always, or in the layers a byte budget takes)")
 remat_kept_layers = default_registry.gauge(
     "iotml_remat_kept_layers",
     "layers of the last traced model whose recomputation keeps a large "
-    "value under the byte budget, by kind (ffn: the feed-forward part's "
-    "first product | ffn_out, mixer_out: a sandwich block's part outputs "
-    "ahead of their post norms)")
+    "value under the byte budget, by kind (the rows of models/hybrid.py "
+    "TABLE the budget buys)")
 remat_keepable_layers = default_registry.gauge(
     "iotml_remat_keepable_layers",
     "layers of the last traced model that make such a value, kept or "
-    "not, by kind (ffn: a dense MLP or a shared expert | ffn_out, "
-    "mixer_out: a part under a post norm)")
+    "not, by kind (as iotml_remat_kept_layers)")
 prefetch_occupancy = default_registry.gauge(
     "iotml_prefetch_occupancy",
     "DevicePrefetcher queue fill fraction (0 = device starving on the "

@@ -275,10 +275,11 @@ def dirty_ratio(slog) -> float:
 
 def sweep_cleaned(dir: str) -> int:
     """Remove leftover ``.cleaned`` rewrite tmps (a compaction pass died
-    before its swap).  Called by SegmentedLog recovery; returns count."""
+    before its swap) and ``atomic_write`` temporaries (a writer died
+    before its rename).  Called by SegmentedLog recovery; returns count."""
     n = 0
     for name in os.listdir(dir):
-        if name.endswith(CLEANED_SUFFIX):
+        if name.endswith((CLEANED_SUFFIX, seg.TMP_SUFFIX)):
             os.remove(os.path.join(dir, name))
             n += 1
     return n

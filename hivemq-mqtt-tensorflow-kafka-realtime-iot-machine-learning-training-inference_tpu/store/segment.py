@@ -46,6 +46,8 @@ they survive a wire hop.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import struct
 from typing import List, Optional, Tuple
@@ -55,6 +57,10 @@ from ..obs import metrics as obs_metrics
 store_fsync_seconds = obs_metrics.default_registry.histogram(
     "iotml_store_fsync_seconds", "segment/offsets fsync latency")
 
+#: `atomic_write`'s temporaries, `<path>.<pid>.<call>.tmp`: a count of
+#: its calls in their names; a mount sweeps what a dead writer left
+TMP_SUFFIX = ".tmp"
+_WRITES = itertools.count()
 #: frame geometry
 _LEN = struct.Struct(">I")
 _HEAD = struct.Struct(">IBqqi")    # crc, attrs, offset, timestamp, key_len
@@ -320,13 +326,22 @@ def atomic_write(path: str, data: bytes, fsync: bool = True) -> None:
     """tmp + rename publication for manifest/offsets compaction — a
     reader never observes a half-written file.  Lives here (not at call
     sites) for the same R9 reason SegmentWriter exists."""
-    tmp = f"{path}.tmp"
-    with open(tmp, "wb") as fh:
-        fh.write(data)
-        if fsync:
-            fh.flush()
-            os.fsync(fh.fileno())
-    os.replace(tmp, path)
+    # a temporary of this call's own: two writers of one path each
+    # rename their own whole file, and the later rename stands
+    tmp = f"{path}.{os.getpid()}.{next(_WRITES)}{TMP_SUFFIX}"
+    try:
+        with open(tmp, "wb") as fh:
+            fh.write(data)
+            if fsync:
+                fh.flush()
+                os.fsync(fh.fileno())
+        os.replace(tmp, path)
+    except BaseException:
+        # a write that failed leaves no temporary behind; one whose
+        # process died is `store.compact.sweep_cleaned`'s at the mount
+        with contextlib.suppress(OSError):
+            os.remove(tmp)
+        raise
 
 
 def fsync_dir(path: str) -> None:

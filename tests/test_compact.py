@@ -262,13 +262,16 @@ def test_fully_dead_segments_drop_but_head_keeps_base(tmp_path):
     log2.close()
 
 
-def test_stale_cleaned_tmp_swept_at_mount(tmp_path):
+@pytest.mark.parametrize("left_behind", [
+    "00000000000000000000.log" + cp.CLEANED_SUFFIX,   # a rewrite's
+    "00000000000000000000.index.4242.7.tmp",          # `atomic_write`'s
+], ids=["cleaned", "atomic_write"])
+def test_stale_cleaned_tmp_swept_at_mount(tmp_path, left_behind):
     pol = _pol()
     log = SegmentedLog(str(tmp_path), pol)
     log.append(b"k", b"v", 1)
     log.close()
-    stale = os.path.join(str(tmp_path), "00000000000000000000.log"
-                         + cp.CLEANED_SUFFIX)
+    stale = os.path.join(str(tmp_path), left_behind)
     with open(stale, "wb") as fh:  # lint-ok: R9 seeding the crash artifact the mount must sweep
         fh.write(b"half-finished rewrite")
     log2 = SegmentedLog(str(tmp_path), pol)

@@ -1,50 +1,30 @@
 """The hybrid stack (Mamba-2 state-space mixers beside grouped-query
-attention): the chunked scan against the step-by-step recurrence, the
-model and one compiled job against the benchmark's plain reference
-(loaded by path, as `benchmark/tests` loads it), grouped heads and an
+attention): the chunked scan against the step-by-step recurrence of the
+benchmark's plain reference (`stacks.reference`), grouped heads and an
 explicit scale through the flash kernels, the convolution kernels
 (interpreted) against the plain form, and what the trace-time counters
-say.  All at a tiny preset on the CPU."""
-
-import importlib.util
-import json
-import os
+say.  The model and one compiled job against the reference are the
+`granite` cases of `test_stack_contract.py`.  All at a tiny preset on
+the CPU."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models.hybrid import HybridConfig, SensorHybrid
 from iotml.ops.attention import attention_reference, flash_attention
 from iotml.ops import ssd
 from iotml.ops.ssd import (causal_conv1d, causal_conv1d_fused,
                            causal_conv1d_silu, ssd_scan)
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIG = os.path.join(ROOT, "benchmark", "configs",
-                      "sensorformer-granite-4.0-h-micro")
-#: width 64, 4 heads of 16 over 2 key/value heads, 4 state heads of 16
-#: (hence the expansion of 1), state 8, chunk 8
-TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-            shared_intermediate_size=128, mamba_n_heads=4, mamba_d_head=16,
-            mamba_expand=1, mamba_d_state=8, mamba_chunk_size=8,
-            num_hidden_layers=3,
-            layer_types=["mamba", "attention", "mamba"])
+from stacks import close as _close
 
 
 @pytest.fixture(scope="module")
 def ref():
     """The configuration's plain reference at the tiny preset."""
-    spec = importlib.util.spec_from_file_location("bench_granite_reference",
-                                                  CONFIG + ".py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with open(CONFIG + ".json") as fh:
-        cfg = json.load(fh)
-    cfg.update(TINY)
-    mod.use(cfg)
-    return mod
+    return stacks.reference("granite")[0]
 
 
 def _scan_inputs(B, T, H=4, P=16, N=8, seed=0, dt_scale=1.0):
@@ -52,13 +32,6 @@ def _scan_inputs(B, T, H=4, P=16, N=8, seed=0, dt_scale=1.0):
     f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
     return (f32(B, T, H, P), dt_scale * jax.nn.softplus(f32(B, T, H)),
             -jnp.exp(f32(H)), f32(B, T, N), f32(B, T, N))
-
-
-def _close(got, want, rtol=2e-4):
-    """Within `rtol` of the reference's largest entry, leaf by leaf."""
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = max(float(jnp.abs(w).max()), 1e-30)
-        assert float(jnp.abs(g - w).max()) <= rtol * scale
 
 
 # ------------------------------------------------------------- the scan
@@ -155,7 +128,7 @@ def test_conv_kernels_match_the_plain_form(C, T, K, activation, B,
         y = causal_conv1d(x, kernel, bias)
         if activation == "silu":
             y = jax.nn.silu(y)
-        return jnp.split(y, [splits[0], splits[0] + n], axis=-1)
+        return tuple(jnp.split(y, [splits[0], splits[0] + n], axis=-1))
 
     def kernels(x, kernel, bias):
         return causal_conv1d_fused(x, kernel, bias, splits=splits,
@@ -210,62 +183,8 @@ def test_conv_kernels_refuse_what_they_cannot_place():
 
 
 # ------------------------------------------------------------ the model
-def _batch(B=2, T=21, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
-
-
-@pytest.mark.parametrize("attn_mode", ["dense", "flash_interpret"])
-def test_model_loss_and_gradients_match_the_reference(ref, attn_mode):
-    from iotml.train.loop import make_loss_fn
-
-    model = SensorHybrid(ref.hybrid_config(ref.CFG), attn_mode=attn_mode)
-    params = ref.init_params(3)
-    x, y, mask = _batch()
-    made = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
-    assert jax.tree.structure(made) == jax.tree.structure(params)
-    loss = make_loss_fn(model, supervised=True)
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.value_and_grad(
-            lambda p: loss(p, x, y, mask)[0]))(params)
-        want = jax.jit(jax.value_and_grad(ref.loss_fn))(params, x, y, mask)
-    _close(got, want)
-
-
-def test_one_compiled_job_matches_the_references_adam(ref):
-    """`Trainer.fit_compiled` over four batches and two epochs: the
-    parameters' change, both moments and the epoch losses."""
-    from iotml.data.dataset import Batch
-    from iotml.train.loop import Trainer
-
-    trainer = Trainer(SensorHybrid(ref.hybrid_config(ref.CFG)),
-                      supervised=True, learning_rate=1e-3)
-    batches = [_batch(seed=s) for s in range(4)]
-    params0 = ref.init_params(11)
-    trainer._ensure_state(batches[0][0])
-    trainer.state = trainer.state.replace(params=params0)
-    params0 = jax.device_get(params0)
-    ref.CFG["model"]["optimizer"]["learning_rate"] = 1e-3
-    with jax.default_matmul_precision("highest"):
-        history = trainer.fit_compiled(
-            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2, first_index=0)
-             for x, y, _ in batches], epochs=2)
-        p, mu, nu, losses = ref.make_fit(ref.loss_fn, 2)(
-            params0, *(jnp.stack(v) for v in zip(*batches)))
-    assert history["fit"] == "scanned"
-    np.testing.assert_allclose(history["loss"], np.asarray(losses),
-                               rtol=1e-5)
-    adam = trainer.state.opt_state[0]
-    sub = lambda a, b: jax.tree.map(lambda u, v: u - v, a, b)  # noqa: E731
-    _close(sub(trainer.state.params, params0), sub(p, params0), rtol=2e-3)
-    _close(adam.mu, mu, rtol=2e-3)
-    _close(adam.nu, nu, rtol=2e-3)
-
-
 def test_layer_types_are_data_of_the_model():
-    x = _batch()[0]
+    x = stacks.batch(T=21)[0]
     for kinds in (("attention",), ("mamba", "mamba"),
                   ("mamba", "attention", "mamba", "attention")):
         model = SensorHybrid(HybridConfig(layer_types=kinds))
@@ -283,15 +202,12 @@ def test_a_tiny_fit_says_what_engaged():
     """The trace-time counters after a fit: the scan's chunking, the
     state a sequence holds, the convolution kernels' blocks, the layers
     by kind, the recomputed blocks."""
-    from iotml.data.dataset import Batch
     from iotml.obs.metrics import default_registry
     from iotml.train.loop import Trainer
 
     cfg = HybridConfig(layer_types=("mamba", "attention", "mamba", "mamba"))
-    x, y, _ = _batch(T=21)
     Trainer(SensorHybrid(cfg), supervised=True).fit_compiled(
-        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2, first_index=0)],
-        epochs=1)
+        stacks.jobs([stacks.batch(T=21)]), epochs=1)
     got = default_registry.collect()
     assert got["iotml_ssd_chunk_size"] == cfg.chunk
     assert got["iotml_ssd_chunks"] == 3          # 21 positions in eights
@@ -313,10 +229,9 @@ def test_a_tiny_fit_says_what_engaged():
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 4 * 42 * 256 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 4
-    # no post norms: a part's output is no candidate
-    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
-               for what in ("kept_bytes", "kept_layers", "keepable_layers")
-               for kind in ("ffn_out", "mixer_out"))
+    # `dense` attention ran no kernel, and the stack has no other part
+    # whose values are kept
+    stacks.only_these_kinds_are_kept(got, "ffn")
 
 
 # ----------------------------- grouped heads and a scale through the kernels

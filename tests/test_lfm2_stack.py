@@ -1,85 +1,36 @@
 """The gated-short-convolution stack (`models.hybrid.SensorHybrid` with
 `short_conv` mixers, grouped attention that norms and turns its queries
 and keys, and expert layers without a shared expert): each new part
-against the equations of the benchmark's plain reference (loaded by
-path, as `benchmark/tests` loads it), outputs and every gradient; the
-model and one compiled job against it; the chip's-share cut of the
-expert layer (the eight shares add up to the uncut layer: there is no
-part every chip computes alike); and what a fit says of the new parts.
-All at a tiny preset on the CPU."""
+against the equations of the benchmark's plain reference
+(`stacks.reference`), outputs and every gradient; the chip's-share cut
+of the expert layer (the eight shares add up to the uncut layer: there
+is no part every chip computes alike); and what a fit says of the new
+parts.  The tree, the model and one compiled job against the reference
+are the `lfm2` cases of `test_stack_contract.py`.  All at a tiny preset
+on the CPU."""
 
 import dataclasses
-import importlib.util
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models import hybrid
 from iotml.models.hybrid import HybridConfig, SensorHybrid
 from iotml.models.latent_moe import ExpertLayer
 from iotml.ops import moe
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIG = os.path.join(ROOT, "benchmark", "configs",
-                      "sensorformer-lfm2-24b-a2b")
-#: width 64; 4 query heads of 16 over 2 key/value heads; a dense MLP of
-#: 96; 16 experts of 24, 3 a token, 4 held; the file's five layers,
-#: `c A c c c`, the first with the dense MLP
-TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-            intermediate_size=96, moe_intermediate_size=24, num_experts=4,
-            num_experts_per_tok=3)
-
-
-def _reference(name, routed=16, **sizes):
-    spec = importlib.util.spec_from_file_location(name, CONFIG + ".py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with open(CONFIG + ".json") as fh:
-        cfg = json.load(fh)
-    cfg.update(TINY)
-    cfg["published"] = dict(cfg["published"], num_experts=routed)
-    cfg["job"] = dict(cfg["job"], window=40)
-    cfg.update(sizes)
-    mod.use(cfg)
-    return mod, cfg
+from stacks import batch as _batch
+from stacks import close as _close
+from stacks import stream as _stream
+from stacks import value_and_grads as _value_and_grads
 
 
 @pytest.fixture(scope="module")
 def ref():
     """The configuration's plain reference at the tiny preset."""
-    return _reference("bench_lfm2_reference")
-
-
-def _batch(B=2, T=40, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
-
-
-def _stream(B=2, T=40, d=64, seed=0):
-    return jnp.asarray(np.random.default_rng(seed).normal(size=(B, T, d)),
-                       jnp.float32)
-
-
-def _close(got, want, rtol=2e-4):
-    """Within `rtol` of the reference's largest entry, leaf by leaf."""
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = max(float(jnp.abs(w).max()), 1e-30)
-        assert float(jnp.abs(g - w).max()) <= rtol * scale
-
-
-def _value_and_grads(f, p, u):
-    """A weighted sum of f(p, u) and its gradients in p and u."""
-    w = _stream(*u.shape, seed=99)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(
-            lambda p, u: jnp.sum(w * f(p, u)), argnums=(0, 1)))(p, u)
+    return stacks.reference("lfm2")
 
 
 def _layer_params(mod, seed, layer):
@@ -181,7 +132,7 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
     expert there is no part every chip computes alike, so the eight
     routed sums, nothing counted once, add up to the uncut reference's
     layer (the reference's own functions, handed all sixteen)."""
-    whole, cfg = _reference("bench_lfm2_uncut", num_experts=16)
+    whole, cfg = stacks.tiny("lfm2", "bench_lfm2_uncut", num_experts=16)
     u = _stream(seed=11)
     p = _layer_params(whole, 11, "layer2")["moe"]
     assert p["experts_in"].shape[0] == 16
@@ -206,138 +157,6 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
     _close(total, want, rtol=1e-5)
 
 
-# --------------------------------------------- the model and the reference
-def test_the_stack_builds_the_references_tree(ref):
-    """`c A c c c`, the first layer with the dense MLP: the program's
-    parameter tree is the reference's, shape by shape, and counts what
-    `short_conv_ops.parameters` counts."""
-    mod, cfg = ref
-    model = SensorHybrid(mod.hybrid_config(cfg))
-    assert model.cfg.layer_types == ("short_conv", "attention") \
-        + ("short_conv",) * 3
-    assert model.cfg.ffn_types == ("dense_ffn",) + ("moe_ffn",) * 4
-    shapes = jax.tree.map(jnp.shape, jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), _batch()[0])["params"])
-    assert shapes == jax.tree.map(jnp.shape, mod.init_params(3))
-    assert sorted(shapes["layer0"]) == ["mixer", "mlp_in", "mlp_out",
-                                        "norm1", "norm2"]
-    assert sorted(shapes["layer1"]["mixer"]) == ["k", "k_norm", "o", "q",
-                                                 "q_norm", "v"]
-    assert sorted(shapes["layer3"]["moe"]) == [
-        "experts_in", "experts_out", "router", "router_bias"]
-    spec = importlib.util.spec_from_file_location(
-        "bench_short_conv_ops", os.path.join(ROOT, "benchmark",
-                                             "short_conv_ops.py"))
-    ops = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ops)
-    assert ops.parameters(cfg) == sum(
-        int(np.prod(s)) for s in jax.tree.leaves(
-            shapes, is_leaf=lambda s: isinstance(s, tuple)))
-    with pytest.raises(ValueError, match="known kinds"):
-        SensorHybrid(HybridConfig(layer_types=("short_conv", "conv"))).init(
-            jax.random.PRNGKey(0), _batch()[0])
-
-
-@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
-def test_model_matches_the_plain_reference(ref, mode):
-    """Loss and every gradient leaf from the same seeded weights."""
-    from iotml.train.loop import make_loss_fn
-
-    mod, cfg = ref
-    x, y, mask = _batch()
-    params = mod.init_params(3)
-    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
-    loss = make_loss_fn(model, supervised=True)
-    with jax.default_matmul_precision("highest"):
-        (got, aux), grads = jax.jit(jax.value_and_grad(
-            loss, has_aux=True))(params, x, y, mask)
-        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
-            params, x, y, mask)
-    assert float(abs(got - want)) <= 1e-5 * float(want)
-    _close(grads, wants)
-    for i in (0, 2, 3, 4):
-        assert np.asarray(grads[f"layer{i}"]["mixer"]["conv_kernel"]).any()
-    assert [int(c.sum()) for c in jax.tree.leaves(aux[2])] == [2 * 40 * 3] * 4
-
-
-def test_two_step_fit_matches_the_reference(ref):
-    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
-    epoch, against the reference's fit written out: losses, updated
-    parameters, both moments — and the expert counts read back with
-    them against the reference's router."""
-    from iotml.data.dataset import Batch
-    from iotml.train.loop import Trainer
-
-    mod, cfg = ref
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-3)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    stacked = [jnp.stack(v) for v in zip(*batches)]
-    try:
-        trainer._ensure_state(batches[0][0])
-        trainer.state = trainer.state.replace(
-            params=jax.tree.map(jnp.array, params))
-        with jax.default_matmul_precision("highest"):
-            history = trainer.fit_compiled(
-                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                       first_index=0) for x, y, _ in batches], epochs=2)
-            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(params, *stacked)
-            _, first = mod._km._loss_counts(params, *(v[0] for v in stacked))
-    finally:
-        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
-    adam = trainer.state.opt_state[0]
-    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
-           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
-    _close(adam.mu, mu)
-    _close(adam.nu, nu)
-    layers = history["reports"]["reports"]
-    counts = [np.asarray(jax.tree.leaves(layers[f"layer{i}"])[0])
-              for i in (1, 2, 3, 4)]
-    assert [c.shape for c in counts] == [(2, 2, 16)] * 4
-    assert np.array_equal(np.stack([c[0, 0] for c in counts]), first)
-
-
-@pytest.mark.parametrize("mode,in_kernel", [("flash_interpret", 2),
-                                            ("dense", 0)])
-def test_a_fit_at_heads_that_fill_the_lanes_says_which_form_turned(
-        mode, in_kernel):
-    """Eight normed heads of 16 on eight key/value heads at a width of
-    128 — `H·D = G·D` = one 128-lane tile, eight heads a chunk: under
-    the kernels q and k are turned by `iotml_rope` on `[B, T, H·D]`
-    after the heads' norms (`iotml_attn_rotary_kernel` 2), under `dense`
-    by the pair form (0), and the compiled job's losses are the
-    reference's either way."""
-    from iotml.data.dataset import Batch
-    from iotml.obs.metrics import default_registry
-    from iotml.train.loop import Trainer
-
-    mod, cfg = _reference("bench_lfm2_lanes_" + mode, hidden_size=128,
-                          num_attention_heads=8, num_key_value_heads=8)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    jax.clear_caches()
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode),
-                      supervised=True, learning_rate=1e-3)
-    trainer._ensure_state(batches[0][0])
-    trainer.state = trainer.state.replace(
-        params=jax.tree.map(jnp.array, params))
-    with jax.default_matmul_precision("highest"):
-        history = trainer.fit_compiled(
-            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                   first_index=0) for x, y, _ in batches], epochs=1)
-        *_, losses = mod.make_fit(mod.loss_fn, 1)(
-            params, *(jnp.stack(v) for v in zip(*batches)))
-    got = default_registry.collect()
-    assert got["iotml_attn_rotary_kernel"] == in_kernel
-    assert got["iotml_attn_rotary_dim"] == 16
-    assert got["iotml_attn_qk_norm"] == 1
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-4)
-
-
 # ------------------------------------------------------- what engaged
 def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     """The trace-time counters after a fit — the layers by kind, the
@@ -345,27 +164,14 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     attention's norms and turned features, the shared expert's width
     (none) — the new scopes in the fit's program, and the fit held to
     ONE `device_get`."""
-    from iotml.data.dataset import Batch
     from iotml.obs.metrics import default_registry
-    from iotml.train import loop
-    from iotml.train.loop import Trainer
 
     mod, cfg = ref
     monkeypatch.setattr(moe, "TILE", 16)
-    jax.clear_caches()
-    gets = []
-    device_get = jax.device_get
-    monkeypatch.setattr(loop.jax, "device_get",
-                        lambda t: gets.append(1) or device_get(t))
-    x, y, _ = _batch()
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-5)
-    history = trainer.fit_compiled(
-        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-               first_index=0)] * 3, epochs=2)
-    got = default_registry.collect()
-    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
-    assert len(gets) == 1          # the reports came back with the losses
+    x = _batch()[0]
+    model = SensorHybrid(mod.hybrid_config(cfg))
+    _, _, got, gets = stacks.tiny_fit(model, monkeypatch)
+    assert gets == 1          # the reports came back with the losses
     assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
             ("short_conv", "attention", "mamba", "mla", "dense_ffn",
              "moe_ffn")] == [4, 1, 0, 0, 1, 4]
@@ -389,27 +195,19 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
     assert got["iotml_moe_top_k"] == 3
     assert got["iotml_moe_dispatch_rows"] == moe.dispatch_rows(80, 3, 4)
-    assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
-            ("router", "experts", "flash", "latent_qk")] \
-        == [4 * moe.plan_kept_bytes(80, 3, 4, 16), 0, 0, 0]
+    assert got['iotml_remat_kept_bytes{kind="router"}'] \
+        == 4 * moe.plan_kept_bytes(80, 3, 4, 16)
     # and under the byte budget the one dense MLP's first product
     # [80, 2 x 96]: an expert layer without a shared expert makes none
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 80 * 192 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 1
-    # no post norms: a part's output is no candidate
-    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
-               for what in ("kept_bytes", "kept_layers", "keepable_layers")
-               for kind in ("ffn_out", "mixer_out"))
-    # the scopes ride the program's operations
-    model = SensorHybrid(mod.hybrid_config(cfg))
-    text = jax.jit(lambda p: model.apply(
-        {"params": p}, x, mutable=["reports"])[0]).lower(
-            mod.init_params(1)).as_text(debug_info=True)
-    for scope in ("conv_proj", "short_conv", "attn", "rope", "qk_norm",
-                  "router", "experts", "mlp"):
-        assert f"/{scope}/" in text or f"{scope}/" in text, scope
-    assert "/shared/" not in text
+    # and nothing else: `dense` attention ran no kernel
+    stacks.only_these_kinds_are_kept(got, "router", "ffn")
+    assert "/shared/" not in stacks.scopes_in_the_program(
+        model, mod.init_params(1), x,
+        ("conv_proj", "short_conv", "attn", "rope", "qk_norm", "router",
+         "experts", "mlp"))
     # the accepted stacks' layers say what they are
     jax.clear_caches()
     SensorHybrid(HybridConfig(ffn_types=("moe_ffn",) * 3)).init(

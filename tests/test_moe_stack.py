@@ -1,137 +1,29 @@
 """The latent-attention, sparse-expert stack (`models.hybrid.SensorHybrid`
-with `mla` mixers and `moe_ffn` layers): the model and one compiled job
-against the benchmark's plain reference (loaded by path, as
-`benchmark/tests` loads it), the chip's-share cut (the shares add up to
-the uncut layer), the dropless dispatch under the worst imbalance
+with `mla` mixers and `moe_ffn` layers) against the benchmark's plain
+reference (`stacks.reference`): the chip's-share cut (the shares add up
+to the uncut layer), the dropless dispatch under the worst imbalance
 against the dense-masked form, the selection-only bias, rotary
-positions, and what a fit says of the routing.  All at a tiny preset on
-the CPU."""
-
-import importlib.util
-import json
-import os
+positions, and what a fit says of the routing.  The model and one
+compiled job against the reference are the `kimi` cases of
+`test_stack_contract.py`.  All at a tiny preset on the CPU."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models.hybrid import SensorHybrid
 from iotml.models.latent_moe import ExpertLayer
 from iotml.ops import moe
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIG = os.path.join(ROOT, "benchmark", "configs",
-                      "sensorformer-kimi-vl-a3b-instruct")
-#: width 64, 4 heads of 16 + 8 rotary beside 16, latent 32; 16 experts
-#: of 24, 3 a token, 4 held, one shared; a dense layer and two that route
-TINY = dict(hidden_size=64, num_attention_heads=4, intermediate_size=96,
-            moe_intermediate_size=24, kv_lora_rank=32, qk_nope_head_dim=16,
-            qk_rope_head_dim=8, v_head_dim=16, num_hidden_layers=3,
-            n_routed_experts=4, num_experts_per_tok=3, n_shared_experts=1)
-
-
-def _reference(name, **sizes):
-    spec = importlib.util.spec_from_file_location(name, CONFIG + ".py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with open(CONFIG + ".json") as fh:
-        cfg = json.load(fh)
-    cfg.update(TINY)
-    cfg["published"] = dict(cfg["published"], n_routed_experts=16)
-    cfg["job"] = dict(cfg["job"], window=40)
-    cfg.update(sizes)
-    mod.use(cfg)
-    return mod, cfg
+from stacks import batch as _batch
+from stacks import close as _close
 
 
 @pytest.fixture(scope="module")
 def ref():
     """The configuration's plain reference at the tiny preset."""
-    return _reference("bench_kimi_reference")
-
-
-def _batch(B=2, T=40, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
-
-
-def _close(got, want, rtol=2e-4):
-    """Within `rtol` of the reference's largest entry, leaf by leaf."""
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = max(float(jnp.abs(w).max()), 1e-30)
-        assert float(jnp.abs(g - w).max()) <= rtol * scale
-
-
-# --------------------------------------------- the model and the reference
-@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
-def test_model_matches_the_plain_reference(ref, mode):
-    """Loss and every gradient leaf, the reference's dense-masked
-    experts against the program's tiles, from the same seeded weights;
-    the bias of the router gets no gradient on either side."""
-    from iotml.train.loop import make_loss_fn
-
-    mod, cfg = ref
-    x, y, mask = _batch()
-    params = mod.init_params(3)
-    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
-    assert jax.tree.map(jnp.shape, params) == jax.tree.map(
-        jnp.shape, jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                                  x)["params"])
-    loss = make_loss_fn(model, supervised=True)
-    with jax.default_matmul_precision("highest"):
-        (got, aux), grads = jax.jit(jax.value_and_grad(
-            loss, has_aux=True))(params, x, y, mask)
-        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
-            params, x, y, mask)
-    assert float(abs(got - want)) <= 1e-5 * float(want)
-    _close(grads, wants)
-    for i in (1, 2):
-        assert not np.asarray(grads[f"layer{i}"]["moe"]["router_bias"]).any()
-        assert np.asarray(grads[f"layer{i}"]["moe"]["router"]).any()
-    # what the layers reported: every assignment of every token
-    counts = jax.tree.leaves(aux[2])
-    assert [int(c.sum()) for c in counts] == [2 * 40 * 3] * 2
-
-
-def test_two_step_fit_matches_the_reference(ref):
-    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
-    epoch, against the reference's fit written out: parameters, both
-    moments, losses — and the assignments it made, read back with them."""
-    from iotml.data.dataset import Batch
-    from iotml.train.loop import Trainer
-
-    mod, cfg = ref
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-3)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    try:
-        trainer._ensure_state(batches[0][0])
-        # the fit donates its state: it gets a copy of the weights
-        trainer.state = trainer.state.replace(
-            params=jax.tree.map(jnp.array, params))
-        with jax.default_matmul_precision("highest"):
-            history = trainer.fit_compiled(
-                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                       first_index=0) for x, y, _ in batches], epochs=2)
-            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(
-                params, *(jnp.stack(v) for v in zip(*batches)))
-    finally:
-        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
-    adam = trainer.state.opt_state[0]
-    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
-           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
-    _close(adam.mu, mu)
-    _close(adam.nu, nu)
-    counts = jax.tree.leaves(history["reports"])
-    assert [c.shape for c in counts] == [(2, 2, 16)] * 2
-    assert all(int(c.sum()) == 2 * 2 * 2 * 40 * 3 for c in counts)
+    return stacks.reference("kimi")
 
 
 def test_the_seeded_weights_keep_the_labelling_the_file_states(ref):
@@ -154,7 +46,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     """Sixteen experts, three a token, one shared expert, four shares
     of four: the routed parts the four shares give, and the shared part
     counted once, are what the uncut reference layer gives."""
-    whole, cfg = _reference("bench_kimi_uncut", n_routed_experts=16)
+    whole, cfg = stacks.tiny("kimi", "bench_kimi_uncut", n_routed_experts=16)
     rng = np.random.default_rng(11)
     u = jnp.asarray(rng.normal(size=(2, 40, 64)), jnp.float32)
     p = jax.jit(lambda k: whole._init(k))(jax.random.PRNGKey(11))[
@@ -367,11 +259,10 @@ def test_the_routing_weights_ride_the_plans_sort(small_tiles, seed, K, first,
     assert not np.asarray(grad)[~here].any()
     _close(grad, grad_dense)
     # the gradient's jaxpr, counted as `test_remat_policy.py` counts it
-    from tests.test_remat_policy import _count, _what
-
     def moves(plan_of):
-        counts = _count(jax.make_jaxpr(jax.grad(
-            through(plan_of), has_aux=True))(weights).jaxpr, _what(N * K), {})
+        counts = stacks.count(jax.make_jaxpr(jax.grad(
+            through(plan_of), has_aux=True))(weights).jaxpr,
+            stacks.what(N * K), {})
         return counts.get("gather", 0), counts.get("scatter-add", 0)
 
     assert moves(moe.dispatch_plan) == (0, 0)
@@ -421,28 +312,19 @@ def test_rotary_turns_neighbouring_pairs_by_position(ref):
 
 
 # ------------------------------------------------------- what engaged
-def test_a_tiny_fit_says_what_engaged(ref):
+def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     """The trace-time counters after a fit — the layers by kind, the
     experts held and routed over, the rows the dispatch is built for —
     and the data read back with the losses: the assignments of the job
     by where they landed, and the load of the busiest expert held."""
-    from iotml.data.dataset import Batch
     from iotml.obs import metrics as obs_metrics
     from iotml.obs.metrics import default_registry
-    from iotml.train.loop import Trainer
 
     mod, cfg = ref
-    jax.clear_caches()
     obs_metrics.attn_rotary_kernel.set(0)   # whatever a test before traced
-    before = default_registry.collect()
-    x, y, _ = _batch()
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-5)
-    history = trainer.fit_compiled(
-        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-               first_index=0)] * 3, epochs=2)
-    got = default_registry.collect()
-    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
+    x = _batch()[0]
+    history, before, got, _ = stacks.tiny_fit(
+        SensorHybrid(mod.hybrid_config(cfg)), monkeypatch)
     assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
             ("mla", "attention", "mamba", "dense_ffn", "moe_ffn")] \
         == [3, 0, 0, 1, 2]
@@ -451,23 +333,20 @@ def test_a_tiny_fit_says_what_engaged(ref):
     # and plan (four [80, 3] arrays — the selection, the selected scores,
     # the sorted order, the sorted weights — three fields of seven tiles
     # of 80 rows, the live tiles' count, `counts`), three layers' q and k
-    # [2, 40, 4, 16 + 8]; no kernel ran (`dense` attention) and no latent
-    # is here
+    # [2, 40, 4, 16 + 8]
     assert moe.plan_kept_bytes(80, 3, 4, 16) == 4 * (4 * 240 + 3 * 7 + 1 + 16)
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
-            ("router", "latent_qk", "flash", "experts")] \
+            ("router", "latent_qk")] \
         == [2 * moe.plan_kept_bytes(80, 3, 4, 16),
-            3 * 2 * 2 * 40 * 4 * 24 * 4, 0, 0]
+            3 * 2 * 2 * 40 * 4 * 24 * 4]
     # and under the byte budget, here in every layer that makes one: the
     # dense MLP's first product [80, 2 x 96], two shared experts' [80, 2 x 24]
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] \
         == 80 * (192 + 2 * 48) * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 3
-    # no post norms: a part's output is no candidate
-    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
-               for what in ("kept_bytes", "kept_layers", "keepable_layers")
-               for kind in ("ffn_out", "mixer_out"))
+    # and nothing else: no kernel ran (`dense` attention)
+    stacks.only_these_kinds_are_kept(got, "router", "latent_qk", "ffn")
     assert got["iotml_latent_assembled_operands"] == 1   # k, by the mixer
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16

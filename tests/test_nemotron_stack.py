@@ -1,78 +1,31 @@
 """The one-part-a-layer stack (`models.hybrid.SensorHybrid` with `none`
 for a layer's mixer or its feed-forward part, a share of the heads, and
-experts in a latent): the model and one compiled job against the
-benchmark's plain reference (loaded by path, as `benchmark/tests` loads
-it), the chip's-share cut of all three kinds of layer (the shares add up
-to the uncut layer, which has grouped B and C and the group norm), the
-accepted configurations' parameter trees, and what a fit says of the
-latent and the tiles.  All at a tiny preset on the CPU."""
-
-import importlib.util
-import json
-import os
+experts in a latent) against the benchmark's plain reference
+(`stacks.reference`): the chip's-share cut of all three kinds of layer
+(the shares add up to the uncut layer, which has grouped B and C and the
+group norm), the accepted configurations' parameter trees, and what a
+fit says of the latent and the tiles.  The model and one compiled job
+against the reference are the `nemotron` cases of
+`test_stack_contract.py`.  All at a tiny preset on the CPU."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models import hybrid
 from iotml.models.hybrid import HybridConfig, SensorHybrid
 from iotml.models.latent_moe import ExpertLayer
 from iotml.ops import moe
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIGS = os.path.join(ROOT, "benchmark", "configs")
-CONFIG = os.path.join(CONFIGS, "sensorformer-nemotron-3-super-120b-a12b")
-#: width 64; the share held: 4 state heads of 8 in one group, state 8;
-#: 2 query heads of 16 on one key/value head; 16 experts of 24 in a
-#: latent of 32, 5 a token, 4 held, a shared expert of 48; `M E * E M`
-TINY = dict(hidden_size=64, mamba_num_heads=4, mamba_head_dim=8,
-            ssm_state_size=8, n_groups=1, chunk_size=8,
-            num_attention_heads=2, num_key_value_heads=1, head_dim=16,
-            moe_latent_size=32, moe_intermediate_size=24,
-            moe_shared_expert_intermediate_size=48, n_routed_experts=4,
-            num_experts_per_tok=5, num_hidden_layers=5,
-            hybrid_override_pattern="ME*EM")
-
-
-def _load(name, path):
-    spec = importlib.util.spec_from_file_location(name, path + ".py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with open(path + ".json") as fh:
-        return mod, json.load(fh)
-
-
-def _reference(name, **sizes):
-    mod, cfg = _load(name, CONFIG)
-    cfg.update(TINY)
-    cfg["published"] = dict(cfg["published"], n_routed_experts=16)
-    cfg["job"] = dict(cfg["job"], window=40)
-    cfg.update(sizes)
-    mod.use(cfg)
-    return mod, cfg
+from stacks import batch as _batch
+from stacks import close as _close
 
 
 @pytest.fixture(scope="module")
 def ref():
     """The configuration's plain reference at the tiny preset."""
-    return _reference("bench_nemotron_reference")
-
-
-def _batch(B=2, T=40, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
-
-
-def _close(got, want, rtol=2e-4):
-    """Within `rtol` of the reference's largest entry, leaf by leaf."""
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = max(float(jnp.abs(w).max()), 1e-30)
-        assert float(jnp.abs(g - w).max()) <= rtol * scale
+    return stacks.reference("nemotron")
 
 
 # --------------------------------------------- the model and the reference
@@ -105,74 +58,6 @@ def test_a_layer_builds_the_norm_of_the_part_it_has(ref):
             jax.random.PRNGKey(0), jnp.zeros((1, 8, 64)))
 
 
-@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
-def test_model_matches_the_plain_reference(ref, mode):
-    """Loss and every gradient leaf from the same seeded weights: the
-    chunked scan against the stepped recurrence, the tiles in the latent
-    against the dense-masked experts."""
-    from iotml.train.loop import make_loss_fn
-
-    mod, cfg = ref
-    x, y, mask = _batch()
-    params = mod.init_params(3)
-    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
-    loss = make_loss_fn(model, supervised=True)
-    with jax.default_matmul_precision("highest"):
-        (got, aux), grads = jax.jit(jax.value_and_grad(
-            loss, has_aux=True))(params, x, y, mask)
-        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
-            params, x, y, mask)
-    assert float(abs(got - want)) <= 1e-5 * float(want)
-    _close(grads, wants)
-    for i in (1, 3):
-        assert not np.asarray(grads[f"layer{i}"]["moe"]["router_bias"]).any()
-        assert np.asarray(grads[f"layer{i}"]["moe"]["latent_in"]
-                          ["kernel"]).any()
-    assert [int(c.sum()) for c in jax.tree.leaves(aux[2])] == [2 * 40 * 5] * 2
-
-
-def test_two_step_fit_matches_the_reference(ref):
-    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
-    epoch, against the reference's fit written out: losses, updated
-    parameters, both moments — and the expert counts read back with
-    them against the reference's router."""
-    from iotml.data.dataset import Batch
-    from iotml.train.loop import Trainer
-
-    mod, cfg = ref
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-3)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    stacked = [jnp.stack(v) for v in zip(*batches)]
-    try:
-        trainer._ensure_state(batches[0][0])
-        trainer.state = trainer.state.replace(
-            params=jax.tree.map(jnp.array, params))
-        with jax.default_matmul_precision("highest"):
-            history = trainer.fit_compiled(
-                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                       first_index=0) for x, y, _ in batches], epochs=2)
-            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(params, *stacked)
-            # the first step's assignments, by the reference's router
-            _, first = mod._km._loss_counts(params, *(v[0] for v in stacked))
-    finally:
-        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
-    adam = trainer.state.opt_state[0]
-    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
-           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
-    _close(adam.mu, mu)
-    _close(adam.nu, nu)
-    layers = history["reports"]["reports"]
-    counts = [np.asarray(jax.tree.leaves(layers[k])[0])
-              for k in ("layer1", "layer3")]
-    assert [c.shape for c in counts] == [(2, 2, 16)] * 2
-    assert all(int(c.sum()) == 2 * 2 * 2 * 40 * 5 for c in counts)
-    assert np.array_equal(np.stack([c[0, 0] for c in counts]), first)
-
-
 # ------------------------------------------------------- the chip's share
 def test_the_shares_add_up_to_the_uncut_layers():
     """The model at a small size — 2 B/C groups, 2 key/value heads, 8
@@ -181,8 +66,8 @@ def test_the_shares_add_up_to_the_uncut_layers():
     range's routed term through `W_back`, with the shared expert, the
     router and the latent projections counted once, add up to the uncut
     reference's layer, which has grouped B and C and the group norm."""
-    whole, cfg = _reference(
-        "bench_nemotron_uncut", mamba_num_heads=8, n_groups=2,
+    whole, cfg = stacks.tiny(
+        "nemotron", "bench_nemotron_uncut", mamba_num_heads=8, n_groups=2,
         num_attention_heads=4, num_key_value_heads=2, n_routed_experts=8,
         hybrid_override_pattern="M*E", num_hidden_layers=3)
     cfg["published"]["n_routed_experts"] = 8
@@ -303,8 +188,8 @@ def test_the_accepted_configurations_trees_are_what_they_were(name,
     another form — and all three what they built before grouped
     attention could norm and turn its heads, a mixer be a gated short
     convolution, an expert layer go without its shared expert."""
-    mod, cfg = _load("bench_tree_" + name.split("-")[1],
-                     os.path.join(CONFIGS, name))
+    mod, cfg = stacks.load(name.removeprefix("sensorformer-"),
+                           "bench_tree_" + name.split("-")[1])
     mod.use(cfg)
     model = SensorHybrid(mod.hybrid_config(cfg))
     assert not model.cfg.qk_norm and model.cfg.attn_rope_theta == 0 \
@@ -338,8 +223,7 @@ def test_the_accepted_configurations_trees_are_what_they_were(name,
                 {"mlp_in": {"kernel": (2048, 22528)},
                  "mlp_out": {"kernel": (11264, 2048)}})
     assert tree == want
-    assert sum(int(np.prod(s)) for s in jax.tree.leaves(
-        tree, is_leaf=lambda s: isinstance(s, tuple))) == parameters
+    assert stacks.parameters(tree) == parameters
     assert tree == jax.tree.map(jnp.shape, jax.eval_shape(
         lambda: mod.init_params(0)))
 
@@ -350,28 +234,14 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     latent's width — the `latent_proj` scope in the fit's program, and
     the data read back with the losses at the fit's one sync: the rows of
     the live tiles by whether they hold an assignment."""
-    from iotml.data.dataset import Batch
     from iotml.obs.metrics import default_registry
-    from iotml.train import loop
-    from iotml.train.loop import Trainer
 
     mod, cfg = ref
     monkeypatch.setattr(moe, "TILE", 16)
-    jax.clear_caches()
-    before = default_registry.collect()
-    gets = []
-    device_get = jax.device_get
-    monkeypatch.setattr(loop.jax, "device_get",
-                        lambda t: gets.append(1) or device_get(t))
-    x, y, _ = _batch()
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-5)
-    history = trainer.fit_compiled(
-        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-               first_index=0)] * 3, epochs=2)
-    got = default_registry.collect()
-    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
-    assert len(gets) == 1          # the reports came back with the losses
+    x = _batch()[0]
+    model = SensorHybrid(mod.hybrid_config(cfg))
+    history, before, got, gets = stacks.tiny_fit(model, monkeypatch)
+    assert gets == 1          # the reports came back with the losses
     assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
             ("mamba", "attention", "mla", "dense_ffn", "moe_ffn")] \
         == [2, 1, 0, 0, 2]
@@ -379,21 +249,18 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     # what the blocks' recomputation keeps, a step: two layers' selection
     # and plan (four [80, 5] arrays with the sorted weights, three fields
     # of 24 tiles of 16 rows, the live tiles' count, `counts`) and their
-    # routed sums [80, 32]; `dense` attention ran no kernel, and no
-    # latent attention is here
+    # routed sums [80, 32]
     assert moe.plan_kept_bytes(80, 5, 4, 16) == 4 * (4 * 400 + 3 * 24 + 1 + 16)
     assert [got[f'iotml_remat_kept_bytes{{kind="{k}"}}'] for k in
-            ("router", "experts", "flash", "latent_qk")] \
-        == [2 * moe.plan_kept_bytes(80, 5, 4, 16), 2 * 80 * 32 * 4, 0, 0]
+            ("router", "experts")] \
+        == [2 * moe.plan_kept_bytes(80, 5, 4, 16), 2 * 80 * 32 * 4]
     # and under the byte budget the two shared experts' first product,
     # [80, 48] each (non-gated: one product's width)
     assert got['iotml_remat_kept_bytes{kind="ffn"}'] == 2 * 80 * 48 * 4
     assert got['iotml_remat_kept_layers{kind="ffn"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn"}'] == 2
-    # no post norms: a part's output is no candidate
-    assert all(got[f'iotml_remat_{what}{{kind="{kind}"}}'] == 0
-               for what in ("kept_bytes", "kept_layers", "keepable_layers")
-               for kind in ("ffn_out", "mixer_out"))
+    # and nothing else: `dense` attention ran no kernel
+    stacks.only_these_kinds_are_kept(got, "router", "experts", "ffn")
     assert got["iotml_moe_latent_dim"] == 32
     assert got['iotml_moe_experts{kind="held"}'] == 4
     assert got['iotml_moe_experts{kind="routed_over"}'] == 16
@@ -410,14 +277,10 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     # every expert held walks whole tiles of 16 rows, a step and layer
     assert moved("iotml_moe_tile_rows_total", "padding") \
         == (-(-steps // 16) * 16).sum() - live > 0
-    # the scope rides the program's operations
-    model = SensorHybrid(mod.hybrid_config(cfg))
-    text = jax.jit(lambda p: model.apply(
-        {"params": p}, x, mutable=["reports"])[0]).lower(
-            mod.init_params(1)).as_text(debug_info=True)
-    for scope in ("latent_proj", "router", "experts", "shared", "ssm_proj",
-                  "ssd", "conv", "attn"):
-        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+    stacks.scopes_in_the_program(
+        model, mod.init_params(1), x,
+        ("latent_proj", "router", "experts", "shared", "ssm_proj", "ssd",
+         "conv", "attn"))
     # a layer that acts at full width says 0
     jax.clear_caches()
     ExpertLayer(HybridConfig()).init(jax.random.PRNGKey(0),

@@ -486,7 +486,7 @@ def test_metrics_server_concurrent_scrape():
 
 
 # -------------------------------------------------- cardinality bound
-def test_label_cardinality_bound():
+def test_label_cardinality_bound(one_deployments_registry):
     """Labels come from closed sets: the default registry is clean, and
     a runaway car_id-style label fails the check before it fails
     production."""

@@ -3,16 +3,14 @@ one set of sandwich-normed layers run several times a step, a final norm
 closing every pass, one head and one exit gate reading every pass's
 output, and the expected loss under the exit distribution as the
 model's own objective): each new part against the equations of the
-benchmark's plain reference (loaded by path, as `benchmark/tests` loads
-it), outputs and every gradient; the loop against an unrolled stack of
-copies; the model and one compiled job against the reference; what a fit
-says of the new parts; and the accepted stacks' parameter trees, which
-the new fields leave alone.  All at a tiny preset on the CPU."""
+benchmark's plain reference (`stacks.reference`), outputs and every
+gradient; the loop against an unrolled stack of copies; what a fit says
+of the new parts; and the accepted stacks' parameter trees, which the
+new fields leave alone.  The tree, the model and one compiled job
+against the reference are the `ouro` cases of `test_stack_contract.py`.
+All at a tiny preset on the CPU."""
 
 import dataclasses
-import importlib.util
-import json
-import os
 
 import flax.linen as nn
 import jax
@@ -20,73 +18,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models import hybrid
 from iotml.models.hybrid import HybridBlock, HybridConfig, SensorHybrid
 from iotml.train.loop import make_loss_fn
+from stacks import batch as _batch
+from stacks import close as _close
+from stacks import stream as _stream
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIGS = os.path.join(ROOT, "benchmark", "configs")
-#: width 64; 4 heads of 16 on 4 key/value heads; an MLP of 96; two
-#: layers, run the file's four times
-TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
-            head_dim=16, intermediate_size=96, num_hidden_layers=2)
-L, R = 2, 4
-
-
-def _load(name, stem):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(CONFIGS, stem + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with open(os.path.join(CONFIGS, stem + ".json")) as fh:
-        return mod, json.load(fh)
+L, R = 2, 4   # the preset's layers, and the passes the file states
 
 
 @pytest.fixture(scope="module")
 def ref():
     """The configuration's plain reference at the tiny preset."""
-    mod, cfg = _load("bench_ouro_reference", "sensorformer-ouro-2.6b")
-    cfg.update(TINY)
-    cfg["layer_types"] = cfg["layer_types"][:L]
-    cfg["job"] = dict(cfg["job"], window=40)
-    mod.use(cfg)
-    return mod, cfg
-
-
-def _batch(B=2, T=40, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
-
-
-def _stream(B=2, T=40, d=64, seed=0):
-    return jnp.asarray(np.random.default_rng(seed).normal(size=(B, T, d)),
-                       jnp.float32)
-
-
-def _close(got, want, rtol=2e-4):
-    """Within `rtol` of the reference's largest entry, leaf by leaf."""
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = max(float(jnp.abs(w).max()), 1e-30)
-        assert float(jnp.abs(g - w).max()) <= rtol * scale
+    return stacks.reference("ouro")
 
 
 def _params(mod, seed):
-    """Seeded weights whose norms' weights are not all one, the gate's
-    bias not zero: a norm on the wrong operand, or a bias left out,
-    would not hide."""
-    rng = np.random.default_rng(seed)
-
-    def unsettle(path, leaf):
-        names = [k.key for k in path]
-        if names[-1] == "scale" or names[-2:] == ["exit_gate", "bias"]:
-            return leaf + jnp.asarray(rng.uniform(-0.5, 0.5, leaf.shape),
-                                      jnp.float32)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(unsettle, mod.init_params(seed))
+    """Seeded weights whose norms' weights are not all one."""
+    return stacks.unsettled(mod.init_params(seed), seed)
 
 
 # ------------------------------------------- the parts and their equations
@@ -215,161 +166,20 @@ def test_the_loop_is_an_unrolled_stack_of_copies(ref):
         > 1e-2 * float(jnp.abs(first).max())
 
 
-# --------------------------------------------- the model and the reference
-def test_the_stack_builds_the_references_tree(ref):
-    """One set of layers however many passes, four norms a block, ONE
-    final norm, head and gate: the program's parameter tree is the
-    reference's, shape by shape, and counts what `loop_ops.parameters`
-    counts."""
-    mod, cfg = ref
-    model = SensorHybrid(mod.hybrid_config(cfg))
-    assert (model.cfg.loop_steps, model.cfg.post_norms) == (R, True)
-    shapes = jax.tree.map(jnp.shape, jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), _batch()[0])["params"])
-    assert shapes == jax.tree.map(jnp.shape, mod.init_params(3))
-    assert sorted(shapes) == ["embed", "exit_gate", "head", "layer0",
-                              "layer1", "norm_f"]
-    assert shapes["exit_gate"] == {"kernel": (64, 1), "bias": (1,)}
-    spec = importlib.util.spec_from_file_location(
-        "bench_loop_ops", os.path.join(ROOT, "benchmark", "loop_ops.py"))
-    ops = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ops)
-    assert ops.parameters(cfg) == sum(
-        int(np.prod(s)) for s in jax.tree.leaves(
-            shapes, is_leaf=lambda s: isinstance(s, tuple)))
-    with pytest.raises(ValueError, match="at least one pass"):
-        SensorHybrid(HybridConfig(loop_steps=0)).init(
-            jax.random.PRNGKey(0), _batch()[0])
-
-
-@pytest.mark.parametrize("mode", ["dense", "flash_interpret"])
-def test_model_matches_the_plain_reference(ref, mode):
-    """Loss and every gradient leaf from the same seeded weights: the
-    scan over the passes against four Python-level passes."""
-    mod, cfg = ref
-    x, y, mask = _batch()
-    params = _params(mod, 3)
-    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode)
-    loss = make_loss_fn(model, supervised=True)
-    with jax.default_matmul_precision("highest"):
-        (got, _), grads = jax.jit(jax.value_and_grad(
-            loss, has_aux=True))(params, x, y, mask)
-        want, wants = jax.jit(jax.value_and_grad(mod.loss_fn))(
-            params, x, y, mask)
-    assert float(abs(got - want)) <= 1e-5 * float(abs(want))
-    _close(grads, wants)
-    assert all(np.asarray(g).any() for g in jax.tree.leaves(grads))
-
-
-def test_two_step_fit_matches_the_reference(ref):
-    """`Trainer.fit_compiled` → the scanned fit, two Adam steps an
-    epoch, against the reference's fit written out: losses, updated
-    parameters, both moments — and the passes' losses and exit masses
-    read back with them against the reference's."""
-    from iotml.data.dataset import Batch
-    from iotml.train.loop import Trainer
-
-    mod, cfg = ref
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg)), supervised=True,
-                      learning_rate=1e-3)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    stacked = [jnp.stack(v) for v in zip(*batches)]
-    try:
-        trainer._ensure_state(batches[0][0])
-        trainer.state = trainer.state.replace(
-            params=jax.tree.map(jnp.array, params))
-        with jax.default_matmul_precision("highest"):
-            history = trainer.fit_compiled(
-                [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                       first_index=0) for x, y, _ in batches], epochs=2)
-            p, mu, nu, losses = mod.make_fit(mod.loss_fn, 2)(params, *stacked)
-            _, (first_loss, first_mass) = mod._objective(
-                params, *(v[0] for v in stacked))
-    finally:
-        cfg["model"]["optimizer"]["learning_rate"] = 1e-5
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-5)
-    adam = trainer.state.opt_state[0]
-    _close(jax.tree.map(lambda a, b: a - b, trainer.state.params, params),
-           jax.tree.map(lambda a, b: a - b, p, params), rtol=2e-3)
-    _close(adam.mu, mu)
-    _close(adam.nu, nu)
-    said = history["reports"][hybrid.OBJECTIVE]
-    assert said[hybrid.PASS_LOSS].shape == said[hybrid.EXIT_MASS].shape \
-        == (2, 2, R)
-    np.testing.assert_allclose(said[hybrid.PASS_LOSS][0, 0], first_loss,
-                               rtol=1e-5)
-    np.testing.assert_allclose(said[hybrid.EXIT_MASS][0, 0], first_mass,
-                               rtol=1e-5)
-
-
-@pytest.mark.parametrize("mode,in_kernel", [("flash_interpret", 2),
-                                            ("dense", 0)])
-def test_a_fit_at_heads_that_fill_the_lanes_says_which_form_turned(
-        mode, in_kernel):
-    """Eight heads of 16 at a width of 128 — `H·D` = one 128-lane tile:
-    under the kernels every application's q and k are turned by
-    `iotml_rope` on `[B, T, H·D]` (`iotml_attn_rotary_kernel` 2), under
-    `dense` by the pair form (0), and the compiled job's losses are the
-    reference's either way."""
-    from iotml.data.dataset import Batch
-    from iotml.obs.metrics import default_registry
-    from iotml.train.loop import Trainer
-
-    mod, cfg = _load("bench_ouro_lanes_" + mode, "sensorformer-ouro-2.6b")
-    cfg.update(TINY, hidden_size=128, num_attention_heads=8,
-               num_key_value_heads=8)
-    cfg["layer_types"] = cfg["layer_types"][:L]
-    cfg["job"] = dict(cfg["job"], window=40)
-    cfg["model"]["optimizer"]["learning_rate"] = 1e-3
-    mod.use(cfg)
-    jax.clear_caches()
-    batches = [_batch(seed=s) for s in (1, 2)]
-    params = mod.init_params(5)
-    trainer = Trainer(SensorHybrid(mod.hybrid_config(cfg), attn_mode=mode),
-                      supervised=True, learning_rate=1e-3)
-    trainer._ensure_state(batches[0][0])
-    trainer.state = trainer.state.replace(
-        params=jax.tree.map(jnp.array, params))
-    with jax.default_matmul_precision("highest"):
-        history = trainer.fit_compiled(
-            [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-                   first_index=0) for x, y, _ in batches], epochs=1)
-        *_, losses = mod.make_fit(mod.loss_fn, 1)(
-            params, *(jnp.stack(v) for v in zip(*batches)))
-    got = default_registry.collect()
-    assert got["iotml_attn_rotary_kernel"] == in_kernel
-    assert got["iotml_attn_rotary_dim"] == 16
-    np.testing.assert_allclose(history["loss"], losses, rtol=1e-4)
-
-
 # ------------------------------------------------------- what engaged
 def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
     """The trace-time gauges after a fit — the passes, the norms on a
     block's outputs, what the recomputation keeps over ALL passes — the
     passes' losses and exit masses as data, the new scopes in the fit's
     program, and the fit held to ONE `device_get`."""
-    from iotml.data.dataset import Batch
     from iotml.obs.metrics import default_registry
-    from iotml.train import loop
-    from iotml.train.loop import Trainer
 
     mod, cfg = ref
-    jax.clear_caches()
-    gets = []
-    device_get = jax.device_get
-    monkeypatch.setattr(loop.jax, "device_get",
-                        lambda t: gets.append(1) or device_get(t))
-    x, y, _ = _batch()
+    x = _batch()[0]
     model = SensorHybrid(mod.hybrid_config(cfg))
-    trainer = Trainer(model, supervised=True, learning_rate=1e-5)
-    history = trainer.fit_compiled(
-        [Batch(x=np.asarray(x), y=np.asarray(y), n_valid=2,
-               first_index=0)] * 2, epochs=1)
-    got = default_registry.collect()
-    assert history["fit"] == "scanned" and np.isfinite(history["loss"]).all()
-    assert len(gets) == 1          # the reports came back with the losses
+    history, _, got, gets = stacks.tiny_fit(model, monkeypatch, steps=2,
+                                            epochs=1)
+    assert gets == 1          # the reports came back with the losses
     assert got["iotml_model_loop_steps"] == R
     assert got["iotml_model_post_norms"] == 2
     assert [got[f'iotml_model_layers{{kind="{k}"}}'] for k in
@@ -395,7 +205,14 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
         == R * L * 80 * 64 * 4
     assert got['iotml_remat_kept_layers{kind="ffn_out"}'] \
         == got['iotml_remat_keepable_layers{kind="ffn_out"}'] == L
-    assert got['iotml_remat_kept_bytes{kind="flash"}'] == 0
+    # the mixer's output ahead of ITS post norm, after the first products
+    assert got['iotml_remat_kept_bytes{kind="mixer_out"}'] \
+        == R * L * 80 * 64 * 4
+    assert got['iotml_remat_kept_layers{kind="mixer_out"}'] \
+        == got['iotml_remat_keepable_layers{kind="mixer_out"}'] == L
+    # and nothing else: no kernel under `dense`, so no `flash` name
+    stacks.only_these_kinds_are_kept(got, "loop_inputs", "ffn", "ffn_out",
+                                     "mixer_out")
     # DATA: the last fit's means, a value a pass
     said = history["reports"][hybrid.OBJECTIVE]
     for name, key in (("iotml_loop_pass_loss", hybrid.PASS_LOSS),
@@ -406,11 +223,9 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
             rtol=1e-6)
     assert sum(got[f'iotml_loop_exit_mass{{kind="pass{t + 1}"}}']
                for t in range(R)) == pytest.approx(1.0, rel=1e-5)
-    # the scopes ride the program's operations
-    text = jax.jit(lambda p: model.apply({"params": p}, x)).lower(
-        mod.init_params(1)).as_text(debug_info=True)
-    for scope in ("attn", "rope", "mlp", "post_norm", "exit_gate"):
-        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+    stacks.scopes_in_the_program(
+        model, mod.init_params(1), x,
+        ("attn", "rope", "mlp", "post_norm", "exit_gate"))
     # with the kernels (traced, not run): out [80, 4 x 16] and lse
     # [80, 4] an application
     jax.eval_shape(SensorHybrid(mod.hybrid_config(cfg),
@@ -448,7 +263,8 @@ def test_the_accepted_hybrid_stacks_trees_are_what_they_were(name):
     tree its own plain reference writes down (each independent of the
     program) — one pass, no norm on a part's output, no gate, no
     objective of its own."""
-    mod, cfg = _load("bench_tree_for_ouro_" + name.split("-")[1], name)
+    mod, cfg = stacks.load(name.removeprefix("sensorformer-"),
+                           "bench_tree_for_ouro_" + name.split("-")[1])
     mod.use(cfg)
     model = SensorHybrid(mod.hybrid_config(cfg))
     assert (model.cfg.loop_steps, model.cfg.post_norms) == (1, False)
@@ -470,7 +286,7 @@ def test_the_sequence_models_tree_is_untouched():
     mean squared error of one output — no objective, no reports."""
     from iotml.models.transformer import SensorFormer
 
-    mod, cfg = _load("bench_tree_for_ouro_gpt2", "sensorformer-gpt2-medium")
+    mod, cfg = stacks.load("gpt2-medium", "bench_tree_for_ouro_gpt2")
     mod.use(cfg)
     m = cfg["model"]
     model = SensorFormer(features=m["features"], d_model=m["d_model"],

@@ -508,12 +508,18 @@ def test_lint_r17_and_phase_under_trace(tmp_path):
 # ------------------------------------------------------------- (h) cost
 def test_an_empty_phase_is_cheap():
     n = 10_000
-    t0 = time.perf_counter()
+    t0, own0 = time.perf_counter(), time.thread_time()
     for _ in range(n):
         with tracing.phase("score", "drain"):
             pass
     per = (time.perf_counter() - t0) / n
-    assert per < 20e-6, f"{per * 1e6:.1f} us per empty phase"
+    own = (time.thread_time() - own0) / n
+    # the thread's own clock holds the code to what it held on the wall's
+    # alone; the wall's, over the same whole loop, also counts what the
+    # worker's other threads and five more workers take under `-n 6`
+    # (150.1 us read there once, 4 us alone: ROADMAP D10)
+    assert own < 20e-6, f"{own * 1e6:.1f} us of its thread per empty phase"
+    assert per < 250e-6, f"{per * 1e6:.1f} us per empty phase"
 
 
 # ------------------------------------------- (i) the span log and the CLI

@@ -5,19 +5,19 @@ the layers a byte budget takes (`BUDGETED`, `budget_takes`), the
 feed-forward part's first product and, in a sandwich block, the parts'
 outputs ahead of their post norms.
 
-Three properties, each over the five shapes of stack the benchmark
-trains with recomputed blocks (Mamba + grouped-query attention; latent
-attention + experts at the stream's width; one-part layers + experts in
-a latent; gated short convolutions + experts without a shared one; a
-looped stack of sandwich-normed layers, whose passes are a scan: what a
-layer keeps it keeps in every pass, stacked) and a sandwich stack that
-is no loop, at
-a tiny preset on the CPU, with the budget held to what keeps every
-candidate, what keeps the first product in the last layer that makes
-one (and what is dearer than it: a sandwich block's feed-forward
-output, in every layer) and nothing: the policy
-changes no gradient and no report; the
-router's hand-written backward is autodiff of its forward; and in the
+Three properties, each over the shapes of stack the catalogue states
+(`stacks.STACKS`: the benchmark's five hybrid configurations at their
+tiny presets — Mamba + grouped-query attention; latent attention +
+experts at the stream's width; one-part layers + experts in a latent;
+gated short convolutions + experts without a shared one; a looped stack
+of sandwich-normed layers, whose passes are a scan: what a layer keeps
+it keeps in every pass, stacked — and a sandwich stack that is no loop),
+on the CPU, with the budget held to what keeps every candidate, what
+keeps the first product in the last layer that makes one (and what is
+dearer than it: a sandwich block's feed-forward output, in every layer)
+and nothing, each program made once a worker (`stacks.policy_run`): the
+policy changes no gradient and no report; the router's hand-written
+backward is autodiff of its forward; and in the
 gradient's jaxpr the kernel and top-k appear once a layer, the `highest`
 product three times and a sort twice (the plan's, and the one that
 brings the routing weights' cotangents back) — with no gather and no
@@ -25,121 +25,57 @@ scatter-add of the routing weights' scalars — and `mlp_in`'s or
 `shared_in`'s product three times in a layer that keeps it, four times
 in one that does not, and a sandwich block's `mlp_out` likewise by its
 output's name.  Then the counter beside the arrays the policy
-saves, and the budget's rule itself, pure functions: the order, the
-candidates of a configuration, and what the rule takes at the shapes of
-the benchmark's five hybrid cells."""
+saves; a pin of what the module listed and said before its kept values
+were rows of one table; and the budget's rule itself, pure functions:
+the order, the candidates of a configuration, and what the rule takes
+at the shapes of the benchmark's five hybrid cells."""
 
 import importlib.util
 import json
 import os
 import re
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from iotml.models import hybrid
 from iotml.models.hybrid import Candidate, HybridConfig, SensorHybrid
 from iotml.ops import moe
-from iotml.train.loop import make_loss_fn
-
-_EXPERTS = dict(experts=16, experts_held=(2, 4), top_k=3, expert_dim=24,
-                shared_dim=48, routed_scale=2.5)
-#: name → (configuration, attention layers, expert layers)
-STACKS = {
-    "mamba_gqa": (HybridConfig(), 1, 0),
-    "mla_experts": (HybridConfig(
-        layer_types=("mla", "mla", "mla"),
-        ffn_types=("dense_ffn", "moe_ffn", "moe_ffn"), **_EXPERTS), 3, 2),
-    "one_part_latent": (HybridConfig(
-        layer_types=("mamba", "none", "attention", "none", "mamba"),
-        ffn_types=("none", "moe_ffn", "none", "moe_ffn", "none"),
-        num_heads=2, num_kv_heads=1, head_dim=16, moe_latent=32,
-        expert_form="relu2", **dict(_EXPERTS, top_k=5)), 1, 2),
-    "short_conv_no_share": (HybridConfig(
-        layer_types=("short_conv", "attention", "short_conv"),
-        ffn_types=("dense_ffn", "moe_ffn", "moe_ffn"), qk_norm=True,
-        attn_rope_theta=10000.0, **dict(_EXPERTS, shared_dim=0)), 1, 2),
-    "looped": (HybridConfig(
-        layer_types=("attention", "attention"), num_kv_heads=4,
-        attn_rope_theta=10000.0, loop_steps=3, post_norms=True), 2, 0),
-    "sandwich": (HybridConfig(
-        layer_types=("mamba", "attention"), post_norms=True), 1, 0),
-}
-MODES = ("flash_interpret", "dense")
-#: the layers whose feed-forward product the budget is held to take
-KEEPS = ("all", "last", "none")
+from stacks import KEEPS, MODES, PLAIN, STACKS, nbytes, policy_run
 
 
-def _batch(B=2, T=40, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, T, 18)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, 18)), jnp.float32),
-            jnp.ones((B,), jnp.float32))
+@pytest.fixture(scope="module", params=[(stack, keep) for stack in STACKS
+                                        for keep in KEEPS],
+                ids=lambda held: "-".join(held))
+def held(request):
+    """(a shape of stack, what the byte budget is held to keep): of
+    module scope, so that the cases of one pair are neighbours whichever
+    test they are of, and a worker that is handed them makes each
+    program (`stacks.policy_run`) once."""
+    return request.param
 
 
-def _hold_budget(monkeypatch, cfg, x, keep) -> tuple:
-    """The byte budget set to what keeps the feed-forward part's first
-    product as `keep` says — `all`: every candidate; `last`: that
-    product in the last layer that makes one, after what is dearer than
-    it; `none`: nothing.  → the layers that then keep that product, the
-    layers that make one, and all the budget took."""
-    candidates = hybrid.budget_candidates(cfg, x.shape[0] * x.shape[1],
-                                          x.dtype.itemsize)
-    first = [c for c in candidates if c.name == hybrid.FFN_KEPT and c.bytes]
-    makes = tuple(c.layer for c in first)
-    dearer = [c for c in candidates if c.density > first[-1].density]
-    budget = {"all": sum(c.bytes for c in candidates), "none": 0,
-              "last": sum(c.bytes for c in dearer) + first[-1].bytes}[keep]
-    monkeypatch.setattr(hybrid, "remat_budget", lambda *sizes: budget)
-    taken = hybrid.budget_takes(candidates, budget)
-    keeps = tuple(c.layer for c in taken if c.name == hybrid.FFN_KEPT)
-    assert keeps == {"all": makes, "last": makes[-1:], "none": ()}[keep]
-    assert set(taken) == {"all": {c for c in candidates if c.bytes},
-                          "last": set(dearer) | {first[-1]},
-                          "none": set()}[keep]
-    # a sandwich block's feed-forward output is dearer than the product
-    assert bool(dearer) == cfg.post_norms
-    return keeps, makes, taken
-
-
-def _grads_and_reports(model, params, batch):
-    loss = make_loss_fn(model, supervised=True)
-    (_, aux), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(
-        params, *batch)
-    return grads, aux[2:]
-
-
-@pytest.mark.parametrize("keep", KEEPS)
 @pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("stack", STACKS)
-def test_the_policy_changes_no_gradient_and_no_report(monkeypatch, stack,
-                                                      mode, keep):
+def test_the_policy_changes_no_gradient_and_no_report(held, mode):
     """The kept values are the ones the recomputation would make: the
     loss's gradients and the expert layers' `reports` with the policy
     equal those of the same model under plain `nn.remat`, whichever
     layers keep their feed-forward product."""
-    model = SensorHybrid(STACKS[stack][0], attn_mode=mode)
-    batch = _batch()
-    params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
-    _hold_budget(monkeypatch, STACKS[stack][0], batch[0], keep)
-    kept, kept_reports = _grads_and_reports(model, params, batch)
-
-    plain = nn.remat
-    monkeypatch.setattr(hybrid.nn, "remat", lambda target, policy=None: plain(target))
-    again, again_reports = _grads_and_reports(model, params, batch)
-
-    assert jax.tree.structure(kept) == jax.tree.structure(again)
-    for got, want in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+    stack, keep = held
+    kept, again = policy_run(stack, mode, keep), policy_run(stack, mode, PLAIN)
+    assert jax.tree.structure(kept.grads) == jax.tree.structure(again.grads)
+    for got, want in zip(jax.tree.leaves(kept.grads),
+                         jax.tree.leaves(again.grads)):
         scale = max(float(jnp.abs(want).max()), 1e-30)
         assert float(jnp.abs(got - want).max()) <= 2e-6 * scale
     assert jax.tree.all(jax.tree.map(
-        lambda a, b: bool((a == b).all()), kept_reports, again_reports))
+        lambda a, b: bool((a == b).all()), kept.reports, again.reports))
     # expert layers report, and a looped stack's objective
-    assert bool(kept_reports) == bool(
-        STACKS[stack][2] or STACKS[stack][0].loop_steps > 1)
+    assert bool(kept.reports) == bool(
+        STACKS[stack].routed or stacks.config(stack).loop_steps > 1)
 
 
 def _route_by_autodiff(u, gate, bias, top_k, scale):
@@ -181,49 +117,7 @@ def test_the_routers_backward_is_autodiff_of_its_forward(seed):
             != moe.route(u, gate, 0 * bias, 5, 2.5)[0]).any()
 
 
-def _count(jaxpr, found, counts):
-    """Equations of `jaxpr` and of every jaxpr inside it, by `found`."""
-    for eqn in jaxpr.eqns:
-        kind = found(eqn)
-        if kind:
-            counts[kind] = counts.get(kind, 0) + 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _count(sub, found, counts)
-    return counts
-
-
-def _what(assignments):
-    """What `_count` counts of an equation; a `gather` or `scatter-add`
-    only where its operand is a float vector of `assignments` entries:
-    a layer's routing weights, or their cotangents, a scalar at a time."""
-    def found(eqn):
-        name = eqn.primitive.name
-        if name == "pallas_call":
-            return eqn.params["name"]
-        if name == "dot_general":
-            # a feed-forward part's first product, forward, recomputed
-            # or backward, by the names its module and layer trace under
-            where = str(eqn.source_info.name_stack)
-            first = re.search(r"\b(?:mlp|shared)_in\b", where)
-            if first:
-                return "ffn_in:" + re.search(r"\blayer(\d+)\b", where)[1]
-            if re.search(r"\bmlp_out\b", where):
-                return "ffn_out:" + re.search(r"\blayer(\d+)\b", where)[1]
-            precision = eqn.params["precision"]
-            return "highest" if precision is not None and all(
-                p == jax.lax.Precision.HIGHEST for p in precision) else None
-        if name in ("gather", "scatter-add"):
-            aval = eqn.invars[0].aval
-            return name if aval.shape == (assignments,) and jnp.issubdtype(
-                aval.dtype, jnp.floating) else None
-        return name if name in ("top_k", "sort") else None
-    return found
-
-
-@pytest.mark.parametrize("keep", KEEPS)
-@pytest.mark.parametrize("stack", STACKS)
-def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
-                                                              stack, keep):
+def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(held):
     """In the gradient's jaxpr: `iotml_flash_fwd` once an attention
     layer (as often as the backward kernel), `top_k` once an expert
     layer, `sort` twice (the plan's, which carries the routing weights
@@ -239,152 +133,127 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(monkeypatch,
     reads it in the backward: three) — and
     under plain `nn.remat`, the recomputed forward's top-k, sort and
     products beside them."""
-    cfg, attention, routed = STACKS[stack]
-    model = SensorHybrid(cfg, attn_mode="flash_interpret")
-    batch = _batch()
-    params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
-    keeps, makes, taken = _hold_budget(monkeypatch, cfg, batch[0], keep)
-    loss = make_loss_fn(model, supervised=True)
-    what = _what(batch[0].shape[0] * batch[0].shape[1] * cfg.top_k)
-
-    def counted():
-        jax.clear_caches()
-        return _count(jax.make_jaxpr(jax.grad(loss, has_aux=True))(
-            params, *batch).jaxpr, what, {})
+    stack, keep = held
+    cfg, attention, routed = stacks.config(stack), \
+        STACKS[stack].attention, STACKS[stack].routed
+    run = policy_run(stack, "flash_interpret", keep)
 
     def of(counts, *names):
         return tuple(counts.get(name, 0) for name in names)
 
-    kept = counted()
+    kept = run.counts
     assert kept.get("iotml_flash_fwd", 0) == attention \
         == kept.get("iotml_flash_bwd_dkv", 0)
     assert of(kept, "top_k", "sort", "highest") \
         == (routed, 2 * routed, 3 * routed)
     assert of(kept, "gather", "scatter-add") == (0, 0)
     assert {k: n for k, n in kept.items() if k.startswith("ffn_in:")} \
-        == {f"ffn_in:{i}": 3 if i in keeps else 4 for i in makes}
+        == {f"ffn_in:{i}": 3 if i in run.keeps else 4 for i in run.makes}
     dense = [i for i, ffn in enumerate(cfg.ffn_kinds()) if ffn == "dense_ffn"]
-    out_kept = {c.layer for c in taken if c.name == hybrid.FFN_OUT}
+    out_kept = {c.layer for c in run.taken if c.name == hybrid.FFN_OUT}
     assert {k: n for k, n in kept.items() if k.startswith("ffn_out:")} \
         == {f"ffn_out:{i}": 3 + (cfg.post_norms and i not in out_kept)
             for i in dense}
 
-    plain = nn.remat
-    monkeypatch.setattr(hybrid.nn, "remat", lambda target, policy=None: plain(target))
-    again = counted()
+    again = policy_run(stack, "flash_interpret", PLAIN).counts
     assert again.get("iotml_flash_fwd", 0) == 2 * attention
     assert of(again, "top_k", "sort", "highest") \
         == (2 * routed, 3 * routed, 4 * routed)
     assert of(again, "gather", "scatter-add") == (0, 0)
-    assert of(again, *(f"ffn_in:{i}" for i in makes)) == (4,) * len(makes)
+    assert of(again, *(f"ffn_in:{i}" for i in run.makes)) \
+        == (4,) * len(run.makes)
     assert of(again, *(f"ffn_out:{i}" for i in dense)) \
         == (3 + cfg.post_norms,) * len(dense)
 
 
-def _through(jaxpr) -> dict:
-    """jax hands a saved residual on through a `reduce_precision`."""
-    return {id(eqn.invars[0]): id(eqn.outvars[0]) for eqn in jaxpr.eqns
-            if eqn.primitive.name == "reduce_precision"}
-
-
-def _read_back(jaxpr) -> set:
-    """The values a recomputation in `jaxpr` reads."""
-    return {id(v) for eqn in jaxpr.eqns
-            if eqn.primitive.name in ("remat2", "checkpoint")
-            for v in eqn.invars}
-
-
-def _named(jaxpr, name):
-    """(the value, or what a `reduce_precision` made of it) of every
-    value named `name` in `jaxpr`."""
-    through = _through(jaxpr)
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "name" and eqn.params["name"] == name:
-            out = id(eqn.outvars[0])
-            yield eqn.outvars[0], through.get(out, out)
-
-
-def _saved(jaxpr, name, found):
-    """The avals of the values named `name` that a recomputation in
-    `jaxpr` reads back from the forward pass: the ones the policy
-    saved.  Where the passes of a loop are a scan, the forward scan
-    stacks what its body named and the backward scan's body reads a
-    pass's slice of it back: the stacked array is what was saved."""
-    read = _read_back(jaxpr)
-    for value, out in _named(jaxpr, name):
-        if out in read:
-            found.append(value.aval)
-    scans = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "scan"]
-    sliced = set()   # stacked arrays a backward body's recomputation reads
-    for eqn in scans:
-        body, first = eqn.params["jaxpr"].jaxpr, \
-            eqn.params["num_consts"] + eqn.params["num_carry"]
-        read = _read_back(body)
-        sliced |= {id(outer) for outer, inner in zip(
-            eqn.invars[first:], body.invars[first:]) if id(inner) in read}
-    for eqn in scans:
-        body = eqn.params["jaxpr"].jaxpr
-        place = {id(v): i for i, v in enumerate(body.outvars)}
-        for _, out in _named(body, name):
-            stacked = eqn.outvars[place[out]] if out in place else None
-            if stacked is not None and id(stacked) in sliced:
-                found.append(stacked.aval)
-    for eqn in jaxpr.eqns:
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _saved(sub, name, found)
-    return found
-
-
-@pytest.mark.parametrize("keep", KEEPS)
-@pytest.mark.parametrize("stack", STACKS)
-def test_the_counter_says_the_bytes_the_policy_saves(monkeypatch, stack,
-                                                     keep):
+def test_the_counter_says_the_bytes_the_policy_saves(held):
     """`iotml_remat_kept_bytes{kind=…}` of each budgeted name's kind
     (`ffn`: `FFN_KEPT`; `ffn_out`, `mixer_out`: a sandwich block's
     parts' outputs) is the bytes of the arrays of that name that the
     gradient's recomputations read back from the forward pass,
     `iotml_remat_kept_layers` their count, and
     `iotml_remat_keepable_layers` the layers that make one."""
-    from iotml.obs.metrics import default_registry
-
-    cfg = STACKS[stack][0]
-    model = SensorHybrid(cfg, attn_mode="flash_interpret")
-    batch = _batch()
-    params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
-    _, makes, taken = _hold_budget(monkeypatch, cfg, batch[0], keep)
-    jax.clear_caches()
-    jaxpr = jax.make_jaxpr(jax.grad(
-        make_loss_fn(model, supervised=True), has_aux=True))(
-            params, *batch).jaxpr
-    said = default_registry.collect()
+    stack, keep = held
+    cfg = stacks.config(stack)
+    run = policy_run(stack, "flash_interpret", keep)
+    said = run.said
     sandwiched = len(cfg.layer_types) * cfg.post_norms
     saved = []
     for name, kind in hybrid.BUDGETED.items():
-        found = _saved(jaxpr, name, [])
+        found = run.saved[name]
         assert said[f'iotml_remat_kept_bytes{{kind="{kind}"}}'] \
-            == sum(a.size * a.dtype.itemsize for a in found)
+            == nbytes(found)
         assert said[f'iotml_remat_kept_layers{{kind="{kind}"}}'] \
-            == len(found) == sum(c.name == name for c in taken)
+            == len(found) == sum(c.name == name for c in run.taken)
         assert said[f'iotml_remat_keepable_layers{{kind="{kind}"}}'] \
-            == (len(makes) if name == hybrid.FFN_KEPT else sandwiched)
+            == (len(run.makes) if name == hybrid.FFN_KEPT else sandwiched)
         saved += found
     assert bool(saved) == (keep != "none")
     if cfg.loop_steps > 1:
         # every pass's, stacked: the kernel's out and lse a layer, and
         # the stream-sized inputs the scan keeps beside the names (a
         # kept part's output is of the stream's size too)
-        flash = _saved(jaxpr, "flash_out", []) + _saved(jaxpr, "flash_lse", [])
+        flash = [*run.saved["flash_out"], *run.saved["flash_lse"]]
         assert all(a.shape[0] == cfg.loop_steps for a in saved + flash)
-        assert said['iotml_remat_kept_bytes{kind="flash"}'] \
-            == sum(a.size * a.dtype.itemsize for a in flash)
-        forward = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
-        stream = (cfg.loop_steps,) + batch[0].shape[:2] + (cfg.d_model,)
+        assert said['iotml_remat_kept_bytes{kind="flash"}'] == nbytes(flash)
         assert sum(said[f'iotml_remat_kept_bytes{{kind="{kind}"}}']
-                   for kind in ("loop_inputs", "ffn_out", "mixer_out")) == sum(
-            v.aval.size * v.aval.dtype.itemsize for v in forward.outvars
-            if v.aval.shape == stream)
+                   for kind in ("loop_inputs", "ffn_out", "mixer_out")) \
+            == run.stacked_streams
     else:
         assert said['iotml_remat_kept_bytes{kind="loop_inputs"}'] == 0
+
+
+#: what every layer's policy lists, and at every row's tiny preset: the
+#: names the budget buys a layer, in the order it buys them; kind →
+#: (bytes, layers kept, layers that make one) of every kind that reads
+#: other than 0 under `dense`; `flash`'s bytes under the kernels
+_ALWAYS = ("flash_out", "flash_lse", "mla_q", "mla_k", "route_experts",
+           "route_picked", "dispatch_plan", "routed_sum")
+_ALL = ("ffn_out", "ffn_hidden", "mixer_out")
+_SAID = {
+    "granite": ((("ffn_hidden",),) * 3, {"ffn": (129024, 3, 3)}, 11424),
+    "kimi": ((("ffn_hidden",),) * 3,
+             {"ffn": (92160, 3, 3), "latent_qk": (184320, 0, 0),
+              "router": (7984, 0, 0)}, 65280),
+    "nemotron": (((), ("ffn_hidden",), (), ("ffn_hidden",), ()),
+                 {"ffn": (30720, 2, 2), "experts": (20480, 0, 0),
+                  "router": (13128, 0, 0)}, 10880),
+    "lfm2": ((("ffn_hidden",), (), (), (), ()),
+             {"ffn": (61440, 1, 1), "router": (15968, 0, 0)}, 21760),
+    "ouro": ((_ALL,) * 2,
+             {"ffn": (491520, 2, 2), "ffn_out": (163840, 2, 2),
+              "mixer_out": (163840, 2, 2), "loop_inputs": (245760, 0, 0)},
+             174080),
+    "sandwich": ((_ALL,) * 2,
+                 {"ffn": (163840, 2, 2), "ffn_out": (40960, 2, 2),
+                  "mixer_out": (40960, 2, 2)}, 21760),
+}
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("stack", STACKS)
+def test_what_every_layer_lists_and_every_gauge_says(stack, mode):
+    """The names each layer's policy lists and every `iotml_remat_*`
+    series, at the row's tiny preset with the budget as the device gives
+    it."""
+    bought, kinds, flash = _SAID[stack]
+    model = SensorHybrid(stacks.config(stack), attn_mode=mode)
+    jax.clear_caches()
+    listed = stacks.policy_names(
+        model, stacks.batch(T=STACKS[stack].window)[0])
+    assert listed == {f"layer{i}": _ALWAYS + names
+                      for i, names in enumerate(bought)}
+    want = {"iotml_remat_blocks": len(bought)}
+    if mode != "dense":
+        kinds = dict(kinds, flash=(flash, 0, 0))
+    for kind, values in kinds.items():
+        want.update({f'iotml_remat_{what}{{kind="{kind}"}}': value
+                     for what, value in zip(stacks.REMAT_GAUGES, values)
+                     if value})
+    said = stacks.remat_gauges(stacks.default_registry.collect())
+    # a kind's bytes; a budgeted kind's layers, kept and keepable; blocks
+    assert len(said) == len(hybrid.TABLE) + 2 * len(hybrid.BUDGETED) + 1
+    assert said == {**dict.fromkeys(said, 0), **want}
 
 
 @pytest.mark.parametrize("candidates, budget, kept", [

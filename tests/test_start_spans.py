@@ -353,7 +353,8 @@ def test_the_compile_cache_counts_by_program(cache_dir, result):
     assert "other" not in report["hit"] + report["missed"]
 
 
-def test_the_label_vocabulary_knows_the_program_of_a_lookup():
+def test_the_label_vocabulary_knows_the_program_of_a_lookup(
+        one_deployments_registry):
     assert obs_metrics.DECLARED_METRIC_LABELS["compile_cache"] == (
         "program", "result")
     assert obs_metrics.DECLARED_METRIC_LABELS["step_seconds"] == (

@@ -71,6 +71,44 @@ def test_segment_writer_rejects_bad_fsync_policy(tmp_path):
 
 
 # ------------------------------------------------------ log + recovery
+def test_two_writers_of_one_path_each_publish_a_whole_file(tmp_path):
+    """`atomic_write`'s temporary is the call's own: two threads that
+    write one path a few hundred times never find the other's rename
+    has taken their file, a reader sees one whole blob or the other at
+    every moment, and no temporary is left behind."""
+    import threading
+
+    path = str(tmp_path / "sidecar.index")
+    blobs = [bytes([i]) * (4096 + i) for i in (1, 2)]
+    failed = []
+
+    def write(blob):
+        try:
+            for _ in range(300):
+                seg.atomic_write(path, blob, fsync=False)
+                with open(path, "rb") as fh:
+                    assert fh.read() in blobs
+        except BaseException as exc:   # a thread's failure is the test's
+            failed.append(exc)
+
+    threads = [threading.Thread(target=write, args=(b,)) for b in blobs]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not failed
+    with open(path, "rb") as fh:
+        assert fh.read() in blobs
+    assert os.listdir(tmp_path) == ["sidecar.index"]
+
+
+def test_a_write_that_fails_leaves_no_temporary(tmp_path):
+    (tmp_path / "taken").mkdir()   # a rename onto a directory fails
+    with pytest.raises(OSError):
+        seg.atomic_write(str(tmp_path / "taken"), b"blob", fsync=False)
+    assert os.listdir(tmp_path) == ["taken"]
+
+
 def test_roll_retention_and_sparse_index(tmp_path):
     pol = StorePolicy(fsync="never", segment_bytes=300,
                       index_interval_bytes=128)
