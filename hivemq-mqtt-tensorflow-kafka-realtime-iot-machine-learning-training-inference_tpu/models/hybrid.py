@@ -1,8 +1,10 @@
 """SensorHybrid: a stack of layers whose two parts are data — a mixer
 (`layer_types`: `mamba`, Mamba-2's chunked state-space scan; `attention`,
-grouped-query attention that may norm and turn its heads; `mla`, the
-latent attention of `models.latent_moe`; `short_conv`, LFM2's gated
-short convolution) and a feed-forward part (`ffn_types`: `dense_ffn`, a
+grouped-query attention that may norm and turn its heads;
+`window_attention`, the same over a sliding window of the last
+`attn_window` keys — a band of the flash kernels' tiles beside the
+triangle; `mla`, the latent attention of `models.latent_moe`;
+`short_conv`, LFM2's gated short convolution) and a feed-forward part (`ffn_types`: `dense_ffn`, a
 gated-SiLU MLP, or `moe_ffn`, that file's sparse-expert layer; left
 empty, every layer is `dense_ffn`) — over long per-car sensor histories.
 
@@ -11,7 +13,10 @@ A layer is IBM Granite 4.0-H's block,
     h ← h + r · mixer(RMSNorm(h))        h ← h + r · ffn(RMSNorm(h))
 
 with the residual multiplier r, weight-only RMSNorm and no bias on any
-projection.  Either part may be `none`: the layer is then ONE part alone
+projection.  Rotary positions are a LAYER's (`rope_layout`: which layers'
+grouped attention turns its heads by `attn_rope_theta`; left empty,
+every one), and an expert layer's router may read the block's own
+input, ahead of the mixer (`router_input`).  Either part may be `none`: the layer is then ONE part alone
 and builds the norm and the residual of the part it has.  A block may
 norm each part's OUTPUT too (`post_norms`: sandwich norms,
 `h + N(part(N(h)))`), and a stack may be a LOOP (`loop_steps` > 1): one
@@ -66,7 +71,9 @@ from .latent_moe import normal as _normal
 #: a device's memory where the backend reports no `bytes_limit` (the
 #: CPU): a TPU v5e's
 DEVICE_BYTES = 16 * 2 ** 30
-KINDS = ("mamba", "attention", "mla", "short_conv")
+KINDS = ("mamba", "attention", "mla", "short_conv", "window_attention")
+#: the mixers that are `GroupedAttention`: over the whole past, or a window
+GROUPED = ("attention", "window_attention")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
 NONE = "none"   # a layer without that part
 #: what a model's own objective reports, beside its layers' collections
@@ -98,6 +105,12 @@ class HybridConfig:
     # rotary positions over the whole head (0: none)
     qk_norm: bool = False
     attn_rope_theta: float = 0.0
+    # which layers' grouped attention turns its heads, a flag a layer
+    # (the source's `rope_layout`); (): every one, where theta is set
+    rope_layout: Tuple[int, ...] = ()
+    # the keys a `window_attention` layer's query meets, its own position
+    # among them: t − attn_window < j ≤ t
+    attn_window: int = 0
     eps: float = 1e-5
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
@@ -125,6 +138,12 @@ class HybridConfig:
     # the latent the routed ones act in (0: at the stream's width)
     expert_form: str = "gated_silu"
     moe_latent: int = 0
+    # the router (`ops.moe.ROUTER_FORMS`: the sigmoid form has the
+    # selection-only bias, the other no such leaf) and what it reads:
+    # `ffn`, the normed stream the experts read, or `block`, the block's
+    # own input ahead of the mixer, un-normed
+    router_form: str = "sigmoid"
+    router_input: str = "ffn"
     # a looped stack: the passes a step makes over its one set of layers
     # (1: none, and no exit gate), whether a block norms each part's
     # OUTPUT ahead of the residual add (sandwich norms), and the weight
@@ -138,6 +157,12 @@ class HybridConfig:
 
     def attn_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    def turns(self, layer: int) -> bool:
+        """Whether layer `layer`'s grouped attention turns its heads."""
+        return bool(self.attn_rope_theta) and self.layer_types[layer] \
+            in GROUPED and bool(not self.rope_layout
+                                or self.rope_layout[layer])
 
 
 class Kept(NamedTuple):
@@ -164,6 +189,7 @@ def part_inner(m: HybridConfig, part: str) -> int:
     return {"dense_ffn": m.mlp_dim,
             "moe_ffn": m.shared_dim + (m.moe_latent or m.top_k * m.expert_dim),
             "attention": m.num_heads * m.attn_head_dim(),
+            "window_attention": m.num_heads * m.attn_head_dim(),
             "mla": m.num_heads * m.v_dim,
             "mamba": m.ssm_heads * m.ssm_head_dim,
             "short_conv": m.d_model}.get(part, 0)
@@ -191,8 +217,8 @@ _SCAN = Kept("loop_inputs", (), {})
 TABLE = (
     # out [B, T, H, Dv] and a float32 lse [B, H, T]
     Kept("flash", ("flash_out", "flash_lse"),
-         {"attention": lambda m, tokens, size: tokens * m.num_heads
-          * (m.attn_head_dim() * size + 4),
+         {**dict.fromkeys(GROUPED, lambda m, tokens, size: tokens
+                          * m.num_heads * (m.attn_head_dim() * size + 4)),
           "mla": lambda m, tokens, size: tokens * m.num_heads
           * (m.v_dim * size + 4)}, kernels=True),
     # latent attention's rotated q and assembled k, [B, T, H, nope + rope]
@@ -396,8 +422,9 @@ def rotary_tables(m: HybridConfig, attn_mode: str, T: int):
     positions — or None where the pair form turns them: no positions,
     `dense` attention (the plain path), or query or key heads that fill
     no whole 128-lane tiles.  `SensorHybrid` makes them once a step,
-    outside the passes' loop and the blocks' recomputation; a layer
-    applied on its own makes its own."""
+    outside the passes' loop and the blocks' recomputation, for the
+    layers that turn (`HybridConfig.turns`); a layer applied on its own
+    makes its own."""
     D = m.attn_head_dim()
     if not m.attn_rope_theta or attn_mode == "dense" or not all(
             rope.lanes(n * D, D) for n in (m.num_heads, m.num_kv_heads)):
@@ -411,29 +438,37 @@ class GroupedAttention(nn.Module):
     own; `qk_norm`: a weight-only RMSNorm over a head's features, one
     weight vector for all query heads and one for all key heads;
     `attn_rope_theta` (0: none, the state-space layers carry order):
-    rotary positions over the whole head, after the norms."""
+    rotary positions over the whole head, after the norms — in the
+    layers that `turn`; `window` (0: the whole causal past): the keys a
+    query meets, its own position among them."""
 
     cfg: HybridConfig
     attn_mode: str   # dense | flash | flash_interpret
+    turn: bool = True
+    window: int = 0
 
     @nn.compact
     def __call__(self, u, rope_tables=None):
         m = self.cfg
         B, T, _ = u.shape
         H, G, D = m.num_heads, m.num_kv_heads, m.attn_head_dim()
+        theta = m.attn_rope_theta if self.turn else 0.0
+        obs_metrics.attn_window.set(self.window)
         q = _dense(H * D, "q")(u).reshape(B, T, H, D)
         k = _dense(G * D, "k")(u).reshape(B, T, G, D)
         v = _dense(G * D, "v")(u).reshape(B, T, G, D)
         obs_metrics.attn_qk_norm.set(int(m.qk_norm))
-        obs_metrics.attn_rotary_dim.set(D if m.attn_rope_theta else 0)
+        obs_metrics.attn_rotary_dim.set(D if theta else 0)
         if m.qk_norm:
             with jax.named_scope("qk_norm"):
                 q = nn.RMSNorm(epsilon=m.eps, name="q_norm")(q)
                 k = nn.RMSNorm(epsilon=m.eps, name="k_norm")(k)
-        if rope_tables is None:
+        if not theta:
+            rope_tables = None   # the stack's, for the layers that turn
+        elif rope_tables is None:
             rope_tables = rotary_tables(m, self.attn_mode, T)
         obs_metrics.attn_rotary_kernel.set(2 * (rope_tables is not None))
-        if m.attn_rope_theta:
+        if theta:
             # in the flash kernels' own layout where they run and the
             # lanes allow, else as XLA's pair form
             with jax.named_scope("rope"):
@@ -444,7 +479,8 @@ class GroupedAttention(nn.Module):
                         a, rope_tables,
                         interpret=self.attn_mode == "flash_interpret")
                         for a in (q, k))
-        o = causal_attention(q, k, v, self.attn_mode, m.attention_multiplier)
+        o = causal_attention(q, k, v, self.attn_mode, m.attention_multiplier,
+                             self.window or None)
         return _dense(m.d_model, "o")(o.reshape(B, T, H * D))
 
 
@@ -453,12 +489,19 @@ class HybridBlock(nn.Module):
     cfg: HybridConfig
     attn_mode: str
     ffn: str = "dense_ffn"
+    turn: bool = True   # grouped attention's heads take rotary positions
 
     @nn.compact
     def __call__(self, h, rope_tables=None):
         m = self.cfg
         obs_metrics.model_post_norms.set(
             m.post_norms * ((self.kind != NONE) + (self.ffn != NONE)))
+        plan = None
+        if self.ffn == "moe_ffn" and m.router_input == "block":
+            # the router reads the block's own input, ahead of the mixer
+            # and un-normed: its plan waits for the experts behind it
+            experts = ExpertLayer(m, name="moe")
+            plan = experts(h, plan_only=True)
         if self.kind != NONE:
             u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
             if self.kind == "mamba":
@@ -472,12 +515,16 @@ class HybridBlock(nn.Module):
                                                 name="mixer")(u)
                     else:
                         mixed = GroupedAttention(
-                            m, self.attn_mode, name="mixer")(u, rope_tables)
+                            m, self.attn_mode, self.turn,
+                            (self.kind == "window_attention")
+                            * m.attn_window, name="mixer")(u, rope_tables)
             h = h + m.residual_multiplier * self._post_norm(mixed, 1)
         if self.ffn == NONE:
             return h
         u = nn.RMSNorm(epsilon=m.eps, name="norm2")(h)
-        if self.ffn == "moe_ffn":
+        if plan is not None:
+            out = experts(u, plan)
+        elif self.ffn == "moe_ffn":
             out = ExpertLayer(m, name="moe")(u)
         else:
             with jax.named_scope("mlp"):
@@ -563,11 +610,19 @@ class SensorHybrid(nn.Module):
         unknown = (set(m.layer_types) - set(KINDS + (NONE,))) \
             | (set(ffns) - set(FFN_KINDS + (NONE,)))
         if unknown or len(ffns) != len(m.layer_types) \
-                or (NONE, NONE) in zip(m.layer_types, ffns):
+                or (NONE, NONE) in zip(m.layer_types, ffns) \
+                or len(m.rope_layout) not in (0, len(m.layer_types)) \
+                or m.router_input not in latent_moe.ROUTER_INPUTS \
+                or ("window_attention" in m.layer_types
+                    and m.attn_window < 1):
             raise ValueError(
                 f"layer_types {m.layer_types} and ffn_types {m.ffn_types}: "
                 f"known kinds are {KINDS} and {FFN_KINDS}, one of each a "
-                f"layer, of which one may be {NONE!r}")
+                f"layer, of which one may be {NONE!r}; rope_layout "
+                f"{m.rope_layout} is a flag a layer or empty, router_input "
+                f"{m.router_input!r} one of {latent_moe.ROUTER_INPUTS}, and "
+                f"a window_attention layer needs attn_window "
+                f"{m.attn_window} >= 1")
         if m.loop_steps < 1:
             raise ValueError(f"loop_steps {m.loop_steps}: a stack makes at "
                              f"least one pass over its layers")
@@ -616,14 +671,15 @@ class SensorHybrid(nn.Module):
 
         # grouped attention's rotary tables, once a step for every layer,
         # pass and recomputation (None: no such layer, or the pair form)
+        turn = [m.turns(i) for i in range(len(m.layer_types))]
         tables = rotary_tables(m, self.attn_mode, x.shape[1]) \
-            if "attention" in m.layer_types else None
+            if any(turn) else None
 
         def layers(stack, h, tables):
             # the modules are `stack`'s: this model's, or its stand-in
             # under a lifted loop
             for i, (kind, ffn) in enumerate(zip(m.layer_types, ffns)):
-                h = block[keeps[i]](kind, m, stack.attn_mode, ffn,
+                h = block[keeps[i]](kind, m, stack.attn_mode, ffn, turn[i],
                                     name=f"layer{i}")(h, tables)
             return h
 

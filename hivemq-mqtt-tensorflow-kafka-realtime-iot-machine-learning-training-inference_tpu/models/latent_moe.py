@@ -24,9 +24,16 @@ layer is `Σ_k w_k · E_{i_k}(u)` alone).  Dropless
 got, a step, through the `reports` collection: data, not shape, so it
 comes back with the losses.
 
-Two more things of the layer are data.  The experts' **form**
-(`expert_form`, `ops.moe.EXPERT_FORMS`: the gated SiLU above or the
-non-gated squared ReLU), the routed and the shared expert's alike.  And
+More of the layer is data.  The router's **form** (`router_form`,
+`ops.moe.ROUTER_FORMS`: sigmoids with a selection-only bias, or the
+largest raw logits and a softmax over the selected, which has no bias
+and whose tree has no such leaf), and **what it reads** (`router_input`): the normed stream the experts read,
+or — `block` — the block's own input, un-normed and ahead of the mixer,
+which the block hands in apart: the layer is then called twice, once to
+route (`plan_only`) and once, behind the mixer, with that plan.  The
+experts' **form** (`expert_form`, `ops.moe.EXPERT_FORMS`: the gated SiLU
+above, the same gated by a ReLU, or the non-gated squared ReLU), the
+routed and the shared expert's alike.  And
 the width the routed experts act at: with `moe_latent` set,
 `y = (Σ_k w_k · E_{i_k}(u W_down)) W_back + S(u)` — the router still
 reads the full-width stream, the tiles gather, compute and scatter rows
@@ -53,6 +60,8 @@ normal = nn.initializers.normal(0.02)
 FFN_HIDDEN = "ffn_hidden"
 #: the variable collection a layer's data-dependent counts ride out in
 REPORTS = "reports"
+#: what an expert layer's router may read (`router_input`)
+ROUTER_INPUTS = ("ffn", "block")
 
 
 def dense(features: int, name: str):
@@ -73,14 +82,17 @@ def gated_mlp(u, width: int, d_model: int, name: str = "mlp",
     return dense(d_model, f"{name}_out")(moe.expert_hidden(hidden, form))
 
 
-def causal_attention(q, k, v, attn_mode: str, scale: float):
+def causal_attention(q, k, v, attn_mode: str, scale: float, window=None):
     """Causal attention the way `attn_mode` says: `dense`, the plain
-    form, or the flash kernels (`flash`; `flash_interpret` off the chip)."""
+    form, or the flash kernels (`flash`; `flash_interpret` off the chip)
+    — over the whole past or, with `window`, over the last `window` keys."""
     if attn_mode == "dense":
-        return attention_reference(q, k, v, causal=True, scale=scale)
+        return attention_reference(q, k, v, causal=True, scale=scale,
+                                   window=window)
     if attn_mode in ("flash", "flash_interpret"):
         return flash_attention(q, k, v, causal=True, scale=scale,
-                               interpret=attn_mode == "flash_interpret")
+                               interpret=attn_mode == "flash_interpret",
+                               window=window)
     raise ValueError(f"unknown attn_mode {attn_mode}")
 
 
@@ -122,7 +134,11 @@ class ExpertLayer(nn.Module):
     cfg: Any
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, plan=None, plan_only: bool = False):
+        """The layer of u [B, T, d].  `plan_only`: u is what the router
+        reads, and the call ends with the plan (`ops.moe.Dispatch`) — a
+        router ahead of the mixer; `plan` given: u is what the experts
+        read, routed as that plan says."""
         m = self.cfg
         B, T, d = u.shape
         first, held = m.experts_held
@@ -142,6 +158,17 @@ class ExpertLayer(nn.Module):
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
         obs_metrics.moe_plan_sorted_operands.set(moe.PLAN_SORTED_OPERANDS)
+        for form in moe.ROUTER_FORMS:
+            obs_metrics.moe_router_form.set(int(form == m.router_form),
+                                            kind=form)
+        for form in moe.EXPERT_FORMS:
+            obs_metrics.moe_expert_form.set(int(form == m.expert_form),
+                                            kind=form)
+        for source in ROUTER_INPUTS:
+            obs_metrics.moe_router_input.set(int(source == m.router_input),
+                                             kind=source)
+        if plan_only:
+            return self._plan(u.reshape(B * T, d))
         # the shared expert first: with it after the routed path, XLA's
         # memory-space assignment moved another of its weights into VMEM
         # once the recomputation kept the router's results, and its
@@ -154,12 +181,8 @@ class ExpertLayer(nn.Module):
                 shared = gated_mlp(u, m.shared_dim, d, "shared",
                                    m.expert_form)
         x = u.reshape(B * T, d)
-        with jax.named_scope("router"):
-            experts, weights = moe.route(
-                x, self.param("router", normal, (d, m.experts)),
-                self.param("router_bias", normal, (m.experts,)),
-                m.top_k, m.routed_scale)
-            plan = moe.dispatch_plan(experts, weights, first, held, m.experts)
+        if plan is None:
+            plan = self._plan(x)
         self.sow(REPORTS, "expert_counts", plan.counts,
                  reduce_fn=lambda _, new: new, init_fn=lambda: 0)
         if m.moe_latent:
@@ -181,6 +204,18 @@ class ExpertLayer(nn.Module):
                 routed = dense(d, "latent_out")(routed)
         routed = routed.reshape(B, T, d)
         return routed if shared is None else routed + shared
+
+    def _plan(self, x):
+        """x [N, d], what the router reads → the dispatch's plan."""
+        m = self.cfg
+        with jax.named_scope("router"):
+            experts, weights = moe.route(
+                x, self.param("router", normal, (x.shape[1], m.experts)),
+                self.param("router_bias", normal, (m.experts,))
+                if m.router_form == "sigmoid" else None,
+                m.top_k, m.routed_scale, m.router_form)
+            return moe.dispatch_plan(experts, weights, *m.experts_held,
+                                     m.experts)
 
 
 def record_reports(cfg, reports) -> None:

@@ -478,6 +478,28 @@ flash_operand_copies = default_registry.gauge(
     "iotml_flash_operand_copies",
     "operands of a flash kernel's call copied ahead of it "
     "(a T pad, a repeated k or v)")
+# the same by kernel AND by mask (kind: dense | causal | band): one
+# program may hold causal calls beside band calls (a sliding window),
+# and the last traced call of each kernel under each mask stands.
+flash_mask_window = default_registry.gauge(
+    "iotml_flash_mask_window",
+    "keys a query meets under a flash kernel's mask, itself among them "
+    "(a band's window; 0: its whole past, or every key), by kernel and "
+    "mask")
+flash_mask_tiles = default_registry.gauge(
+    "iotml_flash_mask_tiles",
+    "score tiles a head's grid walks in a flash kernel's call (the live "
+    "tiles of the triangle or of the band), by kernel and mask")
+flash_mask_walked_area = default_registry.gauge(
+    "iotml_flash_mask_walked_area",
+    "scores a head's walked tiles hold in a flash kernel's call: tiles x "
+    "block_q x block_k, by kernel and mask")
+flash_mask_live_area = default_registry.gauge(
+    "iotml_flash_mask_live_area",
+    "scores of a head the mask lets through at the call's length (T^2, "
+    "the triangle's T(T+1)/2 or the band's), by kernel and mask: over "
+    "iotml_flash_mask_walked_area, the share of the walked tiles' area "
+    "that is required work")
 # the chunked state-space scan (ops/ssd.py) and the hybrid model's layer
 # stack (models/hybrid.py), set at trace time like the flash geometry:
 # what the last traced scan and model engaged.
@@ -514,7 +536,8 @@ conv_operand_copies = default_registry.gauge(
 model_layers = default_registry.gauge(
     "iotml_model_layers",
     "layers of the last traced hybrid model, by the kind of their mixer "
-    "(mamba | attention | mla | short_conv) and of their feed-forward "
+    "(mamba | attention | mla | short_conv | window_attention) and of "
+    "their feed-forward "
     "part (dense_ffn | moe_ffn)")
 model_loop_steps = default_registry.gauge(
     "iotml_model_loop_steps",
@@ -547,6 +570,11 @@ attn_rotary_kernel = default_registry.gauge(
     "projections' own [B, T, H*D]; 0 beside a non-zero "
     "iotml_attn_rotary_dim: XLA's pair form (ops/moe.py rotary) ran, by "
     "attn_mode dense or heads that fill no whole 128-lane tiles")
+attn_window = default_registry.gauge(
+    "iotml_attn_window",
+    "keys a query of the last traced grouped-attention layer meets, "
+    "itself among them (a window_attention layer's sliding window; 0: "
+    "its whole causal past)")
 attn_qk_norm = default_registry.gauge(
     "iotml_attn_qk_norm",
     "1 where the last traced grouped-attention layer normed its queries "
@@ -558,6 +586,21 @@ moe_experts = default_registry.gauge(
     "iotml_moe_experts",
     "experts of the last traced expert layer, by kind (held: computed "
     "here | routed_over: the router's outputs)")
+moe_router_form = default_registry.gauge(
+    "iotml_moe_router_form",
+    "1 beside the form of the last traced expert layer's router, 0 "
+    "beside the others, by kind (sigmoid: sigmoids, a selection-only "
+    "bias, the selected over their sum | softmax_topk: the largest raw "
+    "logits, a softmax over the selected)")
+moe_router_input = default_registry.gauge(
+    "iotml_moe_router_input",
+    "1 beside what the last traced expert layer's router read, 0 beside "
+    "the other, by kind (ffn: the normed stream its experts read | "
+    "block: the block's own input, ahead of the mixer and un-normed)")
+moe_expert_form = default_registry.gauge(
+    "iotml_moe_expert_form",
+    "1 beside the form of the last traced expert layer's experts, 0 "
+    "beside the others, by kind (ops.moe.EXPERT_FORMS)")
 moe_top_k = default_registry.gauge(
     "iotml_moe_top_k", "experts a token is routed to")
 moe_latent_dim = default_registry.gauge(
@@ -676,6 +719,10 @@ DECLARED_METRIC_LABELS = {
     "flash_grid_steps": ("kernel",),
     "flash_heads_per_step": ("kernel",),
     "flash_lanes_per_step": ("kernel",),
+    "flash_mask_live_area": ("kernel", "kind"),
+    "flash_mask_tiles": ("kernel", "kind"),
+    "flash_mask_walked_area": ("kernel", "kind"),
+    "flash_mask_window": ("kernel", "kind"),
     "flash_operand_copies": ("kernel",),
     "flash_value_lanes_per_step": ("kernel",),
     "gateway_promotions": ("shard",),
@@ -685,7 +732,10 @@ DECLARED_METRIC_LABELS = {
     "loop_pass_loss": ("kind",),
     "model_layers": ("kind",),
     "moe_assignments": ("kind",),
+    "moe_expert_form": ("kind",),
     "moe_experts": ("kind",),
+    "moe_router_form": ("kind",),
+    "moe_router_input": ("kind",),
     "moe_tile_rows": ("kind",),
     "model_offsets_lag": ("component",),
     "model_version": ("component",),

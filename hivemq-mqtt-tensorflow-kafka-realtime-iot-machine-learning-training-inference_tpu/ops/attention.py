@@ -16,7 +16,11 @@ never contemplated.  The attention stack here:
   `[1, block, G·D]` column block reached through the index map — no
   transposed copy on either side.  Tiles and heads a step are derived
   from the shape (`flash_geometry`): the heads are what the lanes allow,
-  `G·D` a multiple of 128 or the whole width.
+  `G·D` a multiple of 128 or the whole width.  The mask is causal over
+  the whole past or, with `window`, over the last `window` keys (the
+  position itself among them): the grid then walks the live tiles of a
+  BAND beside the diagonal where it walked a triangle's, and the edge
+  tiles on both sides are masked elementwise.
 - `blockwise_update`: one online-softmax accumulation step, shared between
   the flash kernel's inner loop (conceptually) and the ring-attention
   cross-chip loop (`parallel.ring_attention`), which is the same math with
@@ -44,9 +48,31 @@ BWD_DKV_KERNEL = "iotml_flash_bwd_dkv"
 BWD_DQ_KERNEL = "iotml_flash_bwd_dq"
 
 
+def _band_edges(nq: int, nk: int, block_q: int, block_k: int, order: str,
+                window: Optional[int]) -> tuple:
+    """(first, last) live tile of every q row (`row`: kv tiles) or of
+    every kv column (`col`: q tiles) under the causal mask, of the last
+    `window` keys where one is given: query t meets keys
+    `t − window < j ≤ t`.  A column in the future of every query keeps
+    its one dead diagonal tile."""
+    if order == "row":
+        rows = np.arange(nq, dtype=np.int64) * block_q
+        last = np.minimum(nk - 1, (rows + block_q - 1) // block_k)
+        first = np.zeros_like(last) if window is None else np.minimum(
+            last, np.maximum(rows - window + 1, 0) // block_k)
+    else:
+        cols = np.arange(nk, dtype=np.int64) * block_k
+        first = np.minimum(nq - 1, cols // block_q)
+        last = np.full_like(first, nq - 1) if window is None else np.minimum(
+            nq - 1, (cols + block_k + window - 2) // block_q)
+    return first, last
+
+
 def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int,
-                  order: str) -> tuple:
-    """Enumerate the LIVE causal tiles as (i_map, j_map) int32 arrays.
+                  order: str, window: Optional[int] = None) -> tuple:
+    """Enumerate the LIVE causal tiles as (i_map, j_map) int32 arrays:
+    the lower triangle's or, under `window`, the band's beside the
+    diagonal (`_band_edges`).
 
     The dense grid pays DMA + a grid step for every (i, j) tile and
     `pl.when`s away the strictly-future half — measured at ≈½ a computed
@@ -67,22 +93,15 @@ def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int,
     map memory, which `_flash_forward` caps (falls back to the dense
     grid past _TRI_TILE_CAP) so the prefetch stream can never outgrow
     SMEM-class storage."""
-    if order == "row":
-        jmax = np.minimum(nk - 1, (np.arange(nq, dtype=np.int64) * block_q
-                                   + block_q - 1) // block_k)
-        counts = jmax + 1
-        im = np.repeat(np.arange(nq, dtype=np.int64), counts)
-        # j runs 0..jmax within each row: global arange minus the row start
-        starts = np.repeat(np.cumsum(counts) - counts, counts)
-        jm = np.arange(counts.sum(), dtype=np.int64) - starts
-    else:
-        imin = np.minimum(nq - 1, (np.arange(nk, dtype=np.int64) * block_k)
-                          // block_q)
-        counts = nq - imin
-        jm = np.repeat(np.arange(nk, dtype=np.int64), counts)
-        starts = np.repeat(np.cumsum(counts) - counts, counts)
-        im = np.arange(counts.sum(), dtype=np.int64) - starts + \
-            np.repeat(imin, counts)
+    first, last = _band_edges(nq, nk, block_q, block_k, order, window)
+    counts = last - first + 1
+    outer = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    # the inner index runs first..last within each row or column: a
+    # global arange minus the group's start
+    starts = np.repeat(np.cumsum(counts) - counts, counts)
+    inner = np.arange(counts.sum(), dtype=np.int64) - starts + \
+        np.repeat(first, counts)
+    im, jm = (outer, inner) if order == "row" else (inner, outer)
     return (im.astype(np.int32), jm.astype(np.int32))
 
 
@@ -95,11 +114,14 @@ def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int,
 _TRI_TILE_CAP = 65_536
 
 
-def _tri_tile_count(nq: int, nk: int, block_q: int, block_k: int) -> int:
-    """Live-tile count of the causal triangle (row order; col is equal)."""
-    jmax = np.minimum(nk - 1, (np.arange(nq, dtype=np.int64) * block_q
-                               + block_q - 1) // block_k)
-    return int((jmax + 1).sum())
+def _tri_tile_count(nq: int, nk: int, block_q: int, block_k: int,
+                    window: Optional[int] = None,
+                    order: str = "row") -> int:
+    """Live-tile count of the causal triangle, or of `window`'s band, in
+    the order walked (the triangle's two orders are equal but for a
+    column of padding's dead tile; a band's differ at its edges)."""
+    first, last = _band_edges(nq, nk, block_q, block_k, order, window)
+    return int((last - first + 1).sum())
 
 
 def _repeat_kv(q, k, v):
@@ -117,13 +139,16 @@ def _repeat_kv(q, k, v):
 
 def attention_reference(q, k, v, causal: bool = True,
                         q_offset: int = 0, k_offset: int = 0,
-                        scale: Optional[float] = None):
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
     """Plain softmax attention. q: [B, T, H, D], k,v: [B, T, Hkv, D]
     with Hkv dividing H → [B, Tq, H, D].
 
     q_offset/k_offset give the global positions of local blocks so the
     causal mask stays correct under sequence sharding.  `scale`
-    multiplies the scores; left `None` it is 1/√D.
+    multiplies the scores; left `None` it is 1/√D.  `window` (causal
+    only): a query meets the last `window` keys, its own position among
+    them — `t − window < j ≤ t`.
     """
     k, v = _repeat_kv(q, k, v)
     if scale is None:
@@ -133,6 +158,8 @@ def attention_reference(q, k, v, causal: bool = True,
         qpos = q_offset + jnp.arange(q.shape[1])[:, None]
         kpos = k_offset + jnp.arange(k.shape[1])[None, :]
         mask = qpos >= kpos
+        if window is not None:
+            mask = mask & (kpos > qpos - window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -187,6 +214,9 @@ class FlashGeometry:
     tri: bool         # triangular grid (live causal tiles only) or dense
     tiles: int        # grid tiles a head (the live ones when `tri`)
     grid_steps: int   # grid steps a call: B·H / heads × tiles
+    # the keys a query meets, itself among them; None: its whole past —
+    # with `tri`, the live tiles are a band's and not the triangle's
+    window: Optional[int] = None
 
 
 def _round_up(n: int, m: int) -> int:
@@ -194,17 +224,23 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _geometry(T: int, bh: int, causal: bool, block_q: int, block_k: int,
-              heads: int) -> FlashGeometry:
-    """The grid that `(block_q, block_k, heads)` names at length T."""
+              heads: int, window: Optional[int] = None,
+              kv_outer: bool = False) -> FlashGeometry:
+    """The grid that `(block_q, block_k, heads)` names at length T; a
+    band's live tiles in the order the kernel walks them (`kv_outer`:
+    dK/dV's columns)."""
     t_q, t_k = _round_up(T, block_q), _round_up(T, block_k)
     nq, nk = t_q // block_q, t_k // block_k
-    live = _tri_tile_count(nq, nk, block_q, block_k) if causal else nq * nk
+    live = _tri_tile_count(
+        nq, nk, block_q, block_k, window,
+        "col" if kv_outer and window is not None else "row") \
+        if causal else nq * nk
     # past the cap the scalar-prefetch maps outgrow SMEM-class storage:
     # the dense grid has O(1) metadata and skips dead tiles by pl.when
     tri = causal and live <= _TRI_TILE_CAP
     tiles = live if tri else nq * nk
     return FlashGeometry(block_q, block_k, heads, t_q, t_k, tri, tiles,
-                         bh // heads * tiles)
+                         bh // heads * tiles, window)
 
 
 #: scoped VMEM a kernel may take on the chip: Mosaic's default limit on
@@ -295,7 +331,8 @@ def _head_groups(H: int, D: int, Dv: Optional[int] = None) -> list:
 def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
                    H: int, causal: bool, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
-                   Dv: Optional[int] = None) -> FlashGeometry:
+                   Dv: Optional[int] = None,
+                   window: Optional[int] = None) -> FlashGeometry:
     """The step geometry of one flash kernel (`fwd`, `bwd_dkv`,
     `bwd_dq`), from what the code can see at trace time — the one place
     that knows tile sizes.
@@ -312,13 +349,18 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
     heads a step the lanes allow, the backward kernels capped at
     `_MAX_BLOCK` — and only the other is derived.  `D` is the width of
     a query and key head, `Dv` that of a value head where it differs
-    (latent attention: 192 beside 128)."""
+    (latent attention: 192 beside 128).  Under `window` (causal only; a
+    window of T or more is none) the tiles counted are the band's, so
+    the same model weighs a smaller tile's fuller band against its
+    steps."""
+    if window is not None and (not causal or window >= T):
+        window = None
     named = block_q is not None, block_k is not None
     cap = float("inf") if kernel == "fwd" else _MAX_BLOCK
     n = _round_up(T, 128) // 128
     derived = [128 * m for m in range(1, _MAX_BLOCK // 128 + 1) if n % m == 0]
     groups, bh = _head_groups(H, D, Dv), B * H
-    geoms = [_geometry(T, bh, causal, bq, bk, h)
+    geoms = [_geometry(T, bh, causal, bq, bk, h, window, kernel == "bwd_dkv")
              for bq in ([min(block_q, cap)] if named[0] else derived)
              for bk in ([min(block_k, cap)] if named[1] else derived)
              for h in (groups[:1] if any(named) else groups)]
@@ -384,7 +426,7 @@ def _head_columns(q_ref, v_ref, stat_ref) -> list:
 
 def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
               acc, m_s, l_s, *, scale: float, causal: bool, block_q: int,
-              block_k: int):
+              block_k: int, window: Optional[int] = None):
     """One grid step of the forward: tile (i, j) of every head in the
     column block.  K/V stream through VMEM one [block_k, G·D] tile at a
     time (O(T) VMEM, long-context safe); the online-softmax state lives
@@ -413,6 +455,9 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             qi, kj = _tile_positions(i, j, block_q, block_k)
             mask = qi >= kj
+            if window is not None:
+                # the band's far edge: a key `window` or more back
+                mask = mask & (kj > qi - window)
         for g, cols, vcols in heads:
             m_s[g], l_s[g], acc[:, vcols] = _fwd_tile(
                 q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
@@ -435,11 +480,13 @@ def _fwd_step(i, j, first, last, live, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[:] = jnp.where(l == 0.0, NEG_INF, m_s[:] + jnp.log(safe_l))
 
 
-def _bwd_mask(i, j, *, causal, block_q, block_k, t_real):
+def _bwd_mask(i, j, *, causal, block_q, block_k, t_real, window=None):
     qi, kj = _tile_positions(i, j, block_q, block_k)
     mask = kj < t_real
     if causal:
         mask = mask & (qi >= kj)
+    if window is not None:
+        mask = mask & (kj > qi - window)
     return mask
 
 
@@ -485,7 +532,7 @@ def _dq_tile(q, k, v, do, lse, delta, mask, dq, *, scale: float):
 
 def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
               k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-              block_q, block_k, t_real):
+              block_q, block_k, t_real, window=None):
     """One grid step of dK/dV: for one kv block, the q blocks stream
     through VMEM accumulating dk/dv in scratch; p never touches HBM.
     On the triangular grid a column entirely in the future of every
@@ -502,7 +549,7 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k, t_real=t_real)
+                         block_k=block_k, t_real=t_real, window=window)
         for g, cols, vcols in heads:
             dk_acc[:, cols], dv_acc[:, vcols] = _dkv_tile(
                 q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
@@ -523,7 +570,7 @@ def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
              k_ref, v_ref, dq_ref, dq_acc, *, scale, causal, block_q,
-             block_k, t_real):
+             block_k, t_real, window=None):
     """One grid step of dQ: one q block accumulates over its (causally
     relevant) kv blocks."""
     from jax.experimental import pallas as pl
@@ -536,7 +583,7 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
     def compute():
         mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
-                         block_k=block_k, t_real=t_real)
+                         block_k=block_k, t_real=t_real, window=window)
         for g, cols, vcols in heads:
             dq_acc[:, cols] = _dq_tile(
                 q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
@@ -554,7 +601,7 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dense_kernel(*refs, step, kv_outer: bool, causal: bool, block_q: int,
-                  block_k: int, **static):
+                  block_k: int, window: Optional[int] = None, **static):
     """A step function on the dense grid (B, H/heads, outer, inner): the
     inner axis iterates sequentially on-core — kv blocks for the
     forward and dQ, q blocks for dK/dV (`kv_outer`)."""
@@ -563,12 +610,18 @@ def _dense_kernel(*refs, step, kv_outer: bool, causal: bool, block_q: int,
     outer, inner = pl.program_id(2), pl.program_id(3)
     i, j = (inner, outer) if kv_outer else (outer, inner)
     live = j * block_k <= i * block_q + (block_q - 1) if causal else None
+    if window is not None:
+        # a band past the cap on the maps: the tiles behind it are
+        # skipped as the future's are
+        live = live & (j * block_k + block_k - 1 > i * block_q - window)
     step(i, j, inner == 0, inner == pl.num_programs(3) - 1, live, *refs,
-         causal=causal, block_q=block_q, block_k=block_k, **static)
+         causal=causal, block_q=block_q, block_k=block_k, window=window,
+         **static)
 
 
 def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
-                block_k: int, nq: int, nk: int, **static):
+                block_k: int, nq: int, nk: int,
+                window: Optional[int] = None, **static):
     """A step function on the TRIANGULAR grid (B, H/heads, tiles): the
     last axis walks only the live lower-triangle tiles (row-major for
     the forward and dQ, column-major for dK/dV), the (i, j) tile
@@ -588,13 +641,33 @@ def _tri_kernel(im_ref, jm_ref, *refs, step, kv_outer: bool, block_q: int,
         first = j == 0
         last = j == jnp.minimum(nk - 1,
                                 (i * block_q + block_q - 1) // block_k)
+    # a band's far edge (`_band_edges`, on the scalars of this step): the
+    # last q block that still meets this kv column's last key, the first
+    # kv block that holds a key the row's first query still meets
+    if window is not None and kv_outer:
+        last = i == jnp.minimum(
+            nq - 1, (j * block_k + block_k + window - 2) // block_q)
+    elif window is not None:
+        first = j == jnp.minimum(
+            jnp.minimum(nk - 1, (i * block_q + block_q - 1) // block_k),
+            jnp.maximum(i * block_q - window + 1, 0) // block_k)
     step(i, j, first, last, None, *refs, causal=True, block_q=block_q,
-         block_k=block_k, **static)
+         block_k=block_k, window=window, **static)
+
+
+def mask_area(T: int, causal: bool, window: Optional[int] = None) -> int:
+    """The scores a head's mask lets through at length T: all T², the
+    causal triangle's T (T + 1) / 2, or a band's — a query meets
+    `min(t + 1, window)` keys."""
+    if not causal:
+        return T * T
+    w = T if window is None else min(window, T)
+    return w * (w + 1) // 2 + (T - w) * w
 
 
 def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
-                D: int, Dv: int, kv_outer: bool, causal: bool, ins: str,
-                outs: str, scratch, copies: int, **static):
+                D: int, Dv: int, T: int, kv_outer: bool, causal: bool,
+                ins: str, outs: str, scratch, copies: int, **static):
     """(kernel function, grid spec, compiler params, prefetch operands)
     of one flash kernel on the grid `geom` names — and the record of
     that geometry (`iotml_flash_*{kernel}`, at trace time).  `ins` and
@@ -610,14 +683,15 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
     G, bq, bk = geom.heads, geom.block_q, geom.block_k
     nq, nk, nc = geom.t_q // bq, geom.t_k // bk, H // G
     if geom.tri:
-        im, jm = _causal_tiles(nq, nk, bq, bk, "col" if kv_outer else "row")
+        im, jm = _causal_tiles(nq, nk, bq, bk, "col" if kv_outer else "row",
+                               geom.window)
         prefetch = (jnp.asarray(im), jnp.asarray(jm))
         grid = (B, nc, len(im))
         qtile = lambda t, im, jm: im[t]  # noqa: E731
         ktile = lambda t, im, jm: jm[t]  # noqa: E731
         fn = functools.partial(_tri_kernel, step=step, kv_outer=kv_outer,
                                block_q=bq, block_k=bk, nq=nq, nk=nk,
-                               **static)
+                               window=geom.window, **static)
     else:
         prefetch = ()
         # the grid's last two axes arrive as (outer, inner)
@@ -626,10 +700,10 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
         ktile = lambda o, n: o if kv_outer else n  # noqa: E731
         fn = functools.partial(_dense_kernel, step=step, kv_outer=kv_outer,
                                causal=causal, block_q=bq, block_k=bk,
-                               **static)
+                               window=geom.window, **static)
     assert math.prod(grid) == geom.grid_steps, (grid, geom)
     _record_geometry(kernel, geom, lanes=G * D, value_lanes=G * Dv,
-                     copies=copies)
+                     copies=copies, causal=causal, T=T)
     q_side = lambda b, c, *t: (b, qtile(*t), c)  # noqa: E731
     kv_side = lambda b, c, *t: (b, ktile(*t), c)  # noqa: E731
     blocks = {
@@ -650,11 +724,24 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
 
 
 def _record_geometry(kernel: str, geom: FlashGeometry, *, lanes: int,
-                     value_lanes: int, copies: int) -> None:
+                     value_lanes: int, copies: int, causal: bool,
+                     T: int) -> None:
     """Say what engaged: Python at trace time, once a shape and
     compilation, no cost in the step.  The last newly traced call of
-    each kernel stands."""
+    each kernel stands — and, by kernel AND mask (`dense`, `causal`,
+    `band`), the last of each: one program may hold causal calls beside
+    band calls, and says both."""
     from ..obs import metrics as obs_metrics
+
+    mask = "band" if geom.window is not None else \
+        "causal" if causal else "dense"
+    by_mask = dict(kernel=kernel, kind=mask)
+    obs_metrics.flash_mask_window.set(geom.window or 0, **by_mask)
+    obs_metrics.flash_mask_tiles.set(geom.tiles, **by_mask)
+    obs_metrics.flash_mask_walked_area.set(
+        geom.tiles * geom.block_q * geom.block_k, **by_mask)
+    obs_metrics.flash_mask_live_area.set(
+        mask_area(T, causal, geom.window), **by_mask)
 
     obs_metrics.flash_grid_steps.set(geom.grid_steps, kernel=kernel)
     obs_metrics.flash_block_q.set(geom.block_q, kernel=kernel)
@@ -700,7 +787,7 @@ def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
         raise ValueError(
             f"non-causal flash attention needs T % {geom.block_k} == 0")
     fn, grid_spec, params, prefetch = _flash_grid(
-        "fwd", geom, _fwd_step, B=B, H=H, D=D, Dv=Dv, kv_outer=False,
+        "fwd", geom, _fwd_step, B=B, H=H, D=D, Dv=Dv, T=T, kv_outer=False,
         causal=causal, ins="QKV", outs="Oq",
         scratch=[pltpu.VMEM((bq, G * Dv), jnp.float32),
                  pltpu.VMEM((G, bq, 1), jnp.float32),
@@ -716,10 +803,10 @@ def _flash_fwd(q, k, v, H: int, causal: bool, geom: FlashGeometry,
     )(*prefetch, _pad_t(q, Tq), _pad_t(k, Tk), _pad_t(v, Tk))
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
                    block_k: Optional[int], interpret: bool, scale: float,
-                   repeated: int):
+                   repeated: int, window: Optional[int] = None):
     """Run the Pallas kernel; returns (out [B,T,H,Dv], lse [B,H,T]).
     Jitted, as `_flash_backward` is, so that a model of many equal
     layers traces and lowers the kernels once a shape and not once a
@@ -727,7 +814,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: Optional[int],
     B, T, H, D = q.shape
     Dv = v.shape[-1]
     geom = flash_geometry("fwd", T, D, q.dtype.itemsize, B, H, causal,
-                          block_q, block_k, Dv)
+                          block_q, block_k, Dv, window)
     out, lse = _flash_fwd(*(x.reshape(B, T, -1) for x in (q, k, v)), H,
                           causal, geom, interpret, scale, repeated)
     return (out[:, :T].reshape(B, T, H, Dv),
@@ -761,7 +848,8 @@ def _flash_bwd_dkv(q, do, lse, delta, k, v, H: int, causal: bool,
     G, bk, Tk = geom.heads, geom.block_k, geom.t_k
     operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dkv", geom, _dkv_step, B=B, H=H, D=D, Dv=Dv, kv_outer=True,
+        "bwd_dkv", geom, _dkv_step, B=B, H=H, D=D, Dv=Dv, T=T,
+        kv_outer=True,
         causal=causal, ins="QOqqKV", outs="KV",
         scratch=[pltpu.VMEM((bk, G * D), jnp.float32),
                  pltpu.VMEM((bk, G * Dv), jnp.float32)],
@@ -786,7 +874,7 @@ def _flash_bwd_dq(q, do, lse, delta, k, v, H: int, causal: bool,
     G, bq, Tq = geom.heads, geom.block_q, geom.t_q
     operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
     fn, grid_spec, params, prefetch = _flash_grid(
-        "bwd_dq", geom, _dq_step, B=B, H=H, D=D, Dv=Dv, kv_outer=False,
+        "bwd_dq", geom, _dq_step, B=B, H=H, D=D, Dv=Dv, T=T, kv_outer=False,
         causal=causal, ins="QOqqKV", outs="Q",
         scratch=[pltpu.VMEM((bq, G * D), jnp.float32)],
         copies=copies, scale=scale, t_real=T)
@@ -798,10 +886,11 @@ def _flash_bwd_dq(q, do, lse, delta, k, v, H: int, causal: bool,
     return dq
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_backward(q, k, v, out, lse, do, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
-                    interpret: bool, scale: float, repeated: int):
+                    interpret: bool, scale: float, repeated: int,
+                    window: Optional[int] = None):
     """Pallas flash-attention backward: the standard two-kernel split
     (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
     recomputed blockwise in VMEM, never materialized to HBM), each
@@ -814,7 +903,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     operands = (flat(q), flat(do), lse.reshape(B * H, T, 1),
                 delta.reshape(B * H, T, 1), flat(k), flat(v))
     dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B, H, causal,
-                              block_q, block_k, v.shape[-1])
+                              block_q, block_k, v.shape[-1], window)
                for kernel in ("bwd_dkv", "bwd_dq"))
     dk, dv = _flash_bwd_dkv(*operands, H, causal, dkv, interpret, scale,
                             repeated)
@@ -822,11 +911,12 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     return tuple(x[:, :T].reshape(B, T, H, -1) for x in (dq, dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, block_q, block_k, interpret, scale, repeated):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, block_q, block_k, interpret, scale, repeated,
+           window):
     """`flash_attention` of equal heads: the differentiable core."""
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
-                            scale, repeated)
+                            scale, repeated, window)
     return out
 
 
@@ -843,9 +933,9 @@ def _flash_fwd_rule(q, k, v, *static):
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, scale, repeated,
-                    res, do):
+                    window, res, do):
     return _flash_backward(*res, do, causal, block_q, block_k, interpret,
-                           scale, repeated)
+                           scale, repeated, window)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -854,7 +944,8 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, interpret: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Pallas flash attention. q: [B, T, H, D], k: [B, T, Hkv, D],
     v: [B, T, Hkv, Dv] with Hkv dividing H → [B, T, H, Dv].  `Dv` may
     differ from `D` (latent attention: rotary features ride q and k
@@ -878,7 +969,10 @@ def flash_attention(q, k, v, causal: bool = True,
     the same kernel on CPU for tests.  `scale` multiplies the scores;
     left `None` it is 1/√D.  Fewer key/value heads than query heads
     (grouped-query attention) are repeated over their groups ahead of
-    the kernels, which see equal heads.
+    the kernels, which see equal heads.  `window` (causal only): a query
+    meets its last `window` keys, itself among them; the grids walk the
+    band's tiles only, and a window of T or more is none — the causal
+    call, built as it always was.
 
     Differentiable via custom VJP: the forward kernel emits the per-row
     log-sum-exp; the backward is the standard two-kernel Pallas split
@@ -890,5 +984,11 @@ def flash_attention(q, k, v, causal: bool = True,
     k, v = _repeat_kv(q, k, v)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window {window}: a causal mask's last keys, "
+                             f"at least the position itself")
+        if window >= q.shape[1]:
+            window = None
     return _flash(q, k, v, causal, block_q, block_k, interpret, scale,
-                  repeated)
+                  repeated, window)

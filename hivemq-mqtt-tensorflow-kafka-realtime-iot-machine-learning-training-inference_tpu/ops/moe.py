@@ -2,9 +2,10 @@
 dispatch of tokens to the experts held here.
 
 An expert layer routes every token over ALL `experts` of the model
-(sigmoid scores, a selection-only bias, the `top_k` largest, weights
-renormalised over the selected and scaled) and computes the part of the
-result that the experts it HOLDS give — a contiguous range
+(`ROUTER_FORMS`: sigmoid scores, a selection-only bias, the `top_k`
+largest, weights renormalised over the selected and scaled — or the
+`top_k` largest raw logits and a softmax over the selected) and
+computes the part of the result that the experts it HOLDS give — a contiguous range
 `[first, first + held)`, one chip's share under expert parallelism.
 What the experts held elsewhere would add is left out; on one chip
 there is no exchange.
@@ -22,7 +23,8 @@ assignments that landed here, a tile's padding at most an expert.
 
 An expert's form is data (`EXPERT_FORMS`): `gated_silu`,
 `(silu(g) ⊙ v) W_out` with `[g, v] = x W_in` (`w_in` `[held, d, 2f]`),
-or `relu2`, the non-gated `relu(x W_in)² W_out` (`[held, d, f]`).  The
+`relu_gated`, the same with `relu(g)` for the gate, or `relu2`, the
+non-gated `relu(x W_in)² W_out` (`[held, d, f]`).  The
 rows may be narrower than the stream: a layer that computes its experts
 in a latent hands the projected rows in and projects the sum back.
 """
@@ -78,7 +80,16 @@ def _mask(experts, n_experts: int):
     return experts[..., None] == jnp.arange(n_experts)
 
 
-def _route_weights(picked, scale: float):
+#: a router's form: what a logit's score is and what the selected
+#: scores' weights are — `sigmoid`: sigmoids, the selected over their
+#: sum; `softmax_topk`: the raw logits, a softmax over the selected
+#: (equal to a softmax over all, renormalised over the selected)
+ROUTER_FORMS = ("sigmoid", "softmax_topk")
+
+
+def _route_weights(picked, scale: float, form: str = "sigmoid"):
+    if form == "softmax_topk":
+        return jax.nn.softmax(picked, axis=-1) * scale
     return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
 
 
@@ -86,47 +97,55 @@ def _highest(a, b):
     return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def route(u, gate, bias, top_k: int, scale: float):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def route(u, gate, bias, top_k: int, scale: float, form: str = "sigmoid"):
     """u [N, d] → (experts [N, top_k] int32, weights [N, top_k] float32).
 
     Scores are sigmoids of a float32 product at `highest` (top-k is
     discontinuous: a rounded score picks another expert).  `bias` moves
     the SELECTION only — the weights are the selected scores without
-    it, over their sum, times `scale` — and no gradient reaches it.
+    it, over their sum, times `scale` — and no gradient reaches it; a
+    router without one hands None.  Under `softmax_topk` the scores are
+    the product's own logits and the weights their softmax over the
+    selected.
 
     The backward pass is written out, from the selection and the
     selected scores alone (`route_experts`, `route_picked` by name): a
     sigmoid's derivative at a selected entry is `picked · (1 − picked)`
-    and no other entry of [N, experts] has a gradient, so a caller that
+    (a raw logit's is 1) and no other entry of [N, experts] has a
+    gradient, so a caller that
     recomputes its forward (`jax.checkpoint` with a policy of names)
     runs the product, the sigmoid and top-k once.  The two products of
     the backward are float32 at `highest`, as the forward's is."""
-    return _route_fwd(u, gate, bias, top_k, scale)[0]
+    return _route_fwd(u, gate, bias, top_k, scale, form)[0]
 
 
-def _route_fwd(u, gate, bias, top_k, scale):
-    s = jax.nn.sigmoid(_highest(u.astype(jnp.float32),
-                                gate.astype(jnp.float32)))
-    _, experts = jax.lax.top_k(s + bias, top_k)
+def _route_fwd(u, gate, bias, top_k, scale, form="sigmoid"):
+    if form not in ROUTER_FORMS:
+        raise ValueError(f"unknown router form {form!r}: {ROUTER_FORMS}")
+    s = _highest(u.astype(jnp.float32), gate.astype(jnp.float32))
+    if form == "sigmoid":
+        s = jax.nn.sigmoid(s)
+    _, experts = jax.lax.top_k(s if bias is None else s + bias, top_k)
     experts = checkpoint_name(experts, "route_experts")
     picked = checkpoint_name(
         jnp.sum(jnp.where(_mask(experts, s.shape[-1]), s[:, None, :], 0.0),
                 axis=-1), "route_picked")
-    return (experts, _route_weights(picked, scale)), \
+    return (experts, _route_weights(picked, scale, form)), \
         (u, gate, bias, experts, picked)
 
 
-def _route_bwd(top_k, scale, res, cotangents):
+def _route_bwd(top_k, scale, form, res, cotangents):
     u, gate, bias, experts, picked = res
-    _, pull = jax.vjp(lambda p: _route_weights(p, scale), picked)
+    _, pull = jax.vjp(lambda p: _route_weights(p, scale, form), picked)
     (d_picked,) = pull(cotangents[1])
+    if form == "sigmoid":
+        d_picked = d_picked * picked * (1.0 - picked)
     d_logits = jnp.sum(jnp.where(
-        _mask(experts, gate.shape[-1]),
-        (d_picked * picked * (1.0 - picked))[..., None], 0.0), axis=1)
+        _mask(experts, gate.shape[-1]), d_picked[..., None], 0.0), axis=1)
     return (_highest(d_logits, gate.astype(jnp.float32).T).astype(u.dtype),
             _highest(u.astype(jnp.float32).T, d_logits).astype(gate.dtype),
-            jnp.zeros_like(bias))
+            None if bias is None else jnp.zeros_like(bias))
 
 
 route.defvjp(_route_fwd, _route_bwd)
@@ -258,15 +277,17 @@ def plan_kept_bytes(tokens: int, top_k: int, held: int,
 
 #: an expert's form: the activation between its two products, and how
 #: many times the expert's width `w_in` is wide
-EXPERT_FORMS = {"gated_silu": 2, "relu2": 1}
+EXPERT_FORMS = {"gated_silu": 2, "relu2": 1, "relu_gated": 2}
 
 
 def expert_hidden(h, form: str):
     """What an expert's second product reads, of its first's result h:
-    `silu(g) ⊙ v` with `[g, v] = h`, or `relu(h)²`."""
-    if form == "gated_silu":
+    `silu(g) ⊙ v` with `[g, v] = h`, `relu(g) ⊙ v` of the same, or
+    `relu(h)²`."""
+    if form in ("gated_silu", "relu_gated"):
         gate, value = jnp.split(h, 2, axis=-1)
-        return jax.nn.silu(gate) * value
+        act = jax.nn.silu if form == "gated_silu" else jax.nn.relu
+        return act(gate) * value
     if form == "relu2":
         return jnp.square(jax.nn.relu(h))
     raise ValueError(f"unknown expert form {form!r}: {tuple(EXPERT_FORMS)}")
@@ -348,8 +369,9 @@ def experts_apply(x, plan: Dispatch, w_in, w_out, form: str = "gated_silu"):
     """Σ over the assignments held of `w · E_i(x)`, for x [N, d]:
     `E_i(x) = (silu(x W_gate,i) ⊙ x W_up,i) W_down,i` with
     `w_in[i] = [W_gate,i, W_up,i]` ([held, d, 2f]), or under `relu2`
-    `relu(x W_up,i)² W_down,i` with `w_in` [held, d, f]; `w_out` [held,
-    f, d].  Dropless: every assignment held has its row."""
+    `relu(x W_up,i)² W_down,i` with `w_in` [held, d, f], under
+    `relu_gated` the first with `relu` for `silu`; `w_out` [held, f,
+    d].  Dropless: every assignment held has its row."""
     with jax.named_scope("experts"):
         return _experts(form, x, w_in, w_out, plan.weight, plan)
 

@@ -1,12 +1,12 @@
 """The shapes of stack `models.hybrid.SensorHybrid` is tested in, stated
-once: a row a shape — the benchmark's five hybrid configurations at a
+once: a row a shape — the benchmark's six hybrid configurations at a
 tiny preset, each with its plain reference (loaded by path, as
 `benchmark/tests` loads it), and a sandwich stack that is no loop, which
 has none — with the helpers the stack tests share and the programs they
 share: within a worker process a (stack, mode, keep)'s gradient, the
 counts of its jaxpr and the registry it left are made once
 (`policy_run`), as is a reference's gradient (`reference_gradient`).
-Read by `test_stack_contract.py`, `test_remat_policy.py` and the five
+Read by `test_stack_contract.py`, `test_remat_policy.py` and the six
 stack files; not collected."""
 
 import functools
@@ -104,6 +104,18 @@ STACKS = {
         dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
              head_dim=16, intermediate_size=96, num_hidden_layers=2),
         layers=2, attention=2, ops="loop_ops", unsettled=True),
+    # width 64; 4 query heads of 16 over 2 key/value heads; the file's
+    # four layers, `G W W W`: a global layer without positions, three
+    # that turn their heads and meet the last 24 keys (40 positions: past
+    # the window and no multiple of a block); 16 ReLU-gated experts of
+    # 24, 3 a token, 4 held, routed on the block's own input
+    "smallthinker": Stack(
+        "smallthinker-21b-a3b",
+        dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+             head_dim=16, moe_ffn_hidden_size=24, moe_num_primary_experts=4,
+             moe_num_active_primary_experts=3, sliding_window_size=24),
+        published=dict(moe_num_primary_experts=16), attention=4, routed=4,
+        ops="window_ops"),
     # sandwich norms without the loop: no configuration's, by hand
     "sandwich": Stack(None, {}, attention=1, config=HybridConfig(
         layer_types=("mamba", "attention"), post_norms=True)),
@@ -342,15 +354,18 @@ def hold_budget(patch, cfg, x, keep) -> tuple:
                                           x.dtype.itemsize)
     first = [c for c in candidates if c.name == hybrid.FFN_KEPT and c.bytes]
     makes = tuple(c.layer for c in first)
-    dearer = [c for c in candidates if c.density > first[-1].density]
+    # a stack of expert layers without a shared expert makes no first
+    # product: whatever the budget, nothing is bought
+    dearer = [c for c in candidates
+              if first and c.density > first[-1].density]
     budget = {"all": sum(c.bytes for c in candidates), "none": 0,
-              "last": sum(c.bytes for c in dearer) + first[-1].bytes}[keep]
+              "last": sum(c.bytes for c in dearer + first[-1:])}[keep]
     patch.setattr(hybrid, "remat_budget", lambda *sizes: budget)
     taken = hybrid.budget_takes(candidates, budget)
     keeps = tuple(c.layer for c in taken if c.name == hybrid.FFN_KEPT)
     assert keeps == {"all": makes, "last": makes[-1:], "none": ()}[keep]
     assert set(taken) == {"all": {c for c in candidates if c.bytes},
-                          "last": set(dearer) | {first[-1]},
+                          "last": set(dearer) | set(first[-1:]),
                           "none": set()}[keep]
     # a sandwich block's feed-forward output is dearer than the product
     assert bool(dearer) == cfg.post_norms

@@ -342,3 +342,146 @@ def test_flash_geometry_by_both_widths():
             f'iotml_flash_value_lanes_per_step{{kernel="{kernel}"}}'] == 256
         # nothing padded, nothing repeated: v narrower than q costs no copy
         assert got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}'] == 0
+
+
+# ------------------------------------------------------ a sliding window
+def _live_tiles_by_hand(nq, nk, bq, bk, window, order):
+    """Every tile that holds a score the mask lets through, in the order
+    a grid walks them."""
+    live = []
+    for i in range(nq):
+        for j in range(nk):
+            t = np.arange(i * bq, (i + 1) * bq)[:, None]
+            k = np.arange(j * bk, (j + 1) * bk)[None, :]
+            mask = k <= t
+            if window is not None:
+                mask &= k > t - window
+            if mask.any():
+                live.append((i, j))
+    return sorted(live, key=lambda ij: ij[::-1]) if order == "col" else live
+
+
+@pytest.mark.parametrize("order", ["row", "col"])
+@pytest.mark.parametrize("T,bq,bk,window", [
+    (1024, 128, 128, 200),    # a window that is no multiple of a block
+    (1024, 256, 128, 100),    # … and narrower than either block
+    (1024, 128, 256, 300),
+    (700, 128, 128, 64),      # T pads
+    (1100, 1024, 128, 64),    # the q side pads further than the kv side
+    (2048, 512, 256, None),   # no window: the triangle, as it was
+])
+def test_band_tile_maps_are_the_brute_force_enumeration(T, bq, bk, window,
+                                                        order):
+    nq, nk = -(-T // bq), -(-T // bk)
+    im, jm = attention._causal_tiles(nq, nk, bq, bk, order, window)
+    want = _live_tiles_by_hand(nq, nk, bq, bk, window, order)
+    assert list(zip(im.tolist(), jm.tolist())) == want
+    assert attention._tri_tile_count(nq, nk, bq, bk, window, order) \
+        == len(want)
+    if window is None:
+        # the triangle's count is the one the geometry always used
+        assert attention._tri_tile_count(nq, nk, bq, bk) == len(want)
+
+
+@pytest.mark.parametrize("T,window,block,H,kv_heads", [
+    (300, 50, 128, 4, 2),      # a window narrower than a block
+    (300, 200, 128, 4, 2),     # … that is no multiple of one
+    (300, 130, None, 4, 2),    # derived tiles
+    (300, 1, 128, 4, 2),       # the position itself, alone
+    (200, 72, 128, 7, 1),      # seven query heads on one key/value head
+])
+def test_band_flash_attention_matches_the_reference(T, window, block, H,
+                                                    kv_heads):
+    """Forward and all three gradients of the band call, interpreted,
+    against `attention_reference(window=)`."""
+    q, k, v = _qkv(B=1, T=T, H=H, D=32, seed=T + window, kv_heads=kv_heads)
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, True, block, block, True, window=window)
+    plain = lambda q, k, v: attention_reference(  # noqa: E731
+        q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))), (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=2e-5)
+    # key 0 moves the queries that still meet it (but query 0, which
+    # meets nothing else), and none the window has carried past it
+    moved = plain(q, k.at[:, 0].add(1.0), v) - plain(q, k, v)
+    reach = np.abs(np.asarray(moved)).max(axis=(0, 2, 3)) > 0
+    assert reach[1:window].all() and not reach[window:].any()
+
+
+@pytest.mark.parametrize("kernel", attention.KERNELS)
+def test_a_window_of_the_whole_length_is_the_causal_call(kernel):
+    """`window ≥ T`, or none: the geometry the causal call always had,
+    field by field, and the same traced call — one cache entry for
+    both."""
+    args = (kernel, 4096, 128, 4, 2, 8, True)
+    causal = attention.flash_geometry(*args)
+    assert causal.window is None and causal.tri
+    for window in (4096, 5000):
+        assert attention.flash_geometry(*args, window=window) == causal
+    band = attention.flash_geometry(*args, window=1024)
+    area = lambda g: g.tiles * g.block_q * g.block_k  # noqa: E731
+    assert band.window == 1024 and area(band) < area(causal)
+    q, k, v = _qkv(B=1, T=160, H=2, D=32)
+    jax.clear_caches()
+    want = flash_attention(q, k, v, True, 128, 128, True)
+    traced = attention._flash_forward._cache_size()
+    got = flash_attention(q, k, v, True, 128, 128, True, window=160)
+    assert attention._flash_forward._cache_size() == traced
+    assert np.array_equal(got, want)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, True, 128, 128, True, window=0)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, False, 32, 32, True, window=8)
+
+
+def test_a_band_past_the_cap_on_the_maps_walks_the_dense_grid(monkeypatch):
+    """More live tiles than the scalar-prefetch maps may hold: the dense
+    grid, whose `pl.when` skips the tiles behind the band as it skips
+    the future's."""
+    monkeypatch.setattr(attention, "_TRI_TILE_CAP", 2)
+    jax.clear_caches()
+    q, k, v = _qkv(B=1, T=500, H=2, D=32, seed=9)
+    geom = attention.flash_geometry("fwd", 500, 32, 4, 1, 2, True, 128, 128,
+                                    window=100)
+    assert not geom.tri and geom.window == 100
+    f = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(  # noqa: E731
+        q, k, v, True, 128, 128, True, window=100)))
+    r = lambda q, k, v: jnp.sum(jnp.sin(attention_reference(  # noqa: E731
+        q, k, v, causal=True, window=100)))
+    for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                    jax.grad(r, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=2e-5)
+    jax.clear_caches()
+
+
+def test_the_gauges_tell_a_band_call_from_a_causal_one():
+    """By kernel and mask: a causal call and a band call of one program
+    both stand; the keys by kernel alone are the last traced call's."""
+    from iotml.obs.metrics import default_registry
+
+    jax.clear_caches()
+    q, k, v = _qkv(B=1, T=300, H=2, D=64)
+    for window in (None, 100):
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, True, 128, 128, True, window=window)))(q)
+    said = default_registry.collect()
+    for kernel in attention.KERNELS:
+        by = lambda what, mask: said[  # noqa: E731
+            f'iotml_flash_mask_{what}{{kernel="{kernel}",kind="{mask}"}}']
+        assert (by("window", "causal"), by("window", "band")) == (0, 100)
+        assert (by("tiles", "causal"), by("tiles", "band")) == (6, 5)
+        assert by("walked_area", "band") == 5 * 128 * 128
+        assert by("live_area", "causal") == 300 * 301 // 2
+        assert by("live_area", "band") == attention.mask_area(300, True, 100) \
+            == 100 * 101 // 2 + 200 * 100
+        # the band's call was the last traced
+        assert said[f'iotml_flash_grid_steps{{kernel="{kernel}"}}'] == 5 * 2 \
+            / said[f'iotml_flash_heads_per_step{{kernel="{kernel}"}}']
+    assert attention.mask_area(300, False) == 300 * 300

@@ -97,3 +97,109 @@ def test_ep_train_step_learns():
     # expert weights actually sharded: local leading dim = E/ep = 8/4 = 2
     w1 = state.params["block0"]["moe"]["w1"]
     assert w1.sharding.shard_shape(w1.shape)[0] == 2
+
+
+# ---------------------------------------- ops.moe: router and expert forms
+def _route_softmax_by_autodiff(u, gate, top_k):
+    """`ops.moe.route` under `softmax_topk` as plain differentiable
+    code: the largest raw logits, a softmax over the selected."""
+    s = jnp.dot(u, gate, precision=jax.lax.Precision.HIGHEST)
+    picked, experts = jax.lax.top_k(s, top_k)
+    return experts, jax.nn.softmax(picked, axis=-1)
+
+
+def test_the_softmax_over_selected_router_and_its_backward():
+    """Selection and weights as the plain form gives them — equal to a
+    softmax over all the logits renormalised over the selected — and the
+    hand-written backward equal to `jax.grad` of the plain form, to the
+    stream and to the router's weights; no bias, and none returned."""
+    import pytest
+
+    from iotml.ops import moe
+
+    for seed in (0, 1, 2):
+        rng = np.random.default_rng(seed)
+        u, gate, mix = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                        for shape in ((96, 32), (32, 64), (96, 6)))
+        experts, weights = moe.route(u, gate, None, 6, 1.0, "softmax_topk")
+        want_e, want_w = _route_softmax_by_autodiff(u, gate, 6)
+        assert (experts == want_e).all()
+        np.testing.assert_allclose(weights, want_w, rtol=1e-6)
+        full = jax.nn.softmax(jnp.dot(
+            u, gate, precision=jax.lax.Precision.HIGHEST), axis=-1)
+        picked = jnp.take_along_axis(full, experts, axis=-1)
+        np.testing.assert_allclose(
+            weights, picked / picked.sum(axis=-1, keepdims=True), rtol=1e-5)
+        got = jax.grad(lambda u, g: jnp.sum(moe.route(
+            u, g, None, 6, 1.0, "softmax_topk")[1] * mix), (0, 1))(u, gate)
+        want = jax.grad(lambda u, g: jnp.sum(
+            _route_softmax_by_autodiff(u, g, 6)[1] * mix), (0, 1))(u, gate)
+        for g, w in zip(got, want):
+            assert float(jnp.abs(g - w).max()) \
+                <= 2e-6 * float(jnp.abs(w).max())
+    # the sigmoid form is the one it was, and an unknown form is refused
+    bias = jnp.zeros((64,))
+    assert all((a == b).all() for a, b in zip(
+        moe.route(u, gate, bias, 6, 2.5),
+        moe.route(u, gate, bias, 6, 2.5, "sigmoid")))
+    with pytest.raises(ValueError, match="router form"):
+        moe.route(u, gate, None, 6, 1.0, "softmax")
+
+
+def test_the_relu_gated_tiles_match_the_dense_masked_experts():
+    """`relu(g) ⊙ v`, two products wide: the live tiles against every
+    expert held applied to every token (`experts_dense`), the output and
+    every gradient; and with ONE token's assignment dropped from the
+    plan the tiles differ from the dense form in that token's row alone,
+    by that expert's term exactly."""
+    import pytest
+
+    from iotml.ops import moe
+
+    assert moe.EXPERT_FORMS["relu_gated"] == 2
+    rng = np.random.default_rng(5)
+    N, d, f, E, held, K = 80, 32, 24, 16, 4, 3
+    x, gate, w_in, w_out, mix = (
+        jnp.asarray(rng.normal(size=shape), jnp.float32) * scale
+        for shape, scale in (((N, d), 1.0), ((d, E), 1.0),
+                             ((held, d, 2 * f), 0.2), ((held, f, d), 0.2),
+                             ((N, d), 1.0)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "TILE", 16)
+        jax.clear_caches()
+        experts, weights = moe.route(x, gate, None, K, 1.0, "softmax_topk")
+
+        def tiles(x, w_in, w_out, weights, drop=None):
+            e = experts if drop is None else experts.at[drop].set(E - 1)
+            plan = moe.dispatch_plan(e, weights, 0, held, E)
+            return moe.experts_apply(x, plan, w_in, w_out, "relu_gated")
+
+        def dense(x, w_in, w_out, weights):
+            return moe.experts_dense(x, experts, weights, w_in, w_out, 0,
+                                     held, "relu_gated")
+
+        with jax.default_matmul_precision("highest"):
+            got, want = (jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a) * mix), (0, 1, 2, 3))(
+                    x, w_in, w_out, weights) for fn in (tiles, dense))
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                assert float(jnp.abs(g - w).max()) \
+                    <= 1e-5 * max(float(jnp.abs(w).max()), 1e-30)
+            # the form is the one written out
+            one = jnp.maximum(x @ w_in[0, :, :f], 0) * (x @ w_in[0, :, f:])
+            np.testing.assert_allclose(
+                moe.expert_hidden(x @ w_in[0], "relu_gated"), one, rtol=1e-6)
+            # one token's assignment to an expert held, dropped
+            n, k = next((n, k) for n in range(N) for k in range(K)
+                        if int(experts[n, k]) < held)
+            lost = dense(x, w_in, w_out, weights) \
+                - tiles(x, w_in, w_out, weights, drop=(n, k))
+            e = int(experts[n, k])
+            term = (one[n] if e == 0 else moe.expert_hidden(
+                x[n] @ w_in[e], "relu_gated")) @ w_out[e] * weights[n, k]
+        # (the other rows' places in their tiles moved: rounding alone)
+        size = np.abs(np.asarray(lost)).max(axis=1)
+        rows = np.flatnonzero(size > 1e-3 * size.max())
+        assert rows.tolist() == [n]
+        np.testing.assert_allclose(lost[n], term, rtol=1e-4, atol=1e-6)
+    jax.clear_caches()

@@ -87,6 +87,24 @@ def test_flash_attention_derived_tiles_lower_for_v5e(v5e, shape, dtype):
     jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()
 
 
+@pytest.mark.parametrize("T,H,G,window", [
+    (16384, 28, 4, 4096),    # `st-train-backlog`'s window layers, exactly
+    (1024, 4, 2, 100),       # a window that ends inside a tile
+])
+def test_flash_attention_under_a_window_lowers_for_v5e(v5e, T, H, G, window):
+    """Forward and backward under a sliding window — the band's
+    scalar-prefetch maps and the far edge's mask through Mosaic: 28
+    query heads over 4 key/value heads of 128 at T = 16,384 and the
+    source's window of 4,096, and a small window inside one tile."""
+    q = jax.ShapeDtypeStruct((2, T, H, 128), jnp.float32, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((2, T, G, 128), jnp.float32, sharding=v5e)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window))
+
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+
+
 def test_flash_attention_grouped_heads_lower_for_v5e(v5e):
     """`gh-train-backlog`'s one attention layer, exactly, forward and
     backward: 32 query heads over 8 key/value heads of 64 at T = 4,096,

@@ -6,10 +6,12 @@ feed-forward part's first product and, in a sandwich block, the parts'
 outputs ahead of their post norms.
 
 Three properties, each over the shapes of stack the catalogue states
-(`stacks.STACKS`: the benchmark's five hybrid configurations at their
+(`stacks.STACKS`: the benchmark's six hybrid configurations at their
 tiny presets — Mamba + grouped-query attention; latent attention +
 experts at the stream's width; one-part layers + experts in a latent;
-gated short convolutions + experts without a shared one; a looped stack
+gated short convolutions + experts without a shared one; global and
+sliding-window attention + experts routed on the block's input, which
+makes no value the budget could buy; a looped stack
 of sandwich-normed layers, whose passes are a scan: what a layer keeps
 it keeps in every pass, stacked — and a sandwich stack that is no loop),
 on the CPU, with the budget held to what keeps every candidate, what
@@ -188,7 +190,10 @@ def test_the_counter_says_the_bytes_the_policy_saves(held):
         assert said[f'iotml_remat_keepable_layers{{kind="{kind}"}}'] \
             == (len(run.makes) if name == hybrid.FFN_KEPT else sandwiched)
         saved += found
-    assert bool(saved) == (keep != "none")
+    # (a stack without a first product and without post norms has
+    # nothing the budget could buy, whatever it is held to)
+    assert bool(saved) == (keep != "none" and bool(
+        run.makes or cfg.post_norms))
     if cfg.loop_steps > 1:
         # every pass's, stacked: the kernel's out and lse a layer, and
         # the stream-sized inputs the scan keeps beside the names (a
@@ -220,6 +225,9 @@ _SAID = {
                   "router": (13128, 0, 0)}, 10880),
     "lfm2": ((("ffn_hidden",), (), (), (), ()),
              {"ffn": (61440, 1, 1), "router": (15968, 0, 0)}, 21760),
+    # nothing the budget could buy: experts without a shared one in every
+    # layer, no post norms
+    "smallthinker": (((),) * 4, {"router": (15968, 0, 0)}, 87040),
     "ouro": ((_ALL,) * 2,
              {"ffn": (491520, 2, 2), "ffn_out": (163840, 2, 2),
               "mixer_out": (163840, 2, 2), "loop_inputs": (245760, 0, 0)},
@@ -396,14 +404,16 @@ _NO_OUTS = {"ffn_out": (), "mixer_out": ()}
     ("kimi-vl-a3b-instruct", (0, 1, 2, 3, 4, 5), _NO_OUTS),
     ("nemotron-3-super-120b-a12b", (1, 3, 5, 8, 10), _NO_OUTS),
     ("lfm2-24b-a2b", (0,), _NO_OUTS),
+    # experts without a shared one in every layer: no first product
+    ("smallthinker-21b-a3b", (), _NO_OUTS),
     # a sandwich block under a loop: the MLP's output in all six layers;
     # no room then for a first product of 1.476 GB in the 0.80 GB left
     ("ouro-2.6b", (), {"ffn_out": (0, 1, 2, 3, 4, 5), "mixer_out": (4, 5)}),
 ])
 def test_what_the_rule_takes_at_the_benchmarks_shapes(stem, first_product,
                                                       outs):
-    """By arithmetic alone (shapes, no array): the five hybrid
-    configurations at their jobs' sizes on the chip's memory — the four
+    """By arithmetic alone (shapes, no array): the six hybrid
+    configurations at their jobs' sizes on the chip's memory — the five
     without post norms keep what `kept_layers` alone gave them, and
     `ou` buys its feed-forward outputs first."""
     spec = importlib.util.spec_from_file_location(

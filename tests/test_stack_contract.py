@@ -1,5 +1,5 @@
 """What every shape of stack that has a plain reference (`stacks.STACKS`,
-the benchmark's five hybrid configurations at their tiny presets on the
+the benchmark's six hybrid configurations at their tiny presets on the
 CPU) is held to: the program builds the reference's parameter tree, its
 loss and every gradient leaf are the reference's under either attention,
 and one compiled job of `Trainer.fit_compiled` is the reference's Adam
@@ -60,7 +60,31 @@ def _ouro_tree(model, built):
             jax.random.PRNGKey(0), stacks.batch()[0])
 
 
-TREES = {"lfm2": _lfm2_tree, "ouro": _ouro_tree}
+def _smallthinker_tree(model, built):
+    # `G W W W`: one mixer kind's parameters under two values of
+    # `layer_types`; a router without a bias, no shared expert
+    assert model.cfg.layer_types == ("attention",) \
+        + ("window_attention",) * 3
+    assert model.cfg.rope_layout == (0, 1, 1, 1)
+    assert (model.cfg.attn_window, model.cfg.router_input,
+            model.cfg.router_form, model.cfg.expert_form) \
+        == (24, "block", "softmax_topk", "relu_gated")
+    for i in range(4):
+        assert sorted(built[f"layer{i}"]) == ["mixer", "moe", "norm1",
+                                              "norm2"]
+        assert sorted(built[f"layer{i}"]["mixer"]) == ["k", "o", "q", "v"]
+        assert sorted(built[f"layer{i}"]["moe"]) == [
+            "experts_in", "experts_out", "router"]
+    for bad in (dict(layer_types=("window_attention",)),
+                dict(layer_types=("attention",), rope_layout=(1, 0)),
+                dict(layer_types=("attention",), router_input="mixer")):
+        with pytest.raises(ValueError, match="known kinds"):
+            SensorHybrid(HybridConfig(**bad)).init(
+                jax.random.PRNGKey(0), stacks.batch()[0])
+
+
+TREES = {"lfm2": _lfm2_tree, "ouro": _ouro_tree,
+         "smallthinker": _smallthinker_tree}
 
 
 @pytest.mark.parametrize("stack", REFERENCED)
@@ -102,7 +126,19 @@ def _ouro_gradients(grads, reports):
     assert all(np.asarray(g).any() for g in jax.tree.leaves(grads))
 
 
-GRADIENTS = {"kimi": _routes((1, 2), 3, "router"),
+def _smallthinker_gradients(grads, reports):
+    # no bias leaf; every router and every projection has a gradient
+    for i in range(4):
+        assert "router_bias" not in grads[f"layer{i}"]["moe"]
+        assert np.asarray(grads[f"layer{i}"]["moe"]["router"]).any()
+        assert all(np.asarray(g).any() for g in jax.tree.leaves(
+            grads[f"layer{i}"]["mixer"]))
+    assert [int(c.sum()) for c in jax.tree.leaves(reports[0])] \
+        == [2 * 40 * 3] * 4
+
+
+GRADIENTS = {"smallthinker": _smallthinker_gradients,
+             "kimi": _routes((1, 2), 3, "router"),
              "nemotron": _routes((1, 3), 5, "latent_in"),
              "lfm2": _lfm2_gradients, "ouro": _ouro_gradients}
 
@@ -167,7 +203,8 @@ def _ouro_fit(history, mod, weights, stacked):
 
 FITS = {"kimi": _first_steps_counts((1, 2), 3),
         "nemotron": _first_steps_counts((1, 3), 5),
-        "lfm2": _first_steps_counts((1, 2, 3, 4)), "ouro": _ouro_fit}
+        "lfm2": _first_steps_counts((1, 2, 3, 4)), "ouro": _ouro_fit,
+        "smallthinker": _first_steps_counts((0, 1, 2, 3), 3)}
 
 
 @pytest.mark.parametrize("stack", REFERENCED)

@@ -71,8 +71,10 @@ def test_loop_unit_restarts_after_crash():
 
     with Supervisor(poll_interval_s=0.01) as sup:
         u = sup.add_loop("flappy", loop)
-        assert _wait_for(lambda: u.restarts >= 1 and u.state == RUNNING)
-        assert len(runs) == 2
+        # (the state reads RUNNING before the second incarnation has run
+        # a line: wait for its first line too)
+        assert _wait_for(lambda: u.restarts >= 1 and u.state == RUNNING
+                         and len(runs) == 2)
         assert u.last_error == "RuntimeError: first incarnation dies"
     assert obs_metrics.supervisor_restarts.value(unit="flappy") >= 1
 
