@@ -500,7 +500,7 @@ class HybridBlock(nn.Module):
         if self.ffn == "moe_ffn" and m.router_input == "block":
             # the router reads the block's own input, ahead of the mixer
             # and un-normed: its plan waits for the experts behind it
-            experts = ExpertLayer(m, name="moe")
+            experts = ExpertLayer(m, self.attn_mode, name="moe")
             plan = experts(h, plan_only=True)
         if self.kind != NONE:
             u = nn.RMSNorm(epsilon=m.eps, name="norm1")(h)
@@ -525,7 +525,7 @@ class HybridBlock(nn.Module):
         if plan is not None:
             out = experts(u, plan)
         elif self.ffn == "moe_ffn":
-            out = ExpertLayer(m, name="moe")(u)
+            out = ExpertLayer(m, self.attn_mode, name="moe")(u)
         else:
             with jax.named_scope("mlp"):
                 out = gated_mlp(u, m.mlp_dim, m.d_model)

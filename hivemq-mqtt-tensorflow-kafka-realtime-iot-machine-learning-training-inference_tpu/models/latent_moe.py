@@ -132,6 +132,7 @@ class LatentAttention(nn.Module):
 
 class ExpertLayer(nn.Module):
     cfg: Any
+    attn_mode: str = "dense"   # the model's: `flash` runs its kernels
 
     @nn.compact
     def __call__(self, u, plan=None, plan_only: bool = False):
@@ -158,6 +159,11 @@ class ExpertLayer(nn.Module):
         obs_metrics.moe_dispatch_rows.set(
             moe.dispatch_rows(B * T, m.top_k, held))
         obs_metrics.moe_plan_sorted_operands.set(moe.PLAN_SORTED_OPERANDS)
+        chunk = moe.add_rows_chunk(B * T, width, u.dtype, self.attn_mode)
+        # a walk's two loops, forward and backward, each add a tile's rows
+        obs_metrics.moe_add_rows.set(2 * bool(chunk), kind="kernel")
+        obs_metrics.moe_add_rows.set(2 * (not chunk), kind="scatter")
+        obs_metrics.moe_add_rows_chunk.set(chunk)
         for form in moe.ROUTER_FORMS:
             obs_metrics.moe_router_form.set(int(form == m.router_form),
                                             kind=form)
@@ -193,7 +199,7 @@ class ExpertLayer(nn.Module):
             self.param("experts_in", normal,
                        (held, width, wide * m.expert_dim)),
             self.param("experts_out", normal, (held, m.expert_dim, width)),
-            m.expert_form)
+            m.expert_form, self.attn_mode)
         if m.moe_latent:
             # `latent_out`'s weight gradient reads the routed sum: kept
             # by name, a recomputed forward does not walk the tiles for

@@ -620,6 +620,18 @@ moe_plan_sorted_operands = default_registry.gauge(
     "operands the sort of an expert layer's dispatch plan carries (3: the "
     "key, the index and the routing weights, which so reach their sorted "
     "places, and their cotangents come back, without a gather or a scatter)")
+moe_add_rows = default_registry.gauge(
+    "iotml_moe_add_rows",
+    "tile loops of the last traced expert layer's walk (forward and "
+    "backward: 2, or 0) that add a tile's rows back onto their tokens in "
+    "this form, by kind (kernel: the Pallas call iotml_add_rows, row DMAs "
+    "on the loop's carried accumulator | scatter: XLA's scatter-add, under "
+    "attn_mode dense, for an accumulator small enough for XLA to keep in "
+    "VMEM, or rows that fill no whole 128-lane float32 tiles)")
+moe_add_rows_chunk = default_registry.gauge(
+    "iotml_moe_add_rows_chunk",
+    "rows of a tile a grid step of iotml_add_rows adds back in the last "
+    "traced expert layer (0: XLA's scatter-add ran)")
 moe_assignments = default_registry.counter(
     "iotml_moe_assignments_total",
     "token-to-expert assignments of the fits so far, all expert layers, "
@@ -731,6 +743,7 @@ DECLARED_METRIC_LABELS = {
     "loop_exit_mass": ("kind",),
     "loop_pass_loss": ("kind",),
     "model_layers": ("kind",),
+    "moe_add_rows": ("kind",),
     "moe_assignments": ("kind",),
     "moe_expert_form": ("kind",),
     "moe_experts": ("kind",),

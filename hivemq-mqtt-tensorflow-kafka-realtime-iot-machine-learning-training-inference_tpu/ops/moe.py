@@ -21,6 +21,33 @@ products, scale by the routing weights, add onto the tokens), and its
 backward walks them again.  Work and memory traffic follow the
 assignments that landed here, a tile's padding at most an expert.
 
+How a tile's rows are added back onto their tokens has two forms
+(`add_rows_form`; nothing a configuration sets).
+Where the model runs its kernels (`attn_mode` `flash`; interpreted
+under `flash_interpret`), the rows are float32 of whole 128-lane tiles
+and the `[N, d]` accumulator is larger than XLA keeps in VMEM (over
+64 MiB: `st-train-backlog`'s 320 MiB, `lf-train-backlog`'s 128) the
+Pallas call `iotml_add_rows` (`ops/add_rows.py`) copies a tile's live
+rows of the accumulator into VMEM a row a DMA, adds, and copies them
+back, and the loop carries the accumulator as `[N, 1, d]`, the layout
+in which a row is one piece.  Otherwise — `dense`, the tiny presets'
+and the CPU tests' narrow rows, and the small accumulators of
+`km-train-backlog` (64 MiB) and `ns-train-backlog` (32) — XLA's
+scatter-add: into an accumulator in HBM it takes 0.29 µs a 10 KB row
+where its gather of the same rows takes 0.027 (two of them were 300 µs
+of a 542 µs tile in `st-train-backlog`), into one it keeps in VMEM
+0.08-0.09, and there the kernel's layout costs a loop more than the
+kernel saves it: one change of layout behind a loop's last tile, 335 MB
+at `st`'s shape (`km` ran 0.8% slower under the kernel, `ns` 0.6%
+faster; PERF.md §6, PR 47).  Two cheaper forms of the scatter were
+measured there and dropped — `indices_are_sorted=True`, and gather +
+dense add + overwriting scatter: XLA's scatter stays a fusion that
+takes a row at a time.  `add_rows.add_rows` is a `jax.jit` function,
+for set-up's sake: the walks of every layer — forward, recomputed and
+backward — trace the kernel once a shape and call one lowered function
+(a bare `pallas_call` was traced at each of `st`'s twelve call sites,
+3.65 s of its `setup_s`; PERF.md §6, PR 48).
+
 An expert's form is data (`EXPERT_FORMS`): `gated_silu`,
 `(silu(g) ⊙ v) W_out` with `[g, v] = x W_in` (`w_in` `[held, d, 2f]`),
 `relu_gated`, the same with `relu(g)` for the gate, or `relu2`, the
@@ -37,6 +64,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from . import add_rows
 
 
 # --------------------------------------------------------------- rotary
@@ -299,81 +328,125 @@ def _expert_tile(form, rows, w_in, w_out, weight):
 
 
 def _tile_operands(c, x, w_in, w_out, weight, plan: Dispatch):
-    """Tile c: (its rows' tokens, its expert, which rows are live, the
-    operands of `_expert_tile`).  A row of padding takes token N: zeros
-    in, weight 0, nothing added back."""
+    """Tile c: (its rows' tokens, its expert, which rows are live, how
+    many — they come first —, the operands of `_expert_tile`).  A row of
+    padding takes token N: zeros in, weight 0, nothing added back."""
     tile = _tile(x.shape[0])
-    live = jnp.arange(tile) < plan.tile_rows[c]
+    row = jnp.arange(tile)
+    live_rows = plan.tile_rows[c]
+    live = row < live_rows
     cut = lambda v: jax.lax.dynamic_slice_in_dim(  # noqa: E731
         v, plan.tile_first[c], tile)
     tokens = jnp.where(live, cut(plan.token), x.shape[0])
     e = plan.tile_expert[c]
-    return tokens, e, live, (
+    return tokens, e, live, live_rows, (
         x.at[tokens].get(mode="fill", fill_value=0), w_in[e], w_out[e],
         jnp.where(live, cut(weight), 0).astype(x.dtype))
 
 
-def _add_rows(acc, tokens, rows):
-    # a token meets an expert once, so a tile's live rows are distinct
-    return acc.at[tokens].add(rows, mode="drop", unique_indices=True)
+def add_rows_chunk(tokens: int, d: int, dtype, attn_mode: str) -> int:
+    """The rows a grid step of `iotml_add_rows` adds back, for a layer
+    of `tokens` tokens whose experts read and give rows `d` wide — or 0:
+    XLA's scatter-add runs.  The kernel where the model runs its kernels
+    (`attn_mode` `flash`, `flash_interpret`), the accumulator is too
+    large for XLA to keep in VMEM (`add_rows.RESIDENT_BYTES`) and the
+    tile suits the kernel (`add_rows.chunk_rows`: float32 rows of whole
+    128-lane tiles); the scatter under `dense`, for a small accumulator
+    and for narrow rows."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if attn_mode == "dense" or tokens * d * itemsize <= add_rows.RESIDENT_BYTES:
+        return 0
+    return add_rows.chunk_rows(_tile(tokens), d, itemsize)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(form, x, w_in, w_out, weight, plan: Dispatch):
+def add_rows_form(tokens: int, d: int, dtype, attn_mode: str) -> str:
+    """How such a layer's tiles add their rows back (`_add_rows`):
+    `scatter`, XLA's scatter-add, or the Pallas call `iotml_add_rows`,
+    `kernel` or — interpreted — `kernel_interpret`."""
+    if not add_rows_chunk(tokens, d, dtype, attn_mode):
+        return "scatter"
+    return "kernel" if attn_mode == "flash" else "kernel_interpret"
+
+
+def _accumulator(x, add: str):
+    """Zeros for the tiles' rows to be added onto: `[N, d]`, or for the
+    kernel `[N, 1, d]`, whose layout on a TPU holds a row in one piece
+    (`ops/add_rows.py`) — the loop carries it so, and the one change of
+    layout a walk pays is behind its last tile."""
+    N, d = x.shape
+    return jnp.zeros((N, d) if add == "scatter" else (N, 1, d), x.dtype)
+
+
+def _add_rows(acc, tokens, live_rows, rows, add: str):
+    """`rows` onto `acc` at `tokens`, of which the first `live_rows`
+    are live and distinct (a token meets an expert once) and the rest,
+    a tile's padding, token N: dropped."""
+    if add == "scatter":
+        return acc.at[tokens].add(rows, mode="drop", unique_indices=True)
+    return add_rows.add_rows(acc, tokens, live_rows, rows,
+                             interpret=add == "kernel_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts(form, add, x, w_in, w_out, weight, plan: Dispatch):
     def tile_step(c, out):
-        tokens, _, _, operands = _tile_operands(c, x, w_in, w_out, weight,
-                                                plan)
-        return _add_rows(out, tokens, _expert_tile(form, *operands))
+        tokens, _, _, live_rows, operands = _tile_operands(
+            c, x, w_in, w_out, weight, plan)
+        return _add_rows(out, tokens, live_rows,
+                         _expert_tile(form, *operands), add)
 
     return jax.lax.fori_loop(0, plan.live_tiles, tile_step,
-                             jnp.zeros_like(x))
+                             _accumulator(x, add)).reshape(x.shape)
 
 
-def _experts_fwd(form, x, w_in, w_out, weight, plan):
-    return _experts(form, x, w_in, w_out, weight, plan), \
+def _experts_fwd(form, add, x, w_in, w_out, weight, plan):
+    return _experts(form, add, x, w_in, w_out, weight, plan), \
         (x, w_in, w_out, weight, plan)
 
 
-def _experts_bwd(form, res, d_out):
+def _experts_bwd(form, add, res, d_out):
     """The live tiles again: each recomputed and pulled back; an
     expert's weight gradients accumulate in place."""
     x, w_in, w_out, weight, plan = res
 
     def tile_step(c, grads):
         dx, d_in, d_outw, d_weight = grads
-        tokens, e, live, operands = _tile_operands(c, x, w_in, w_out, weight,
-                                                   plan)
+        tokens, e, live, live_rows, operands = _tile_operands(
+            c, x, w_in, w_out, weight, plan)
         _, pull = jax.vjp(functools.partial(_expert_tile, form), *operands)
         d_rows, g_in, g_out, g_weight = pull(
             d_out.at[tokens].get(mode="fill", fill_value=0))
         at = plan.tile_first[c]
         # a tile's padding lies over the next group's first assignments
         kept = jax.lax.dynamic_slice_in_dim(d_weight, at, live.shape[0])
-        return (_add_rows(dx, tokens, d_rows), d_in.at[e].add(g_in),
-                d_outw.at[e].add(g_out),
+        return (_add_rows(dx, tokens, live_rows, d_rows, add),
+                d_in.at[e].add(g_in), d_outw.at[e].add(g_out),
                 jax.lax.dynamic_update_slice_in_dim(
                     d_weight, jnp.where(live, g_weight.astype(kept.dtype),
                                         kept), at, 0))
 
     dx, d_in, d_outw, d_weight = jax.lax.fori_loop(
         0, plan.live_tiles, tile_step,
-        (jnp.zeros_like(x), jnp.zeros_like(w_in), jnp.zeros_like(w_out),
+        (_accumulator(x, add), jnp.zeros_like(w_in), jnp.zeros_like(w_out),
          jnp.zeros_like(weight)))
-    return dx, d_in, d_outw, d_weight, None
+    return dx.reshape(x.shape), d_in, d_outw, d_weight, None
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def experts_apply(x, plan: Dispatch, w_in, w_out, form: str = "gated_silu"):
+def experts_apply(x, plan: Dispatch, w_in, w_out, form: str = "gated_silu",
+                  attn_mode: str = "dense"):
     """Σ over the assignments held of `w · E_i(x)`, for x [N, d]:
     `E_i(x) = (silu(x W_gate,i) ⊙ x W_up,i) W_down,i` with
     `w_in[i] = [W_gate,i, W_up,i]` ([held, d, 2f]), or under `relu2`
     `relu(x W_up,i)² W_down,i` with `w_in` [held, d, f], under
     `relu_gated` the first with `relu` for `silu`; `w_out` [held, f,
-    d].  Dropless: every assignment held has its row."""
+    d].  Dropless: every assignment held has its row.  `attn_mode`, the
+    model's, says whether its kernels run (`add_rows_form`)."""
     with jax.named_scope("experts"):
-        return _experts(form, x, w_in, w_out, plan.weight, plan)
+        return _experts(form, add_rows_form(*x.shape, x.dtype, attn_mode),
+                        x, w_in, w_out, plan.weight, plan)
 
 
 @functools.partial(jax.jit, static_argnames=("first", "held", "form"))

@@ -2,10 +2,13 @@
 path must match the single-device dense dispatch, and routing must respect
 capacity with static shapes throughout."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from iotml.models.moe import MoEFFN, MoESensorFormer
 from iotml.parallel.expert_parallel import (expert_param_specs,
@@ -202,4 +205,191 @@ def test_the_relu_gated_tiles_match_the_dense_masked_experts():
         rows = np.flatnonzero(size > 1e-3 * size.max())
         assert rows.tolist() == [n]
         np.testing.assert_allclose(lost[n], term, rtol=1e-4, atol=1e-6)
+    jax.clear_caches()
+
+
+# ------------------------------ ops.moe: how a tile's rows are added back
+def _tile_of(N, tile, live, rng):
+    """A tile's tokens as `_tile_operands` gives them: `live` distinct
+    ascending tokens, then the padding's token N."""
+    tokens = np.full(tile, N, np.int32)
+    tokens[:live] = np.sort(rng.choice(N, size=live, replace=False))
+    return jnp.asarray(tokens)
+
+
+@pytest.mark.parametrize("N,tile,d,live,zeros,form", [
+    (1024, 512, 2560, 512, False, "kernel"),    # `st-train-backlog`'s rows
+    (640, 512, 1024, 300, False, "kernel"),     # `ns`'s latent
+    (600, 512, 256, 0, False, "kernel"),        # a tile of padding alone
+    (600, 512, 256, 1, False, "kernel"),
+    (600, 512, 256, 511, False, "kernel"),      # one row of padding
+    (600, 512, 128, 512, True, "kernel"),       # a loop's first tile
+    (200, 200, 128, 77, False, "kernel"),       # a short window's tile
+    (300, 304, 96, 200, False, "scatter"),      # rows of no whole lane tile
+])
+def test_add_rows_is_the_scatter_add_bit_for_bit(monkeypatch, N, tile, d,
+                                                 live, zeros, form):
+    """`iotml_add_rows`, interpreted, against `acc.at[tokens].add(rows,
+    mode="drop")`: the same float32 add of the same rows, so equal
+    exactly — the live rows alone, whatever the accumulator held.  (A
+    few hundred tokens: the accumulators here are of the size the rule
+    leaves to XLA, so the size's threshold is taken away.)"""
+    from iotml.ops import add_rows, moe
+
+    assert moe._tile(N) == tile
+    assert moe.add_rows_form(N, d, jnp.float32, "flash") == "scatter"
+    monkeypatch.setattr(add_rows, "RESIDENT_BYTES", 0)
+    got_form = moe.add_rows_form(N, d, jnp.float32, "flash_interpret")
+    assert got_form == {"kernel": "kernel_interpret"}.get(form, form)
+    assert moe.add_rows_form(N, d, jnp.float32, "dense") == "scatter"
+    assert moe.add_rows_form(N, d, jnp.bfloat16, "flash") == "scatter"
+    rng = np.random.default_rng(N + live)
+    acc = jnp.zeros((N, d), jnp.float32) if zeros else jnp.asarray(
+        rng.normal(size=(N, d)), jnp.float32)
+    tokens = _tile_of(N, tile, live, rng)
+    rows = jnp.asarray(rng.normal(size=(tile, d)), jnp.float32)
+    want = acc.at[tokens].add(rows, mode="drop")
+    shaped = acc if got_form == "scatter" else acc.reshape(N, 1, d)
+    got = jax.jit(functools.partial(moe._add_rows, add=got_form))(
+        shaped, tokens, jnp.int32(live), rows)
+    np.testing.assert_array_equal(np.asarray(got).reshape(N, d), want)
+    if 0 < live < tile:    # nothing but the live rows' tokens moved
+        touched = np.flatnonzero((np.asarray(got).reshape(N, d)
+                                  != np.asarray(acc)).any(axis=1))
+        assert set(touched) <= set(np.asarray(tokens[:live]).tolist())
+
+
+@pytest.mark.parametrize("form", ["gated_silu", "relu2", "relu_gated"])
+def test_the_tiles_under_the_kernel_match_the_dense_masked_experts(form):
+    """`experts_apply` with its rows added back by `iotml_add_rows`
+    (interpreted: 128-wide float32 rows under `flash_interpret`): value
+    and the four gradients against `experts_dense`, and against the
+    scatter form's own, which they equal to rounding's last bit."""
+    from iotml.ops import add_rows, moe
+
+    rng = np.random.default_rng(7)
+    N, d, f, E, held, K = 80, 128, 24, 16, 4, 3
+    x, gate, w_in, w_out, mix = (
+        jnp.asarray(rng.normal(size=shape), jnp.float32) * scale
+        for shape, scale in (((N, d), 1.0), ((d, E), 1.0),
+                             ((held, d, moe.EXPERT_FORMS[form] * f), 0.1),
+                             ((held, f, d), 0.1), ((N, d), 1.0)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "TILE", 16)
+        patch.setattr(add_rows, "RESIDENT_BYTES", 0)   # 80 tokens
+        jax.clear_caches()
+        assert moe.add_rows_form(N, d, x.dtype, "flash_interpret") \
+            == "kernel_interpret"
+        experts, weights = moe.route(x, gate, None, K, 1.0, "softmax_topk")
+
+        def tiles(attn_mode, x, w_in, w_out, weights):
+            plan = moe.dispatch_plan(experts, weights, 0, held, E)
+            return moe.experts_apply(x, plan, w_in, w_out, form, attn_mode)
+
+        def dense(x, w_in, w_out, weights):
+            return moe.experts_dense(x, experts, weights, w_in, w_out, 0,
+                                     held, form)
+
+        with jax.default_matmul_precision("highest"):
+            got, scattered, want = (jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a) * mix), (0, 1, 2, 3))(
+                    x, w_in, w_out, weights) for fn in (
+                        functools.partial(tiles, "flash_interpret"),
+                        functools.partial(tiles, "dense"), dense))
+        assert len(jax.tree.leaves(got)) == 5
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert float(jnp.abs(g - w).max()) \
+                <= 1e-5 * max(float(jnp.abs(w).max()), 1e-30)
+        for g, s in zip(jax.tree.leaves(got), jax.tree.leaves(scattered)):
+            np.testing.assert_array_equal(g, s)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("attn_mode,shape,latent,kernel,chunk", [
+    ("flash", (2, 16384, 2560), 0, 2, 64),    # `st-train-backlog`: 320 MiB
+    ("flash", (2, 8192, 2048), 0, 2, 128),    # `lf-train-backlog`: 128 MiB
+    ("flash", (1, 8192, 2048), 0, 0, 0),      # `km`: 64 MiB, XLA's in VMEM
+    ("flash", (1, 8192, 4096), 1024, 0, 0),   # `ns`'s latent: 32 MiB
+    ("dense", (2, 16384, 2560), 0, 0, 0),     # the plain path
+    ("flash", (2, 16384, 96), 0, 0, 0),       # a tiny preset's narrow rows
+])
+def test_the_gauge_says_how_a_traced_layer_adds_its_rows_back(
+        attn_mode, shape, latent, kernel, chunk):
+    """`iotml_moe_add_rows{kind}` after a trace of an expert layer at a
+    cell's tokens and widths: the kernel where the model runs its
+    kernels and the accumulator is larger than XLA keeps in VMEM,
+    XLA's scatter-add for the small accumulators, under `dense` and for
+    rows of no whole lane tile."""
+    from iotml.models.hybrid import HybridConfig
+    from iotml.models.latent_moe import ExpertLayer
+    from iotml.obs.metrics import default_registry
+
+    cfg = HybridConfig(d_model=shape[2], moe_latent=latent, experts=8,
+                       experts_held=(0, 4), top_k=2, expert_dim=128)
+    layer = ExpertLayer(cfg, attn_mode)
+    jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                   jax.ShapeDtypeStruct(shape, jnp.float32))
+    got = default_registry.collect()
+    assert got['iotml_moe_add_rows{kind="kernel"}'] == kernel
+    assert got['iotml_moe_add_rows{kind="scatter"}'] == 2 - kernel
+    assert got["iotml_moe_add_rows_chunk"] == chunk
+
+
+def test_a_start_traces_the_add_rows_kernel_once_a_shape(monkeypatch):
+    """The guard of `setup_s`: a start — the state's `init`, then the
+    scanned fit — of three expert layers of one shape, each block under
+    the stack's recomputation: twelve call sites of `add_rows.add_rows`
+    (`init`'s forward; the fit's forward, its recomputed forward and its
+    backward), and the kernel's body is traced ONCE by `init` and ONCE
+    by the fit, because the call is a `jax.jit` function whose cache's
+    key is the operands' shapes (the fit's plain forward finds `init`'s
+    trace; what autodiff traces is keyed apart, by JAX's trace context,
+    once for all layers).  Another count of tokens is another
+    accumulator and its own traces.  (A bare `pl.pallas_call` there was
+    traced at every site: twelve times in `st-train-backlog`'s fit,
+    3.65 s of its set-up.)"""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+    from iotml.obs.metrics import default_registry
+    from iotml.ops import add_rows
+    from iotml.train.loop import TrainState, make_scanned_fit
+
+    traced = []
+    body = add_rows._step
+
+    def counted(*refs, **geometry):
+        traced.append(refs[3].shape)     # the accumulator's
+        return body(*refs, **geometry)
+
+    monkeypatch.setattr(add_rows, "_step", counted)
+    monkeypatch.setattr(add_rows, "RESIDENT_BYTES", 0)    # 32 tokens
+    model = SensorHybrid(HybridConfig(
+        d_model=128, layer_types=("mamba",) * 3, ffn_types=("moe_ffn",) * 3,
+        experts=8, experts_held=(0, 4), top_k=2, expert_dim=16,
+        shared_dim=16), features=6, attn_mode="flash_interpret")
+    tx = optax.adam(1e-3)
+
+    def fresh(rng, x):
+        params = model.init(rng, x)["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=tx.init(params), apply_fn=model.apply,
+                          tx=tx)
+
+    def start(B, T=16, F=6, steps=2):
+        """→ (what `init` traced, what the fit traced after it)"""
+        jax.clear_caches()
+        traced.clear()
+        state = jax.eval_shape(fresh, jax.random.PRNGKey(0),
+                               jnp.zeros((B, T, F)))
+        by_init = list(traced)
+        make_scanned_fit(model, tx, supervised=True).trace(
+            state, *(jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
+                (steps, B, T, F), (steps, B, 1, F), (steps, B))), epochs=2)
+        return by_init, traced[len(by_init):]
+
+    assert start(B=2) == ([(32, 1, 128)], [(32, 1, 128)])
+    said = default_registry.collect()
+    assert said['iotml_moe_add_rows{kind="kernel"}'] == 2
+    assert said['iotml_model_layers{kind="moe_ffn"}'] \
+        == said["iotml_remat_blocks"] == 3
+    assert start(B=3) == ([(48, 1, 128)], [(48, 1, 128)])
     jax.clear_caches()

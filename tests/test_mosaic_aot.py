@@ -438,3 +438,88 @@ def test_the_looped_fit_runs_the_flash_forward_once_an_application(
                 if re.search(r"f32\[1,8192,16,64,2\]", line)]
     # the passes' loop is in the program: the stacked kernel outputs
     assert any(re.search(r"f32\[2,1,8192,16,128\]", line) for line in lines)
+
+
+def _called(text: str, name: str) -> str:
+    """The computation `name` of a compiled module's text, and every
+    computation it calls, fusions' bodies among them."""
+    bodies = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text,
+                             re.M | re.S))
+    seen, todo = [], [name]
+    while todo:
+        at = todo.pop()
+        if at in seen or at not in bodies:
+            continue
+        seen.append(at)
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                           bodies[at])
+    return "\n".join(bodies[at] for at in seen)
+
+
+@pytest.mark.parametrize("stem,tokens,d,form", [
+    ("sensorformer-smallthinker-21b-a3b", 32768, 2560, "kernel"),
+    ("sensorformer-lfm2-24b-a2b", 16384, 2048, "kernel"),
+    # 64 and 32 MiB of accumulator: the rule leaves these two to XLA
+    # (it keeps them in VMEM); the kernel lowers at their shapes too
+    ("sensorformer-kimi-vl-a3b-instruct", 8192, 2048, "scatter"),
+    ("sensorformer-nemotron-3-super-120b-a12b", 8192, 1024, "scatter"),
+])
+def test_add_rows_in_a_tile_loop_lowers_for_v5e_in_place(v5e, stem, tokens,
+                                                         d, form):
+    """`iotml_add_rows` the way the experts' walk calls it — inside a
+    `fori_loop` whose trip count is data, the accumulator the loop's
+    carry, two such loops as a layer's forward and backward are — at the
+    four sparse-expert cells' shapes, read off their configuration
+    files: Mosaic takes the row copies (a row of the carried `[N, 1, d]`
+    is one piece: a row of `[N, d]`, a sublane of an (8, 128) tile, it
+    refuses), the lowered module holds ONE function `add_rows`, with the
+    one kernel in it, that both loops' bodies call (`add_rows.add_rows`
+    is jitted: a call site more is a `func.call` more, not the kernel
+    traced and lowered again), and the alias holds through that call and
+    the carry — each compiled loop's body holds the call and NO copy of
+    the accumulator (335 MB a tile at `st`'s shape, where a tile's add
+    moves 16)."""
+    import json
+
+    from iotml.ops import moe
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", stem + ".json")) as f:
+        cfg = json.load(f)
+    job = cfg["job"]
+    assert job["batch_size"] * job["window"] == tokens
+    assert (cfg.get("moe_latent_size") or cfg["hidden_size"]) == d
+    tile, tiles = moe._tile(tokens), 24
+    assert moe.add_rows_form(tokens, d, jnp.float32, "flash") == form
+
+    def walk(x, d_out, token, tile_rows, live_tiles):
+        def loop(of):
+            def tile_step(c, acc):
+                at = jax.lax.dynamic_slice_in_dim(token, c * tile, tile)
+                return moe._add_rows(acc, at, tile_rows[c],
+                                     of[:tile] * tile_rows[c], "kernel")
+            return jax.lax.fori_loop(0, live_tiles, tile_step,
+                                     moe._accumulator(of, "kernel")
+                                     ).reshape(of.shape)
+        return loop(x), loop(d_out)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    lowered = jax.jit(walk).lower(
+        sds((tokens, d)), sds((tokens, d)), sds((tiles * tile,), jnp.int32),
+        sds((tiles,), jnp.int32), sds((), jnp.int32))
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @add_rows\(", text)) == 1
+    assert len(re.findall(r"call @add_rows\(", text)) == 2
+    assert len(re.findall(r"kernel_name = \"iotml_add_rows\"", text)) == 1
+    text = lowered.compile().as_text()
+    loops = re.findall(r" while\([^\n]*body=%?([\w.\-]+)", text)
+    assert len(loops) == 2
+    carried = rf"f32\[{tokens},(?:1,)?{d}\]"
+    for loop in loops:
+        body = _called(text, loop)
+        assert len(re.findall(r"custom-call\([^\n]*iotml_add_rows",
+                              body)) == 1
+        assert re.search(carried + r"[^\n]* custom-call\(", body)
+        assert not re.search(carried + r"\S* copy\(", body)
