@@ -449,7 +449,7 @@ compile_cache = default_registry.counter(
     "program")
 # the flash kernels' step geometry (ops/attention.py `flash_geometry`),
 # set at trace time by each pallas_call site: what the last compiled
-# call of each kernel (fwd | bwd_dkv | bwd_dq) engaged.
+# call of each kernel (fwd | bwd_dkv | bwd_dq | bwd_fused) engaged.
 flash_grid_steps = default_registry.gauge(
     "iotml_flash_grid_steps",
     "grid steps a call of a flash-attention kernel, by kernel")
@@ -478,6 +478,17 @@ flash_operand_copies = default_registry.gauge(
     "iotml_flash_operand_copies",
     "operands of a flash kernel's call copied ahead of it "
     "(a T pad, a repeated k or v)")
+flash_backward_fused = default_registry.gauge(
+    "iotml_flash_backward_fused",
+    "1 where the last traced flash backward took the one kernel "
+    "(iotml_flash_bwd_fused: dQ's column resident on dK/dV's grid, five "
+    "products a tile), 0 where its column fits no geometry and it took "
+    "the two (iotml_flash_bwd_dkv, iotml_flash_bwd_dq: seven)")
+flash_bwd_column_bytes = default_registry.gauge(
+    "iotml_flash_bwd_column_bytes",
+    "bytes of the float32 dQ column [t_q, heads a step x D] a step of the "
+    "last traced fused flash backward holds in VMEM, one buffer of the "
+    "pipeline's two (0 where the backward took the two kernels)")
 # the same by kernel AND by mask (kind: dense | causal | band): one
 # program may hold causal calls beside band calls (a sliding window),
 # and the last traced call of each kernel under each mask stands.

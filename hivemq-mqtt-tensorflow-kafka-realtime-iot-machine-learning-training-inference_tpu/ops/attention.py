@@ -42,10 +42,12 @@ from jax.ad_checkpoint import checkpoint_name
 NEG_INF = -1e30
 
 #: the kernels' names in a device trace and in the HLO: one per kernel,
-#: whichever grid (dense or triangular) runs it
+#: whichever grid (dense or triangular) runs it; a backward is the fused
+#: kernel alone, or the dK/dV and dQ pair
 FWD_KERNEL = "iotml_flash_fwd"
 BWD_DKV_KERNEL = "iotml_flash_bwd_dkv"
 BWD_DQ_KERNEL = "iotml_flash_bwd_dq"
+BWD_FUSED_KERNEL = "iotml_flash_bwd_fused"
 
 
 def _band_edges(nq: int, nk: int, block_q: int, block_k: int, order: str,
@@ -196,8 +198,11 @@ def finalize_blockwise(o, l):
 
 
 # ------------------------------------------------------------------- geometry
-#: the three kernels the rule knows, by the suffix of their trace names
-KERNELS = ("fwd", "bwd_dkv", "bwd_dq")
+#: the kernels the rule knows, by the suffix of their trace names: a
+#: backward is `bwd_fused` alone where its dQ column fits, else the pair
+KERNELS = ("fwd", "bwd_dkv", "bwd_dq", "bwd_fused")
+#: those whose grid walks kv blocks outer, q blocks inner
+_KV_OUTER = ("bwd_dkv", "bwd_fused")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,14 +249,26 @@ def _geometry(T: int, bh: int, causal: bool, block_q: int, block_k: int,
 
 
 #: scoped VMEM a kernel may take on the chip: Mosaic's default limit on
-#: a v5e, which no call here raises
+#: a v5e, which one call here raises — the fused backward's, whose dQ
+#: column (a whole [t_q, G·D] float32 column of the step's heads, twice
+#: with the pipeline's second buffer) stays in VMEM for a walk and is
+#: larger than this beside the blocks at every long T; that call states
+#: its own limit, its counted bytes and `_VMEM_MARGIN`, up to
+#: `_FUSED_VMEM_CAP` of the chip's 128 MiB
 _VMEM_BUDGET = 16 * 2 ** 20
+_FUSED_VMEM_CAP = 64 * 2 ** 20
+_VMEM_MARGIN = 8 * 2 ** 20
 #: the float32 [block_q, block_k] arrays a tile's arithmetic holds at
 #: once, counted from the kernels' bodies: the forward's scores and
 #: probabilities; the backward's probabilities, dP and dS.  (The mask's
 #: iotas and compare are consumed as they are made.)  Heads of one step
-#: run one after the other and reuse them.
-_TILE_TEMPS = {"fwd": 2, "bwd_dkv": 3, "bwd_dq": 3}
+#: run one after the other and reuse them.  The fused backward counts
+#: two more, the transposed p and dS its dV and dK products contract
+#: over: under its larger cap the rule reaches 1,024² tiles at several
+#: heads a step, where Mosaic's own allocation ran 8.6-12.1 MiB over a
+#: count of three (compiled for a described v5e: 58.56 MiB against 50,
+#: 72.14 against 60)
+_TILE_TEMPS = {"fwd": 2, "bwd_dkv": 3, "bwd_dq": 3, "bwd_fused": 5}
 #: the largest block the rule derives, and the cap on a block named for
 #: the backward kernels: at 2048² the temporaries alone are 32-48 MiB
 _MAX_BLOCK = 1024
@@ -274,11 +291,33 @@ _MAX_HEADS = 8
 #: moves the constants far.  PERF.md §6 (PR 25) has the table.
 _COST_US = {"fwd": (0.25, 0.030, 0.28, 0.025),
             "bwd_dkv": (0.31, 0.040, 0.21, 0.075),
-            "bwd_dq": (0.26, 0.043, 0.078, 0.062)}
+            "bwd_dq": (0.26, 0.043, 0.078, 0.062),
+            # PR 49's sweep of the fused call alone, 116 geometries at
+            # the cells' shapes (PERF.md §6): median error 5%, a head of
+            # 192 under-predicted by a quarter (no term knows D)
+            "bwd_fused": (0.44, 0.113, 0.077, 0.028)}
+#: the largest tile the fused backward derives, in scores: at 1,024² its
+#: five temporaries are 20 MiB, the same sweep measured such tiles no
+#: faster a head than 512 × 1,024 (65.85 against 66.29 ms at T 16,384,
+#: 5.00 against 5.02 at 8,192) — a cost no term of `_COST_US` has — and
+#: they leave the cap no room for the second head a step, which is
+#: worth 5-10%
+_FUSED_MAX_TILE = 512 * 1024
+
+
+def _vmem_cap(kernel: str) -> int:
+    """The scoped VMEM the rule may count for a call of `kernel`."""
+    return _FUSED_VMEM_CAP if kernel == "bwd_fused" else _VMEM_BUDGET
+
+
+def _column_bytes(t_q: int, heads: int, D: int) -> int:
+    """The fused backward's dQ column: `t_q` rows of the step's heads'
+    lanes in float32, one buffer of it."""
+    return t_q * _round_up(heads * D, 128) * 4
 
 
 def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
-                itemsize: int, Dv: Optional[int] = None) -> int:
+                itemsize: int, Dv: Optional[int] = None, t_q: int = 0) -> int:
     """Scoped VMEM one grid step needs, counted: every operand's block
     twice (the pipeline double-buffers), the float32 scratch, and the
     tile's temporaries.  A block of q, k or their gradients is `heads·D`
@@ -286,16 +325,18 @@ def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
     `None` is D) — the step's heads side by side, padded to 128 only
     where the whole width is narrower — and a [block_q, 1] row statistic
     pads to 128 lanes a head, so one head's is as wide as a 128-lane q
-    block."""
+    block.  The fused backward's blocks are dK/dV's, and its dQ output
+    is the whole column of `t_q` rows (`_column_bytes`, twice)."""
     lanes = _round_up(heads * D, 128)
     v_lanes = _round_up(heads * (D if Dv is None else Dv), 128)
     q_blk, k_blk = block_q * lanes, block_k * lanes
     o_blk, v_blk = block_q * v_lanes, block_k * v_lanes
     stat = heads * block_q * 128 * 4
+    column = 2 * _column_bytes(t_q, heads, D) if kernel == "bwd_fused" else 0
     if kernel == "fwd":
         piped = (q_blk + o_blk + k_blk + v_blk) * itemsize + stat  # .. lse
         scratch = o_blk * 4 + 2 * stat                      # acc; m, l
-    elif kernel == "bwd_dkv":
+    elif kernel in _KV_OUTER:
         # q, dO; k, v, dk, dv
         piped = (q_blk + o_blk + 2 * k_blk + 2 * v_blk) * itemsize + 2 * stat
         scratch = (k_blk + v_blk) * 4                       # dk, dv
@@ -303,7 +344,8 @@ def _vmem_bytes(kernel: str, block_q: int, block_k: int, heads: int, D: int,
         # q, dO, dq; k, v
         piped = (2 * q_blk + o_blk + k_blk + v_blk) * itemsize + 2 * stat
         scratch = q_blk * 4                                 # dq
-    return 2 * piped + scratch + _TILE_TEMPS[kernel] * block_q * block_k * 4
+    return 2 * piped + column + scratch \
+        + _TILE_TEMPS[kernel] * block_q * block_k * 4
 
 
 def _cost_us(kernel: str, geom: FlashGeometry, bh: int) -> float:
@@ -332,15 +374,18 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
                    H: int, causal: bool, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
                    Dv: Optional[int] = None,
-                   window: Optional[int] = None) -> FlashGeometry:
+                   window: Optional[int] = None) -> Optional[FlashGeometry]:
     """The step geometry of one flash kernel (`fwd`, `bwd_dkv`,
-    `bwd_dq`), from what the code can see at trace time — the one place
-    that knows tile sizes.
+    `bwd_dq`, `bwd_fused`), from what the code can see at trace time —
+    the one place that knows tile sizes, and which form a backward
+    takes: one backward kernel, two where the dQ column does not fit.
 
     A block left `None` is derived: of the multiples of 128 that divide
     T padded to 128 (up to `_MAX_BLOCK`) and the heads a step that the
     lanes allow (`_head_groups`), the geometry whose counted VMEM fits
-    `_VMEM_BUDGET` and whose modelled time (`_COST_US`) is least.
+    the kernel's cap (`_VMEM_BUDGET`; the fused backward's
+    `_FUSED_VMEM_CAP`, its tile no larger than `_FUSED_MAX_TILE`) and
+    whose modelled time (`_COST_US`) is least.
     Larger tiles save grid steps and per-chunk state and compute more
     of the causal triangle's dead area (at T = 1,024: 128² 36 units of
     128² in 36 steps a head, 512² 48 in 3, 1024² 64 in 1); more heads a
@@ -352,7 +397,15 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
     (latent attention: 192 beside 128).  Under `window` (causal only; a
     window of T or more is none) the tiles counted are the band's, so
     the same model weighs a smaller tile's fuller band against its
-    steps."""
+    steps.
+
+    `bwd_fused` is dK/dV's grid with dQ's whole column of the step's
+    heads resident beside the blocks — `t_q × G·D` float32, twice: 8 MiB
+    at T = 16,384 and one head of 128, 12.6 MB at 8,192 and two of 192
+    — so five products a tile where the pair makes seven.  Where no
+    geometry's count fits its cap (T = 65,536 at a head of 128: 32 MiB,
+    twice), named blocks or not, the answer is `None`: that backward
+    takes the two kernels."""
     if window is not None and (not causal or window >= T):
         window = None
     named = block_q is not None, block_k is not None
@@ -360,17 +413,23 @@ def flash_geometry(kernel: str, T: int, D: int, itemsize: int, B: int,
     n = _round_up(T, 128) // 128
     derived = [128 * m for m in range(1, _MAX_BLOCK // 128 + 1) if n % m == 0]
     groups, bh = _head_groups(H, D, Dv), B * H
-    geoms = [_geometry(T, bh, causal, bq, bk, h, window, kernel == "bwd_dkv")
+    geoms = [_geometry(T, bh, causal, bq, bk, h, window, kernel in _KV_OUTER)
              for bq in ([min(block_q, cap)] if named[0] else derived)
              for bk in ([min(block_k, cap)] if named[1] else derived)
+             if kernel != "bwd_fused" or all(named)
+             or bq * bk <= _FUSED_MAX_TILE
              for h in (groups[:1] if any(named) else groups)]
-    if not all(named):
+    fits = [g for g in geoms if _vmem_bytes(
+        kernel, g.block_q, g.block_k, g.heads, D, itemsize, Dv, g.t_q)
+        <= _vmem_cap(kernel)]
+    if kernel == "bwd_fused":
+        # a column that does not fit is no smaller under other blocks
+        geoms = fits
+    elif not all(named):
         # the first is the smallest: what is left when nothing fits, for
         # the compiler to refuse
-        geoms = [g for g in geoms if _vmem_bytes(
-            kernel, g.block_q, g.block_k, g.heads, D, itemsize, Dv)
-            <= _VMEM_BUDGET] or geoms[:1]
-    return min(geoms, key=lambda g: _cost_us(kernel, g, bh))
+        geoms = fits or geoms[:1]
+    return min(geoms, key=lambda g: _cost_us(kernel, g, bh), default=None)
 
 
 # --------------------------------------------------------------------- pallas
@@ -530,6 +589,24 @@ def _dq_tile(q, k, v, do, lse, delta, mask, dq, *, scale: float):
         preferred_element_type=jnp.float32)
 
 
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _fused_tile(q, k, v, do, lse, delta, mask, dq, dk, dv, *, scale: float):
+    """One head's tile of the one-kernel backward, as values: p and ds
+    made once, then dK/dV's two products and dQ's — five a tile."""
+    p, ds = _bwd_tile(q, k, v, do, lse, delta, mask, scale)
+    ds = ds.astype(q.dtype)
+    dv = dv + jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dk = dk + jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dq = dq + jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return dq, dk, dv
+
+
 def _dkv_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
               k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
               block_q, block_k, t_real, window=None):
@@ -600,6 +677,54 @@ def _dq_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
+def _fused_step(i, j, first, last, live, q_ref, do_ref, lse_ref, delta_ref,
+                k_ref, v_ref, dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, *,
+                scale, causal, block_q, block_k, t_real, window=None):
+    """One grid step of the one-kernel backward, on dK/dV's grid: dk and
+    dv accumulate in scratch along a kv column as in `_dkv_step`, and dQ
+    in its output block — the WHOLE float32 q column of the step's
+    heads, whose block index does not move along the walk of one (batch
+    row, head group), so it stays in VMEM from the walk's first tile,
+    which zeroes it, to its last, after which it is written back once.
+    Columns come in rising j, so a q row's sums over kv blocks run in
+    `_dq_step`'s order."""
+    from jax.experimental import pallas as pl
+
+    heads = _head_columns(q_ref, v_ref, lse_ref)
+
+    # the walk's first tile on either grid: the first of kv column 0
+    @pl.when(first & (j == 0))
+    def _zero():
+        dq_ref[:] = jnp.zeros_like(dq_ref)
+
+    @pl.when(first)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def compute():
+        mask = _bwd_mask(i, j, causal=causal, block_q=block_q,
+                         block_k=block_k, t_real=t_real, window=window)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        for g, cols, vcols in heads:
+            dq_ref[0, rows, cols], dk_acc[:, cols], dv_acc[:, vcols] = \
+                _fused_tile(
+                    q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, vcols],
+                    do_ref[0, :, vcols], lse_ref[g], delta_ref[g], mask,
+                    dq_ref[0, rows, cols], dk_acc[:, cols],
+                    dv_acc[:, vcols], scale=scale)
+
+    if live is None:
+        compute()
+    else:
+        pl.when(live)(compute)
+
+    @pl.when(last)
+    def _emit():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _dense_kernel(*refs, step, kv_outer: bool, causal: bool, block_q: int,
                   block_k: int, window: Optional[int] = None, **static):
     """A step function on the dense grid (B, H/heads, outer, inner): the
@@ -667,7 +792,8 @@ def mask_area(T: int, causal: bool, window: Optional[int] = None) -> int:
 
 def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
                 D: int, Dv: int, T: int, kv_outer: bool, causal: bool,
-                ins: str, outs: str, scratch, copies: int, **static):
+                ins: str, outs: str, scratch, copies: int,
+                vmem_limit: Optional[int] = None, **static):
     """(kernel function, grid spec, compiler params, prefetch operands)
     of one flash kernel on the grid `geom` names — and the record of
     that geometry (`iotml_flash_*{kernel}`, at trace time).  `ins` and
@@ -676,7 +802,9 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
     batch row b, the c-th group of G heads — O/V the same of a
     `[B, T, H·Dv]` array (out and dO along q; v and dv along kv), and q
     the [G, block_q, 1] row statistics of the same heads in a
-    `[B·H, t_q, 1]` array."""
+    `[B·H, t_q, 1]` array; C is the whole `[1, t_q, G·D]` column of
+    those heads, the same block at every tile of a walk.  `vmem_limit`
+    is stated by the one call whose blocks outgrow Mosaic's default."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -712,14 +840,16 @@ def _flash_grid(kernel: str, geom: FlashGeometry, step, *, B: int, H: int,
         "q": pl.BlockSpec((G, bq, 1),
                           lambda b, c, *t: (b * nc + c, qtile(*t), 0)),
         "K": pl.BlockSpec((1, bk, G * D), kv_side),
-        "V": pl.BlockSpec((1, bk, G * Dv), kv_side)}
+        "V": pl.BlockSpec((1, bk, G * Dv), kv_side),
+        "C": pl.BlockSpec((1, geom.t_q, G * D), lambda b, c, *t: (b, 0, c))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[blocks[c] for c in ins],
         out_specs=[blocks[c] for c in outs],
         scratch_shapes=scratch)
     params = pltpu.CompilerParams(dimension_semantics=(
-        "parallel", "parallel") + ("arbitrary",) * (len(grid) - 2))
+        "parallel", "parallel") + ("arbitrary",) * (len(grid) - 2),
+        vmem_limit_bytes=vmem_limit)
     return fn, grid_spec, params, prefetch
 
 
@@ -886,15 +1016,57 @@ def _flash_bwd_dq(q, do, lse, delta, k, v, H: int, causal: bool,
     return dq
 
 
+def _flash_bwd_fused(q, do, lse, delta, k, v, H: int, causal: bool,
+                     geom: FlashGeometry, interpret: bool, scale: float,
+                     repeated: int = 0):
+    """dK, dV and dQ of the same operands in ONE call on `geom`, dK/dV's
+    grid: ([B, t_k, H·D], [B, t_k, H·Dv], float32 [B, t_q, H·D]).  The
+    call states its scoped VMEM: what `_vmem_bytes` counts, the dQ
+    column in the count, and `_VMEM_MARGIN`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, HD = q.shape
+    D, Dv = HD // H, v.shape[2] // H
+    G, bk, Tq, Tk = geom.heads, geom.block_k, geom.t_q, geom.t_k
+    operands, copies = _bwd_operands(q, do, lse, delta, k, v, geom, repeated)
+    fn, grid_spec, params, prefetch = _flash_grid(
+        "bwd_fused", geom, _fused_step, B=B, H=H, D=D, Dv=Dv, T=T,
+        kv_outer=True, causal=causal, ins="QOqqKV", outs="KVC",
+        scratch=[pltpu.VMEM((bk, G * D), jnp.float32),
+                 pltpu.VMEM((bk, G * Dv), jnp.float32)],
+        copies=copies,
+        vmem_limit=_VMEM_MARGIN + _vmem_bytes(
+            "bwd_fused", geom.block_q, bk, G, D, q.dtype.itemsize, Dv, Tq),
+        scale=scale, t_real=T)
+    return pl.pallas_call(
+        fn, name=BWD_FUSED_KERNEL, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, Tk, HD), k.dtype),
+                   jax.ShapeDtypeStruct((B, Tk, H * Dv), v.dtype),
+                   jax.ShapeDtypeStruct((B, Tq, HD), jnp.float32)],
+        compiler_params=params, interpret=interpret,
+    )(*prefetch, *operands)
+
+
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_backward(q, k, v, out, lse, do, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
                     interpret: bool, scale: float, repeated: int,
                     window: Optional[int] = None):
-    """Pallas flash-attention backward: the standard two-kernel split
-    (dkv sweeping q per kv block; dq sweeping kv per q block — p/ds
-    recomputed blockwise in VMEM, never materialized to HBM), each
-    kernel on its own geometry."""
+    """Pallas flash-attention backward — p and ds recomputed blockwise
+    in VMEM, never materialized to HBM: one backward kernel, two where
+    the dQ column does not fit.  The one (`iotml_flash_bwd_fused`) walks
+    dK/dV's grid with dQ's float32 column of the step's heads resident
+    (`t_q × G·D × 4` bytes, twice), makes scores, dP, the exponent, the
+    mask and ds once a tile and five products from them; where
+    `flash_geometry("bwd_fused", …)` finds no geometry under its cap,
+    the standard two-kernel split runs as it always did (dkv sweeping q
+    per kv block; dq sweeping kv per q block: seven products a tile
+    pair), each kernel on its own geometry.  Which ran is read off the
+    shape, and said: `iotml_flash_backward_fused`,
+    `iotml_flash_bwd_column_bytes`."""
+    from ..obs import metrics as obs_metrics
+
     B, T, H, D = q.shape
     # rowwise D_i = sum_d dO_i·O_i (softmax-jacobian diagonal term)
     delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
@@ -902,12 +1074,23 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool,
     flat = lambda x: x.reshape(B, T, -1)  # noqa: E731
     operands = (flat(q), flat(do), lse.reshape(B * H, T, 1),
                 delta.reshape(B * H, T, 1), flat(k), flat(v))
-    dkv, dq = (flash_geometry(kernel, T, D, q.dtype.itemsize, B, H, causal,
-                              block_q, block_k, v.shape[-1], window)
-               for kernel in ("bwd_dkv", "bwd_dq"))
-    dk, dv = _flash_bwd_dkv(*operands, H, causal, dkv, interpret, scale,
-                            repeated)
-    dq = _flash_bwd_dq(*operands, H, causal, dq, interpret, scale, repeated)
+    geometry = lambda kernel: flash_geometry(  # noqa: E731
+        kernel, T, D, q.dtype.itemsize, B, H, causal, block_q, block_k,
+        v.shape[-1], window)
+    fused = geometry("bwd_fused")
+    obs_metrics.flash_backward_fused.set(fused is not None)
+    obs_metrics.flash_bwd_column_bytes.set(
+        _column_bytes(fused.t_q, fused.heads, D) if fused else 0)
+    if fused is not None:
+        dk, dv, dq = _flash_bwd_fused(*operands, H, causal, fused, interpret,
+                                      scale, repeated)
+        # the float32 column cast once, where XLA fuses it
+        dq = dq.astype(q.dtype)
+    else:
+        dk, dv = _flash_bwd_dkv(*operands, H, causal, geometry("bwd_dkv"),
+                                interpret, scale, repeated)
+        dq = _flash_bwd_dq(*operands, H, causal, geometry("bwd_dq"),
+                           interpret, scale, repeated)
     return tuple(x[:, :T].reshape(B, T, H, -1) for x in (dq, dk, dv))
 
 
@@ -958,8 +1141,8 @@ def flash_attention(q, k, v, causal: bool = True,
     copied on the way in or out — q, k, v as the projections wrote them,
     the gradients as the projections' backward reads them.
 
-    `block_q`/`block_k` left `None` are derived from the shape, each of
-    the three kernels its own (`flash_geometry`), with as many heads a
+    `block_q`/`block_k` left `None` are derived from the shape, each
+    kernel its own (`flash_geometry`), with as many heads a
     step as the lanes allow and the model of their cost prefers; a value
     given is honoured as it stands, with the fewest heads the lanes
     allow (`G·D` a multiple of 128, or all H).  T is padded to the
@@ -975,10 +1158,15 @@ def flash_attention(q, k, v, causal: bool = True,
     call, built as it always was.
 
     Differentiable via custom VJP: the forward kernel emits the per-row
-    log-sum-exp; the backward is the standard two-kernel Pallas split
-    (dK/dV sweeping q blocks per kv block, dQ sweeping kv blocks per q
-    block) with blockwise probability recompute in VMEM — O(T·block)
-    memory and no HBM round trip for the probability matrices.
+    log-sum-exp; the backward is one backward kernel, two where the dQ
+    column does not fit — dK/dV's grid with dQ's float32 column of the
+    step's heads resident in VMEM (`T × G·D × 4` bytes, twice: 8 MiB a
+    buffer at T = 16,384 and a head of 128), five products a tile; past
+    the fused call's cap on counted VMEM (T = 65,536 at a head of 128)
+    the standard two-kernel Pallas split (dK/dV sweeping q blocks per kv
+    block, dQ sweeping kv blocks per q block), seven — with blockwise
+    probability recompute in VMEM either way: no HBM round trip for the
+    probability matrices.
     """
     repeated = 2 * (k.shape[2] != q.shape[2])
     k, v = _repeat_kv(q, k, v)

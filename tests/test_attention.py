@@ -20,6 +20,26 @@ def _qkv(B=2, T=32, H=2, D=8, seed=0, dtype=jnp.float32, kv_heads=None):
     return mk(), mk(kv_heads or H), mk(kv_heads or H)
 
 
+#: what a backward of each form runs, by the gauges' `kernel`
+FORMS = {"fused": ("fwd", "bwd_fused"), "pair": ("fwd", "bwd_dkv", "bwd_dq")}
+
+
+@pytest.fixture
+def form(request, monkeypatch):
+    """The form a backward takes, held the way the rule decides it: the
+    pair is what a shape gets whose dQ column fits no geometry, so a cap
+    of nothing leaves every shape the two kernels.  The traced calls of
+    the other form are dropped on the way in and on the way out."""
+    if request.param == "pair":
+        monkeypatch.setattr(attention, "_FUSED_VMEM_CAP", 0)
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+
+
+both_forms = pytest.mark.parametrize("form", list(FORMS), indirect=True)
+
+
 def test_reference_attention_is_causal():
     q, k, v = _qkv()
     out = attention_reference(q, k, v, causal=True)
@@ -162,15 +182,17 @@ def test_flash_attention_derived_tiles_match_reference(B, T, H, D, dtype,
                                    **grad_tol)
 
 
-def test_flash_grad_transposes_nothing_and_copies_no_operand():
+@both_forms
+def test_flash_grad_transposes_nothing_and_copies_no_operand(form):
     """The kernels take q, k, v, dO and give out, dq, dk, dv where the
     projections leave and take them: at `sf-train-backlog`'s shape the
     gradient's jaxpr holds no transpose of a rank-4 array (the folds
     were ten of them), each operand reaches its kernel uncopied, and a
-    step takes 128 lanes or more."""
+    step takes 128 lanes or more — in the one backward kernel and in
+    the two."""
     from iotml.obs.metrics import default_registry
 
-    jax.clear_caches()   # the geometry is recorded when a shape is traced
+    kernels = FORMS[form]   # the geometry is recorded when a shape is traced
     qkv = [jax.ShapeDtypeStruct((4, 1024, 16, 64), jnp.float32)] * 3
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True)),
@@ -184,13 +206,14 @@ def test_flash_grad_transposes_nothing_and_copies_no_operand():
                     yield from equations(sub)
 
     names = [e.primitive.name for e in equations(jaxpr.jaxpr)]
-    assert names.count("pallas_call") == 3
+    assert names.count("pallas_call") == len(kernels)
     assert not [e for e in equations(jaxpr.jaxpr)
                 if e.primitive.name == "transpose"
                 and e.invars[0].aval.ndim >= 4]
     assert not {"pad", "concatenate", "gather"} & set(names)
     got = default_registry.collect()
-    for kernel in attention.KERNELS:
+    assert got["iotml_flash_backward_fused"] == (form == "fused")
+    for kernel in kernels:
         assert got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}'] == 0
         lanes = got[f'iotml_flash_lanes_per_step{{kernel="{kernel}"}}']
         heads = got[f'iotml_flash_heads_per_step{{kernel="{kernel}"}}']
@@ -203,7 +226,7 @@ def test_flash_grad_transposes_nothing_and_copies_no_operand():
         q, k, v, causal=True, interpret=True)), argnums=(0, 1, 2))(q, k, v)
     got = default_registry.collect()
     assert [got[f'iotml_flash_operand_copies{{kernel="{kernel}"}}']
-            for kernel in attention.KERNELS] == [3, 6, 6]
+            for kernel in kernels] == [3, 6, 6][:len(kernels)]
 
 
 def test_non_causal_flash_attention_still_needs_whole_tiles():
@@ -230,6 +253,15 @@ def test_flash_geometry_invariants(kernel, T, D, itemsize, B, H, causal):
     g = attention.flash_geometry(kernel, T, D, itemsize, B, H, causal)
     bh = B * H
     t_pad = -(-T // 128) * 128
+    if kernel == "bwd_fused":
+        # the one kernel wherever some geometry holds the dQ column,
+        # twice, beside its blocks: the smallest's count says which
+        fits = attention._vmem_bytes(
+            kernel, 128, 128, attention._head_groups(H, D)[0], D, itemsize,
+            t_q=t_pad) <= attention._FUSED_VMEM_CAP
+        assert (g is not None) == fits == (T < 65536)
+        if g is None:
+            return
     for block in (g.block_q, g.block_k):
         assert block % 128 == 0 and t_pad % block == 0
         assert block <= attention._MAX_BLOCK
@@ -239,7 +271,8 @@ def test_flash_geometry_invariants(kernel, T, D, itemsize, B, H, causal):
     assert g.heads * D % 128 == 0 or g.heads == H
     assert g.heads <= attention._MAX_HEADS or g.heads == H
     assert attention._vmem_bytes(kernel, g.block_q, g.block_k, g.heads, D,
-                                 itemsize) <= attention._VMEM_BUDGET
+                                 itemsize, t_q=g.t_q) \
+        <= attention._vmem_cap(kernel)
     nq, nk = g.t_q // g.block_q, g.t_k // g.block_k
     live = attention._tri_tile_count(nq, nk, g.block_q, g.block_k)
     assert g.tri == (causal and live <= attention._TRI_TILE_CAP)
@@ -272,6 +305,10 @@ def test_flash_geometry_explicit_blocks_win(kernel):
     # named, the backward kernels stop at the largest tile that compiles
     g = attention.flash_geometry(kernel, 65536, 128, 2, 1, 2, True,
                                  2048, 2048)
+    if kernel == "bwd_fused":
+        # no block makes a 32 MiB column smaller: the two kernels
+        assert g is None
+        return
     want = 2048 if kernel == "fwd" else attention._MAX_BLOCK
     assert (g.block_q, g.block_k) == (want, want)
 
@@ -320,11 +357,12 @@ def test_flash_geometry_by_both_widths():
                                         Dv=128)
         assert geom.heads * 192 % 128 == 0 and geom.heads * 128 % 128 == 0
         narrow, wide = (attention._vmem_bytes(
-            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4, dv)
-            for dv in (128, 192))
+            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4, dv,
+            geom.t_q) for dv in (128, 192))
         assert narrow < wide == attention._vmem_bytes(
-            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4)
-        assert narrow <= attention._VMEM_BUDGET
+            kernel, geom.block_q, geom.block_k, geom.heads, 192, 4,
+            t_q=geom.t_q)
+        assert narrow <= attention._vmem_cap(kernel)
         for shape in ((1024, 64, 4, 4, 16, True), (4096, 64, 4, 1, 32, True)):
             assert attention.flash_geometry(kernel, *shape) \
                 == attention.flash_geometry(kernel, *shape, Dv=shape[1])
@@ -336,7 +374,9 @@ def test_flash_geometry_by_both_widths():
         q, k, v[..., :128], interpret=True)),
         argnums=(0, 1, 2))(q, k, v)
     got = default_registry.collect()
-    for kernel in attention.KERNELS:
+    # the column is q's width: two heads of 192, 256 rows, float32
+    assert got["iotml_flash_bwd_column_bytes"] == 256 * 384 * 4
+    for kernel in FORMS["fused"]:
         assert got[f'iotml_flash_lanes_per_step{{kernel="{kernel}"}}'] == 384
         assert got[
             f'iotml_flash_value_lanes_per_step{{kernel="{kernel}"}}'] == 256
@@ -461,18 +501,18 @@ def test_a_band_past_the_cap_on_the_maps_walks_the_dense_grid(monkeypatch):
     jax.clear_caches()
 
 
-def test_the_gauges_tell_a_band_call_from_a_causal_one():
+@both_forms
+def test_the_gauges_tell_a_band_call_from_a_causal_one(form):
     """By kernel and mask: a causal call and a band call of one program
     both stand; the keys by kernel alone are the last traced call's."""
     from iotml.obs.metrics import default_registry
 
-    jax.clear_caches()
     q, k, v = _qkv(B=1, T=300, H=2, D=64)
     for window in (None, 100):
         jax.grad(lambda q: jnp.sum(flash_attention(
             q, k, v, True, 128, 128, True, window=window)))(q)
     said = default_registry.collect()
-    for kernel in attention.KERNELS:
+    for kernel in FORMS[form]:
         by = lambda what, mask: said[  # noqa: E731
             f'iotml_flash_mask_{what}{{kernel="{kernel}",kind="{mask}"}}']
         assert (by("window", "causal"), by("window", "band")) == (0, 100)
@@ -485,3 +525,176 @@ def test_the_gauges_tell_a_band_call_from_a_causal_one():
         assert said[f'iotml_flash_grid_steps{{kernel="{kernel}"}}'] == 5 * 2 \
             / said[f'iotml_flash_heads_per_step{{kernel="{kernel}"}}']
     assert attention.mask_area(300, False) == 300 * 300
+
+
+# ------------------------------------------- the one-kernel backward
+#: B, T, H, D, Dv, dtype, causal, key/value heads, window, blocks
+FUSED_CASES = {
+    "triangle": (2, 384, 2, 32, 32, jnp.float32, True, None, None, 128),
+    # a band whose near edge cuts the diagonal's tiles and whose far
+    # edge ends inside a tile two columns back
+    "band": (1, 512, 2, 32, 32, jnp.float32, True, None, 200, 128),
+    "dense": (1, 256, 2, 32, 32, jnp.float32, False, None, None, 128),
+    "padded": (2, 300, 2, 32, 32, jnp.float32, True, None, None, 128),
+    "padded_band": (1, 300, 2, 32, 32, jnp.float32, True, None, 130, None),
+    "four_heads_of_64": (1, 256, 4, 64, 64, jnp.float32, True, None, None,
+                         None),
+    "latent_widths": (1, 300, 2, 192, 128, jnp.float32, True, None, None,
+                      128),
+    "repeated_kv": (1, 300, 4, 64, 64, jnp.float32, True, 2, None, 128),
+    "bfloat16": (1, 300, 2, 128, 128, jnp.bfloat16, True, None, None, None),
+    "small_blocks": (2, 40, 2, 8, 8, jnp.float32, True, None, None, 16),
+}
+
+
+def _grads(case, fn, seed=0):
+    B, T, H, D, Dv, dtype, causal, kv_heads, window, block = FUSED_CASES[case]
+    rng = np.random.default_rng(seed)
+    mk = lambda h, d: jnp.asarray(  # noqa: E731
+        rng.normal(size=(B, T, h, d)), dtype)
+    q, k, v = mk(H, D), mk(kv_heads or H, D), mk(kv_heads or H, Dv)
+    w = mk(H, Dv).astype(jnp.float32)
+    if fn is attention_reference:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        call = lambda q, k, v: fn(  # noqa: E731
+            q, k, v, causal=causal, window=window)
+    else:
+        call = lambda q, k, v: fn(  # noqa: E731
+            q, k, v, causal, block, block, True, window=window)
+    return jax.grad(lambda *a: jnp.sum(w * call(*a).astype(jnp.float32)),
+                    (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_the_fused_backward_gives_the_two_kernels_gradients(case,
+                                                            monkeypatch):
+    """dq, dk and dv of the one backward kernel — dQ summed into its
+    resident float32 column on dK/dV's grid — against
+    `attention_reference`'s gradients, to this file's tolerances, AND
+    against the two-kernel form's on the same operands, to float32's
+    rounding: the same five products on the same operands, the sums
+    over kv blocks in the same order."""
+    from iotml.obs.metrics import default_registry
+
+    dtype = FUSED_CASES[case][5]
+    jax.clear_caches()
+    fused = _grads(case, flash_attention)
+    said = default_registry.collect()
+    assert said["iotml_flash_backward_fused"] == 1
+    if case == "four_heads_of_64":
+        assert said['iotml_flash_heads_per_step{kernel="bwd_fused"}'] == 4
+    monkeypatch.setattr(attention, "_FUSED_VMEM_CAP", 0)
+    jax.clear_caches()
+    pair = _grads(case, flash_attention)
+    assert default_registry.collect()["iotml_flash_backward_fused"] == 0
+    jax.clear_caches()
+    tol = dict(rtol=1e-4, atol=2e-5) if dtype == jnp.float32 \
+        else dict(rtol=2e-2, atol=2e-2)
+    up = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    for one, two, want in zip(fused, pair, _grads(case, attention_reference)):
+        assert one.dtype == two.dtype == dtype and one.shape == want.shape
+        np.testing.assert_allclose(up(one), up(want), **tol)
+        # bfloat16: the float32 column cast once, as the scratch was
+        np.testing.assert_allclose(up(one), up(two), rtol=1e-6, atol=1e-6)
+
+
+def test_the_fused_backward_on_the_dense_causal_grid(monkeypatch):
+    """Past the cap on the maps the fused kernel walks the dense grid
+    and skips dead tiles by `pl.when`: the column is zeroed at the
+    walk's first tile whether or not that tile is live."""
+    monkeypatch.setattr(attention, "_TRI_TILE_CAP", 2)
+    jax.clear_caches()
+    fused = _grads("band", flash_attention)
+    assert not attention.flash_geometry(
+        "bwd_fused", 512, 32, 4, 1, 2, True, 128, 128, window=200).tri
+    for one, want in zip(fused, _grads("band", attention_reference)):
+        np.testing.assert_allclose(np.asarray(one), np.asarray(want),
+                                   rtol=1e-4, atol=2e-5)
+    jax.clear_caches()
+
+
+#: the attention call of every listed cell, (B, T, H, D, Dv, window),
+#: float32: `flash_attention`'s operands after the repeat of k and v
+CELL_SHAPES = {
+    "sf": (4, 1024, 16, 64, 64, None),
+    "gh": (1, 4096, 32, 64, 64, None),
+    "km": (1, 8192, 16, 192, 128, None),
+    "ns": (1, 8192, 4, 128, 128, None),
+    "lf": (2, 8192, 32, 64, 64, None),
+    "ou": (1, 8192, 16, 128, 128, None),
+    "st": (2, 16384, 28, 128, 128, None),
+    "st_band": (2, 16384, 28, 128, 128, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_every_listed_cells_backward_takes_the_one_kernel(cell):
+    """Which form a backward takes is read off the shape: at every
+    listed cell's shape some geometry holds the dQ column twice beside
+    its blocks under the fused call's cap — on dK/dV's grid, the band's
+    tiles in column order — and the limit the call states is its own
+    count and the margin."""
+    B, T, H, D, Dv, window = CELL_SHAPES[cell]
+    g = attention.flash_geometry("bwd_fused", T, D, 4, B, H, True, Dv=Dv,
+                                 window=window)
+    assert g is not None and g.tri and g.window == window
+    counted = attention._vmem_bytes("bwd_fused", g.block_q, g.block_k,
+                                    g.heads, D, 4, Dv, g.t_q)
+    column = attention._column_bytes(g.t_q, g.heads, D)
+    assert column == T * g.heads * D * 4
+    assert 2 * column < counted <= attention._FUSED_VMEM_CAP
+    assert counted + attention._VMEM_MARGIN <= 96 * 2 ** 20
+    dkv = attention._geometry(T, B * H, True, g.block_q, g.block_k, g.heads,
+                              window, kv_outer=True)
+    assert g == dkv
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 65536, 2, 128, 2),       # the long context: 32 MiB, twice
+    (2, 65536, 2, 64, 4),
+    (1, 1_000_000, 2, 128, 2),
+])
+def test_a_column_that_fits_no_geometry_takes_the_two_kernels(shape):
+    B, T, H, D, itemsize = shape
+    assert attention.flash_geometry("bwd_fused", T, D, itemsize, B, H,
+                                    True) is None
+    for kernel in ("bwd_dkv", "bwd_dq"):
+        assert attention.flash_geometry(kernel, T, D, itemsize, B, H, True)
+
+
+@both_forms
+def test_the_gauges_say_which_backward_ran(form):
+    """`iotml_flash_backward_fused`, the `kernel="bwd_fused"` series and
+    the column's bytes after a traced backward of each form; the form
+    that did not run sets nothing new."""
+    from iotml.obs.metrics import default_registry
+
+    before = default_registry.collect()
+    q, k, v = _qkv(B=1, T=300, H=2, D=64)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, True, 128, 128, True, window=100)))(q)
+    said = default_registry.collect()
+    other = [kern for kern in attention.KERNELS if kern not in FORMS[form]]
+    for key in said:
+        if any(f'kernel="{kern}"' in key for kern in other):
+            assert said[key] == before.get(key), key
+    if form == "pair":
+        assert (said["iotml_flash_backward_fused"],
+                said["iotml_flash_bwd_column_bytes"]) == (0, 0)
+        return
+    assert said["iotml_flash_backward_fused"] == 1
+    # T pads to 384 rows; two heads of 64 a step: 128 lanes of float32
+    assert said["iotml_flash_bwd_column_bytes"] == 384 * 128 * 4
+    by = lambda name: said[  # noqa: E731
+        f'iotml_flash_{name}{{kernel="bwd_fused"}}']
+    assert (by("block_q"), by("block_k"), by("heads_per_step"),
+            by("lanes_per_step"), by("value_lanes_per_step")) \
+        == (128, 128, 2, 128, 128)
+    # the band's live tiles in column order, one step for both heads
+    assert by("grid_steps") == 5
+    assert by("operand_copies") == 6
+    mask = lambda name: said[  # noqa: E731
+        f'iotml_flash_mask_{name}{{kernel="bwd_fused",kind="band"}}']
+    assert (mask("window"), mask("tiles"), mask("walked_area"),
+            mask("live_area")) == (100, 5, 5 * 128 * 128,
+                                   attention.mask_area(300, True, 100))

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from iotml.ops import fused_train, rope
+from iotml.ops import attention, fused_train, rope
 from iotml.ops.attention import flash_attention
 from iotml.ops.moe import rotary
 from iotml.ops.ssd import causal_conv1d_fused
@@ -130,12 +130,14 @@ def test_flash_attention_latent_heads_lower_for_v5e(v5e):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # the forward and the one backward kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert attention.BWD_FUSED_KERNEL in text
 
 
 @pytest.mark.parametrize("form,calls", [
-    ("pairs", 3),         # XLA's pair form: the three flash kernels
-    ("lanes", 3 + 4),     # and q's and k's turn, and their cotangents'
+    ("pairs", 2),         # XLA's pair form: the two flash kernels
+    ("lanes", 2 + 4),     # and q's and k's turn, and their cotangents'
 ])
 def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e, form, calls):
     """`lf-train-backlog`'s one attention layer, exactly, forward and
@@ -159,6 +161,52 @@ def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e, form, calls):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+
+@pytest.mark.parametrize("cell,B,T,H,D,Dv,window", [
+    # one of `st`'s two windows a step: the second changes no step's
+    # geometry (held below) and makes the compile 55 s where this is 2
+    ("st", 1, 16384, 28, 128, 128, None),     # its global layer
+    ("st_band", 1, 16384, 28, 128, 128, 4096),    # its three window layers
+    ("ou", 1, 8192, 16, 128, 128, None),
+    ("km", 1, 8192, 16, 192, 128, None),      # the latent's two widths
+    ("sf", 4, 1024, 16, 64, 64, None),        # heads of 64, several a step
+])
+def test_the_fused_flash_backward_lowers_for_v5e(v5e, cell, B, T, H, D, Dv,
+                                                 window):
+    """The one backward kernel at the cells' shapes (k and v as the
+    kernels see them, repeated): Mosaic takes the whole `[1, T, G·D]`
+    float32 dQ column as an output block that stays put along a walk,
+    the dynamic row slice its tiles add into, and the scoped VMEM the
+    call STATES — its counted bytes, the column twice in the count, and
+    the margin — where its default of 16 MiB would refuse the column
+    alone; the compiled gradient holds the forward and
+    `iotml_flash_bwd_fused` and neither of the two."""
+    q, k = (jax.ShapeDtypeStruct((B, T, H, D), jnp.float32, sharding=v5e),) * 2
+    v = jax.ShapeDtypeStruct((B, T, H, Dv), jnp.float32, sharding=v5e)
+    geom = attention.flash_geometry("bwd_fused", T, D, 4, B, H, True, Dv=Dv,
+                                    window=window)
+    more = attention.flash_geometry("bwd_fused", T, D, 4, 2 * B, H, True,
+                                    Dv=Dv, window=window)
+    assert (more.block_q, more.block_k, more.heads, more.tiles) == (
+        geom.block_q, geom.block_k, geom.heads, geom.tiles)
+    limit = attention._VMEM_MARGIN + attention._vmem_bytes(
+        "bwd_fused", geom.block_q, geom.block_k, geom.heads, D, 4, Dv,
+        geom.t_q)
+    assert 2 * T * geom.heads * D * 4 < limit <= 96 * 2 ** 20
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
+    said = re.findall(r"scoped_memory_configs[^\]]*?size[^:\]]*: (\d+)",
+                      lowered.as_text())
+    assert said == [str(limit)]     # the forward states none
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if " custom-call(" in line]
+    assert [sum(name in line for line in calls) for name in (
+        attention.FWD_KERNEL, attention.BWD_FUSED_KERNEL,
+        attention.BWD_DKV_KERNEL, attention.BWD_DQ_KERNEL)] == [1, 1, 0, 0]
 
 
 @pytest.mark.parametrize("shape", [
@@ -271,9 +319,15 @@ def test_conv_kernels_lower_for_v5e(v5e, monkeypatch, shape, splits, K,
 
 
 def _compiled_fit(v5e, model, T: int, B: int = 1) -> str:
+    """The scanned fit of `model` compiled for the described v5e from
+    shapes alone: its text."""
+    return _lowered_fit(v5e, model, T, B).compile().as_text()
+
+
+def _lowered_fit(v5e, model, T: int, B: int = 1):
     """The scanned fit of `model` — four steps of B windows of T
-    positions, two epochs, Adam and all — compiled for the described
-    v5e from shapes alone: its text."""
+    positions, two epochs, Adam and all — lowered for the described
+    v5e from shapes alone."""
     import optax
 
     from iotml.train.loop import TrainState, make_scanned_fit
@@ -296,7 +350,7 @@ def _compiled_fit(v5e, model, T: int, B: int = 1) -> str:
         jax.ShapeDtypeStruct(s, jnp.float32)
         for s in ((4, B, T, 18), (4, B, 1, 18), (4, B))))
     return make_scanned_fit(model, tx, supervised=True).lower(
-        state, xs, ys, masks, epochs=2).compile().as_text()
+        state, xs, ys, masks, epochs=2)
 
 
 def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):
@@ -383,9 +437,9 @@ def test_the_fit_runs_the_flash_forward_once_a_layer(v5e, monkeypatch):
         logits_scaling=1.0), attn_mode="flash")
     lines = _compiled_fit(v5e, model, T).splitlines()
     calls = [line for line in lines if " custom-call(" in line]
-    for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
-                   "iotml_flash_bwd_dq"):
-        assert sum(kernel in line for line in calls) == 1, kernel
+    for kernel, count in (("iotml_flash_fwd", 1), ("iotml_flash_bwd_fused", 1),
+                          ("iotml_flash_bwd_dkv", 0), ("iotml_flash_bwd_dq", 0)):
+        assert sum(kernel in line for line in calls) == count, kernel
     sorts = [line for line in lines if re.search(r" = .* sort\(", line)]
     assert len(sorts) == 3
     assert sum("f32[49152]" in line.split(" sort(")[0] for line in sorts) == 2
@@ -418,15 +472,16 @@ def test_the_looped_fit_runs_the_flash_forward_once_an_application(
         logits_scaling=1.0), attn_mode="flash")
     lines = _compiled_fit(v5e, model, 8192).splitlines()
     # by the instruction's own name: a call's operands are named too
-    # (`iotml_rope` reads `%iotml_flash_bwd_dq.n`)
+    # (`iotml_rope` reads `%iotml_flash_bwd_fused.n`)
     calls = [line.split(" = ")[0] for line in lines
              if " custom-call(" in line]
-    for kernel in ("iotml_flash_fwd", "iotml_flash_bwd_dkv",
-                   "iotml_flash_bwd_dq"):
-        assert sum(kernel in name for name in calls) == 2, kernel
+    for kernel, count in (("iotml_flash_fwd", 2), ("iotml_flash_bwd_fused", 2),
+                          ("iotml_flash_bwd_dkv", 0), ("iotml_flash_bwd_dq", 0)):
+        assert sum(kernel in name for name in calls) == count, kernel
     said = default_registry.collect()
+    assert said["iotml_flash_backward_fused"] == 1
     assert [said[f'iotml_flash_operand_copies{{kernel="{k}"}}']
-            for k in ("fwd", "bwd_dkv", "bwd_dq")] == [0, 0, 0]
+            for k in ("fwd", "bwd_fused")] == [0, 0]
     assert said["iotml_model_loop_steps"] == 2
     # q and k turned in the kernels' layout: a layer's forward, its
     # recomputation and its backward hold the call twice each — by
@@ -438,6 +493,31 @@ def test_the_looped_fit_runs_the_flash_forward_once_an_application(
                 if re.search(r"f32\[1,8192,16,64,2\]", line)]
     # the passes' loop is in the program: the stacked kernel outputs
     assert any(re.search(r"f32\[2,1,8192,16,128\]", line) for line in lines)
+
+
+def test_the_fit_holds_one_body_of_the_fused_backward_a_shape(
+        v5e, monkeypatch):
+    """`_flash_backward` is jitted, so a stack of many layers traces
+    and lowers the one backward kernel once a SHAPE and not once a
+    layer: the lowered fit of a global layer and three window layers —
+    `st-train-backlog`'s `G W W W` at small widths — holds two bodies of
+    `iotml_flash_bwd_fused` (the triangle's grid, the band's), four
+    calls of them, and no body of the two-kernel form."""
+    from iotml.models.hybrid import HybridConfig, SensorHybrid
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    jax.clear_caches()
+    model = SensorHybrid(HybridConfig(
+        d_model=256, layer_types=("attention",) + ("window_attention",) * 3,
+        num_heads=2, num_kv_heads=1, head_dim=128, attn_window=256,
+        mlp_dim=512, embedding_multiplier=1.0, residual_multiplier=1.0,
+        logits_scaling=1.0), attn_mode="flash")
+    text = _lowered_fit(v5e, model, 1024).as_text()
+    bodies = re.findall(r'kernel_name = "(iotml_flash_\w+)"', text)
+    assert sorted(bodies) == ["iotml_flash_bwd_fused"] * 2 \
+        + ["iotml_flash_fwd"] * 2
+    assert len(re.findall(r"func\.func private @_flash_backward", text)) == 2
+    assert len(re.findall(r"call @_flash_backward", text)) == 4
 
 
 def _called(text: str, name: str) -> str:

@@ -461,14 +461,15 @@ def test_every_pallas_call_in_ops_is_named():
                      and getattr(n.func, "attr", None) == "pallas_call"
                      for n in ast.walk(tree))
         assert [f for f in lint_file(path) if f.rule == "R17"] == [], path
-    # the three flash kernels (a dense and a triangular grid each behind
+    # the four flash kernels (a dense and a triangular grid each behind
     # one call since PR 25) and the fused fit
-    assert calls >= 4
+    assert calls >= 5
     from iotml.ops import attention
 
     assert (attention.FWD_KERNEL, attention.BWD_DKV_KERNEL,
-            attention.BWD_DQ_KERNEL) == (
-        "iotml_flash_fwd", "iotml_flash_bwd_dkv", "iotml_flash_bwd_dq")
+            attention.BWD_DQ_KERNEL, attention.BWD_FUSED_KERNEL) == (
+        "iotml_flash_fwd", "iotml_flash_bwd_dkv", "iotml_flash_bwd_dq",
+        "iotml_flash_bwd_fused")
 
 
 def test_lint_r17_and_phase_under_trace(tmp_path):

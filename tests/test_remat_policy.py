@@ -145,7 +145,8 @@ def test_the_gradient_runs_kernel_top_k_and_sort_once_a_layer(held):
 
     kept = run.counts
     assert kept.get("iotml_flash_fwd", 0) == attention \
-        == kept.get("iotml_flash_bwd_dkv", 0)
+        == kept.get("iotml_flash_bwd_fused", 0)
+    assert "iotml_flash_bwd_dkv" not in kept
     assert of(kept, "top_k", "sort", "highest") \
         == (routed, 2 * routed, 3 * routed)
     assert of(kept, "gather", "scatter-add") == (0, 0)

@@ -319,7 +319,9 @@ def test_a_tiny_fit_says_what_engaged(ref, monkeypatch):
         == 4 * 80 * 4 * (16 * 4 + 4)
     stacks.only_these_kinds_are_kept(got, "router", "flash")
     # 40 positions in one 128² tile either way: the masks' areas differ
-    for kernel in ("fwd", "bwd_dkv", "bwd_dq"):
+    # the backward is the one kernel at this shape
+    assert got["iotml_flash_backward_fused"] == 1
+    for kernel in ("fwd", "bwd_fused"):
         said = {mask: [got[f'iotml_flash_mask_{what}{{kernel="{kernel}",'
                            f'kind="{mask}"}}']
                        for what in ("window", "tiles", "walked_area",
