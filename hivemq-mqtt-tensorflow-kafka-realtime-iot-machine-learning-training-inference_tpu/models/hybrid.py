@@ -3,8 +3,11 @@
 grouped-query attention that may norm and turn its heads;
 `window_attention`, the same over a sliding window of the last
 `attn_window` keys — a band of the flash kernels' tiles beside the
-triangle; `mla`, the latent attention of `models.latent_moe`;
-`short_conv`, LFM2's gated short convolution) and a feed-forward part (`ffn_types`: `dense_ffn`, a
+triangle; `mla`, the latent attention of `models.latent_moe`, with
+rotary positions or (`mla_rope` off) without; `short_conv`, LFM2's gated
+short convolution; `kda`, Kimi Delta Attention — a matrix state a head
+under a channel-wise gate and a delta rule, `ops.delta.kda_scan`, the
+second chunked form beside the state-space scan's) and a feed-forward part (`ffn_types`: `dense_ffn`, a
 gated-SiLU MLP, or `moe_ffn`, that file's sparse-expert layer; left
 empty, every layer is `dense_ffn`) — over long per-car sensor histories.
 
@@ -37,7 +40,8 @@ application of that part.  A row without `inner` is kept always — what a
 kernel or the router made: small, and dear to make again.  A row with
 `inner` is large, and a byte budget decides in WHICH layers a policy
 lists its name (`remat_budget`: a third of what the device's memory
-holds beyond a trainer's arrays and the rows kept always).  The budget
+holds beyond a trainer's arrays, the rows kept always and what the
+widest mixer's backward holds at once, `backward_bytes`).  The budget
 buys what is dearest to remake a byte first (`budget_takes`): a kept
 element spares a product over `inner` features, 2 × `inner` operations
 by an element's bytes; among equals in the table's order, and within a
@@ -61,6 +65,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs import metrics as obs_metrics
 from ..ops import moe, rope
+from ..ops.delta import kda_scan
 from ..ops.ssd import causal_conv1d_fused, causal_conv1d_silu, ssd_scan
 from . import latent_moe
 from .latent_moe import (ExpertLayer, LatentAttention, causal_attention,
@@ -71,7 +76,8 @@ from .latent_moe import normal as _normal
 #: a device's memory where the backend reports no `bytes_limit` (the
 #: CPU): a TPU v5e's
 DEVICE_BYTES = 16 * 2 ** 30
-KINDS = ("mamba", "attention", "mla", "short_conv", "window_attention")
+KINDS = ("mamba", "attention", "mla", "short_conv", "window_attention",
+         "kda")
 #: the mixers that are `GroupedAttention`: over the whole past, or a window
 GROUPED = ("attention", "window_attention")
 FFN_KINDS = ("dense_ffn", "moe_ffn")
@@ -125,6 +131,17 @@ class HybridConfig:
     rope_dim: int = 8
     v_dim: int = 16
     rope_theta: float = 10000.0
+    # whether latent attention turns its `rope_dim`-wide parts at all (a
+    # model whose latent layers carry no positions keeps the parts,
+    # un-turned)
+    mla_rope: bool = True
+    # Kimi Delta Attention (`kda`): heads of `kda_head_dim` keys and
+    # values each (the rank of its two low-rank gates too), the taps of
+    # its three convolutions, and the positions a chunk of its scan holds
+    kda_heads: int = 4
+    kda_head_dim: int = 16
+    kda_conv_width: int = 4
+    kda_chunk: int = 8
     # sparse experts (`moe_ffn`): routed over `experts`, `top_k` a
     # token; (first, count) of those held here; an expert's width, and
     # the shared expert's
@@ -185,13 +202,14 @@ def part_inner(m: HybridConfig, part: str) -> int:
     (what remaking an element of that output costs, in multiply-adds):
     the MLP's `mlp_dim`, an expert layer's shared width and a token's
     routed ones (or the latent they act in), a mixer's heads × their
-    width; 0 for `none`."""
+    (value) width; 0 for `none`."""
     return {"dense_ffn": m.mlp_dim,
             "moe_ffn": m.shared_dim + (m.moe_latent or m.top_k * m.expert_dim),
             "attention": m.num_heads * m.attn_head_dim(),
             "window_attention": m.num_heads * m.attn_head_dim(),
             "mla": m.num_heads * m.v_dim,
             "mamba": m.ssm_heads * m.ssm_head_dim,
+            "kda": m.kda_heads * m.kda_head_dim,
             "short_conv": m.d_model}.get(part, 0)
 
 
@@ -222,9 +240,10 @@ TABLE = (
           "mla": lambda m, tokens, size: tokens * m.num_heads
           * (m.v_dim * size + 4)}, kernels=True),
     # latent attention's rotated q and assembled k, [B, T, H, nope + rope]
+    # (without positions q is not turned, and not kept)
     Kept("latent_qk", ("mla_q", "mla_k"),
-         {"mla": lambda m, tokens, size: 2 * tokens * m.num_heads
-          * (m.nope_dim + m.rope_dim) * size}),
+         {"mla": lambda m, tokens, size: (1 + m.mla_rope) * tokens
+          * m.num_heads * (m.nope_dim + m.rope_dim) * size}),
     # the selection, the selected scores and the plan: a top-k and a sort
     Kept("router", ("route_experts", "route_picked", "dispatch_plan"),
          {"moe_ffn": lambda m, tokens, size: moe.plan_kept_bytes(
@@ -303,12 +322,27 @@ def device_bytes() -> int:
     return stats.get("bytes_limit", DEVICE_BYTES)
 
 
-def remat_budget(limit: int, held_bytes: int, kept_bytes: int) -> int:
+def backward_bytes(m: HybridConfig, tokens: int, itemsize: int) -> int:
+    """What the widest mixer's backward pass holds at once where that is
+    more than `remat_budget`'s two thirds are there for: a `kda`
+    mixer's twelve arrays of `[tokens, heads × width]` — the `qkv`
+    product (three wide), its three convolved runs, both gates, and the
+    four cotangent stacks the scan's backward hands back (q's, k's, v's
+    and the decay gate's), alive together where that backward ends; one
+    block's, since the blocks are recomputed one at a time.  0 for the
+    other kinds, whose widest blocks the two thirds have covered."""
+    return ("kda" in m.layer_types) * 12 * tokens \
+        * m.kda_heads * m.kda_head_dim * itemsize
+
+
+def remat_budget(limit: int, held_bytes: int, kept_bytes: int,
+                 backward: int) -> int:
     """The bytes a step the large names may keep on a device of `limit`
-    bytes: a third of what a trainer's arrays (`held_bytes`) and
-    `KEPT`'s names leave — the program's other temporaries (1.6-3.8 GB
-    in the benchmark's cells) and room to spare take the rest."""
-    return max(0, limit - held_bytes - kept_bytes) // 3
+    bytes: a third of what a trainer's arrays (`held_bytes`), `KEPT`'s
+    names and the widest mixer's backward (`backward_bytes`) leave — the
+    program's other temporaries (1.6-3.8 GB in the benchmark's cells)
+    and room to spare take the rest."""
+    return max(0, limit - held_bytes - kept_bytes - backward) // 3
 
 
 def kept_layers(candidates, budget: int) -> tuple:
@@ -391,6 +425,52 @@ class MambaMixer(nn.Module):
                 y.reshape(B, T, inner) * nn.silu(z))
         with jax.named_scope("ssm_proj"):
             return _dense(m.d_model, "out_proj")(y)
+
+
+class KdaMixer(nn.Module):
+    """Kimi Delta Attention: `[q̃, k̃, ṽ] = u W_qkv`, each run through
+    its own causal depthwise convolution of `kda_conv_width` taps (no
+    bias) and SiLU — `ops.ssd`'s two kernels, three runs of one array;
+    `q`, `k` L2-normed a head (ε 1e-6 inside the root), `q` scaled by
+    `kda_head_dim`^-½; the decay a vector a head and position,
+    `g = −exp(A_log) · softplus((u W_f↓) W_f↑ + dt_bias)`, `β =
+    sigmoid(u W_β)` a head, both gates' rank a head's width; `o =
+    kda_scan(q̃, k̃, v, f, β, A_log, dt_bias)` (the norms and the decay's
+    softplus are the scan's, a segment at a time); out `(RMSNorm_head(o)
+    ⊙ sigmoid((u W_g↓) W_g↑)) W_o`, the norm's one weight shared by the
+    heads.  The three thin products of u — the two gates'
+    down projections and β's — are one (`gates_in`)."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, u):
+        m = self.cfg
+        B, T, _ = u.shape
+        H, D = m.kda_heads, m.kda_head_dim
+        inner = H * D
+        with jax.named_scope("kda_proj"):
+            qkv = _dense(3 * inner, "qkv")(u)
+            f, gate, beta = jnp.split(_dense(2 * D + H, "gates_in")(u),
+                                      [D, 2 * D], axis=-1)
+            f = _dense(inner, "f_up")(f)
+            gate = _dense(inner, "g_up")(gate)
+        with jax.named_scope("kda_conv"):
+            q, k, v = causal_conv1d_fused(
+                qkv, self.param("conv_kernel", _normal,
+                                (m.kda_conv_width, 3 * inner)),
+                splits=(inner,) * 3)
+        q, k, v, f = (a.reshape(B, T, H, D) for a in (q, k, v, f))
+        o = kda_scan(q, k, v, f, nn.sigmoid(beta),
+                     self.param("A_log", _a_log_init, (H,)),
+                     self.param("dt_bias", _dt_bias_init,
+                                (inner,)).reshape(H, D), m.kda_chunk)
+        with jax.named_scope("gate_norm"):
+            # the norm a head, one weight for all of them; then the gate
+            o = nn.RMSNorm(epsilon=m.eps, name="norm")(o).reshape(
+                B, T, inner) * nn.sigmoid(gate)
+        with jax.named_scope("kda_proj"):
+            return _dense(m.d_model, "o")(o)
 
 
 class ShortConvMixer(nn.Module):
@@ -508,6 +588,8 @@ class HybridBlock(nn.Module):
                 mixed = MambaMixer(m, name="mixer")(u)
             elif self.kind == "short_conv":
                 mixed = ShortConvMixer(m, name="mixer")(u)
+            elif self.kind == "kda":
+                mixed = KdaMixer(m, name="mixer")(u)
             else:
                 with jax.named_scope("attn"):
                     if self.kind == "mla":
@@ -646,10 +728,11 @@ class SensorHybrid(nn.Module):
         held = (4 + (m.loop_steps > 1)) * sum(
             p.size * p.dtype.itemsize for p in jax.tree.leaves(
                 self.variables.get("params", {})))
-        candidates = budget_candidates(m, x.shape[0] * x.shape[1],
-                                       x.dtype.itemsize)
+        tokens, size = x.shape[0] * x.shape[1], x.dtype.itemsize
+        candidates = budget_candidates(m, tokens, size)
         taken = budget_takes(candidates, remat_budget(
-            device_bytes(), held, sum(kept.values())))
+            device_bytes(), held, sum(kept.values()),
+            backward_bytes(m, tokens, size)))
         for name, kind in BUDGETED.items():
             kept[kind] = sum(c.bytes for c in taken if c.name == name)
             obs_metrics.remat_kept_layers.set(

@@ -7,7 +7,10 @@ form, no query low-rank): with `u` the normed stream, `q = u W_q` is H
 heads of `nope + rope` features; `[c, k_pe] = u W_kva` is a latent of
 `kv_rank` and ONE rotary key head shared by all H; `c ← RMSNorm(c)`;
 `[k_nope, v] = c W_kvb` is H heads of `nope + v`; rotary positions turn
-`q_pe` and `k_pe`; `k = [k_nope, k_pe]`; scores `q kᵀ / √(nope+rope)`,
+`q_pe` and `k_pe` — or, with `mla_rope` off, turn NOTHING: the
+`rope`-wide slice of q and the one shared key head stay where they are,
+un-turned, and the layer carries no positions (order reaches it through
+the causal mask and the layers beside it); `k = [k_nope, k_pe]`; scores `q kᵀ / √(nope+rope)`,
 causal softmax, `o = P v` → `W_o`.  Query/key heads are wider than
 value heads (192 beside 128 at the published widths), which the flash
 kernels take as they are.  The weight-absorbed form and a latent cache
@@ -111,19 +114,29 @@ class LatentAttention(nn.Module):
         kv = dense(H * (nope + dv), "kv_b")(
             nn.RMSNorm(epsilon=m.eps, name="kv_norm")(c)
         ).reshape(B, T, H, nope + dv)
-        with jax.named_scope("rope"):
-            q = jnp.concatenate(
-                [q[..., :nope], moe.rotary(q[..., nope:], m.rope_theta)],
-                axis=-1)
-            k_pe = moe.rotary(k_pe[:, :, None, :], m.rope_theta)
-            # one rotary head for all H: the copy the kernels' layout asks
+        # without positions nothing is turned: q is the product as it
+        # stands, and no `rope` scope is in the program
+        turns = m.mla_rope
+        obs_metrics.model_mla_rope.set(int(turns))
+        k_pe = k_pe[:, :, None, :]
+        if turns:
+            with jax.named_scope("rope"):
+                q = jnp.concatenate(
+                    [q[..., :nope], moe.rotary(q[..., nope:], m.rope_theta)],
+                    axis=-1)
+                k_pe = moe.rotary(k_pe, m.rope_theta)
+        with jax.named_scope("rope" if turns else "mla_key"):
+            # one shared head for all H: the copy the kernels' layout asks
             obs_metrics.latent_assembled_operands.set(1)
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, H, rope))],
                 axis=-1)
         # kept by name from a recomputation: making them again is the
-        # rotary's pads and slices and the copy that assembles k
-        q, k = checkpoint_name(q, "mla_q"), checkpoint_name(k, "mla_k")
+        # rotary's pads and slices and the copy that assembles k — an
+        # un-turned q is its product as it stands, and is made again
+        if turns:
+            q = checkpoint_name(q, "mla_q")
+        k = checkpoint_name(k, "mla_k")
         v = kv[..., nope:]
         scale = 1.0 / math.sqrt(nope + rope)
         o = causal_attention(q, k, v, self.attn_mode, scale)

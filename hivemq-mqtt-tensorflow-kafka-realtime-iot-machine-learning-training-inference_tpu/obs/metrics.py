@@ -521,6 +521,19 @@ ssd_chunks = default_registry.gauge(
 ssd_state_bytes = default_registry.gauge(
     "iotml_ssd_state_bytes",
     "bytes of recurrent state one sequence holds in one state-space layer")
+# the chunked gated delta rule (ops/delta.py `kda_scan`), at trace time as
+# the state-space scan's: what the last traced call engaged
+kda_chunk_size = default_registry.gauge(
+    "iotml_kda_chunk_size",
+    "positions a chunk of the gated delta rule's scan holds")
+kda_chunks = default_registry.gauge(
+    "iotml_kda_chunks",
+    "chunks a window of the gated delta rule's scan is cut in")
+kda_state_bytes = default_registry.gauge(
+    "iotml_kda_state_bytes",
+    "bytes of matrix states a call of the gated delta rule's scan keeps for "
+    "its backward pass: the state entering each segment of chunks, "
+    "[segments, B, H, K, V] in float32")
 # the convolution kernels ahead of the scan (ops/ssd.py
 # `causal_conv1d_silu`), set where the calls of a direction (fwd | bwd)
 # are built: what `conv_geometry` gave the last traced convolution.
@@ -547,9 +560,13 @@ conv_operand_copies = default_registry.gauge(
 model_layers = default_registry.gauge(
     "iotml_model_layers",
     "layers of the last traced hybrid model, by the kind of their mixer "
-    "(mamba | attention | mla | short_conv | window_attention) and of "
-    "their feed-forward "
-    "part (dense_ffn | moe_ffn)")
+    "(mamba | attention | mla | short_conv | window_attention | kda) and "
+    "of their feed-forward part (dense_ffn | moe_ffn)")
+model_mla_rope = default_registry.gauge(
+    "iotml_model_mla_rope",
+    "1 where the last traced latent-attention layer turned its 64-wide "
+    "parts by rotary positions, 0 where it left them un-turned (a model "
+    "whose latent attention carries no positions)")
 model_loop_steps = default_registry.gauge(
     "iotml_model_loop_steps",
     "passes a step the last traced hybrid model makes over its one set of "

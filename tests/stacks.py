@@ -1,5 +1,5 @@
 """The shapes of stack `models.hybrid.SensorHybrid` is tested in, stated
-once: a row a shape — the benchmark's six hybrid configurations at a
+once: a row a shape — the benchmark's seven hybrid configurations at a
 tiny preset, each with its plain reference (loaded by path, as
 `benchmark/tests` loads it), and a sandwich stack that is no loop, which
 has none — with the helpers the stack tests share and the programs they
@@ -47,6 +47,7 @@ class Stack(NamedTuple):
     unsettled: bool = False   # compare at norms' weights that are not one
     fit_seeds: tuple = (1, 2)   # a compiled job's batches
     moments_rtol: float = 2e-4  # how close Adam's moments come
+    update_rtol: float = 2e-3   # and the parameters' change
     config: Optional[HybridConfig] = None
 
 
@@ -116,6 +117,26 @@ STACKS = {
              moe_num_active_primary_experts=3, sliding_window_size=24),
         published=dict(moe_num_primary_experts=16), attention=4, routed=4,
         ops="window_ops"),
+    # width 64; 4 delta-rule heads of 16 under gates of that rank, chunks of
+    # 16 (40 positions: no multiple of one); 4 latent heads of 16 + 8
+    # beside 16 over a latent of 32, WITHOUT positions; an MLP of 96; 16
+    # experts of 24, 3 a token, 4 held, one shared; the file's five
+    # layers, `K K K L K`, the first with the dense MLP
+    "kimi_linear": Stack(
+        "kimi-linear-48b-a3b",
+        dict(hidden_size=64, num_attention_heads=4, intermediate_size=96,
+             moe_intermediate_size=24, kv_lora_rank=32, qk_nope_head_dim=16,
+             qk_rope_head_dim=8, v_head_dim=16, num_experts=4,
+             num_experts_per_token=3, kda_chunk_size=16,
+             linear_attn_config=dict(
+                 full_attn_layers=[4], kda_layers=[1, 2, 3, 5], head_dim=16,
+                 num_heads=4, short_conv_kernel_size=4)),
+        # Adam's early steps are the rate times g / |g|: behind the L2
+        # norms q's and k's columns hold gradients of the order of their
+        # rounding, whose steps are noise (3e-3 of the leaf's largest,
+        # both moments within 2e-4)
+        published=dict(num_experts=16), attention=1, routed=4,
+        ops="delta_ops", update_rtol=5e-3),
     # sandwich norms without the loop: no configuration's, by hand
     "sandwich": Stack(None, {}, attention=1, config=HybridConfig(
         layer_types=("mamba", "attention"), post_norms=True)),

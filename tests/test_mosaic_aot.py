@@ -10,6 +10,7 @@ Compilation only: whether the kernels run and agree on the device is
 `chip_smoke.py`'s job.
 """
 
+import importlib.util
 import os
 import re
 
@@ -170,6 +171,8 @@ def test_flash_attention_turned_grouped_heads_lower_for_v5e(v5e, form, calls):
     ("st_band", 1, 16384, 28, 128, 128, 4096),    # its three window layers
     ("ou", 1, 8192, 16, 128, 128, None),
     ("km", 1, 8192, 16, 192, 128, None),      # the latent's two widths
+    # twice `km`'s heads and twice its window: tiles no other cell runs
+    ("kl", 1, 16384, 32, 192, 128, None),
     ("sf", 4, 1024, 16, 64, 64, None),        # heads of 64, several a step
 ])
 def test_the_fused_flash_backward_lowers_for_v5e(v5e, cell, B, T, H, D, Dv,
@@ -283,6 +286,9 @@ def test_a_turned_layer_keeps_its_stream_feature_minor(v5e):
     ((2, 8192, 2048), (2048,), 3, "none", (0, 0)),
     # blocks with a lane tile ahead of them: T past the longest block
     ((2, 16384, 512), (512,), 4, "silu", (0, 0)),
+    # `kl-train-backlog`, exactly: q, k and v of a delta-rule mixer,
+    # three runs of 4,096 channels of one product, read where they lie
+    ((1, 16384, 12288), (4096,) * 3, 4, "silu", (0, 0)),
     # sublanes that divide nothing and positions that fill no lane
     # tile: every run sliced out and padded ahead of the kernels
     ((2, 203, 256), (200, 56), 4, "silu", (2, 4)),
@@ -351,6 +357,79 @@ def _lowered_fit(v5e, model, T: int, B: int = 1):
         for s in ((4, B, T, 18), (4, B, 1, 18), (4, B))))
     return make_scanned_fit(model, tx, supervised=True).lower(
         state, xs, ys, masks, epochs=2)
+
+
+def test_the_delta_rule_fit_lowers_for_v5e_chunked_and_unturned(
+        v5e, monkeypatch):
+    """`kl-train-backlog`'s whole fit at the published widths — four
+    delta-rule layers, one latent layer, a dense MLP and four expert
+    layers, Adam and all — lowered for the described v5e from shapes
+    alone: the scan says 256 chunks of 64 a window and the sixteen
+    states it keeps, the latent layer turned nothing (no `rope` scope in
+    the module, no call of `ops.moe.rotary` while it was traced), the
+    convolutions and the latent layer's flash kernels are Pallas calls,
+    and no loop of the module steps the window's 16,384 positions.
+    COMPILED, the fit's temporaries are stated against what the byte
+    budget counted: it set a delta-rule mixer's backward (3.22 GB)
+    aside and bought four shared experts' first products, the compiled
+    temporaries beyond every kept name are no less than that backward,
+    and with a trainer's state they leave a GiB of the chip free."""
+    import json
+
+    from iotml.models import hybrid
+    from iotml.models.hybrid import SensorHybrid
+    from iotml.obs.metrics import default_registry
+    from iotml.ops import moe
+
+    def never(*a, **k):
+        raise AssertionError("a rotary turn in a stack without positions")
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    monkeypatch.setattr(moe, "rotary", never)
+    chip = 16_909_336_064            # a v5e's `bytes_limit`, as runs read it
+    monkeypatch.setattr(hybrid, "device_bytes", lambda: chip)
+    stem = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "sensorformer-kimi-linear-48b-a3b")
+    with open(stem + ".json") as f:
+        cfg = json.load(f)
+    spec = importlib.util.spec_from_file_location("bench_kl_aot",
+                                                  stem + ".py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    job = cfg["job"]
+    model = SensorHybrid(mod.hybrid_config(cfg), attn_mode="flash")
+    jax.clear_caches()
+    lowered = _lowered_fit(v5e, model, job["window"], job["batch_size"])
+    text = lowered.as_text()
+    said = default_registry.collect()
+    assert (said["iotml_kda_chunk_size"], said["iotml_kda_chunks"]) \
+        == (64, 256)
+    assert said["iotml_kda_state_bytes"] \
+        == 16 * job["batch_size"] * 32 * 128 * 128 * 4
+    assert said['iotml_model_layers{kind="kda"}'] == 4
+    assert said["iotml_model_mla_rope"] == 0 and "rope" not in text
+    bodies = re.findall(r'kernel_name = "(iotml_\w+)"', text)
+    assert {"iotml_conv_fwd", "iotml_conv_bwd", "iotml_flash_fwd",
+            "iotml_flash_bwd_fused"} <= set(bodies)
+    # the loops' trip counts are constants of the module (the experts'
+    # walks alone stop on data): the fit's epochs and batches, a window's
+    # sixteen segments and a segment's sixteen chunks — never its positions
+    trips = set(re.findall(r"cond \{\s+%\w+ = stablehlo\.constant "
+                           r"dense<(\d+)> : tensor<i32>", text))
+    assert {"16"} <= trips <= {"2", "4", "16"}
+    # the budget: what it set aside, what it bought, what was compiled
+    tokens = job["batch_size"] * job["window"]
+    backward = hybrid.backward_bytes(model.cfg, tokens, 4)
+    assert backward == 12 * tokens * 4096 * 4
+    assert said['iotml_remat_kept_layers{kind="ffn"}'] == 4
+    assert said['iotml_remat_kept_bytes{kind="ffn"}'] \
+        == 4 * tokens * 2 * 1024 * 4
+    kept = sum(v for k, v in said.items()
+               if k.startswith("iotml_remat_kept_bytes"))
+    memory = lowered.compile().memory_analysis()
+    assert memory.temp_size_in_bytes - kept >= backward
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        <= chip - 2 ** 30
 
 
 def test_the_fit_keeps_the_mixers_stream_time_minor(v5e, monkeypatch):

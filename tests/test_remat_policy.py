@@ -6,7 +6,7 @@ feed-forward part's first product and, in a sandwich block, the parts'
 outputs ahead of their post norms.
 
 Three properties, each over the shapes of stack the catalogue states
-(`stacks.STACKS`: the benchmark's six hybrid configurations at their
+(`stacks.STACKS`: the benchmark's seven hybrid configurations at their
 tiny presets — Mamba + grouped-query attention; latent attention +
 experts at the stream's width; one-part layers + experts in a latent;
 gated short convolutions + experts without a shared one; global and
@@ -229,6 +229,11 @@ _SAID = {
     # nothing the budget could buy: experts without a shared one in every
     # layer, no post norms
     "smallthinker": (((),) * 4, {"router": (15968, 0, 0)}, 87040),
+    # the dense MLP's product and four shared experts'; one latent layer
+    # WITHOUT positions keeps its assembled k and not its un-turned q
+    "kimi_linear": ((("ffn_hidden",),) * 5,
+                    {"ffn": (122880, 5, 5), "latent_qk": (30720, 0, 0),
+                     "router": (15968, 0, 0)}, 21760),
     "ouro": ((_ALL,) * 2,
              {"ffn": (491520, 2, 2), "ffn_out": (163840, 2, 2),
               "mixer_out": (163840, 2, 2), "loop_inputs": (245760, 0, 0)},
@@ -375,19 +380,20 @@ def test_a_layer_without_a_first_product_is_no_candidate(overrides, tokens,
         == tuple(i for i, b in enumerate(want) if b)
 
 
-@pytest.mark.parametrize("limit, held, kept, budget", [
-    (1000, 400, 300, 100),    # a third of what the arrays and the names leave
-    (1000, 1200, 0, 0),       # arrays past the device's memory: nothing
-    (hybrid.DEVICE_BYTES, 2 ** 30, 0, 5 * 2 ** 30),
+@pytest.mark.parametrize("limit, held, kept, backward, budget", [
+    (1000, 400, 300, 0, 100),  # a third of what the arrays and the names leave
+    (1000, 400, 200, 100, 100),       # and the widest mixer's backward
+    (1000, 1200, 0, 0, 0),     # arrays past the device's memory: nothing
+    (hybrid.DEVICE_BYTES, 2 ** 30, 0, 0, 5 * 2 ** 30),
     # a looped stack at the sixth cell's size: five times its 1.64 GB of
     # parameters (the gradients live through the backward pass) and what
     # 32 applications keep leave 1.37 GB, under a layer's four 369 MB
-    (16_909_336_064, 5 * 1_644_748_876, 2_164_260_864 + 2_415_919_104,
+    (16_909_336_064, 5 * 1_644_748_876, 2_164_260_864 + 2_415_919_104, 0,
      1_368_470_572),
 ])
 def test_the_budget_is_a_third_of_what_the_arrays_leave(limit, held, kept,
-                                                        budget):
-    assert hybrid.remat_budget(limit, held, kept) == budget
+                                                        backward, budget):
+    assert hybrid.remat_budget(limit, held, kept, backward) == budget
     # this backend reports no `bytes_limit`: the constant stands in
     assert hybrid.device_bytes() == hybrid.DEVICE_BYTES
 
@@ -407,14 +413,18 @@ _NO_OUTS = {"ffn_out": (), "mixer_out": ()}
     ("lfm2-24b-a2b", (0,), _NO_OUTS),
     # experts without a shared one in every layer: no first product
     ("smallthinker-21b-a3b", (), _NO_OUTS),
+    # one window a step: a delta-rule mixer's backward holds 3.22 GB at
+    # once, so the budget is 1.62 GB — four shared experts' 134 MB each,
+    # and not the dense MLP's 1.208 GB behind them
+    ("kimi-linear-48b-a3b", (1, 2, 3, 4), _NO_OUTS),
     # a sandwich block under a loop: the MLP's output in all six layers;
     # no room then for a first product of 1.476 GB in the 0.80 GB left
     ("ouro-2.6b", (), {"ffn_out": (0, 1, 2, 3, 4, 5), "mixer_out": (4, 5)}),
 ])
 def test_what_the_rule_takes_at_the_benchmarks_shapes(stem, first_product,
                                                       outs):
-    """By arithmetic alone (shapes, no array): the six hybrid
-    configurations at their jobs' sizes on the chip's memory — the five
+    """By arithmetic alone (shapes, no array): the seven hybrid
+    configurations at their jobs' sizes on the chip's memory — the six
     without post norms keep what `kept_layers` alone gave them, and
     `ou` buys its feed-forward outputs first."""
     spec = importlib.util.spec_from_file_location(
@@ -432,9 +442,11 @@ def test_what_the_rule_takes_at_the_benchmarks_shapes(stem, first_product,
     held = (4 + (m.loop_steps > 1)) * sum(
         p.size * p.dtype.itemsize for p in jax.tree.leaves(
             jax.eval_shape(model.init, jax.random.PRNGKey(0), x)))
-    budget = hybrid.remat_budget(
-        _CHIP_BYTES, held, sum(model._kept_bytes(x).values()))
     tokens = x.shape[0] * x.shape[1]
+    backward = hybrid.backward_bytes(m, tokens, 4)
+    assert backward == ("kda" in m.layer_types) * 3_221_225_472
+    budget = hybrid.remat_budget(
+        _CHIP_BYTES, held, sum(model._kept_bytes(x).values()), backward)
     taken = hybrid.budget_takes(
         hybrid.budget_candidates(m, tokens, 4), budget)
     got = {name: tuple(c.layer for c in taken if c.name == name)
