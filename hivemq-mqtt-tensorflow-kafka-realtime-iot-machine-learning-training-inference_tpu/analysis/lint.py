@@ -182,7 +182,7 @@ _ALLOWED_METRIC_LABELS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
     "unit", "le", "slo", "window", "shard", "route", "code", "program",
-    "result", "kernel", "kind",
+    "result", "kernel", "kind", "direction",
 })
 
 RULES: Dict[str, str] = {

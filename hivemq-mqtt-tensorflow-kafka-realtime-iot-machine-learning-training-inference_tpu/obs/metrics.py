@@ -534,6 +534,10 @@ kda_state_bytes = default_registry.gauge(
     "bytes of matrix states a call of the gated delta rule's scan keeps for "
     "its backward pass: the state entering each segment of chunks, "
     "[segments, B, H, K, V] in float32")
+kda_intra_kernel = default_registry.gauge(
+    "iotml_kda_intra_kernel",
+    "head-chunks one call of the delta rule's inner-part kernel covers, by "
+    "direction (fwd | bwd); 0 where the plain form ran")
 # the convolution kernels ahead of the scan (ops/ssd.py
 # `causal_conv1d_silu`), set where the calls of a direction (fwd | bwd)
 # are built: what `conv_geometry` gave the last traced convolution.
@@ -725,7 +729,7 @@ ALLOWED_LABEL_KEYS = frozenset({
     "stage", "topic", "partition", "group", "phase", "loop", "process",
     "component", "detector", "action", "fault", "source", "outcome",
     "unit", "le", "slo", "window", "shard", "route", "code", "program",
-    "result", "kernel", "kind",
+    "result", "kernel", "kind", "direction",
 })
 
 #: per-metric ceiling on distinct label-value combinations.  Generous —
@@ -768,6 +772,7 @@ DECLARED_METRIC_LABELS = {
     "gateway_promotions": ("shard",),
     "gateway_standby_lag": ("shard",),
     "isr_size": ("partition", "topic"),
+    "kda_intra_kernel": ("direction",),
     "loop_exit_mass": ("kind",),
     "loop_pass_loss": ("kind",),
     "model_layers": ("kind",),

@@ -394,11 +394,16 @@ def hold_budget(patch, cfg, x, keep) -> tuple:
 
 
 def count(jaxpr, found, counts):
-    """Equations of `jaxpr` and of every jaxpr inside it, by `found`."""
+    """Equations of `jaxpr` and of every jaxpr inside it, by `found` —
+    a Pallas call counted as the one equation it is to the model: what
+    its kernel computes inside (the delta rule's float32 products at
+    `highest`, say) is not the model's."""
     for eqn in jaxpr.eqns:
         kind = found(eqn)
         if kind:
             counts[kind] = counts.get(kind, 0) + 1
+        if eqn.primitive.name == "pallas_call":
+            continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
             count(sub, found, counts)
     return counts

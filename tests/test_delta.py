@@ -4,7 +4,10 @@ decay, against the recurrence stepped position by position
 and the gradients of q, k, v, the gate (so g), β, `A_log` and `dt_bias` —
 at windows that are no multiple of a chunk and shorter than one, at
 decays no `e^−G` survives, at the rule's two plain corners, and two
-windows of a batch apart."""
+windows of a batch apart.  The scan's inner part runs through the two
+Pallas kernels here, interpreted (chunks of 8 and more): they are held
+to the plain jnp form too, results and cotangents, and say that they
+engaged."""
 
 import jax
 import jax.numpy as jnp
@@ -173,9 +176,105 @@ def test_the_inverse_in_blocks_is_the_inverse(C, sub):
                                rtol=0, atol=2e-4 * np.abs(want).max())
 
 
+def _inner_operands(b, n, C, h, K, V, g=None, seed=0):
+    """What the inner part reads of a segment of n chunks: unit keys,
+    queries of length K^-½, G summed from each chunk's start (of `g` a
+    position, or −0.1 · [0.1, 2] a channel), β in (0, 1)."""
+    rng = np.random.default_rng(seed)
+    q, k = (a / np.linalg.norm(a, axis=-1, keepdims=True)
+            for a in rng.normal(size=(2, b, n, C, h, K)))
+    decay = -0.1 * rng.uniform(0.1, 2.0, size=(b, n, C, h, K)) \
+        if g is None else np.full((b, n, C, h, K), g)
+    return tuple(jnp.asarray(a, jnp.float32) for a in (
+        q * K ** -0.5, k, np.cumsum(decay, axis=2),
+        rng.normal(size=(b, n, C, h, V)),
+        rng.uniform(0.0, 1.0, size=(b, n, C, h))))
+
+
+def _inner_kernels(q, k, G, v, beta, heads):
+    """`kda_intra` of a segment as the scan hands it over."""
+    b, n, C, h, K = k.shape
+    L = n * C
+    geom = delta.intra_geometry(L, h, K, v.shape[-1], C, True)
+    if heads:
+        geom = geom._replace(heads=heads)
+    w, u, qk = delta.kda_intra(
+        *(a.reshape(b, L, -1) for a in (q, k, G, v)), beta.reshape(b, L, h),
+        C, geom)
+    return w.reshape(k.shape), u.reshape(v.shape), qk
+
+
+def _inner_both(operands, heads):
+    """((w, u, qk), cotangents of q, k, G, v, β) by the kernels and by
+    the plain form, under one seeded weighting of the three results."""
+    C = operands[1].shape[2]
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for inner in (lambda *a: _inner_kernels(*a, heads),
+                      lambda *a: delta.intra_plain(*a, min(delta.SUB, C))):
+            shapes = jax.eval_shape(inner, *operands)
+            ws = [jnp.asarray(np.random.default_rng(7 + i).normal(
+                size=s.shape), jnp.float32) for i, s in enumerate(shapes)]
+            (_, got), grads = jax.jit(jax.value_and_grad(
+                lambda *a: (lambda o: (sum(jnp.sum(w * r) for w, r in
+                                           zip(ws, o)), o))(inner(*a)),
+                argnums=range(5), has_aux=True))(*operands)
+            out.append(tuple(got) + tuple(grads))
+    return out
+
+
+@pytest.mark.parametrize("b,n,C,h,heads,g", [
+    (1, 2, 64, 2, None, None),   # the cell's chunk: two chunks a step
+    (1, 3, 64, 4, 2, None),      # an odd segment (T no multiple of two
+                                 # chunks): a chunk a step, two head groups
+    (2, 7, 16, 2, 1, None),      # two windows, seven chunks, a head a step
+    (2, 16, 8, 2, None, None),   # a chunk of one sub-block of 8: no merge
+    (1, 4, 32, 3, 3, None),      # one merge; three heads a step
+    (1, 2, 64, 2, 1, -2.0),      # G reaches −128 inside a chunk
+])
+def test_the_kernels_are_the_plain_inner_part(b, n, C, h, heads, g):
+    """`iotml_kda_intra_fwd` and `iotml_kda_intra_bwd`, interpreted,
+    against `intra_plain`: W, U, the queries' scores and the cotangents
+    of q, k, G, v and β, all finite."""
+    got, want = _inner_both(_inner_operands(b, n, C, h, 8, 6, g, seed=n + C),
+                            heads)
+    if g is not None:
+        # no e^−G survives; the scores between far sub-blocks vanish
+        assert float(jnp.abs(want[2]).max()) > 1e-3
+    for name, a, w in zip(("w", "u", "qk", "dq", "dk", "dG", "dv", "dbeta"),
+                          got, want):
+        _close(a, w), name
+
+
+@pytest.mark.parametrize("L,H,K,V,chunk,interpret,want", [
+    (1024, 32, 128, 128, 64, False, (128, 8)),   # `kl-train-backlog`
+    (1024, 4, 128, 128, 64, False, (128, 4)),    # few heads: all a step
+    (1024, 32, 128, 128, 4, False, None),        # under the sublane tile
+    (1024, 32, 128, 128, 256, False, None),      # over a step's lanes
+    (1024, 32, 64, 128, 64, False, None),        # heads of half a tile
+    (192, 32, 128, 128, 64, False, None),        # three chunks: no 128 rows
+    (192, 2, 8, 6, 64, True, (64, 2)),           # … which interpreted run
+    (112, 2, 8, 6, 16, True, (112, 2)),
+])
+def test_the_kernels_tiling_is_derived_from_the_shapes(L, H, K, V, chunk,
+                                                       interpret, want):
+    got = delta.intra_geometry(L, H, K, V, chunk, interpret)
+    assert (got and tuple(got)) == want
+
+
 def test_a_call_says_its_chunks_and_the_states_it_keeps():
     jax.clear_caches()
-    kda_scan(*_operands(300, B=2, H=2, K=8, V=6), 16)
+    operands = _operands(300, B=2, H=2, K=8, V=6)
+    jax.grad(lambda *a: jnp.sum(kda_scan(*a, 16)))(*operands)
+    said = default_registry.collect()
+    # a segment's sixteen chunks of two heads, a call of either kernel
+    assert said['iotml_kda_intra_kernel{direction="fwd"}'] == 32
+    assert said['iotml_kda_intra_kernel{direction="bwd"}'] == 32
+    kda_scan(*_operands(40), 4)        # a chunk under the sublane tile
+    said = default_registry.collect()
+    assert said['iotml_kda_intra_kernel{direction="fwd"}'] == 0
+    assert said['iotml_kda_intra_kernel{direction="bwd"}'] == 0
+    kda_scan(*operands, 16)
     said = default_registry.collect()
     assert said["iotml_kda_chunk_size"] == 16
     assert said["iotml_kda_chunks"] == 19          # ⌈300 / 16⌉

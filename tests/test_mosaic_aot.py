@@ -359,15 +359,52 @@ def _lowered_fit(v5e, model, T: int, B: int = 1):
         state, xs, ys, masks, epochs=2)
 
 
+@pytest.mark.parametrize("L,H,chunk", [
+    (1024, 32, 64),     # `kl-train-backlog`'s segment, exactly
+    (256, 4, 16),       # eight chunks a step, four heads: all of them
+    (128, 8, 128),      # a chunk a step: three merges
+])
+def test_the_delta_rule_kernels_lower_for_v5e(v5e, monkeypatch, L, H, chunk):
+    """`iotml_kda_intra_fwd` and `iotml_kda_intra_bwd` through the entry
+    point and `jax.grad`, a segment `[1, L, H·128]` as the scan hands it
+    over: a lane gather, a turned tile or a lane roll Mosaic cannot
+    place, or blocks past the VMEM the calls ask for, fail here and not
+    on the chip."""
+    from iotml.ops import delta
+
+    monkeypatch.setattr(fused_train, "interpret_mode", lambda: False)
+    geom = delta.intra_geometry(L, H, 128, 128, chunk, False)
+    assert geom == (128, min(H, 8))
+    wide, beta = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
+                  for s in ((1, L, H * 128), (1, L, H)))
+
+    def loss(q, k, G, v, beta):
+        return sum(jnp.sum(a * a) for a in delta.kda_intra(
+            q, k, G, v, beta, chunk, geom))
+
+    lowered = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        wide, wide, wide, wide, beta)
+    bodies = re.findall(r'kernel_name = "(iotml_\w+)"', lowered.as_text())
+    assert sorted(bodies) == [delta.KDA_BWD_KERNEL, delta.KDA_FWD_KERNEL]
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+
+
 def test_the_delta_rule_fit_lowers_for_v5e_chunked_and_unturned(
         v5e, monkeypatch):
     """`kl-train-backlog`'s whole fit at the published widths — four
     delta-rule layers, one latent layer, a dense MLP and four expert
     layers, Adam and all — lowered for the described v5e from shapes
     alone: the scan says 256 chunks of 64 a window and the sixteen
-    states it keeps, the latent layer turned nothing (no `rope` scope in
-    the module, no call of `ops.moe.rotary` while it was traced), the
-    convolutions and the latent layer's flash kernels are Pallas calls,
+    states it keeps, its inner part is the two kernels — each says the
+    512 head-chunks a segment's call covers, the backward's body is in
+    the module once and the forward's once a context (the pass, the
+    block's recomputation, the segment's: jitted calls, as the fused
+    flash backward's), twelve calls of the forward and four of the
+    backward, and compiled the scope's only `[…, 64, 64]` blocks are the
+    scores the forward calls return (none is XLA's own) — the latent
+    layer turned nothing (no `rope` scope in the module, no call of
+    `ops.moe.rotary` while it was traced), the convolutions and the latent layer's flash kernels are Pallas calls,
     and no loop of the module steps the window's 16,384 positions.
     COMPILED, the fit's temporaries are stated against what the byte
     budget counted: it set a delta-rule mixer's backward (3.22 GB)
@@ -411,6 +448,20 @@ def test_the_delta_rule_fit_lowers_for_v5e_chunked_and_unturned(
     bodies = re.findall(r'kernel_name = "(iotml_\w+)"', text)
     assert {"iotml_conv_fwd", "iotml_conv_bwd", "iotml_flash_fwd",
             "iotml_flash_bwd_fused"} <= set(bodies)
+    # the inner part: both kernels engaged, one body and one function each
+    assert said['iotml_kda_intra_kernel{direction="fwd"}'] == 16 * 32
+    assert said['iotml_kda_intra_kernel{direction="bwd"}'] == 16 * 32
+    # (jitted, so traced ONCE; jax stages a jitted call's jaxpr anew
+    # where a recomputation re-derives it, so the module holds the
+    # forward's function once a context — the pass itself, the block's
+    # recomputation, the segment's — each called by the four layers)
+    for kernel, function, copies, calls in (
+            ("iotml_kda_intra_fwd", "_intra_forward", 3, 12),
+            ("iotml_kda_intra_bwd", "_intra_backward", 1, 4)):
+        assert bodies.count(kernel) == copies
+        assert len(re.findall(rf"func\.func private @{function}(_\d+)?\(",
+                              text)) == copies
+        assert len(re.findall(rf"call @{function}(_\d+)?\(", text)) == calls
     # the loops' trip counts are constants of the module (the experts'
     # walks alone stop on data): the fit's epochs and batches, a window's
     # sixteen segments and a segment's sixteen chunks — never its positions
@@ -426,7 +477,16 @@ def test_the_delta_rule_fit_lowers_for_v5e_chunked_and_unturned(
         == 4 * tokens * 2 * 1024 * 4
     kept = sum(v for k, v in said.items()
                if k.startswith("iotml_remat_kept_bytes"))
-    memory = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    # … but the queries' scores a forward call hands to `kda_out`: no
+    # fusion, copy or product of XLA's makes a block of scores there
+    blocks = re.findall(
+        r"^.* = f32\[[\d,]*64,64\]\S* ([\w\-]+)\(([^\n]*kda_intra[^\n]*)$",
+        compiled.as_text(), re.M)
+    assert len(blocks) == 12 and all(
+        op == "get-tuple-element" and "iotml_kda_intra_fwd" in rest
+        for op, rest in blocks)
+    memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes - kept >= backward
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
         <= chip - 2 ** 30
